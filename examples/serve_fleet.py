@@ -71,6 +71,9 @@ SPEC = {"seed": 7,
                    "attention_dropout": 0.0},
         "engine": {"max_seqs": 4}}
 PROMPTS = [[1, 2, 3 + i] for i in range(6)]
+# these drills run N worker subprocesses, which cannot share one chip
+# (one process per chip; see ReplicaManager) — they are CPU drills
+CPU_ENV = {"JAX_PLATFORMS": "cpu"}
 
 
 def reference_outputs(max_new):
@@ -84,7 +87,8 @@ def reference_outputs(max_new):
 
 def start_fleet(run_dir, journal=False):
     reg = MetricsRegistry()
-    mgr = ReplicaManager(SPEC, replicas=2, registry=reg, run_dir=run_dir)
+    mgr = ReplicaManager(SPEC, replicas=2, registry=reg, run_dir=run_dir,
+                         env=CPU_ENV)
     mgr.start()
     router = Router(mgr.replicas, manager=mgr, registry=reg,
                     run_dir=run_dir if journal else None)
@@ -359,7 +363,7 @@ def trace_drill(run_dir):
                                         flush_every=1))
     mgr = ReplicaManager(SPEC, replicas=2, registry=reg,
                          run_dir=run_dir,
-                         env={"PTPU_METRICS_DIR": mdir})
+                         env={**CPU_ENV, "PTPU_METRICS_DIR": mdir})
     mgr.start()
     router = Router(mgr.replicas, manager=mgr, registry=reg,
                     run_dir=run_dir)       # journaled: WAL cross-check

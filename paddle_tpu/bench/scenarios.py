@@ -287,8 +287,9 @@ def _vision_train_payload(model, B: int, hw: int, steps: int, warmup: int,
     # vision rows keep tokens_per_sec null; img/s lives in extra and the
     # MFU (when a per-image FLOPs figure exists for the config) uses the
     # shared peak definition
-    mfu_val = (img_s * 3.0 * flops_per_img / peak_flops_per_sec()
-               if flops_per_img else None)
+    peak = peak_flops_per_sec()
+    mfu_val = (img_s * 3.0 * flops_per_img / peak
+               if flops_per_img and peak else None)
     return {
         "config": {"batch": B, "hw": hw, "steps": steps,
                    "warmup": warmup,

@@ -9,9 +9,8 @@ comparable by construction:
   them (the same doctrine as ``observability/aggregate.py``);
 - ``fingerprint`` + ``git_sha`` — where the number came from: device
   kind/count, jax/python versions, the commit that produced it;
-- ``device_kind`` / ``fallback_reason`` — the row is self-describing
-  about *what hardware actually ran* (a TPU-unreachable CPU fallback is
-  a field, not a stderr note);
+- ``device_kind`` — the row is self-describing about *what hardware
+  actually ran*;
 - ``step_time_ms`` p50/p99 plus the ``phases_ms`` breakdown
   (data / compute / readback / collective) — the axes perfdiff
   attributes a regression to;
@@ -156,7 +155,6 @@ def new_row(scenario: str, mode: str, *,
             compile_stats: Optional[Dict[str, Any]] = None,
             bytes_on_wire: int = 0,
             peak_hbm_bytes: Optional[int] = None,
-            fallback_reason: Optional[str] = None,
             roofline: Optional[Dict[str, Any]] = None,
             interconnect: Optional[Dict[str, Any]] = None,
             extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
@@ -202,7 +200,6 @@ def new_row(scenario: str, mode: str, *,
         "ts": time.time(),
         "git_sha": _git_sha(),
         "device_kind": fp["device_kind"],
-        "fallback_reason": fallback_reason,
         "fingerprint": fp,
         "config": dict(config or {}),
         "steps": len(times),
@@ -247,9 +244,6 @@ def validate_row(row: Any) -> List[str]:
         errors.append("missing/invalid ts")
     if not isinstance(row.get("device_kind"), str):
         errors.append("missing/invalid device_kind")
-    fr = row.get("fallback_reason")
-    if fr is not None and not isinstance(fr, str):
-        errors.append("fallback_reason must be null or a string")
     fp = row.get("fingerprint")
     if not isinstance(fp, dict):
         errors.append("missing fingerprint")

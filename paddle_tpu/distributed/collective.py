@@ -104,11 +104,8 @@ def _observed(fn):
 
 
 def bound_axis_size(name: str):
-    """Size of a bound (shard_map/pmap) axis — ``lax.axis_size`` on
-    jax>=0.5, the constant-folded ``psum(1, axis)`` idiom before that."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
+    """Size of a bound (shard_map/pmap) axis."""
+    return lax.axis_size(name)
 
 
 def _in_axis(group: Optional[str]) -> bool:

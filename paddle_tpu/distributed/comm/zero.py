@@ -166,6 +166,10 @@ class ShardedOptimizer:
         self._grad_op = grad_op
         self._bound: Optional[Tuple[Any, str, int]] = None
         self._zstate = None     # dygraph-style step() state
+        # leaf index -> the NamedSharding each parameter was placed with
+        # when init() saw it (GSPMD mode): where the updated parameters
+        # must come back to
+        self._placed: Dict[int, NamedSharding] = {}
 
     # -- delegation ---------------------------------------------------------
     @property
@@ -241,50 +245,69 @@ class ShardedOptimizer:
 
     def _pack_flat(self, leaves, meta: _PackMeta,
                    fill_missing: bool = False) -> jnp.ndarray:
+        present = [leaves[info.index] for info in meta.packed
+                   if leaves[info.index] is not None]
+        # concrete leaves (init time) pack on the HOST: leaves of a
+        # TP-placed model carry mixed shardings, which an eager
+        # concatenate miscompiles on this stack (observed: replicated LN
+        # weights summed across devices), and a device-side pack would
+        # build the whole fp32 master on the default device.  Traced
+        # packs (the jitted step) are resharded by the partitioner.
+        xp = (np if present and not any(isinstance(l, jax.core.Tracer)
+                                        for l in present) else jnp)
         parts = []
         for info in meta.packed:
             leaf = leaves[info.index]
             if leaf is None:
                 enforce(fill_missing,
                         f"missing leaf for {info.path} in pack")
-                parts.append(jnp.zeros((info.size,), jnp.float32))
+                parts.append(xp.zeros((info.size,), xp.float32))
             else:
-                arr = jnp.asarray(leaf)
-                if (isinstance(arr, jax.Array)
-                        and not isinstance(arr, jax.core.Tracer)
-                        and len(getattr(arr, "devices", lambda: [])()) > 1):
-                    # concrete leaves of a TP-placed model carry MIXED
-                    # shardings; eagerly concatenating those miscompiles
-                    # on this stack (observed: replicated LN weights
-                    # summed across devices).  Round-trip through host —
-                    # init-time only; traced packs (the jitted step) are
-                    # resharded correctly by the partitioner.
-                    arr = jnp.asarray(np.asarray(arr))
-                parts.append(jnp.ravel(arr).astype(jnp.float32))
+                parts.append(xp.ravel(xp.asarray(leaf)).astype(xp.float32))
         pad = meta.padded - meta.total
         if pad or not parts:
-            parts.append(jnp.zeros((meta.padded - meta.total,),
-                                   jnp.float32))
-        return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+            parts.append(xp.zeros((pad,), xp.float32))
+        return xp.concatenate(parts) if len(parts) > 1 else parts[0]
 
-    def _unpack(self, flat, meta: _PackMeta, params):
+    def _unpack(self, flat, meta: _PackMeta, params, placed=None):
+        """Flat master -> parameter leaves.  ``placed`` (leaf index ->
+        NamedSharding) pins each leaf back to its parameter's own layout:
+        left to propagation, a leaf sliced out of the axis-sharded flat
+        stays sharded like the flat, so the step returns every parameter
+        in a layout other than the one it arrived in — no aliasing with
+        the donated input, a second compile on the next call, and the
+        parameter all-gather that ZeRO-1 ends a step with never emitted."""
         leaves = list(meta.treedef.flatten_up_to(params))
         for info in meta.packed:
             seg = lax.slice(flat, (info.offset,),
                             (info.offset + info.size,))
-            leaves[info.index] = seg.reshape(info.shape).astype(info.dtype)
+            leaf = seg.reshape(info.shape).astype(info.dtype)
+            if placed and info.index in placed:
+                leaf = lax.with_sharding_constraint(leaf,
+                                                    placed[info.index])
+            leaves[info.index] = leaf
         return jax.tree_util.tree_unflatten(meta.treedef, leaves)
 
-    def _coeff_flat(self, params, meta: _PackMeta, tree) -> jnp.ndarray:
-        """Static per-leaf coefficient tree (decay / L1) → flat np
-        vector matching the pack layout (zeros in the padding)."""
+    def _coeff_flat(self, params, meta: _PackMeta, tree):
+        """Static per-leaf coefficient tree (decay / L1) in the pack
+        layout: a scalar when every leaf shares one coefficient (the
+        padding holds zeros, which any coefficient leaves at zero), else
+        a ``(padded,)`` vector built on device from one broadcast per
+        run of equal coefficients — never a host constant the size of
+        the model baked into the program."""
         leaves = meta.treedef.flatten_up_to(tree)
-        out = np.zeros((meta.padded,), np.float32)
+        runs: List[List[float]] = []        # [coefficient, length]
         for info in meta.packed:
             c = float(leaves[info.index])
-            if c:
-                out[info.offset:info.offset + info.size] = c
-        return jnp.asarray(out)
+            if runs and runs[-1][0] == c:
+                runs[-1][1] += info.size
+            else:
+                runs.append([c, info.size])
+        if len(runs) <= 1:
+            return jnp.float32(runs[0][0] if runs else 0.0)
+        runs.append([0.0, meta.padded - meta.total])
+        return jnp.concatenate([jnp.full((int(n),), c, jnp.float32)
+                                for c, n in runs if n])
 
     # -- functional contract ------------------------------------------------
     def init(self, params) -> Dict[str, Any]:
@@ -301,15 +324,20 @@ class ShardedOptimizer:
             idx = lax.axis_index(axis)
             flat = lax.dynamic_slice(flat, (idx * meta.chunk,),
                                      (meta.chunk,))
-        state = {"step": jnp.zeros((), jnp.int32), "flat": flat,
-                 "slots": self._inner._init_slot(flat)}
-        if (not _in_axis(axis) and mesh is not None and n > 1
-                and axis in mesh.axis_names):
-            shard = NamedSharding(mesh, P(axis))
-            state["flat"] = jax.device_put(state["flat"], shard)
-            state["slots"] = jax.tree_util.tree_map(
-                lambda s: jax.device_put(s, shard), state["slots"])
-        return state
+        elif mesh is not None and n > 1 and axis in mesh.axis_names:
+            self._placed = {
+                info.index: leaves[info.index].sharding
+                for info in meta.packed
+                if isinstance(getattr(leaves[info.index], "sharding", None),
+                              NamedSharding)
+                and leaves[info.index].sharding.mesh == mesh}
+            # place the master BEFORE deriving the slots from it: slots
+            # built from an unplaced master are whole copies on the
+            # default device (3x the model in fp32 on device 0)
+            flat = jax.device_put(flat, NamedSharding(mesh, P(axis)))
+        flat = jnp.asarray(flat)
+        return {"step": jnp.zeros((), jnp.int32), "flat": flat,
+                "slots": self._inner._init_slot(flat)}
 
     def relayout_state(self, state, params):
         """Re-pack a (host or globally-gathered) ZeRO-1 state built for a
@@ -392,6 +420,13 @@ class ShardedOptimizer:
         l1_flat = (self._coeff_flat(params, meta, inner._l1_tree(params))
                    if getattr(inner, "_l1", 0.0) else None)
 
+        def my_chunk(coeff):
+            # this rank's slice of a per-element coefficient vector
+            if coeff is None or coeff.ndim == 0:
+                return coeff
+            return lax.dynamic_slice(
+                coeff, (lax.axis_index(axis) * meta.chunk,), (meta.chunk,))
+
         if sharded:
             if self._comm is not None and self._comm.dtype == "int8":
                 _account(meta.padded, self._comm, rounds=1)
@@ -403,11 +438,7 @@ class ShardedOptimizer:
                                            scatter_dimension=0, tiled=True)
                 if self._grad_op == "avg":
                     g_shard = g_shard / n
-            idx = lax.axis_index(axis)
-            off = idx * meta.chunk
-            wd = lax.dynamic_slice(wd_flat, (off,), (meta.chunk,))
-            l1 = (lax.dynamic_slice(l1_flat, (off,), (meta.chunk,))
-                  if l1_flat is not None else None)
+            wd, l1 = my_chunk(wd_flat), my_chunk(l1_flat)
         else:
             g_shard, wd, l1 = flat_g, wd_flat, l1_flat
             if mesh is not None and n > 1 and axis in mesh.axis_names:
@@ -438,7 +469,8 @@ class ShardedOptimizer:
             if mesh is not None and n > 1 and axis in mesh.axis_names:
                 full = lax.with_sharding_constraint(
                     full, NamedSharding(mesh, P(axis)))
-        new_params = self._unpack(full, meta, params)
+        new_params = self._unpack(full, meta, params,
+                                  placed=None if sharded else self._placed)
         return new_params, {"step": step, "flat": new_shard,
                             "slots": new_slots}
 

@@ -43,19 +43,6 @@ def _env(name: str, *alts: str, default: Optional[str] = None
     return default
 
 
-def _distributed_initialized() -> bool:
-    """jax.distributed.is_initialized() with a fallback for jax<0.6,
-    which only exposes the coordination client via internal state."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None
-    except (ImportError, AttributeError):
-        return False
-
-
 def init_from_env() -> None:
     """Bring up multi-host JAX from launcher env vars.
 
@@ -66,7 +53,7 @@ def init_from_env() -> None:
     With none set on a TPU pod, jax.distributed.initialize() lets the
     runtime discover everything (the TPU-native path).
     """
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return  # idempotent: the launcher already initialized this process
     coord = _env("PADDLE_MASTER", "JAX_COORDINATOR_ADDRESS")
     nproc = _env("PADDLE_TRAINERS_NUM", "JAX_NUM_PROCESSES")

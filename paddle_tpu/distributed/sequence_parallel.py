@@ -38,10 +38,7 @@ from .collective import bound_axis_size
 
 __all__ = ["ring_attention", "ring_attention_sharded", "shard_map"]
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax<0.6 only exposes the experimental spelling
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 _NEG_INF = -1e30
 

@@ -24,18 +24,7 @@ def force_virtual_cpu_mesh(n: int) -> None:
     """
     def _update():
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", n)
-        except AttributeError:
-            # older jax has no in-process option for the CPU device count;
-            # the XLA flag is read at (re)initialization, so setting it
-            # before the first backend touch is equivalent
-            import os
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    f"{flags} --xla_force_host_platform_device_count={n}"
-                ).strip()
+        jax.config.update("jax_num_cpu_devices", n)
 
     try:
         # must run before the first backend touch — even len(jax.devices())

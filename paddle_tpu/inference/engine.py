@@ -52,7 +52,11 @@ same robustness treatment the training path earned:
   ``PTPU_SERVE_NAN_GUARD``) bisects the batch, evicts the culprit(s)
   with ``reason="poisoned"`` plus a durable record under
   ``<run_dir>/serve_quarantine/``, and replays the step so every other
-  request completes token-exact (decode rows are independent);
+  request completes token-exact (decode rows are independent).  The
+  boundary only covers a step program that has run to completion at
+  least once: an error from a program's first run (a lowering or
+  compile failure, a missing device) is the engine's fault, not a
+  request's, and propagates out of ``step()``;
 - **supervision + graceful drain** — ``step()`` arms the PR 2 watchdog
   (a hung step gets a stack dump; the engine rebuilds its jitted fns
   and re-admits the running set via recompute-prefill), and
@@ -209,9 +213,11 @@ class ServingEngine:
                  replica_id: Optional[int] = None,
                  step_fault: Optional[Callable] = None):
         from ..distributed.topology import get_mesh
+        from ..observability.compilecache import enable_persistent_cache
         enforce(get_mesh() is None,
                 "ServingEngine is single-host (the paged path is opaque "
                 "to GSPMD) — run it outside fleet meshes")
+        enable_persistent_cache()
         cfg = model.config
         self.model = model
         model.eval()
@@ -250,6 +256,9 @@ class ServingEngine:
         self.status_server = None
         self._decode_tracked = None
         self._prefill_tracked: Dict[int, Callable] = {}
+        # step programs ("decode", ("prefill", bucket)) that have run to
+        # completion once — only those can poison a request
+        self._proven: set = set()
         self._cb_queue: Optional[queue.Queue] = None
         self._cb_thread: Optional[threading.Thread] = None
         # request-lifecycle guard (ISSUE 15)
@@ -549,6 +558,7 @@ class ServingEngine:
         self._jit_step = None
         self._decode_tracked = None
         self._prefill_tracked = {}
+        self._proven.clear()
         victims = self.sched.preempt_all()
         self.watchdog_restarts += 1
         reg = self._reg()
@@ -613,9 +623,9 @@ class ServingEngine:
         nxt, logits, new_caches = self._prefill_fn(bucket)(
             self._params, jnp.asarray(ids), jnp.zeros((1,), jnp.int32),
             jnp.asarray(L - 1, jnp.int32), caches, key)
-        nxt_np = np.asarray(nxt)
-        logits_np = self._apply_fault("prefill", [seq],
-                                      np.asarray(logits))
+        nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
+        self._proven.add(("prefill", bucket))
+        logits_np = self._apply_fault("prefill", [seq], logits_np)
         return nxt_np, logits_np, new_caches
 
     def _apply_decode(self, seqs: List[SequenceState], key):
@@ -642,8 +652,9 @@ class ServingEngine:
         nxt, logits, new_caches = self._decode_fn()(
             self._params, jnp.asarray(ids), jnp.asarray(positions),
             jnp.asarray(0, jnp.int32), caches, key)
-        nxt_np = np.asarray(nxt)
-        logits_np = self._apply_fault("decode", seqs, np.asarray(logits))
+        nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
+        self._proven.add("decode")
+        logits_np = self._apply_fault("decode", seqs, logits_np)
         return nxt_np, logits_np, new_caches
 
     def _run_prefill(self, plan: StepPlan) -> List[Dict[str, Any]]:
@@ -656,6 +667,8 @@ class ServingEngine:
         except StepTimeout:
             raise                      # the watchdog owns this one
         except Exception as e:
+            if ("prefill", plan.bucket) not in self._proven:
+                raise                  # never ran: not a request's fault
             self._quarantine_step("prefill", [seq], e, key)
             return []
         self.cache.update_pages(new_caches)
@@ -697,6 +710,8 @@ class ServingEngine:
         except StepTimeout:
             raise
         except Exception as e:
+            if "decode" not in self._proven:
+                raise                  # never ran: not a request's fault
             survivors = self._quarantine_step("decode", seqs, e, key)
             if not survivors:
                 return []

@@ -250,6 +250,15 @@ class ReplicaManager:
     identically, so greedy decode is token-exact across the fleet and
     failover is provable against a single-engine reference.
 
+    One process per chip: a TPU belongs to the first process that
+    touches it, and workers have no per-replica device pinning, so N
+    subprocess workers cannot share the chips of one host — each would
+    claim all of them and all but one fail at start-up.  On an
+    accelerator host run the replicas in ONE process
+    (:class:`LocalReplicaManager` over :class:`LocalReplica`); this
+    manager is for CPU drills (pass ``env={"JAX_PLATFORMS": "cpu"}``)
+    and for one worker per machine.
+
     State machine per slot (mirrored into ``fleet.replicas[state=...]``
     gauges by :meth:`poll_states`):
 
@@ -300,11 +309,11 @@ class ReplicaManager:
                "--model", json.dumps(self.model_spec)]
         if self.run_dir:
             cmd += ["--run-dir", self.run_dir]
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        env.update(self.env)
-        proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                                stderr=subprocess.DEVNULL, text=True,
+        # workers inherit the parent's platform and stderr: a fleet
+        # started on a chip machine must not serve from CPUs silently,
+        # and a dying worker must be able to say why
+        env = {**os.environ, **self.env}
+        proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
                                 env=env)
         # handshake: the worker prints ONE line once its server is bound
         # (ephemeral ports make this the only way to learn the port)

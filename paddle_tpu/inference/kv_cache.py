@@ -18,19 +18,27 @@ Three layers in this module:
   ids: ``alloc / free / defrag`` plus occupancy accounting.  Pure python,
   no device traffic; the scheduler calls it every step.
 - :class:`PagedLayerCache` — the **device-side** view one decoder layer
-  sees inside a jitted step: flat ``(num_slots, heads, head_dim)`` key
-  and value page arrays plus the batch's ``block_tables`` /
-  ``seq_lens`` / ``slot_mapping`` int32 arrays.  It is a NamedTuple, so
-  it flows through ``jax.jit`` as a pytree with fixed structure — the
-  decode step never retraces on cache state.
+  sees inside a jitted step: ``(num_blocks, heads, block_size,
+  head_dim)`` key and value page arrays plus the batch's
+  ``block_tables`` / ``seq_lens`` / ``slot_mapping`` int32 arrays.  It
+  is a registered pytree, so it flows through ``jax.jit`` with fixed
+  structure — the decode step never retraces on cache state.
 - :class:`PagedKVCache` — the whole-model container: per-layer page
   arrays + the allocator + per-sequence tables, with the array-building
   helpers the engine uses to assemble fixed-shape step inputs.
 
-Slots: block ``b`` owns flat rows ``[b*block_size, (b+1)*block_size)``
-of the page arrays; ``slot = block_table[pos // bs] * bs + pos % bs``.
-``SLOT_PAD`` (== ``num_slots``, deliberately out of bounds) marks padded
-positions — page writes use ``mode="drop"`` so padding never lands.
+Page layout: ``pages[block, head, offset, :]`` — ``(block_size,
+head_dim)`` are the two minor dims because that is the tile the TPU
+decode kernel DMAs per block (Mosaic wants a block's minor dims to be
+the array's, or multiples of (8, 128)).  The layout is private to this
+module and ``paged_attention``: models write through
+:meth:`PagedLayerCache.write`.
+
+Slots: a flat slot id addresses one token row, ``slot = block_table[pos
+// bs] * bs + pos % bs``, i.e. ``pages[slot // bs, :, slot % bs]``.
+``slot_pad`` (== ``num_slots``, whose block id is out of bounds) marks
+padded positions — page writes use ``mode="drop"`` so padding never
+lands.
 """
 from __future__ import annotations
 
@@ -167,15 +175,14 @@ class BlockAllocator:
 class PagedLayerCache:
     """One decoder layer's jit-visible paged-cache view.
 
-    ``k_pages`` / ``v_pages``: ``(num_slots + 1, heads, head_dim)`` flat
-    page arrays (the +1 row never holds data — the pad-slot sentinel
-    lands out of bounds and is dropped, reads never touch it).
+    ``k_pages`` / ``v_pages``: ``(num_blocks, heads, block_size,
+    head_dim)`` page arrays.
     ``block_tables``: ``(batch, max_blocks_per_seq)`` int32 block ids
     (padded rows/entries are 0 — masked out by ``seq_lens``).
     ``seq_lens``: ``(batch,)`` int32 context length *including* the
     tokens written by this call (0 = padding row).
     ``slot_mapping``: ``(batch, chunk)`` int32 flat write slot per new
-    token; ``num_slots`` (out of bounds) marks padding.
+    token; ``num_slots`` (its block is out of bounds) marks padding.
 
     Registered as a pytree with ``block_size`` as static aux data, so a
     jitted step sees the arrays as traced leaves but the page geometry
@@ -198,6 +205,18 @@ class PagedLayerCache:
         fields = {s: getattr(self, s) for s in self.__slots__}
         fields.update(kw)
         return PagedLayerCache(**fields)
+
+    def write(self, new_k, new_v) -> "PagedLayerCache":
+        """Scatter this call's ``(batch * chunk, heads, head_dim)`` keys
+        and values into the pages at ``slot_mapping``; padded slots are
+        out of bounds and dropped."""
+        slots = self.slot_mapping.reshape(-1)
+        blk, off = slots // self.block_size, slots % self.block_size
+        k_pages = self.k_pages.at[blk, :, off].set(
+            new_k.astype(self.k_pages.dtype), mode="drop")
+        v_pages = self.v_pages.at[blk, :, off].set(
+            new_v.astype(self.v_pages.dtype), mode="drop")
+        return self.replace(k_pages=k_pages, v_pages=v_pages)
 
 
 def _plc_flatten(c: PagedLayerCache):
@@ -238,7 +257,7 @@ class PagedKVCache:
         self.dtype = jnp.dtype(dtype)
         self.allocator = BlockAllocator(self.num_blocks, block_size)
         self._tables: Dict[object, List[int]] = {}
-        shape = (self.num_slots + 1, self.num_heads, self.head_dim)
+        shape = (self.num_blocks, self.num_heads, block_size, self.head_dim)
         self._pages: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
             (jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype))
             for _ in range(self.num_layers)]
@@ -349,11 +368,7 @@ class PagedKVCache:
         perm = self.allocator.defrag(self._tables)
         if perm is None:
             return False
-        slot_perm = (perm[:, None] * self.block_size
-                     + np.arange(self.block_size)[None, :]).reshape(-1)
-        # the sentinel row stays the sentinel row
-        slot_perm = np.concatenate([slot_perm, [self.num_slots]])
-        idx = jnp.asarray(slot_perm)
+        idx = jnp.asarray(perm)
         self._pages = [(jnp.take(k, idx, axis=0), jnp.take(v, idx, axis=0))
                        for (k, v) in self._pages]
         return True

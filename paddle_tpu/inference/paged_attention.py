@@ -15,15 +15,18 @@ Attention* (block-tabled KV, ragged decode batches).
 Two implementations behind one routing entry point:
 
 - :func:`paged_attention_pallas` — the kernel, built on the same Pallas
-  surface as ``ops/flash_attention.py`` (shared ``_dot`` precision rule,
-  lane-broadcast statistics, online-softmax recurrence).  Grid is
-  ``(batch, heads, max_blocks)`` with the block table and sequence
-  lengths as **scalar-prefetch** operands, so the k/v BlockSpec index
-  maps dereference the table and Mosaic DMAs exactly one KV block per
-  grid step — per-step VMEM residency is O(block_size · head_dim)
-  regardless of pool size, and a block past ``seq_lens[b]`` is skipped
-  (its flash state update is predicated off; the redundant page-0 DMA it
-  still costs is the ragged tax also paid by the upstream TPU kernel).
+  surface as ``ops/flash_attention.py`` (lane-broadcast statistics,
+  online-softmax recurrence).  Pages are ``(num_blocks, heads,
+  block_size, head_dim)`` (``inference/kv_cache.py``); the grid is
+  ``(batch, max_blocks)`` with the block table and sequence lengths as
+  **scalar-prefetch** operands, so the k/v BlockSpec index maps
+  dereference the table and Mosaic DMAs exactly one KV block — all
+  heads of it — per grid step.  Per-step VMEM residency is
+  O(heads · block_size · head_dim) regardless of pool size, and a block
+  past ``seq_lens[b]`` is skipped (its flash state update is predicated
+  off; the redundant page-0 DMA it still costs is the ragged tax also
+  paid by the upstream TPU kernel).  The one-row-per-head products run
+  on the VPU in f32.
 - :func:`paged_attention_reference` — a pure ``jax.numpy``/``lax``
   gather-softmax with identical semantics.  It is the default off-TPU
   (interpret-mode Pallas is orders slower than XLA CPU), which is what
@@ -32,7 +35,9 @@ Two implementations behind one routing entry point:
 
 Routing: :func:`paged_attention` picks the kernel on a TPU backend, the
 reference elsewhere; ``PTPU_PAGED_KERNEL=pallas|reference`` forces one
-(the CPU kernel test forces ``pallas`` to run it under interpret).
+(``chip_smoke.py`` runs one engine on each and compares their logits).
+The kernel of a compiled decode step is the ``paged_decode``
+``tpu_custom_call`` in its HLO.
 
 Decode is memory-bound, so the win is never FLOPs — it is that the
 gather never materializes a per-sequence contiguous KV copy in HBM.
@@ -49,7 +54,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..framework.errors import enforce
-from ..ops.flash_attention import _dot, _interpret, _LANES, _NEG_INF
+from ..ops.flash_attention import _interpret, _LANES, _NEG_INF
 
 __all__ = ["paged_attention", "paged_attention_pallas",
            "paged_attention_reference"]
@@ -60,17 +65,14 @@ PAGED_KERNEL_ENV = "PTPU_PAGED_KERNEL"
 def _check_shapes(q, k_pages, v_pages, block_tables, seq_lens,
                   block_size: int):
     b, h, d = q.shape
-    enforce(k_pages.ndim == 3 and k_pages.shape == v_pages.shape,
+    enforce(k_pages.ndim == 4 and k_pages.shape == v_pages.shape,
             f"page shape mismatch: k={k_pages.shape} v={v_pages.shape}")
-    enforce(k_pages.shape[1] == h and k_pages.shape[2] == d,
-            f"pages {k_pages.shape} disagree with q {q.shape}")
+    enforce(k_pages.shape[1:] == (h, block_size, d),
+            f"pages {k_pages.shape} disagree with q {q.shape} at "
+            f"block_size {block_size}")
     enforce(block_tables.shape[0] == b and seq_lens.shape == (b,),
             f"tables {block_tables.shape} / lens {seq_lens.shape} "
             f"disagree with batch {b}")
-    num_slots = k_pages.shape[0] - 1    # trailing sentinel row
-    enforce(num_slots % block_size == 0,
-            f"{num_slots} slots not a multiple of block_size "
-            f"{block_size}")
 
 
 # ---------------------------------------------------------------------------
@@ -80,8 +82,9 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                               block_size: int,
                               scale: Optional[float] = None):
     """Pure-jax ragged paged attention over ``(batch, heads, head_dim)``
-    single-token queries.  A row with ``seq_lens[b] == 0`` (a padding
-    row of the decode batch) returns zeros."""
+    single-token queries against ``(num_blocks, heads, block_size,
+    head_dim)`` pages.  A row with ``seq_lens[b] == 0`` (a padding row of
+    the decode batch) returns zeros."""
     _check_shapes(q, k_pages, v_pages, block_tables, seq_lens, block_size)
     b, h, d = q.shape
     if scale is None:
@@ -89,36 +92,41 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
     max_ctx = block_tables.shape[1] * block_size
 
     def per_seq(qb, table, ln):
-        # (T,) block ids -> (T*bs,) flat slots -> gathered (L, h, d)
-        slots = (table[:, None] * block_size
-                 + jnp.arange(block_size)[None, :]).reshape(-1)
-        k = jnp.take(k_pages, slots, axis=0)       # (L, h, d)
-        v = jnp.take(v_pages, slots, axis=0)
-        s = jnp.einsum("hd,lhd->hl", qb.astype(jnp.float32),
-                       k.astype(jnp.float32)) * scale
+        # (T,) block ids -> gathered (T, h, bs, d) -> (h, T*bs, d)
+        k = jnp.take(k_pages, table, axis=0).transpose(1, 0, 2, 3)
+        v = jnp.take(v_pages, table, axis=0).transpose(1, 0, 2, 3)
+        k = k.reshape(h, max_ctx, d)
+        v = v.reshape(h, max_ctx, d)
+        # an oracle computes in f32 for real: on a TPU an f32 einsum at
+        # the default precision rounds its operands (the probabilities
+        # below among them) to bf16 first
+        s = jnp.einsum("hd,hld->hl", qb.astype(jnp.float32),
+                       k.astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST) * scale
         valid = (jnp.arange(max_ctx) < ln)[None, :]
         s = jnp.where(valid, s, _NEG_INF)
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.where(valid, jnp.exp(s - m), 0.0)
         l = jnp.sum(p, axis=1, keepdims=True)
-        out = jnp.einsum("hl,lhd->hd", p, v.astype(jnp.float32))
+        out = jnp.einsum("hl,hld->hd", p, v.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
         return (out / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
     return jax.vmap(per_seq)(q, block_tables, seq_lens)
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: one KV block per grid step, table-driven DMA
+# Pallas kernel: one KV block (all heads) per grid step, table-driven DMA
 # ---------------------------------------------------------------------------
 def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, scale, block_size):
-    # grid (batch, heads, max_blocks): the index maps already steered this
-    # step's k/v refs to block_tables[b, t] via scalar prefetch; the flash
+    # grid (batch, max_blocks): the index maps already steered this step's
+    # k/v refs to block_tables[b, t] via scalar prefetch; the flash
     # (m, l, acc) state lives in VMEM scratch across the innermost t steps
-    # (same recurrence as ops/flash_attention._fwd_kernel).
+    # (same recurrence as ops/flash_attention._fwd_kernel), one row per head.
     b = pl.program_id(0)
-    t = pl.program_id(2)
-    num_t = pl.num_programs(2)
+    t = pl.program_id(1)
+    num_t = pl.num_programs(1)
     kv_len = lens_ref[b]
 
     @pl.when(t == 0)
@@ -129,80 +137,74 @@ def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(t * block_size < kv_len)
     def _step():
-        q = q_ref[0, 0][None, :]                       # (1, d)
-        k = k_ref[0, :, 0, :]                          # (bs, d)
-        v = v_ref[0, :, 0, :]
-        s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale   # (1, bs)
+        # one query row per head: these are matrix-vector products, so they
+        # run on the VPU in f32 (exact products of the stored values, f32
+        # accumulation) instead of padding a one-row operand onto the MXU
+        q = q_ref[0].astype(jnp.float32)               # (h, d)
+        k = k_ref[0].astype(jnp.float32)               # (h, bs, d)
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.sum(q[:, None, :] * k, axis=2) * scale     # (h, bs)
         cols = t * block_size + lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
+            jnp.int32, s.shape, 1)
         s = jnp.where(cols < kv_len, s, _NEG_INF)
-        m_prev = m_scr[...]                            # (1, _LANES)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
-        p = jnp.where(cols < kv_len,
-                      jnp.exp(s - m_new[:, :1]), 0.0)
+        m_prev = m_scr[...]                            # (h, _LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(cols < kv_len, jnp.exp(s - m_new[:, :1]), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1)[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _dot(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * alpha[:, :1]
+                        + jnp.sum(p[:, :, None] * v, axis=1))
         m_scr[...] = m_new
 
     @pl.when(t == num_t - 1)
     def _finalize():
         # kv_len == 0 (a padding row) never entered _step: l stays 0 and
         # the guarded divide returns zeros, matching the reference
-        o_ref[0, 0] = (acc_scr[...][0]
-                       / jnp.maximum(l_scr[...][0, :1], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...][:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
                            block_size: int,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
-    """The table-driven Pallas kernel (interpret-mode off TPU)."""
+    """The table-driven Pallas kernel (interpret-mode off TPU).
+
+    Blocks are ``(1, heads, block_size, head_dim)`` page tiles and
+    ``(1, heads, head_dim)`` q/out tiles: the two minor dims of every
+    block equal the array's, which is what Mosaic's tiling rule asks of a
+    block narrower than (8, 128)."""
     from jax.experimental.pallas import tpu as pltpu
     _check_shapes(q, k_pages, v_pages, block_tables, seq_lens, block_size)
     b, h, d = q.shape
     max_blocks = block_tables.shape[1]
     if scale is None:
         scale = d ** -0.5
-    # pages reshaped to (num_blocks, block_size, h, d) so one grid step's
-    # BlockSpec is exactly one block of one head; the sentinel row is
-    # sliced off (reads never need it)
-    num_slots = k_pages.shape[0] - 1
-    kp = k_pages[:num_slots].reshape(-1, block_size, h, d)
-    vp = v_pages[:num_slots].reshape(-1, block_size, h, d)
+    page_spec = pl.BlockSpec((1, h, block_size, d),
+                             lambda bi, ti, lens, tbl:
+                             (tbl[bi, ti], 0, 0, 0))
+    row_spec = pl.BlockSpec((1, h, d), lambda bi, ti, lens, tbl: (bi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # seq_lens, block_tables
-        grid=(b, h, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bi, hi, ti, lens, tbl:
-                         (bi, hi, 0)),                       # q
-            pl.BlockSpec((1, block_size, 1, d),
-                         lambda bi, hi, ti, lens, tbl:
-                         (tbl[bi, ti], 0, hi, 0)),           # k block
-            pl.BlockSpec((1, block_size, 1, d),
-                         lambda bi, hi, ti, lens, tbl:
-                         (tbl[bi, ti], 0, hi, 0)),           # v block
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bi, hi, ti, lens, tbl:
-                               (bi, hi, 0)),
+        grid=(b, max_blocks),
+        in_specs=[row_spec, page_spec, page_spec],
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, _LANES), jnp.float32),   # m
-            pltpu.VMEM((1, _LANES), jnp.float32),   # l
-            pltpu.VMEM((1, d), jnp.float32),        # acc
+            pltpu.VMEM((h, _LANES), jnp.float32),   # m
+            pltpu.VMEM((h, _LANES), jnp.float32),   # l
+            pltpu.VMEM((h, d), jnp.float32),        # acc
         ],
     )
     kernel = functools.partial(_paged_decode_kernel, scale=float(scale),
                                block_size=int(block_size))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        name="paged_decode",
         interpret=_interpret() if interpret is None else interpret,
     )(jnp.asarray(seq_lens, jnp.int32),
-      jnp.asarray(block_tables, jnp.int32), q, kp, vp)
-    return out
+      jnp.asarray(block_tables, jnp.int32), q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------

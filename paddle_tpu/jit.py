@@ -73,11 +73,10 @@ def to_static(function=None, input_spec=None, **kw):
     function (the reference's debug-eagerly workflow)."""
     def deco(fn):
         from .observability.compilation import track_jit
-        from .observability.compilecache import (
-            maybe_enable_persistent_cache)
-        # opt-in disk cache (PTPU_COMPILE_CACHE_DIR) so a warm process
-        # re-loads instead of re-compiling these programs (ROADMAP 5a)
-        maybe_enable_persistent_cache()
+        from .observability.compilecache import enable_persistent_cache
+        # disk cache so a warm process re-loads instead of re-compiling
+        # these programs
+        enable_persistent_cache()
         # every to_static callsite reports compiles/retraces to the run
         # doctor under its own name (ISSUE 4)
         jitted = track_jit(jax.jit(fn),

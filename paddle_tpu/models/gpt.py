@@ -222,7 +222,13 @@ class GPTAttention(Layer):
                 q = shard_constraint(q, "dp", ("mp", "sp"), None, None)
                 k = shard_constraint(k, "dp", ("mp", "sp"), None, None)
                 v = shard_constraint(v, "dp", ("mp", "sp"), None, None)
-            if c.use_pallas_attention and cache is None:
+            if c.use_pallas_attention and get_mesh() is None:
+                # Mosaic refuses to lower a pallas_call inside a GSPMD
+                # program ("cannot be automatically partitioned"), which
+                # the interpret-mode CPU mesh hides — so, like the decode
+                # branch above and functional.py's routing, a mesh takes
+                # the partitionable SDPA route until the kernel is
+                # wrapped per shard (ROADMAP C6)
                 from ..ops import flash_attention
                 out = flash_attention(
                     q, k, v, causal=True, dropout_p=self.attn_dropout_p,
@@ -241,9 +247,8 @@ class GPTAttention(Layer):
     def _paged_cache_forward(self, q, k, v, cache, b, s):
         """Paged-KV attention (ISSUE 6 serving path).
 
-        Writes this call's k/v into the shared page arrays at
-        ``cache.slot_mapping`` (padding slots are out of bounds and
-        dropped), then attends:
+        Writes this call's k/v into the shared page arrays
+        (``PagedLayerCache.write``), then attends:
 
         - ``s == 1`` (batched decode): ragged paged attention over the
           block tables up to ``seq_lens`` — each row sees its own
@@ -255,17 +260,11 @@ class GPTAttention(Layer):
         """
         from ..inference.paged_attention import paged_attention
         c = self.config
-        new_k = k.transpose(0, 2, 1, 3).reshape(b * s, c.num_heads,
-                                                c.head_dim)
-        new_v = v.transpose(0, 2, 1, 3).reshape(b * s, c.num_heads,
-                                                c.head_dim)
-        slots = cache.slot_mapping.reshape(-1)
-        k_pages = cache.k_pages.at[slots].set(
-            new_k.astype(cache.k_pages.dtype), mode="drop")
-        v_pages = cache.v_pages.at[slots].set(
-            new_v.astype(cache.v_pages.dtype), mode="drop")
+        cache = cache.write(
+            k.transpose(0, 2, 1, 3).reshape(b * s, c.num_heads, c.head_dim),
+            v.transpose(0, 2, 1, 3).reshape(b * s, c.num_heads, c.head_dim))
         if s == 1:
-            o = paged_attention(q[:, :, 0, :], k_pages, v_pages,
+            o = paged_attention(q[:, :, 0, :], cache.k_pages, cache.v_pages,
                                 cache.block_tables, cache.seq_lens,
                                 block_size=cache.block_size)
             out = o.astype(q.dtype).reshape(b, 1, c.hidden_size)
@@ -279,7 +278,7 @@ class GPTAttention(Layer):
                 q, k, v, attn_mask=bias[:, None].astype(q.dtype),
                 is_causal=False, dropout_p=0.0, training=False)
             out = out.transpose(0, 2, 1, 3).reshape(b, s, c.hidden_size)
-        return out, cache.replace(k_pages=k_pages, v_pages=v_pages)
+        return out, cache
 
     def fused_paged_forward(self, x, ln, cache):
         """Fused-epilogue serving step (ISSUE 7): LN→QKV as one fused
@@ -523,8 +522,8 @@ class GPTForCausalLM(Layer):
                 and _lce_chunk(hidden.shape[1]) is not None):
             # memory-efficient path: loss from (hidden, table) directly —
             # the full [B, S, V] logits are never built (the 16GB-chip
-            # budget that makes the full-vocab 1.3B trainable at all, see
-            # BASELINE.md), so the logits slot of the return is None; set
+            # budget that makes the full-vocab 1.3B trainable at all),
+            # so the logits slot of the return is None; set
             # fused_lm_loss=False to get (loss, logits)
             loss = linear_softmax_cross_entropy(
                 hidden, table, shifted,

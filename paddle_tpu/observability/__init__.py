@@ -65,8 +65,9 @@ flow only when a sink is attached — by the run supervisor under its
 Env knobs: ``PTPU_METRICS_DIR`` (auto-attach a JSONL writer),
 ``PTPU_METRICS_INTERVAL`` (sink flush/summary period, default 30s),
 ``PTPU_TRACE_BUFFER`` (span buffer bound, default 65536),
-``PTPU_MEM_SAMPLE_EVERY`` (HBM watermark cadence, default 16 steps),
-``PTPU_COMPILE_CACHE_DIR`` (persistent compile cache, :mod:`compilecache`).
+``PTPU_MEM_SAMPLE_EVERY`` (HBM watermark cadence, default 16 steps).
+The persistent compile cache (:mod:`compilecache`) is placed by jax's own
+``JAX_COMPILATION_CACHE_DIR``, else at ``.jax_cache`` in the checkout.
 See docs/ARCHITECTURE.md "Telemetry" and "Run doctor".
 """
 from __future__ import annotations
@@ -75,7 +76,7 @@ from .aggregate import (StreamTail, aggregate_run, read_worker_stream,
                         straggler_stats)
 from .compilation import (CompileTracker, arg_signature, diff_signatures,
                           get_tracker, track_jit)
-from .compilecache import maybe_enable_persistent_cache, persistent_cache_dir
+from .compilecache import enable_persistent_cache, persistent_cache_dir
 from .doctor import diagnose, render_report
 from .flight import FlightRecorder, flight_dir, read_flight_bundles
 from .memory import (MemorySampler, get_sampler, is_oom_error,
@@ -120,8 +121,8 @@ __all__ = [
     # compile/retrace tracking (ISSUE 4)
     "CompileTracker", "arg_signature", "diff_signatures", "get_tracker",
     "track_jit",
-    # persistent compile cache (ISSUE 13 / ROADMAP 5a)
-    "maybe_enable_persistent_cache", "persistent_cache_dir",
+    # persistent compile cache
+    "enable_persistent_cache", "persistent_cache_dir",
     # memory watermarks (ISSUE 4)
     "MemorySampler", "get_sampler", "is_oom_error", "oom_postmortem",
     # run doctor (ISSUE 4)

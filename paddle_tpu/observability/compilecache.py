@@ -1,24 +1,26 @@
-"""Persistent compilation cache (ISSUE 13 satellite; ROADMAP 5a).
+"""Persistent compilation cache: where it goes, and who turns it on.
 
 jax can persist compiled executables to disk so a *second process* with
-the same program shapes skips XLA entirely — on real pods that turns a
-multi-minute cold start into seconds.  This module is the one switch:
+the same program shapes skips XLA entirely — on a chip that turns a
+minutes-long cold start into seconds.  The cache directory is part of
+the cache key's environment, so a directory that moves never hits; the
+placement rule is therefore fixed and decided outside the program:
 
-- ``PTPU_COMPILE_CACHE_DIR=/path`` enables the cache; unset leaves jax
-  untouched (the cache is opt-in, never a surprise write to disk);
-- the min-compile-time floor is zeroed so even tiny functions persist —
-  without this the smoke-sized tests/benches would never populate the
-  cache and the warm-start guarantee would be untestable;
-- disk hit/miss traffic is surfaced as registry counters
-  ``compile.persistent_cache_hits`` / ``compile.persistent_cache_requests``
-  via jax's monitoring events, so the PR 4 compile tracker's in-process
-  view (calls − traces) composes with the cross-process view: a warm
-  start shows ``persistent_hits == persistent_requests > 0`` while the
-  tracker still counts one trace per function.
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax's own handling of that variable
+  places the cache.  This module sets no directory on that branch.
+- unset: :func:`enable_persistent_cache` points jax at ``.jax_cache``
+  beside the package (the checkout root, git-ignored) — never a temp
+  name, pid or time.
 
-Call sites: ``jit.to_static``, ``hapi.Model.prepare`` and the bench
-runner — i.e. every place the framework is about to hand jax a program
-worth caching.  The call is idempotent and cheap when the knob is unset.
+Either way the min-compile-time / min-entry-size floors are zeroed so
+even small programs persist, and disk hit/miss traffic is surfaced as
+registry counters ``compile.persistent_cache_hits`` /
+``compile.persistent_cache_requests`` via jax's monitoring events: a
+warm start shows ``hits == requests > 0``.
+
+Call sites: ``jit.to_static``, ``hapi.Model.prepare``, ``ServingEngine``,
+the bench entries and ``chip_smoke.py`` — every place the framework is
+about to hand jax a program worth caching.  Idempotent.
 """
 from __future__ import annotations
 
@@ -26,20 +28,23 @@ import os
 import threading
 from typing import Optional
 
-__all__ = ["maybe_enable_persistent_cache", "persistent_cache_dir",
+__all__ = ["enable_persistent_cache", "persistent_cache_dir",
            "reset_for_tests"]
 
 _lock = threading.Lock()
-_state = {"configured": False, "dir": None, "listener": False}
+_state = {"enabled": False, "dir": None, "listener": False}
 
-# jax monitoring event names (stable across the 0.4.x line; the listener
-# ignores anything else so a rename degrades to zero counters, not a crash)
+# jax monitoring event names; the listener ignores anything else
 _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_REQ = "/jax/compilation_cache/compile_requests_use_cache"
 
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 
 def persistent_cache_dir() -> Optional[str]:
-    """The directory the cache was enabled with (None = disabled)."""
+    """The directory the cache was enabled with (None = not yet)."""
     return _state["dir"]
 
 
@@ -54,83 +59,54 @@ def _listener(event: str, **kwargs) -> None:
         reg.counter("compile.persistent_cache_requests").inc()
 
 
-def maybe_enable_persistent_cache(registry=None) -> Optional[str]:
-    """Enable jax's persistent compilation cache if
-    ``PTPU_COMPILE_CACHE_DIR`` is set.  Idempotent; returns the cache
-    dir in effect (None = knob unset, cache untouched).
-
-    ``registry`` is accepted for call-site symmetry; the event listener
-    always resolves the process-global registry at event time (events
-    fire long after this call, possibly under a different registry in
-    tests).
-    """
-    cache_dir = os.environ.get("PTPU_COMPILE_CACHE_DIR", "").strip()
-    if not cache_dir:
-        return None
+def enable_persistent_cache() -> str:
+    """Turn on jax's persistent compilation cache under the placement
+    rule above.  Idempotent; returns the cache dir in effect."""
     with _lock:
-        if _state["configured"]:
+        if _state["enabled"]:
             return _state["dir"]
         import jax
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        from jax._src import compilation_cache, monitoring
+        if os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip():
+            cache_dir = jax.config.jax_compilation_cache_dir
+        else:
+            cache_dir = _CHECKOUT_CACHE
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # persist everything: the default floors (compile time / entry
         # size) silently skip small programs, which breaks the
         # warm-start contract for smoke-sized workloads
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:  # noqa: swallow
-            pass  # knob absent on older jax: compile-time floor suffices
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # jax latches a cache-used decision on the process's FIRST
         # compile (is_cache_used sets _cache_checked); any eager op
         # before this call — model construction, pt.seed — freezes the
         # cache OFF for the process even though the config above lands.
         # reset_cache() clears the latch; the cache re-initializes
         # lazily from the config on the next compile.
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:  # noqa: swallow
-            pass  # latch absent on this jax: config alone suffices
+        compilation_cache.reset_cache()
         if not _state["listener"]:
-            try:
-                from jax._src import monitoring
-                monitoring.register_event_listener(_listener)
-                _state["listener"] = True
-            except Exception:  # noqa: swallow
-                pass  # cache still works; only the hit counters go dark
-        _state["configured"] = True
-        _state["dir"] = cache_dir
+            monitoring.register_event_listener(_listener)
+            _state["listener"] = True
+        _state["enabled"], _state["dir"] = True, cache_dir
         return cache_dir
 
 
 def reset_for_tests() -> None:
-    """Forget the configured state so a test can re-enable with a fresh
-    dir.  Does not unregister the jax listener (jax keeps listeners for
-    the process lifetime); re-enabling is still idempotent.
+    """Forget the enabled state and restore jax's floors and latch, so a
+    test can enable again.  The directory is left where it was placed.
 
-    Also undoes the jax-side config when we had enabled it: leaving
-    ``jax_compilation_cache_dir`` latched bleeds disk-cache warm starts
-    into every later compile in the process — concretely, a test that
-    enabled the cache made the doctor-e2e straggler drill misattribute
-    the slow worker (worker 0 paid cold compiles, worker 1 got warm
-    hits and outran its injected delay)."""
+    A cache that is on for every hapi/engine flow changes what a test
+    session measures — a warm second worker once outran its injected
+    delay in the doctor straggler drill — so ``tests/conftest.py`` keeps
+    the session hermetic with jax's own switches (a per-session
+    ``JAX_COMPILATION_CACHE_DIR`` and ``JAX_ENABLE_COMPILATION_CACHE=0``)
+    rather than with a rule in this module."""
     with _lock:
-        was_enabled = _state["configured"] and _state["dir"]
-        _state["configured"] = False
-        _state["dir"] = None
-        if not was_enabled:
+        if not _state["enabled"]:
             return
+        _state["enabled"], _state["dir"] = False, None
         import jax
-        jax.config.update("jax_compilation_cache_dir", None)
+        from jax._src import compilation_cache
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:  # noqa: swallow
-            pass  # knob absent on older jax
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:  # noqa: swallow
-            pass  # no latch to clear on this jax
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()

@@ -356,10 +356,13 @@ def fit_roofline(ops: List[Dict[str, Any]],
     excess booked as memory-bound; HBM ops contribute ``bytes/bw``.
     Comm/host op *time* belongs to the measured phase split — only
     their bytes are tallied.  Ops missing shapes/flops are counted
-    ``unmodeled``; they never silently vanish."""
-    peak_bf16 = float(spec["bf16_tflops"]) * 1e12
-    peak_int8 = float(spec["int8_tops"]) * 1e12
-    bw = float(spec["hbm_gbps"]) * 1e9
+    ``unmodeled``; they never silently vanish.  A ``spec`` with
+    ``known=False`` has no peaks: flops and bytes are still tallied but
+    every modeled time stays 0.0."""
+    known = bool(spec.get("known"))
+    peak_bf16 = float(spec["bf16_tflops"]) * 1e12 if known else None
+    peak_int8 = float(spec["int8_tops"]) * 1e12 if known else None
+    bw = float(spec["hbm_gbps"]) * 1e9 if known else None
     fit = _zero_fit()
     fit["ops_total"] = len(ops)
     for op in ops:
@@ -386,12 +389,13 @@ def fit_roofline(ops: List[Dict[str, Any]],
             if f is None or b is None:
                 fit["ops_unmodeled"] += 1
                 continue
-            peak = peak_int8 if op.get("integer") else peak_bf16
-            t_flops = f / peak
-            t_bytes = b / bw
-            fit["mxu_s"] += t_flops
-            if t_bytes > t_flops:
-                fit["memory_s"] += t_bytes - t_flops
+            if known:
+                peak = peak_int8 if op.get("integer") else peak_bf16
+                t_flops = f / peak
+                t_bytes = b / bw
+                fit["mxu_s"] += t_flops
+                if t_bytes > t_flops:
+                    fit["memory_s"] += t_bytes - t_flops
             fit["flops"] += f
             fit["bytes"] += b
             fit["ops_modeled"] += 1
@@ -399,7 +403,8 @@ def fit_roofline(ops: List[Dict[str, Any]],
             if b is None:
                 fit["ops_unmodeled"] += 1
                 continue
-            fit["memory_s"] += b / bw
+            if known:
+                fit["memory_s"] += b / bw
             fit["bytes"] += b
             fit["ops_modeled"] += 1
     return fit
@@ -576,9 +581,10 @@ def gap_budget(step_p50_ms: float, phases_ms: Dict[str, float], *,
                     "int8_tops", "hbm_gbps")},
         "measured_step_ms": round(measured, 6),
         # the roofline prediction: modeled compute + the measured
-        # comm/host phases (nominal-peak extrapolation when known=False)
-        "modeled_step_ms": round(
-            model_mxu_ms + model_mem_ms + comm_ms + host_ms, 6),
+        # comm/host phases; a device without peaks has no prediction
+        "modeled_step_ms": (round(
+            model_mxu_ms + model_mem_ms + comm_ms + host_ms, 6)
+            if spec.get("known") else None),
         "buckets_ms": {k: round(v, 6) for k, v in buckets.items()},
         "coverage": round(coverage, 6),
         "dominant_sink": dominant,

@@ -243,6 +243,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, dropout_p, kv_len, offset):
             pltpu.VMEM((bq, _LANES), jnp.float32),   # l
             pltpu.VMEM((bq, d), jnp.float32),        # acc
         ],
+        name="flash_fwd",
         interpret=_interpret(),
     )(seed, q, k, v)
     return out, lse[:, :, 0]  # keep the compact (bh, sq) form as residual
@@ -396,6 +397,7 @@ def _flash_bwd(scale, causal, dropout_p, kv_len, offset, res, g):
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
         ],
+        name="flash_bwd_dkdv",
         interpret=_interpret(),
     )(seed, q, k, v, do, lse_b, delta_b)
     dk = dk.astype(k.dtype)
@@ -419,6 +421,7 @@ def _flash_bwd(scale, causal, dropout_p, kv_len, offset, res, g):
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(seed, q, k, v, do, lse_b, delta_b).astype(q.dtype)
     seed_zero = np.zeros(seed.shape, jax.dtypes.float0)
@@ -553,6 +556,7 @@ def flash_attention_kvcache(q, k_cache, v_cache, cache_seqlen,
         ],
         out_specs=pl.BlockSpec((1, sq, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        name="flash_decode",
         interpret=_interpret(),
     )(len_arr, qf, kf, vf)
     return out.reshape(b, h, sq, d)

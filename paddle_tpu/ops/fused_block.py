@@ -54,8 +54,10 @@ on a real TPU, the pure-jnp reference route elsewhere — the reference IS
 the tier-1/CPU default and the numerics oracle.  ``PTPU_FUSED_BLOCK=
 pallas|reference`` forces a route; ``FLAGS_pallas_interpret_routing``
 also forces the kernels (interpret mode) for cross-path tests.  Shapes a
-Mosaic block can't tile (rows % 8, GEMM cols % 128) silently take the
-reference route.
+Mosaic block can't tile (rows % 8, GEMM cols % 128) take the reference
+route; which route a compiled program got is in its HLO (the
+``fused_ln_linear`` / ``fused_linear_residual`` / ``fused_ffn``
+``tpu_custom_call`` s — ``tests/test_tpu_hw.py`` asserts them).
 """
 from __future__ import annotations
 
@@ -118,16 +120,22 @@ def _pallas_ok(rows: int, *gemm_cols: int) -> bool:
     return rows % 8 == 0 and all(c % 128 == 0 for c in gemm_cols)
 
 
-def _pick_rows(n: int) -> int:
+# Block sizes are capped so that one grid step's double-buffered tiles stay
+# inside Mosaic's 16 MiB scoped-VMEM limit at hidden 2048 / ffn 8192: 4-byte
+# operands take half the rows and half the columns of 2-byte ones (at
+# 256 x 512 the f32 kernels asked for 22-23 MiB and failed to compile).
+def _pick_rows(n: int, dtype, cap: int = 256) -> int:
+    cap = cap if jnp.dtype(dtype).itemsize <= 2 else cap // 2
     for b in (256, 128, 64, 32, 16, 8):
-        if n % b == 0:
+        if b <= cap and n % b == 0:
             return b
     return n
 
 
-def _pick_cols(n: int) -> int:
+def _pick_cols(n: int, dtype, cap: int = 512) -> int:
+    cap = cap if jnp.dtype(dtype).itemsize <= 2 else cap // 2
     for b in (512, 256, 128):
-        if n % b == 0:
+        if b <= cap and n % b == 0:
             return b
     return n
 
@@ -198,7 +206,7 @@ def _ln_linear_pallas(x, w, b, g, beta, epsilon):
     from jax.experimental.pallas import tpu as pltpu
     n, h = x.shape
     cols = w.shape[1]
-    br, bc = _pick_rows(n), _pick_cols(cols)
+    br, bc = _pick_rows(n, w.dtype), _pick_cols(cols, w.dtype)
     return pl.pallas_call(
         functools.partial(_ln_linear_kernel, epsilon=epsilon),
         grid=(n // br, cols // bc),
@@ -212,6 +220,7 @@ def _ln_linear_pallas(x, w, b, g, beta, epsilon):
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, cols), w.dtype),
         scratch_shapes=[pltpu.VMEM((br, h), w.dtype)],
+        name="fused_ln_linear",
         interpret=_interpret(),
     )(x, w, b.reshape(1, -1), g.reshape(1, -1), beta.reshape(1, -1))
 
@@ -283,7 +292,7 @@ def _linear_residual_kernel(seed_ref, x_ref, w_ref, b_ref, r_ref, o_ref, *,
 def _linear_residual_pallas(x, w, b, r, seed, dropout_p, salt):
     n, k = x.shape
     cols = w.shape[1]
-    br, bc = _pick_rows(n), _pick_cols(cols)
+    br, bc = _pick_rows(n, w.dtype), _pick_cols(cols, w.dtype)
     return pl.pallas_call(
         functools.partial(_linear_residual_kernel, dropout_p=dropout_p,
                           salt=salt, block_r=br, block_c=bc),
@@ -297,6 +306,7 @@ def _linear_residual_pallas(x, w, b, r, seed, dropout_p, salt):
         ],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, cols), r.dtype),
+        name="fused_linear_residual",
         interpret=_interpret(),
     )(seed.reshape(1, 1), x, w, b.reshape(1, -1), r)
 
@@ -356,6 +366,31 @@ def fused_linear_residual(x, w, b, residual, *, dropout_p: float = 0.0,
     return out.reshape(shape)
 
 
+# Mosaic lowers neither erf nor erfc (jax 0.9.0), so the kernel's exact GELU
+# evaluates erf by the f32 rational approximation XLA itself uses on other
+# backends (odd P(x^2)/Q(x^2) on [-4, 4]; max abs error 3.5e-7 against
+# math.erf, measured over [-6, 6]).
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08,
+          -2.10102402082508e-06, -5.69250639462346e-05,
+          -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def _gelu_erf(x):
+    z = jnp.clip(x * np.float32(0.7071067811865476), -4.0, 4.0)
+    z2 = z * z
+    p = jnp.full_like(z, _ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * z2 + np.float32(c)
+    q = jnp.full_like(z, _ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * z2 + np.float32(c)
+    return 0.5 * x * (1.0 + z * p / q)
+
+
 # ---------------------------------------------------------------------------
 # K3: the FFN half as ONE kernel — LN → GEMM → act(+drop) → GEMM → drop →
 # + residual; the (rows, ffn) intermediate exists only as a VMEM tile
@@ -380,8 +415,7 @@ def _ffn_kernel(seed_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, g_ref,
 
     h = (_dot(lnx_scr[...], w1_ref[...], (((1,), (0,)), ((), ())))
          + b1_ref[...].astype(jnp.float32))
-    h = jax.nn.gelu(h, approximate=False) if activation == "gelu" \
-        else jnp.maximum(h, 0.0)
+    h = _gelu_erf(h) if activation == "gelu" else jnp.maximum(h, 0.0)
     if dropout1 > 0.0:
         rows = i * block_r + lax.broadcasted_iota(
             jnp.int32, (block_r, block_f), 0)
@@ -411,8 +445,9 @@ def _ffn_pallas(x, w1, b1, w2, b2, g, beta, seed, activation, dropout1,
     from jax.experimental.pallas import tpu as pltpu
     n, h = x.shape
     ffn = w1.shape[1]
-    br = min(_pick_rows(n), 128)   # x + lnx + acc + both weight tiles ≤ VMEM
-    bf = _pick_cols(ffn)
+    # x + lnx + acc + both weight tiles ≤ VMEM
+    br = _pick_rows(n, w1.dtype, cap=128)
+    bf = _pick_cols(ffn, w1.dtype)
     return pl.pallas_call(
         functools.partial(_ffn_kernel, epsilon=epsilon,
                           activation=activation, dropout1=dropout1,
@@ -435,6 +470,7 @@ def _ffn_pallas(x, w1, b1, w2, b2, g, beta, seed, activation, dropout1,
             pltpu.VMEM((br, h), w1.dtype),                 # LN(x)
             pltpu.VMEM((br, h), jnp.float32),              # W2 accumulator
         ],
+        name="fused_ffn",
         interpret=_interpret(),
     )(seed.reshape(1, 1), x, w1, b1.reshape(1, -1), w2, b2.reshape(1, -1),
       g.reshape(1, -1), beta.reshape(1, -1))

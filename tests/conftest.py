@@ -2,7 +2,16 @@
 localhost multi-process distributed tests, SURVEY.md §4) in-process, BEFORE
 any test touches a backend — see paddle_tpu.framework.vmesh for why env vars
 don't work here."""
-from paddle_tpu.framework.vmesh import force_virtual_cpu_mesh
+import os
+
+# The program turns jax's persistent compile cache on in every hapi /
+# engine / bench flow.  A test session must neither read nor write it — a
+# warm second worker once outran its injected delay in the doctor
+# straggler drill — so jax's own master switch is off here, before jax is
+# imported.  Tests of the cache itself switch it on in their own processes.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
+
+from paddle_tpu.framework.vmesh import force_virtual_cpu_mesh  # noqa: E402
 
 force_virtual_cpu_mesh(8)
 

@@ -50,17 +50,21 @@ def test_validate_row_catches_violations():
     assert any("phases_ms.compute" in e for e in schema.validate_row(bad))
     bad = dict(row, step_time_ms={})
     assert any("p50" in e for e in schema.validate_row(bad))
-    bad = dict(row, fallback_reason=123)
-    assert any("fallback_reason" in e for e in schema.validate_row(bad))
     bad = dict(row, bytes_on_wire="lots")
     assert any("bytes_on_wire" in e for e in schema.validate_row(bad))
 
 
-def test_fallback_reason_is_a_field_not_prose():
-    row = _mk_row(fallback_reason="tpu_unreachable")
-    assert schema.validate_row(row) == []
-    assert row["fallback_reason"] == "tpu_unreachable"
-    assert row["device_kind"]  # what actually ran is always stamped
+def test_no_cpu_unless_asked(monkeypatch):
+    """A run that was not told to use a CPU never continues on one: with
+    neither BENCH_CPU=1 nor JAX_PLATFORMS=cpu, finding no TPU is fatal."""
+    from paddle_tpu.bench import runner
+    monkeypatch.delenv("BENCH_CPU", raising=False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="not a TPU"):
+        runner.ensure_devices()
+    monkeypatch.setenv("BENCH_CPU", "1")
+    assert runner.ensure_devices() == "cpu"
+    assert _mk_row()["device_kind"]  # what actually ran is always stamped
 
 
 def test_pct_matches_aggregate_definition():

@@ -2,7 +2,7 @@
 op_tester harness compiling even without GPUs — same doctrine here): the
 bench helpers that only run inside bench.main()'s on-TPU branch get
 tiny-shape CPU executions so a regression surfaces in the suite, not as a
-silent '[...] failed:' stderr line during the one-shot hardware-evidence run.
+failed one-shot run on the chip.
 Covered directly: _bench_resnet50, _bench_bert_base, _sweep_seqlen_ab,
 _bench_slice_estimate (the 1.3B/6.7B slice methodology), _bench_config (the
 headline path).  _bench_flash_ab / _sweep_block_sizes / _bench_1p3b_fullstep
@@ -66,7 +66,7 @@ def test_bench_slice_estimate_smoke():
     before = _artifact_mtimes()
     tok_s, mfu = bench._bench_slice_estimate(gpt_tiny, (1, 2), B=2, S=64,
                                              tag="smoke-slice")
-    assert tok_s > 0 and mfu >= 0
+    assert tok_s > 0 and mfu is None    # a CPU has no peak to divide by
     assert _artifact_mtimes() == before
 
 
@@ -102,8 +102,7 @@ def test_fused_ce_op_memory_smoke():
     temp bytes once the chunked scan engages (small-shape rendering of
     the fused_ce_ab.json evidence)."""
     out = bench._fused_ce_op_memory(B=1, S=256, H=64, V=4096, chunk=128)
-    if out["fused"] and out["unfused"]:       # memory analysis available
-        assert out["temp_bytes_saved"] > 0, out
+    assert out["temp_bytes_saved"] > 0, out
 
 
 @pytest.mark.slow
@@ -113,4 +112,4 @@ def test_bench_gpt_smoke():
     cfg = gpt_tiny(hidden_dropout=0.0, attention_dropout=0.0)
     tok_s, mfu = bench._bench_config(cfg, B=2, S=128, steps=2, warmup=1,
                                      tag="suite-smoke")
-    assert tok_s > 0 and mfu >= 0
+    assert tok_s > 0 and mfu is None

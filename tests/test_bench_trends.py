@@ -27,7 +27,7 @@ def _row(scenario="moe", mode="smoke", p50=50.0, phases=None, sha="aaaa1111",
     return {
         "schema_version": schema.SCHEMA_VERSION,
         "scenario": scenario, "mode": mode, "ts": float(ts),
-        "git_sha": sha, "device_kind": "cpu", "fallback_reason": None,
+        "git_sha": sha, "device_kind": "cpu",
         "fingerprint": dict(fingerprint or _FP), "config": {}, "steps": 4,
         "step_time_ms": {"p50": p50, "p99": p50 * 1.05, "mean": p50,
                          "min": p50 * 0.95},

@@ -5,15 +5,13 @@ ShardedOptimizer parity with replicated Adam on the 8-device virtual dp
 mesh (the MULTICHIP-style correctness drill), fleet/strategy wiring, the
 deprecation alias over the old ``all_reduce_quantized`` stub, byte
 accounting, and the doctor's ``comm_bound`` verdict."""
-import inspect
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as pt
@@ -41,12 +39,9 @@ def make_mesh():
 
 def smap(f, mesh, in_specs, out_specs):
     """shard_map with the replication check off (collective outputs are
-    value-replicated but VMA-typed device-varying; kwarg renamed across
-    jax versions)."""
-    params = inspect.signature(shard_map).parameters
-    kw = {("check_vma" if "check_vma" in params else "check_rep"): False}
+    value-replicated but VMA-typed device-varying)."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
+                     check_vma=False)
 
 
 @pytest.fixture(autouse=True)
@@ -391,6 +386,13 @@ class TestShardedOptimizer:
         self._parity(lambda: pt.optimizer.AdamW(learning_rate=1e-2,
                                                 weight_decay=0.1))
 
+    def test_parity_adamw_decay_skips_a_leaf(self):
+        # per-leaf coefficients: the decay vector is built on device from
+        # one broadcast per run, and each rank takes its own chunk of it
+        self._parity(lambda: pt.optimizer.AdamW(
+            learning_rate=1e-2, weight_decay=0.1,
+            apply_decay_param_fun=lambda name: name != "b"))
+
     def test_parity_momentum_coupled_decay(self):
         self._parity(lambda: pt.optimizer.Momentum(
             learning_rate=1e-2, momentum=0.9, weight_decay=0.05))
@@ -466,6 +468,18 @@ class TestShardedOptimizer:
             seg = flat[info.offset:info.offset + info.size]
             want = np.ravel(np.asarray(leaves[info.index], np.float32))
             np.testing.assert_array_equal(seg, want, err_msg=info.path)
+        # what a real mesh punishes and a virtual one forgives: the state
+        # must be born sharded (not 3x the model on the default device) ...
+        state = zo.init(params)
+        for leaf in [state["flat"], *state["slots"].values()]:
+            assert tuple(leaf.sharding.spec) == ("dp",), leaf.sharding
+            assert len(leaf.sharding.device_set) == 8
+        # ... and a step must hand every parameter back in the layout it
+        # arrived in, not the flat master's (donation, no second compile)
+        grads = jax.tree_util.tree_map(jnp.ones_like, params)
+        new_p, _ = jax.jit(zo.apply_gradients)(grads, params, state)
+        for k, v in params.items():
+            assert new_p[k].sharding.is_equivalent_to(v.sharding, v.ndim), k
 
     def test_rejects_non_elementwise_and_bad_comm(self):
         from paddle_tpu.optimizer import Lamb

@@ -1,6 +1,13 @@
-"""Persistent compile cache (ISSUE 13 / ROADMAP 5a): PTPU_COMPILE_CACHE_DIR
-wiring and the cross-process warm-start guarantee."""
+"""Persistent compile cache: the placement rule (jax's own
+JAX_COMPILATION_CACHE_DIR when set, else ``.jax_cache`` in the checkout)
+and the cross-process warm-start guarantee.
+
+conftest.py turns jax's cache master switch off for the session, so the
+in-process tests here only look at configuration; the processes below
+switch it back on for themselves."""
+import json
 import os
+import pathlib
 import subprocess
 import sys
 
@@ -8,37 +15,57 @@ import pytest
 
 from paddle_tpu.observability import compilecache
 
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
-def test_disabled_without_knob(monkeypatch):
-    monkeypatch.delenv("PTPU_COMPILE_CACHE_DIR", raising=False)
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Record what enable_persistent_cache() asks jax.config to change,
+    without changing it (nothing to restore, nothing bleeds)."""
+    import jax
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.__setitem__(name, value))
     compilecache.reset_for_tests()
-    assert compilecache.maybe_enable_persistent_cache() is None
-    assert compilecache.persistent_cache_dir() is None
-
-
-def test_enable_is_idempotent(tmp_path, monkeypatch):
-    cdir = str(tmp_path / "cc")
-    monkeypatch.setenv("PTPU_COMPILE_CACHE_DIR", cdir)
+    yield calls
+    monkeypatch.undo()
     compilecache.reset_for_tests()
-    try:
-        assert compilecache.maybe_enable_persistent_cache() == cdir
-        assert os.path.isdir(cdir)
-        # second call: same answer, no reconfiguration
-        assert compilecache.maybe_enable_persistent_cache() == cdir
-        assert compilecache.persistent_cache_dir() == cdir
-        import jax
-        assert jax.config.jax_compilation_cache_dir == cdir
-    finally:
-        compilecache.reset_for_tests()
+
+
+def test_env_branch_sets_no_directory(tmp_path, monkeypatch, config_updates):
+    """With JAX_COMPILATION_CACHE_DIR set, jax's own handling places the
+    cache: enabling must not touch ``jax_compilation_cache_dir``."""
+    import jax
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    placed_by_jax = jax.config.jax_compilation_cache_dir
+    assert compilecache.enable_persistent_cache() == placed_by_jax
+    assert "jax_compilation_cache_dir" not in config_updates
+    # the floors are zeroed on both branches, and the call is idempotent
+    assert config_updates == {
+        "jax_persistent_cache_min_compile_time_secs": 0,
+        "jax_persistent_cache_min_entry_size_bytes": -1}
+    config_updates.clear()
+    assert compilecache.enable_persistent_cache() == placed_by_jax
+    assert not config_updates
+
+
+def test_unset_branch_is_a_fixed_directory_in_the_checkout(
+        monkeypatch, config_updates):
+    """No variable: one fixed path beside the package — never a temp
+    name, pid or time (the path is part of what makes a cache hit)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = str(REPO / ".jax_cache")
+    assert compilecache.enable_persistent_cache() == want
+    assert config_updates["jax_compilation_cache_dir"] == want
+    assert compilecache.persistent_cache_dir() == want
 
 
 _WORKLOAD = r"""
 import os, sys, json
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, jax.numpy as jnp
 from paddle_tpu.observability import get_registry
-from paddle_tpu.observability.compilecache import maybe_enable_persistent_cache
-assert maybe_enable_persistent_cache() == os.environ["PTPU_COMPILE_CACHE_DIR"]
+from paddle_tpu.observability.compilecache import enable_persistent_cache
+assert enable_persistent_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
 
 @jax.jit
 def f(x, y):
@@ -61,19 +88,18 @@ print(json.dumps({
 
 @pytest.mark.slow
 def test_warm_start_compiles_nothing(tmp_path):
-    """The ROADMAP 5a contract: a second process with the same program
-    shapes loads every executable from disk — persistent hits equal the
-    cacheable compile requests and no XLA compilation runs fresh."""
-    env = dict(os.environ, PTPU_COMPILE_CACHE_DIR=str(tmp_path / "cc"),
-               JAX_PLATFORMS="cpu")
+    """A second process with the same program shapes loads every
+    executable from disk — persistent hits equal the cacheable compile
+    requests and no XLA compilation runs fresh."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               JAX_ENABLE_COMPILATION_CACHE="1", JAX_PLATFORMS="cpu")
     env.pop("PTPU_METRICS_DIR", None)
 
     def run():
         out = subprocess.run([sys.executable, "-c", _WORKLOAD],
-                             capture_output=True, text=True, timeout=300,
-                             env=env)
+                             cwd=str(REPO), capture_output=True, text=True,
+                             timeout=300, env=env)
         assert out.returncode == 0, out.stderr
-        import json
         return json.loads(out.stdout.strip().splitlines()[-1])
 
     cold = run()

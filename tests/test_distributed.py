@@ -110,12 +110,9 @@ class TestCollectives:
         x = jnp.arange(8.0)
         # all_gather output is device-varying by VMA typing even though the
         # values coincide — disable the static replication check
-        import inspect
-        no_rep_check = ("check_vma" if "check_vma" in inspect.signature(
-            shard_map).parameters else "check_rep")  # renamed in jax 0.6
         f = shard_map(lambda v: dist.all_gather(v, group="dp"),
                       mesh=mesh, in_specs=P("dp"), out_specs=P(None),
-                      **{no_rep_check: False})
+                      check_vma=False)
         out = f(x)  # every shard holds the full vector
         np.testing.assert_allclose(out, x)
 

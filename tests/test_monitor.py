@@ -106,7 +106,8 @@ class TestStatusServer:
         assert sz["step"] is not None and sz["step"] >= 2
         assert sz["step_time_ms"]["p50"] > 0
         assert sz["step_time_ms"]["p99"] >= sz["step_time_ms"]["p50"]
-        assert sz["mfu"] is not None
+        assert sz["tokens_per_sec"] > 0
+        assert "mfu" in sz          # None on a device with no known peak
         assert sz["heartbeat"]["beats"] >= 1
         assert sz["watchdog"]["timeouts"] == 0
         assert not sz["watchdog"]["closed"]

@@ -43,13 +43,27 @@ def test_sink_taxonomy_is_pinned_across_modules():
 
 
 def test_device_spec_known_and_unknown():
-    spec = device_spec("TPU v5e chip")
+    # the table is keyed by the device_kind string the chip reports — a
+    # v5e says "TPU v5 lite", and the marketing name is NOT a key
+    spec = device_spec("TPU v5 lite")
     assert spec["known"] and spec["gen"] == "v5e"
-    assert spec["bf16_tflops"] == DEVICE_SPECS["v5e"]["bf16_tflops"]
-    assert spec["int8_tops"] > spec["bf16_tflops"]  # v5e: 2x int8
-    unk = device_spec("Frobnicator 9000")
-    assert not unk["known"]
-    assert unk["hbm_gbps"] > 0  # nominal fallback still usable
+    assert (spec["bf16_tflops"], spec["int8_tops"], spec["hbm_gbps"]) == (
+        197.0, 393.0, 819.0)
+    assert spec["bf16_tflops"] == DEVICE_SPECS["TPU v5 lite"]["bf16_tflops"]
+    for kind in ("TPU v5e", "Frobnicator 9000", "cpu"):
+        unk = device_spec(kind)
+        assert not unk["known"] and unk["gen"] is None
+        # an unknown device carries NO peaks — nothing to divide by
+        assert not {"bf16_tflops", "int8_tops", "hbm_gbps"} & set(unk)
+
+
+def test_mfu_is_none_without_a_known_peak():
+    from paddle_tpu.observability.mfu import mfu, peak_flops_per_sec
+    import jax
+    assert jax.devices()[0].device_kind not in DEVICE_SPECS   # the CPU mesh
+    assert peak_flops_per_sec() is None
+    assert mfu(1e5, 1e9) is None
+    assert mfu(1e5, 1e9, peak=2e14) == pytest.approx(0.5)
 
 
 # -- HLO parsing ------------------------------------------------------------
@@ -89,7 +103,7 @@ def test_normalize_cost_analysis_sparse_and_absent():
 
 
 def test_fit_roofline_counts_unmodeled_ops():
-    spec = device_spec("TPU v5e")
+    spec = device_spec("TPU v5 lite")
     ops = [{"name": "a", "opcode": "dot", "klass": "mxu",
             "flops": 1e9, "bytes": 1e6, "integer": False},
            {"name": "b", "opcode": "mystery", "klass": "hbm",
@@ -120,7 +134,7 @@ def test_gap_budget_sums_to_measured_unknown_device():
 
 
 def test_gap_budget_known_device_uses_fit():
-    spec = device_spec("TPU v5e")
+    spec = device_spec("TPU v5 lite")
     analyses = {"step": {"name": "step", "error": None, "cost": {},
                          "fit": {"mxu_s": 0.004, "memory_s": 0.002,
                                  "comm_s": 0.0, "flops": 1e12,
@@ -139,7 +153,7 @@ def test_gap_budget_known_device_uses_fit():
 
 
 def test_gap_budget_call_share_weighting():
-    spec = device_spec("TPU v5e")
+    spec = device_spec("TPU v5 lite")
     fit_a = {"mxu_s": 0.004, "memory_s": 0.0, "comm_s": 0.0,
              "flops": 0, "bytes": 0, "comm_bytes": 0,
              "ops_modeled": 1, "ops_unmodeled": 0}

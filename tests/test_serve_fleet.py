@@ -282,12 +282,16 @@ def fleet_spec(max_pos=64):
             "engine": {"max_seqs": 4}}
 
 
+# workers inherit the parent's platform; a CPU drill says so
+_CPU_ENV = {"JAX_PLATFORMS": "cpu"}
+
+
 @pytest.mark.slow
 class TestMultiProcessDrills:
     def test_sigkill_failover_drill(self, tmp_path):
         reg = MetricsRegistry()
         mgr = ReplicaManager(fleet_spec(), replicas=2, registry=reg,
-                             run_dir=str(tmp_path))
+                             run_dir=str(tmp_path), env=_CPU_ENV)
         mgr.start()
         try:
             router = Router(mgr.replicas, manager=mgr, registry=reg)
@@ -323,7 +327,7 @@ class TestMultiProcessDrills:
     def test_rolling_upgrade_zero_drops(self, tmp_path):
         reg = MetricsRegistry()
         mgr = ReplicaManager(fleet_spec(), replicas=2, registry=reg,
-                             run_dir=str(tmp_path))
+                             run_dir=str(tmp_path), env=_CPU_ENV)
         mgr.start()
         try:
             router = Router(mgr.replicas, manager=mgr, registry=reg)
@@ -348,7 +352,7 @@ class TestMultiProcessDrills:
     def test_worker_spill_namespaced_per_replica(self, tmp_path):
         reg = MetricsRegistry()
         mgr = ReplicaManager(fleet_spec(), replicas=1, registry=reg,
-                             run_dir=str(tmp_path))
+                             run_dir=str(tmp_path), env=_CPU_ENV)
         mgr.start()
         try:
             router = Router(mgr.replicas, manager=mgr, registry=reg)

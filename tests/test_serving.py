@@ -139,16 +139,22 @@ class TestPagedKVCache:
         c = self.make(blocks=6, bs=4)
         c.ensure_capacity("a", 8)
         c.ensure_capacity("b", 8)
-        # write a recognizable value into b's first slot
+        # write a recognizable value into b's first slot, the way a model
+        # does: through the layer view's write()
         slot_b = c.slot("b", 0)
-        k, v = c._pages[0]
-        c._pages[0] = (k.at[slot_b].set(7.5), v)
+        (layer,) = c.layer_caches(np.zeros((1, 2), np.int32),
+                                  np.ones((1,), np.int32),
+                                  np.asarray([[slot_b]], np.int32))
+        kv = jnp.full((1, 2, 4), 7.5)
+        c.update_pages([layer.write(kv, kv)])
         c.free_seq("a")
         assert c.defrag() is True
         # b's tables were renumbered to the compact prefix; its data moved
         assert sorted(c.table("b")) == [0, 1]
-        new_slot = c.slot("b", 0)
-        assert float(c._pages[0][0][new_slot, 0, 0]) == 7.5
+        blk, off = divmod(c.slot("b", 0), c.block_size)
+        k_pages = np.asarray(c._pages[0][0])
+        assert (k_pages[blk, :, off] == 7.5).all()
+        assert (k_pages != 0).sum() == 2 * 4      # exactly that one row
         # idempotent when already compact
         assert c.defrag() is False
 
@@ -161,8 +167,8 @@ class TestPagedAttention:
         rng = np.random.RandomState(0)
         B, H, D, bs, nb, T = 4, 2, 8, 4, 12, 5
         q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
-        kp = jnp.asarray(rng.randn(nb * bs + 1, H, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(nb * bs + 1, H, D).astype(np.float32))
+        kp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
         tbl = jnp.asarray(rng.randint(0, nb, (B, T)), jnp.int32)
         lens = jnp.asarray([7, 0, 20, 1], jnp.int32)
         ref = paged_attention_reference(q, kp, vp, tbl, lens, bs)
@@ -176,16 +182,16 @@ class TestPagedAttention:
         rng = np.random.RandomState(1)
         H, D, bs, nb = 3, 16, 4, 8
         q = jnp.asarray(rng.randn(1, H, D).astype(np.float32))
-        kp = jnp.asarray(rng.randn(nb * bs + 1, H, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(nb * bs + 1, H, D).astype(np.float32))
+        kp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
         tbl = jnp.asarray([[5, 2, 7, 0]], jnp.int32)
         ln = 11
         out = paged_attention_reference(q, kp, vp, tbl,
                                         jnp.asarray([ln], jnp.int32), bs)
-        slots = (np.asarray(tbl[0])[:, None] * bs
-                 + np.arange(bs)).reshape(-1)[:ln]
-        k = np.asarray(kp)[slots]
-        v = np.asarray(vp)[slots]
+        # (T, H, bs, D) blocks in table order -> (T*bs, H, D) token rows
+        gather = lambda p: np.asarray(p)[np.asarray(tbl[0])].transpose(  # noqa: E731
+            0, 2, 1, 3).reshape(-1, H, D)[:ln]
+        k, v = gather(kp), gather(vp)
         s = np.einsum("hd,lhd->hl", np.asarray(q[0]), k) * D ** -0.5
         p = np.exp(s - s.max(1, keepdims=True))
         p /= p.sum(1, keepdims=True)

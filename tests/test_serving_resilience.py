@@ -177,6 +177,26 @@ class TestQuarantine:
         assert eng.quarantined[bad]["step_kind"] == "prefill"
         assert eng.collect(rids[1])["tokens"] == []
 
+    def test_first_run_failure_is_not_a_poisoned_request(self, monkeypatch):
+        """A step program that has never run to completion cannot poison
+        a request: a lowering/compile failure on the first decode (here a
+        raising kernel stub) propagates out of run() instead of ending
+        with every request 'poisoned' and exit 0."""
+        import importlib
+        # (the package re-exports the function under the module's name)
+        pa = importlib.import_module("paddle_tpu.inference.paged_attention")
+
+        def boom(*a, **kw):
+            raise NotImplementedError("Mosaic could not lower this block")
+        monkeypatch.setattr(pa, "paged_attention", boom)
+        eng = make_engine(tiny_model(), max_seqs=2, kv_block_size=4)
+        rids = [eng.submit([1, 2, 3], max_new_tokens=4) for _ in range(2)]
+        with pytest.raises(NotImplementedError, match="Mosaic"):
+            eng.run(max_steps=50)
+        assert not eng.quarantined
+        assert eng.stats()["resilience"]["poisoned"] == 0
+        assert not any(r in eng.sched.finished for r in rids)
+
     def test_nan_guard_names_culprit_without_bisection(self, tmp_path):
         model = tiny_model()
         _, _, clean = self._traffic(model)
