@@ -1,0 +1,3 @@
+"""1 - (union of device-operation intervals over the traced stretch), percent,
+averaged over the chips used."""
+from perfbench.harness.reads import idle_share as read  # noqa: F401
