@@ -410,67 +410,73 @@ class ShardedOptimizer:
             enforce(int(bound_axis_size(axis)) == n,
                     f"bound axis {axis} has size {bound_axis_size(axis)} "
                     f"but state was built for {n} shards")
-        g_leaves = meta.treedef.flatten_up_to(grads)
-        flat_g = self._pack_flat(g_leaves, meta, fill_missing=True)
+        # the scopes name the three legs in the trace and the HLO; the
+        # all-to-all and all-gathers GSPMD inserts are its re-layouts of
+        # what each leg produces
+        with jax.named_scope("zero.pack"):
+            g_leaves = meta.treedef.flatten_up_to(grads)
+            flat_g = self._pack_flat(g_leaves, meta, fill_missing=True)
 
-        step = state["step"] + 1
-        lr_t = (jnp.asarray(lr, jnp.float32) if lr is not None
-                else inner._lr_at(step - 1))
-        wd_flat = self._coeff_flat(params, meta, inner._decay_tree(params))
-        l1_flat = (self._coeff_flat(params, meta, inner._l1_tree(params))
-                   if getattr(inner, "_l1", 0.0) else None)
+            step = state["step"] + 1
+            lr_t = (jnp.asarray(lr, jnp.float32) if lr is not None
+                    else inner._lr_at(step - 1))
+            wd_flat = self._coeff_flat(params, meta, inner._decay_tree(params))
+            l1_flat = (self._coeff_flat(params, meta, inner._l1_tree(params))
+                       if getattr(inner, "_l1", 0.0) else None)
 
-        def my_chunk(coeff):
-            # this rank's slice of a per-element coefficient vector
-            if coeff is None or coeff.ndim == 0:
-                return coeff
-            return lax.dynamic_slice(
-                coeff, (lax.axis_index(axis) * meta.chunk,), (meta.chunk,))
+            def my_chunk(coeff):
+                # this rank's slice of a per-element coefficient vector
+                if coeff is None or coeff.ndim == 0:
+                    return coeff
+                return lax.dynamic_slice(
+                    coeff, (lax.axis_index(axis) * meta.chunk,), (meta.chunk,))
 
-        if sharded:
-            if self._comm is not None and self._comm.dtype == "int8":
-                _account(meta.padded, self._comm, rounds=1)
-                g_shard, _own = _int8_reduce_scatter_flat(
-                    flat_g, axis, self._comm, self._grad_op)
+            if sharded:
+                if self._comm is not None and self._comm.dtype == "int8":
+                    _account(meta.padded, self._comm, rounds=1)
+                    g_shard, _own = _int8_reduce_scatter_flat(
+                        flat_g, axis, self._comm, self._grad_op)
+                else:
+                    _account(meta.padded, CommConfig(), rounds=1)
+                    g_shard = lax.psum_scatter(flat_g, axis,
+                                               scatter_dimension=0, tiled=True)
+                    if self._grad_op == "avg":
+                        g_shard = g_shard / n
+                wd, l1 = my_chunk(wd_flat), my_chunk(l1_flat)
             else:
-                _account(meta.padded, CommConfig(), rounds=1)
-                g_shard = lax.psum_scatter(flat_g, axis,
-                                           scatter_dimension=0, tiled=True)
-                if self._grad_op == "avg":
-                    g_shard = g_shard / n
-            wd, l1 = my_chunk(wd_flat), my_chunk(l1_flat)
-        else:
-            g_shard, wd, l1 = flat_g, wd_flat, l1_flat
-            if mesh is not None and n > 1 and axis in mesh.axis_names:
-                cons = NamedSharding(mesh, P(axis))
-                g_shard = lax.with_sharding_constraint(g_shard, cons)
+                g_shard, wd, l1 = flat_g, wd_flat, l1_flat
+                if mesh is not None and n > 1 and axis in mesh.axis_names:
+                    cons = NamedSharding(mesh, P(axis))
+                    g_shard = lax.with_sharding_constraint(g_shard, cons)
 
-        g_shard = self._clip_scale(g_shard, axis, sharded)
-        p_shard = state["flat"]
-        if l1 is not None:
-            g_shard = g_shard + l1 * jnp.sign(p_shard)
-        # weight decay as a flat vector: the inner's scalar-wd branches
-        # (`if wd`) can't take one, so reproduce its two decay modes
-        # around a wd=0 update — coupled (L2 into the gradient) before,
-        # decoupled (AdamW's -lr·wd·p) after
-        decoupled = bool(getattr(inner, "_decoupled", False))
-        if not decoupled:
-            g_shard = g_shard + wd * p_shard
-        new_shard, new_slots = inner._update(
-            g_shard, p_shard, state["slots"], lr_t, step, 0.0)
-        if decoupled:
-            new_shard = new_shard - lr_t * wd * p_shard
+        with jax.named_scope("zero.update"):
+            g_shard = self._clip_scale(g_shard, axis, sharded)
+            p_shard = state["flat"]
+            if l1 is not None:
+                g_shard = g_shard + l1 * jnp.sign(p_shard)
+            # weight decay as a flat vector: the inner's scalar-wd branches
+            # (`if wd`) can't take one, so reproduce its two decay modes
+            # around a wd=0 update — coupled (L2 into the gradient) before,
+            # decoupled (AdamW's -lr·wd·p) after
+            decoupled = bool(getattr(inner, "_decoupled", False))
+            if not decoupled:
+                g_shard = g_shard + wd * p_shard
+            new_shard, new_slots = inner._update(
+                g_shard, p_shard, state["slots"], lr_t, step, 0.0)
+            if decoupled:
+                new_shard = new_shard - lr_t * wd * p_shard
 
-        if sharded:
-            _account(meta.padded, CommConfig(), rounds=1)  # param gather
-            full = lax.all_gather(new_shard, axis, axis=0, tiled=True)
-        else:
-            full = new_shard
-            if mesh is not None and n > 1 and axis in mesh.axis_names:
-                full = lax.with_sharding_constraint(
-                    full, NamedSharding(mesh, P(axis)))
-        new_params = self._unpack(full, meta, params,
-                                  placed=None if sharded else self._placed)
+        with jax.named_scope("zero.unpack"):
+            if sharded:
+                _account(meta.padded, CommConfig(), rounds=1)  # param gather
+                full = lax.all_gather(new_shard, axis, axis=0, tiled=True)
+            else:
+                full = new_shard
+                if mesh is not None and n > 1 and axis in mesh.axis_names:
+                    full = lax.with_sharding_constraint(
+                        full, NamedSharding(mesh, P(axis)))
+            new_params = self._unpack(full, meta, params,
+                                      placed=None if sharded else self._placed)
         return new_params, {"step": step, "flat": new_shard,
                             "slots": new_slots}
 

@@ -113,10 +113,12 @@ class ColumnParallelLinear(Layer):
             self.bias = None
 
     def forward(self, x):
-        y = F.linear(x, self.weight, self.bias)
-        if self.gather_output:
-            return shard_constraint(y, *((None,) * y.ndim))
-        return shard_constraint(y, *((None,) * (y.ndim - 1)), self.mp_axis)
+        with jax.named_scope("mp.column_parallel"):
+            y = F.linear(x, self.weight, self.bias)
+            if self.gather_output:
+                return shard_constraint(y, *((None,) * y.ndim))
+            return shard_constraint(y, *((None,) * (y.ndim - 1)),
+                                    self.mp_axis)
 
 
 class RowParallelLinear(Layer):
@@ -145,14 +147,17 @@ class RowParallelLinear(Layer):
             self.bias = None
 
     def forward(self, x):
-        if self.input_is_parallel:
-            x = shard_constraint(
-                x, *((None,) * (jnp.ndim(x) - 1)), self.mp_axis)
-        y = F.linear(x, self.weight, None)
-        y = shard_constraint(y, *((None,) * jnp.ndim(y)))
-        if self.bias is not None:
-            y = y + self.bias.value.astype(y.dtype)
-        return y
+        # the all-reduce GSPMD inserts to complete the contraction inherits
+        # this scope
+        with jax.named_scope("mp.row_parallel"):
+            if self.input_is_parallel:
+                x = shard_constraint(
+                    x, *((None,) * (jnp.ndim(x) - 1)), self.mp_axis)
+            y = F.linear(x, self.weight, None)
+            y = shard_constraint(y, *((None,) * jnp.ndim(y)))
+            if self.bias is not None:
+                y = y + self.bias.value.astype(y.dtype)
+            return y
 
 
 class VocabParallelEmbedding(Layer):

@@ -30,7 +30,11 @@ SLO telemetry rides the PR 3 registry: gauges ``serve.queue_depth`` /
 ``serve.running`` / ``serve.waiting`` / ``serve.kv_occupancy``,
 histograms ``serve.ttft_ms`` / ``serve.tpot_ms``, counters
 ``serve.tokens`` / ``serve.requests`` / ``serve.finished`` /
-``serve.preemptions``.  ``start_status_server()`` exposes them on the
+``serve.preemptions`` / ``serve.h2d_bytes`` / ``serve.d2h_bytes`` (what
+a step puts on the device and copies back).  Every ``step()`` is an
+``engine.step`` span of :mod:`observability.tracing` whose children name
+its phases (see :meth:`ServingEngine.step`); ``stats()["phases"]`` sums
+them.  ``start_status_server()`` exposes them on the
 PR 5 monitor (``/statusz`` serving section; ``/healthz`` goes 503 when
 the admission queue exceeds ``PTPU_SHED_QUEUE_DEPTH`` — load shedding).
 
@@ -98,6 +102,7 @@ import jax.numpy as jnp
 from ..framework.errors import enforce
 from ..observability import requesttrace
 from ..observability.compilation import track_jit
+from ..observability.tracing import span, span_tree_totals
 from ..supervisor.watchdog import StepTimeout, Watchdog, guarded
 from ..utils import fsio
 from .kv_cache import PagedKVCache, default_kv_block_size
@@ -317,7 +322,9 @@ class ServingEngine:
         return get_registry()
 
     def _next_key(self):
-        self._key, sub = jax.random.split(self._key)
+        # a small device program of its own, so it is timed as dispatch
+        with self._phase("dispatch"):
+            self._key, sub = jax.random.split(self._key)
         return sub
 
     # -- jitted step functions --------------------------------------------
@@ -498,55 +505,78 @@ class ServingEngine:
         requests first, arm the watchdog around the device work, recover
         from a hung step by rebuilding the jitted fns and re-admitting
         the running set (recompute-prefill).  Returns the token events
-        produced; empty when idle AND no queued work remains."""
-        events = self._reap()
-        try:
-            with self._step_guard():
-                events += self._step_inner()
-        except StepTimeout:
-            events += self._recover_from_hang()
-        self.steps += 1
-        self._update_gauges()
+        produced; empty when idle AND no queued work remains.
+
+        The step is one ``engine.step`` span (attributes ``step``,
+        ``kind``, ``rows``, ``bucket``) whose children name where its
+        host time goes — ``reap``, ``schedule``, ``tables``, ``h2d``,
+        ``dispatch``, ``device_wait``, ``logits_copy``, ``guard``,
+        ``accept``, ``gauges``, and the rare ``quarantine`` /
+        ``recover``; ``stats()["phases"]`` sums them."""
+        with self._phase("engine.step") as root:
+            with self._phase("reap"):
+                events = self._reap()
+            try:
+                with self._step_guard():
+                    events += self._step_inner(root)
+            except StepTimeout:
+                with self._phase("recover"):
+                    events += self._recover_from_hang()
+            with self._phase("gauges"):
+                self.steps += 1
+                self._update_gauges()
         return events
 
-    def _step_inner(self) -> List[Dict[str, Any]]:
-        plan = self.sched.schedule()
+    def _phase(self, name: str) -> span:
+        """A span of this step: children share the root's ``step``."""
+        return span(name, step=self.steps)
+
+    def _step_inner(self, root: span) -> List[Dict[str, Any]]:
         reg = self._reg()
-        for victim in plan.preempted:
-            reg.counter("serve.preemptions").inc()
-            reg.emit("serve.preempt", request_id=victim.request_id,
-                     generated=len(victim.output),
-                     trace_id=victim.trace_id)
-            now = float(self.clock())
-            requesttrace.emit_span(reg, victim.trace_id,
-                                   victim.request_id, "preempt",
-                                   "preempt", now, now, self._proc)
-        if plan.kind not in ("prefill", "decode"):
-            return []
-        # head-of-line stall: residents live on this engine but not in
-        # this step's batch wait the full step out.  When the served
-        # step is induced work (a recompute prefill), their stall is
-        # that cause's cost — the survivor decodes late *because of*
-        # the failover, not by scheduler bad luck.
-        stall_comp = "stall"
-        if plan.kind == "prefill" and plan.seqs:
-            why = plan.seqs[0].resume_why
-            if why:
-                stall_comp = _RESUME_COMPONENT.get(why, "stall")
-        served = {s.request_id for s in plan.seqs}
-        t_step0 = float(self.clock())
+        with self._phase("schedule"):
+            plan = self.sched.schedule()
+            for victim in plan.preempted:
+                reg.counter("serve.preemptions").inc()
+                reg.emit("serve.preempt", request_id=victim.request_id,
+                         generated=len(victim.output),
+                         trace_id=victim.trace_id)
+                now = float(self.clock())
+                requesttrace.emit_span(reg, victim.trace_id,
+                                       victim.request_id, "preempt",
+                                       "preempt", now, now, self._proc)
+            step_kind = (plan.kind if plan.kind in ("prefill", "decode")
+                         else "other")
+            root.set(kind=step_kind, rows=len(plan.seqs),
+                     bucket=plan.bucket)
+            if step_kind == "other":
+                return []
+            # head-of-line stall: residents live on this engine but not
+            # in this step's batch wait the full step out.  When the
+            # served step is induced work (a recompute prefill), their
+            # stall is that cause's cost — the survivor decodes late
+            # *because of* the failover, not by scheduler bad luck.
+            stall_comp = "stall"
+            if plan.kind == "prefill" and plan.seqs:
+                why = plan.seqs[0].resume_why
+                if why:
+                    stall_comp = _RESUME_COMPONENT.get(why, "stall")
+            served = {s.request_id for s in plan.seqs}
+            t_step0 = float(self.clock())
         if plan.kind == "prefill":
             events = self._run_prefill(plan)
         else:
             events = self._run_decode(plan)
-        stalled = [(s.request_id, s.trace_id)
-                   for s in self.sched.running
-                   if s.request_id not in served and s.trace_id is not None]
-        if stalled:
-            requesttrace.emit_stall_span(reg, stalled, t_step0,
-                                         float(self.clock()), self._proc,
-                                         component=stall_comp,
-                                         cause=plan.kind)
+        with self._phase("accept"):
+            stalled = [(s.request_id, s.trace_id)
+                       for s in self.sched.running
+                       if s.request_id not in served
+                       and s.trace_id is not None]
+            if stalled:
+                requesttrace.emit_stall_span(reg, stalled, t_step0,
+                                             float(self.clock()),
+                                             self._proc,
+                                             component=stall_comp,
+                                             cause=plan.kind)
         return events
 
     def _recover_from_hang(self) -> List[Dict[str, Any]]:
@@ -597,62 +627,95 @@ class ServingEngine:
         """Fault seam + NaN guard, applied to every executed step
         (bisection probes included — injected faults must re-fire on the
         subset that still contains the target)."""
-        if self.step_fault is not None:
-            out = self.step_fault(self, kind,
-                                  [s.request_id for s in seqs], logits_np)
-            if out is not None:
-                logits_np = np.asarray(out)
-        if self.nan_guard:
-            bad = [s.request_id for i, s in enumerate(seqs)
-                   if not np.isfinite(logits_np[i]).all()]
-            if bad:
-                raise _NonfiniteLogits(bad)
+        with self._phase("guard"):
+            if self.step_fault is not None:
+                out = self.step_fault(self, kind,
+                                      [s.request_id for s in seqs],
+                                      logits_np)
+                if out is not None:
+                    logits_np = np.asarray(out)
+            if self.nan_guard:
+                bad = [s.request_id for i, s in enumerate(seqs)
+                       if not np.isfinite(logits_np[i]).all()]
+                if bad:
+                    raise _NonfiniteLogits(bad)
         return logits_np
 
+    def _device_step(self, fn, ids: np.ndarray, positions: np.ndarray,
+                     last_index: int, tables: np.ndarray, lens: np.ndarray,
+                     slots: np.ndarray, key):
+        """Host arrays in, host ``(next tokens, logits)`` and the device's
+        new caches out, one span a leg: ``h2d`` (the puts), ``dispatch``
+        (the jitted call until it returns; the step's PRNG-key split is
+        a ``dispatch`` span too), ``device_wait`` (until the
+        device is done — the copy below would wait for the same), and
+        ``logits_copy`` (a pure device-to-host copy by then)."""
+        reg = self._reg()
+        with self._phase("h2d"):
+            last = np.asarray(last_index, np.int32)
+            caches = self.cache.layer_caches(tables, lens, slots)
+            ids_d, positions_d, last_d = (jnp.asarray(ids),
+                                          jnp.asarray(positions),
+                                          jnp.asarray(last))
+            reg.counter("serve.h2d_bytes").inc(
+                ids.nbytes + positions.nbytes + last.nbytes
+                + tables.nbytes + lens.nbytes + slots.nbytes)
+        with self._phase("dispatch"):
+            nxt, logits, new_caches = fn(self._params, ids_d, positions_d,
+                                         last_d, caches, key)
+        with self._phase("device_wait"):
+            jax.block_until_ready((nxt, logits))
+        with self._phase("logits_copy"):
+            nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
+            reg.counter("serve.d2h_bytes").inc(
+                nxt_np.nbytes + logits_np.nbytes)
+        return nxt_np, logits_np, new_caches
+
     def _apply_prefill(self, seq: SequenceState, bucket: int, key):
-        ctx = seq.context()
-        L = len(ctx)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :L] = ctx
-        self._note_padding(L, bucket)
-        tables = self.cache.table_array([seq.request_id],
-                                        self.sched.max_blocks_per_seq)
-        lens = np.asarray([L], np.int32)
-        slots = self.cache.slot_array([seq.request_id], [0], bucket)
-        caches = self.cache.layer_caches(tables, lens, slots)
-        nxt, logits, new_caches = self._prefill_fn(bucket)(
-            self._params, jnp.asarray(ids), jnp.zeros((1,), jnp.int32),
-            jnp.asarray(L - 1, jnp.int32), caches, key)
-        nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
+        with self._phase("tables"):
+            ctx = seq.context()
+            L = len(ctx)
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :L] = ctx
+            self._note_padding(L, bucket)
+            tables = self.cache.table_array([seq.request_id],
+                                            self.sched.max_blocks_per_seq)
+            lens = np.asarray([L], np.int32)
+            slots = self.cache.slot_array([seq.request_id], [0], bucket)
+            fn = self._prefill_fn(bucket)
+        nxt_np, logits_np, new_caches = self._device_step(
+            fn, ids, np.zeros((1,), np.int32), L - 1, tables, lens, slots,
+            key)
         self._proven.add(("prefill", bucket))
         logits_np = self._apply_fault("prefill", [seq], logits_np)
         return nxt_np, logits_np, new_caches
 
     def _apply_decode(self, seqs: List[SequenceState], key):
-        B = self.max_seqs
-        enforce(len(seqs) <= B, f"{len(seqs)} decode rows > max_seqs {B}")
-        self._note_padding(len(seqs), B)
-        sids = [s.request_id for s in seqs] + \
-            [_PAD_SEQ] * (B - len(seqs))
-        ids = np.zeros((B, 1), np.int32)
-        positions = np.zeros((B,), np.int32)
-        lens = np.zeros((B,), np.int32)
-        starts = [-1] * B
-        for i, s in enumerate(seqs):
-            enforce(s.pending is not None,
-                    f"{s.request_id}: decode row without a pending token")
-            ids[i, 0] = s.pending
-            positions[i] = s.computed_len
-            lens[i] = s.computed_len + 1      # includes the written token
-            starts[i] = s.computed_len
-        tables = self.cache.table_array(sids,
-                                        self.sched.max_blocks_per_seq)
-        slots = self.cache.slot_array(sids, starts, 1)
-        caches = self.cache.layer_caches(tables, lens, slots)
-        nxt, logits, new_caches = self._decode_fn()(
-            self._params, jnp.asarray(ids), jnp.asarray(positions),
-            jnp.asarray(0, jnp.int32), caches, key)
-        nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
+        with self._phase("tables"):
+            B = self.max_seqs
+            enforce(len(seqs) <= B,
+                    f"{len(seqs)} decode rows > max_seqs {B}")
+            self._note_padding(len(seqs), B)
+            sids = [s.request_id for s in seqs] + \
+                [_PAD_SEQ] * (B - len(seqs))
+            ids = np.zeros((B, 1), np.int32)
+            positions = np.zeros((B,), np.int32)
+            lens = np.zeros((B,), np.int32)
+            starts = [-1] * B
+            for i, s in enumerate(seqs):
+                enforce(s.pending is not None,
+                        f"{s.request_id}: decode row without a pending "
+                        "token")
+                ids[i, 0] = s.pending
+                positions[i] = s.computed_len
+                lens[i] = s.computed_len + 1  # includes the written token
+                starts[i] = s.computed_len
+            tables = self.cache.table_array(sids,
+                                            self.sched.max_blocks_per_seq)
+            slots = self.cache.slot_array(sids, starts, 1)
+            fn = self._decode_fn()
+        nxt_np, logits_np, new_caches = self._device_step(
+            fn, ids, positions, 0, tables, lens, slots, key)
         self._proven.add("decode")
         logits_np = self._apply_fault("decode", seqs, logits_np)
         return nxt_np, logits_np, new_caches
@@ -671,35 +734,36 @@ class ServingEngine:
                 raise                  # never ran: not a request's fault
             self._quarantine_step("prefill", [seq], e, key)
             return []
-        self.cache.update_pages(new_caches)
-        self.sched.mark_prefilled(seq)
-        reg = self._reg()
-        reg.counter("serve.prefills").inc()
-        if seq.trace_id is not None:
-            # the (re-)prefill plus the queue wait before it; a
-            # recompute's wait is attributed to its cause, not "queue"
-            comp = _RESUME_COMPONENT.get(seq.resume_why, "prefill")
-            t_q0 = seq.trace_enqueued
-            if t_q0 is None:
-                t_q0 = seq.arrival
-            if t_prefill0 > t_q0:
-                requesttrace.emit_span(
-                    reg, seq.trace_id, seq.request_id, "queue",
-                    "queue" if seq.resume_why is None else comp,
-                    t_q0, t_prefill0, self._proc)
-            requesttrace.emit_span(reg, seq.trace_id, seq.request_id,
-                                   "prefill", comp, t_prefill0,
-                                   float(self.clock()), self._proc,
-                                   bucket=plan.bucket)
-        seq.resume_why = None
-        seq.trace_enqueued = None
-        if seq.pending is not None:
-            # recompute prefill after preemption: the next token was
-            # already sampled (and streamed) before eviction — only the
-            # KV was rebuilt; nothing new to emit
-            return []
-        return [self._accept_token(seq, int(nxt_np[0]),
-                                   logits_np[0], first=True)]
+        with self._phase("accept"):
+            self.cache.update_pages(new_caches)
+            self.sched.mark_prefilled(seq)
+            reg = self._reg()
+            reg.counter("serve.prefills").inc()
+            if seq.trace_id is not None:
+                # the (re-)prefill plus the queue wait before it; a
+                # recompute's wait is attributed to its cause, not "queue"
+                comp = _RESUME_COMPONENT.get(seq.resume_why, "prefill")
+                t_q0 = seq.trace_enqueued
+                if t_q0 is None:
+                    t_q0 = seq.arrival
+                if t_prefill0 > t_q0:
+                    requesttrace.emit_span(
+                        reg, seq.trace_id, seq.request_id, "queue",
+                        "queue" if seq.resume_why is None else comp,
+                        t_q0, t_prefill0, self._proc)
+                requesttrace.emit_span(reg, seq.trace_id, seq.request_id,
+                                       "prefill", comp, t_prefill0,
+                                       float(self.clock()), self._proc,
+                                       bucket=plan.bucket)
+            seq.resume_why = None
+            seq.trace_enqueued = None
+            if seq.pending is not None:
+                # recompute prefill after preemption: the next token was
+                # already sampled (and streamed) before eviction — only
+                # the KV was rebuilt; nothing new to emit
+                return []
+            return [self._accept_token(seq, int(nxt_np[0]),
+                                       logits_np[0], first=True)]
 
     def _run_decode(self, plan: StepPlan) -> List[Dict[str, Any]]:
         seqs = plan.seqs
@@ -720,20 +784,22 @@ class ServingEngine:
             # attention makes the survivors' logits (and, greedy,
             # their tokens) identical to the un-faulted step
             return self._run_decode(StepPlan("decode", survivors))
-        self.cache.update_pages(new_caches)
-        reg = self._reg()
-        reg.counter("serve.decode_steps").inc()
-        reg.histogram("serve.decode_batch").observe(float(len(seqs)))
-        events = []
-        for i, s in enumerate(seqs):
-            self.sched.mark_decoded(s)
-            events.append(self._accept_token(s, int(nxt_np[i]),
-                                             logits_np[i], first=False))
-        # one batch-level decode span; the assembler amortizes the step
-        # across its residents to produce per-request decode time
-        requesttrace.emit_decode_span(
-            reg, [(s.request_id, s.trace_id) for s in seqs], len(seqs),
-            t0, float(self.clock()), self._proc)
+        with self._phase("accept"):
+            self.cache.update_pages(new_caches)
+            reg = self._reg()
+            reg.counter("serve.decode_steps").inc()
+            reg.histogram("serve.decode_batch").observe(float(len(seqs)))
+            events = []
+            for i, s in enumerate(seqs):
+                self.sched.mark_decoded(s)
+                events.append(self._accept_token(s, int(nxt_np[i]),
+                                                 logits_np[i],
+                                                 first=False))
+            # one batch-level decode span; the assembler amortizes the
+            # step across its residents to produce per-request decode time
+            requesttrace.emit_decode_span(
+                reg, [(s.request_id, s.trace_id) for s in seqs], len(seqs),
+                t0, float(self.clock()), self._proc)
         return events
 
     # -- poisoned-request quarantine ---------------------------------------
@@ -770,25 +836,26 @@ class ServingEngine:
         """Fault-boundary handler: identify the culprit rows, evict each
         with ``reason="poisoned"`` and a durable record, return the
         surviving sequences for replay."""
-        t0 = float(self.clock())
-        if isinstance(error, _NonfiniteLogits):
-            bad = set(error.request_ids)
-            culprits = [s for s in seqs if s.request_id in bad]
-        elif kind == "prefill" or len(seqs) == 1:
-            culprits = list(seqs)
-        else:
-            culprits = self._bisect(seqs, key)
-        for seq in culprits:
-            self._quarantine(seq, error, kind)
-        # the bisect stalls every row in the faulted batch — attribute
-        # that time to quarantine for culprits and survivors alike
-        t1 = float(self.clock())
-        reg = self._reg()
-        for seq in seqs:
-            requesttrace.emit_span(reg, seq.trace_id, seq.request_id,
-                                   "quarantine_bisect", "quarantine",
-                                   t0, t1, self._proc)
-        return [s for s in seqs if s not in culprits]
+        with self._phase("quarantine"):
+            t0 = float(self.clock())
+            if isinstance(error, _NonfiniteLogits):
+                bad = set(error.request_ids)
+                culprits = [s for s in seqs if s.request_id in bad]
+            elif kind == "prefill" or len(seqs) == 1:
+                culprits = list(seqs)
+            else:
+                culprits = self._bisect(seqs, key)
+            for seq in culprits:
+                self._quarantine(seq, error, kind)
+            # the bisect stalls every row in the faulted batch — attribute
+            # that time to quarantine for culprits and survivors alike
+            t1 = float(self.clock())
+            reg = self._reg()
+            for seq in seqs:
+                requesttrace.emit_span(reg, seq.trace_id, seq.request_id,
+                                       "quarantine_bisect", "quarantine",
+                                       t0, t1, self._proc)
+            return [s for s in seqs if s not in culprits]
 
     def _quarantine(self, seq: SequenceState, error: Exception,
                     kind: str) -> None:
@@ -1192,8 +1259,15 @@ class ServingEngine:
         resilience section)."""
         c = self.sched.counts()
         leak = self.cache.leak_report()
+        # where engine.step's host time went, from the span tree (which is
+        # the process's: engines that share a process share these sums)
+        phases = {path.split("/")[1]: row
+                  for path, row in span_tree_totals().items()
+                  if path.startswith("engine.step/")
+                  and path.count("/") == 1}
         return {
             "steps": self.steps,
+            "phases": phases,
             "replica_id": self.replica_id,
             "queue_depth": self.sched.queue_depth,
             "waiting": c["waiting"],

@@ -365,49 +365,49 @@ class GPTDecoderLayer(Layer):
         """ISSUE 7 hot path: the two halves of the block as fused ops
         (ops/fused_block.py) — Pallas kernels on TPU, the jnp composition
         as the CPU default and interpret oracle."""
-        from ..ops.fused_block import fused_attention_block, fused_ffn_block
+        from ..ops.fused_block import fused_attention_block
         c = self.config
         a = self.attn
-        x = fused_attention_block(
-            x, a.qkv_proj.weight, a.qkv_proj.bias, a.out_proj.weight,
-            a.out_proj.bias, self.ln_1.weight, self.ln_1.bias,
-            num_heads=c.num_heads, causal=True,
-            epsilon=c.layer_norm_epsilon, attn_dropout=c.attention_dropout,
-            hidden_dropout=c.hidden_dropout, training=self.training)
-        m = self.mlp
-        x = fused_ffn_block(
-            x, m.fc_in.weight, m.fc_in.bias, m.fc_out.weight, m.fc_out.bias,
-            self.ln_2.weight, self.ln_2.bias, activation="gelu",
-            dropout2=c.hidden_dropout, epsilon=c.layer_norm_epsilon,
-            training=self.training)
-        return x, jnp.zeros((), jnp.float32)
+        with jax.named_scope("attn"):
+            x = fused_attention_block(
+                x, a.qkv_proj.weight, a.qkv_proj.bias, a.out_proj.weight,
+                a.out_proj.bias, self.ln_1.weight, self.ln_1.bias,
+                num_heads=c.num_heads, causal=True,
+                epsilon=c.layer_norm_epsilon,
+                attn_dropout=c.attention_dropout,
+                hidden_dropout=c.hidden_dropout, training=self.training)
+        return self._ffn_fused(x), jnp.zeros((), jnp.float32)
+
+    def _ffn_fused(self, x):
+        from ..ops.fused_block import fused_ffn_block
+        c, m = self.config, self.mlp
+        with jax.named_scope("mlp"):
+            return fused_ffn_block(
+                x, m.fc_in.weight, m.fc_in.bias, m.fc_out.weight,
+                m.fc_out.bias, self.ln_2.weight, self.ln_2.bias,
+                activation="gelu", dropout2=c.hidden_dropout,
+                epsilon=c.layer_norm_epsilon, training=self.training)
 
     def _fused_cache_forward(self, x, cache):
         """Fused decode step (ISSUE 7): covers both the fixed-shape
         (k_buf, v_buf, used) cache and the PR 6 paged cache."""
         from ..inference.kv_cache import PagedLayerCache
-        from ..ops.fused_block import (fused_attention_block_kvcache,
-                                       fused_ffn_block)
+        from ..ops.fused_block import fused_attention_block_kvcache
         c = self.config
-        if isinstance(cache, PagedLayerCache):
-            x, new_cache = self.attn.fused_paged_forward(x, self.ln_1,
-                                                         cache)
-        else:
-            k_buf, v_buf, used = cache
-            a = self.attn
-            x, k_buf, v_buf = fused_attention_block_kvcache(
-                x, a.qkv_proj.weight, a.qkv_proj.bias, a.out_proj.weight,
-                a.out_proj.bias, self.ln_1.weight, self.ln_1.bias,
-                k_buf, v_buf, used, num_heads=c.num_heads,
-                epsilon=c.layer_norm_epsilon)
-            new_cache = (k_buf, v_buf, used + x.shape[1])
-        m = self.mlp
-        x = fused_ffn_block(
-            x, m.fc_in.weight, m.fc_in.bias, m.fc_out.weight, m.fc_out.bias,
-            self.ln_2.weight, self.ln_2.bias, activation="gelu",
-            dropout2=c.hidden_dropout, epsilon=c.layer_norm_epsilon,
-            training=self.training)
-        return x, new_cache
+        with jax.named_scope("attn"):
+            if isinstance(cache, PagedLayerCache):
+                x, new_cache = self.attn.fused_paged_forward(x, self.ln_1,
+                                                             cache)
+            else:
+                k_buf, v_buf, used = cache
+                a = self.attn
+                x, k_buf, v_buf = fused_attention_block_kvcache(
+                    x, a.qkv_proj.weight, a.qkv_proj.bias,
+                    a.out_proj.weight, a.out_proj.bias, self.ln_1.weight,
+                    self.ln_1.bias, k_buf, v_buf, used,
+                    num_heads=c.num_heads, epsilon=c.layer_norm_epsilon)
+                new_cache = (k_buf, v_buf, used + x.shape[1])
+        return self._ffn_fused(x), new_cache
 
     def _block(self, x):
         """Returns (x, aux): MoE aux losses are collected INSIDE so they
@@ -417,20 +417,37 @@ class GPTDecoderLayer(Layer):
             return self._block_fused(x)
         from ..distributed.moe import collect_aux_losses
         with collect_aux_losses() as aux_items:
-            x = x + self.attn(self.ln_1(x))
-            x = x + self.mlp(self.ln_2(x))
+            x, _ = self._attn_mlp(x)
         aux = sum(aux_items) if aux_items else jnp.zeros((), jnp.float32)
         return x, aux
 
+    def _attn_mlp(self, x, cache=None):
+        """The unfused block, each part under the scope that names it in
+        the trace and in the lowered HLO (``gpt.block/attn`` ...)."""
+        with jax.named_scope("ln"):
+            h = self.ln_1(x)
+        with jax.named_scope("attn"):
+            if cache is None:
+                h, new_cache = self.attn(h), None
+            else:
+                h, new_cache = self.attn(h, cache=cache)
+            x = x + h
+        with jax.named_scope("ln"):
+            h = self.ln_2(x)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(h)
+        return x, new_cache
+
     def forward(self, x, cache=None):
+        with jax.named_scope("gpt.block"):
+            return self._forward(x, cache)
+
+    def _forward(self, x, cache):
         from ..distributed.moe import _record_aux
         if cache is not None:
             if self._fused_block_ok():
                 return self._fused_cache_forward(x, cache)
-            h, new_cache = self.attn(self.ln_1(x), cache=cache)
-            x = x + h
-            x = x + self.mlp(self.ln_2(x))
-            return x, new_cache
+            return self._attn_mlp(x, cache)
         if self._use_recompute:
             x, aux = recompute(self._block, x, policy=self._recompute_policy)
         else:
@@ -470,13 +487,14 @@ class GPTModel(Layer):
         off = jnp.asarray(position_offset)
         pos = (off[:, None] + jnp.arange(s) if off.ndim
                else off + jnp.arange(s))
-        x = self.wte(input_ids) + self.wpe.value[pos]
-        if c.dtype != "float32":
-            x = x.astype(c.dtype)
-        x = self.drop(x)
-        seq_ax = ("sp" if c.sequence_parallel or c.context_parallel
-                  else None)
-        x = shard_constraint(x, "dp", seq_ax, None)
+        with jax.named_scope("gpt.embed"):
+            x = self.wte(input_ids) + self.wpe.value[pos]
+            if c.dtype != "float32":
+                x = x.astype(c.dtype)
+            x = self.drop(x)
+            seq_ax = ("sp" if c.sequence_parallel or c.context_parallel
+                      else None)
+            x = shard_constraint(x, "dp", seq_ax, None)
         new_caches = []
         for i, layer in enumerate(self.h):
             if caches is not None:
@@ -484,7 +502,8 @@ class GPTModel(Layer):
                 new_caches.append(kv)
             else:
                 x = layer(x)
-        x = self.ln_f(x)
+        with jax.named_scope("gpt.ln_f"):
+            x = self.ln_f(x)
         if caches is not None:
             return x, new_caches
         return x
@@ -503,6 +522,10 @@ class GPTForCausalLM(Layer):
         from ..distributed.moe import collect_aux_losses
         with collect_aux_losses() as aux_losses:
             hidden = self.gpt(input_ids)        # (b, s, h)
+        with jax.named_scope("gpt.head_loss"):
+            return self._head_loss(hidden, labels, aux_losses)
+
+    def _head_loss(self, hidden, labels, aux_losses):
         # tied head: logits = h @ wte.T → vocab-sharded over mp
         c = self.config
         table = self.gpt.wte.weight.value.astype(hidden.dtype)
@@ -550,8 +573,9 @@ class GPTForCausalLM(Layer):
         fused_attention_op.cc:235)."""
         hidden, new_caches = self.gpt(
             input_ids, position_offset=position_offset, caches=caches)
-        table = self.gpt.wte.weight.value.astype(hidden.dtype)
-        logits = jnp.einsum("bsh,vh->bsv", hidden[:, -1:], table)
+        with jax.named_scope("gpt.head"):
+            table = self.gpt.wte.weight.value.astype(hidden.dtype)
+            logits = jnp.einsum("bsh,vh->bsv", hidden[:, -1:], table)
         return logits, new_caches
 
     def serving_step(self, input_ids, caches, position_offset, last_index):
@@ -566,11 +590,12 @@ class GPTForCausalLM(Layer):
         """
         hidden, new_caches = self.gpt(
             input_ids, position_offset=position_offset, caches=caches)
-        b = hidden.shape[0]
-        idx = jnp.broadcast_to(jnp.asarray(last_index, jnp.int32), (b,))
-        h_last = hidden[jnp.arange(b), idx]              # (b, h)
-        table = self.gpt.wte.weight.value.astype(h_last.dtype)
-        logits = jnp.einsum("bh,vh->bv", h_last, table)
+        with jax.named_scope("gpt.head"):
+            b = hidden.shape[0]
+            idx = jnp.broadcast_to(jnp.asarray(last_index, jnp.int32), (b,))
+            h_last = hidden[jnp.arange(b), idx]              # (b, h)
+            table = self.gpt.wte.weight.value.astype(h_last.dtype)
+            logits = jnp.einsum("bh,vh->bv", h_last, table)
         return logits, new_caches
 
     def make_caches(self, batch_size: int, max_length: int):

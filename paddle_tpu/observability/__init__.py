@@ -8,9 +8,10 @@ they all report through:
 
 - :mod:`registry` — process-wide counters / gauges / bounded-reservoir
   histograms, thread-safe, near-zero cost when no sink is attached;
-- :mod:`tracing` — nesting ``span()`` context managers that feed the
-  profiler's host annotations, an aggregated span tree, and a
-  chrome-trace exporter;
+- :mod:`tracing` — nesting ``span()`` context managers (with
+  attributes, on ``time.perf_counter()``) that feed the profiler's host
+  annotations, an aggregated span tree, ``spans_between`` for a
+  benchmark's readers, and a chrome-trace exporter;
 - :mod:`sinks` — the run-scoped JSONL ``MetricsWriter`` (fsync'd via
   ``utils/fsio``), a periodic stderr summary line, and a Prometheus
   textfile exporter;
@@ -64,7 +65,6 @@ flow only when a sink is attached — by the run supervisor under its
 
 Env knobs: ``PTPU_METRICS_DIR`` (auto-attach a JSONL writer),
 ``PTPU_METRICS_INTERVAL`` (sink flush/summary period, default 30s),
-``PTPU_TRACE_BUFFER`` (span buffer bound, default 65536),
 ``PTPU_MEM_SAMPLE_EVERY`` (HBM watermark cadence, default 16 steps).
 The persistent compile cache (:mod:`compilecache`) is placed by jax's own
 ``JAX_COMPILATION_CACHE_DIR``, else at ``.jax_cache`` in the checkout.
@@ -95,16 +95,16 @@ from .roofline import (RooflineObservatory, capture_window, degraded_block,
                        gap_budget, get_observatory, parse_hlo_ops)
 from .sinks import (MetricsWriter, PrometheusTextfile, StderrSummary,
                     default_interval, metrics_dir, render_prometheus)
-from .tracing import (export_chrome_trace, reset_tracing, span,
-                      span_tree_totals, trace_events)
+from .tracing import (dropped, export_chrome_trace, reset_tracing, span,
+                      span_tree_totals, spans_between, trace_events)
 
 __all__ = [
     # registry
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "split_labels",
     # tracing
-    "span", "span_tree_totals", "export_chrome_trace", "trace_events",
-    "reset_tracing",
+    "span", "span_tree_totals", "spans_between", "dropped",
+    "export_chrome_trace", "trace_events", "reset_tracing",
     # sinks
     "MetricsWriter", "StderrSummary", "PrometheusTextfile", "metrics_dir",
     "default_interval", "render_prometheus",

@@ -51,12 +51,10 @@ CLI::
 """
 from __future__ import annotations
 
-import functools
 import json
 import math
 import os
 import re
-import time
 import uuid
 import zlib
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -67,7 +65,7 @@ from .aggregate import read_worker_stream
 __all__ = ["TRACE_REQUESTS_ENV", "TRACE_SAMPLE_ENV", "tracing_enabled",
            "sample_fraction", "sampled", "mint_trace_id", "emit_span",
            "emit_decode_span", "emit_stall_span", "component_bucket",
-           "emission_cost", "TraceAssembler",
+           "TraceAssembler",
            "assemble_run", "tail_latency_attribution",
            "chrome_trace_events", "export_chrome_trace", "main"]
 
@@ -141,55 +139,6 @@ def component_bucket(component: str) -> str:
 
 
 # -- emission --------------------------------------------------------------
-class _EmissionCost:
-    """Wall-clock accounting of the span-emission hot path (record
-    construction + sink writes).  Off by default — when enabled, every
-    ``emit_*`` call below adds its duration here, giving a direct
-    measurement of what tracing costs the serving loop.  The bench's
-    ``serve_fleet`` scenario uses this to price tracing against step
-    p50: at millisecond-scale steps, A/B run differencing has a noise
-    floor far above the 1% budget, while direct accounting resolves
-    microseconds.  Single accumulator, no lock — intended for
-    single-threaded bench harnesses, not production concurrency."""
-
-    def __init__(self) -> None:
-        self.enabled = False
-        self.seconds = 0.0
-        self.count = 0
-
-    def start(self) -> None:
-        self.enabled = True
-        self.seconds = 0.0
-        self.count = 0
-
-    def stop(self) -> None:
-        self.enabled = False
-
-    def add(self, dt: float) -> None:
-        self.seconds += dt
-        self.count += 1
-
-
-#: process-wide emission-cost meter (see :class:`_EmissionCost`)
-emission_cost = _EmissionCost()
-
-
-def _costed(fn):
-    """Route a function through :data:`emission_cost` when metering is
-    on; zero-branch passthrough otherwise."""
-    @functools.wraps(fn)
-    def wrap(*args, **kwargs):
-        if not emission_cost.enabled:
-            return fn(*args, **kwargs)
-        t0 = time.perf_counter()
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            emission_cost.add(time.perf_counter() - t0)
-    return wrap
-
-
-@_costed
 def emit_span(registry, trace_id: Optional[str], request_id: str,
               name: str, component: str, t0: float, t1: float,
               proc: str, **fields) -> None:
@@ -207,7 +156,6 @@ def emit_span(registry, trace_id: Optional[str], request_id: str,
                   **fields)
 
 
-@_costed
 def emit_decode_span(registry, requests: Sequence[Tuple[str, Optional[str]]],
                      residents: int, t0: float, t1: float,
                      proc: str) -> None:
@@ -226,7 +174,6 @@ def emit_decode_span(registry, requests: Sequence[Tuple[str, Optional[str]]],
                   requests=traced)
 
 
-@_costed
 def emit_stall_span(registry, requests: Sequence[Tuple[str, Optional[str]]],
                     t0: float, t1: float, proc: str,
                     component: str = "stall", cause: str = "") -> None:

@@ -1,22 +1,27 @@
-"""Typed span tracing (ISSUE 3).
+"""Typed span tracing (ISSUE 3; repaired for the serving loop, ISSUE 26).
 
 ``with span("data_load"): ...`` / ``with span("step"): ...`` nest on a
 per-thread stack; a nested span's identity is its *path* ("step/dispatch"),
-so the same leaf name under different parents stays distinguishable.
+so the same leaf name under different parents stays distinguishable and
+the path names the span that caused it.  Keyword attributes
+(``span("engine.step", step=7)``, or ``sp.set(kind="decode")`` once they
+are known) stay with the record: spans of one step share its ``step``.
 Every span feeds three consumers at once:
 
 - the existing :mod:`paddle_tpu.profiler` host-annotation machinery
-  (``RecordEvent`` → jax TraceAnnotation + the flat host table), so spans
-  land inside the XPlane device timeline exactly like hand-written
-  annotations;
+  (``RecordEvent`` → jax TraceAnnotation named by the path + the flat
+  host table), so spans land inside the XPlane device timeline exactly
+  like hand-written annotations;
 - an aggregated **span tree** (path → count / total ms / self ms, where
   self excludes child spans) — surfaced by ``Profiler.summary()``;
-- a bounded in-memory buffer of completed spans, exportable as a
-  chrome://tracing JSON via :func:`export_chrome_trace`.
+- a bounded in-memory buffer of completed spans on
+  ``time.perf_counter()`` — the clock a benchmark harness stamps its own
+  spans with, so the two join — read by :func:`spans_between` and
+  exportable as a chrome://tracing JSON via :func:`export_chrome_trace`.
 
-All three are process-wide and thread-safe; the buffer is bounded
-(``PTPU_TRACE_BUFFER`` spans, default 65536) so tracing never grows
-without bound on long runs.
+All three are process-wide and thread-safe; the buffer holds the newest
+``BUFFER_SPANS`` spans, and :func:`dropped` says when a reader's window is
+no longer whole.
 """
 from __future__ import annotations
 
@@ -25,42 +30,53 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import fsio
 
-__all__ = ["span", "span_tree_totals", "export_chrome_trace",
-           "reset_tracing", "trace_events"]
+__all__ = ["span", "span_tree_totals", "spans_between", "dropped",
+           "export_chrome_trace", "reset_tracing", "trace_events"]
 
-TRACE_BUFFER_ENV = "PTPU_TRACE_BUFFER"
+BUFFER_SPANS = 65536
+# perf_counter() + this = time.time(): taken once, so exported wall times
+# keep the spacing the monotonic clock measured
+_WALL_OFFSET = time.time() - time.perf_counter()
 
+_RecordEvent = None                # paddle_tpu.profiler's, on first use
 _tls = threading.local()
 _lock = threading.Lock()
 # path -> [count, total_s, self_s]
 _tree: Dict[str, list] = {}
-_buffer: deque = deque(
-    maxlen=int(os.environ.get(TRACE_BUFFER_ENV, "65536")))
+# (path, t0, dur, tid, attrs) in order of completion, t0 on perf_counter()
+_buffer: deque = deque(maxlen=BUFFER_SPANS)
+_dropped = [0, float("-inf")]      # evicted spans, end of the newest one
 
 
 class span:
     """Nesting context manager timing one region of host code.
 
-    >>> with span("step"):
+    >>> with span("step", step=3) as sp:
     ...     with span("dispatch"):
     ...         ...        # recorded as "step/dispatch"
+    ...     sp.set(kind="decode")
 
     ``elapsed`` (seconds) is available after exit — callers that need the
     number (hapi's step breakdown) read it instead of re-timing.
     """
 
-    __slots__ = ("name", "path", "elapsed", "_t0", "_wall0", "_child",
+    __slots__ = ("name", "path", "elapsed", "attrs", "_t0", "_child",
                  "_event")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **attrs: Any):
         self.name = str(name)
         self.path = self.name
         self.elapsed = 0.0
+        self.attrs = attrs
         self._child = 0.0
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes learned while the span is open."""
+        self.attrs.update(attrs)
 
     def __enter__(self) -> "span":
         stack = getattr(_tls, "stack", None)
@@ -71,10 +87,11 @@ class span:
         stack.append(self)
         # feed the profiler's host-annotation machinery (TraceAnnotation
         # into the device timeline + the flat host table)
-        from .. import profiler
-        self._event = profiler.RecordEvent(self.path)
+        global _RecordEvent
+        if _RecordEvent is None:
+            from ..profiler import RecordEvent as _RecordEvent
+        self._event = _RecordEvent(self.path)
         self._event.begin()
-        self._wall0 = time.time()
         self._t0 = time.perf_counter()
         return self
 
@@ -83,7 +100,12 @@ class span:
         self._event.end()
         self.elapsed = dt
         stack = _tls.stack
-        stack.pop()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            # an asynchronous exception (the watchdog's) cut a child off
+            # between its push and its ``with``: it goes with its parent
+            del stack[stack.index(self):]
         if stack:
             stack[-1]._child += dt
         self_s = max(0.0, dt - self._child)
@@ -96,7 +118,11 @@ class span:
                 row[0] += 1
                 row[1] += dt
                 row[2] += self_s
-            _buffer.append((self.path, self._wall0, dt, tid))
+            if len(_buffer) == _buffer.maxlen:
+                old = _buffer[0]
+                _dropped[0] += 1
+                _dropped[1] = max(_dropped[1], old[1] + old[2])
+            _buffer.append((self.path, self._t0, dt, tid, self.attrs))
 
 
 def span_tree_totals(reset: bool = False) -> Dict[str, Dict[str, float]]:
@@ -111,14 +137,35 @@ def span_tree_totals(reset: bool = False) -> Dict[str, Dict[str, float]]:
     return out
 
 
+def spans_between(t0: float, t1: float
+                  ) -> List[Tuple[str, float, float, Dict[str, Any]]]:
+    """The buffered spans that overlap ``[t0, t1]`` (``perf_counter()``
+    seconds), oldest first, as ``(path, start, end, attrs)``.  Ask
+    :func:`dropped` whether the buffer still holds all of them."""
+    with _lock:
+        items = list(_buffer)
+    return [(path, s, s + dur, attrs) for path, s, dur, _, attrs in items
+            if s < t1 and s + dur > t0]
+
+
+def dropped(since: float = float("-inf")) -> int:
+    """How many spans the bounded buffer has let go, if any of them ended
+    after ``since`` (``perf_counter()`` seconds); else 0.  Nonzero means a
+    window that opens at ``since`` is no longer whole."""
+    with _lock:
+        return _dropped[0] if _dropped[1] > since else 0
+
+
 def trace_events() -> list:
-    """The buffered completed spans as chrome trace events (µs units)."""
+    """The buffered completed spans as chrome trace events (µs units,
+    wall clock)."""
     with _lock:
         items = list(_buffer)
     pid = os.getpid()
-    return [{"name": path, "ph": "X", "ts": wall0 * 1e6, "dur": dur * 1e6,
-             "pid": pid, "tid": tid}
-            for path, wall0, dur, tid in items]
+    return [{"name": path, "ph": "X", "ts": (t0 + _WALL_OFFSET) * 1e6,
+             "dur": dur * 1e6, "pid": pid, "tid": tid,
+             **({"args": attrs} if attrs else {})}
+            for path, t0, dur, tid, attrs in items]
 
 
 def export_chrome_trace(path: str, reset: bool = False) -> int:
@@ -139,6 +186,7 @@ def reset_tracing() -> None:
     with _lock:
         _tree.clear()
         _buffer.clear()
+        _dropped[:] = [0, float("-inf")]
 
 
 def current_span() -> Optional[Any]:

@@ -148,7 +148,13 @@ class Optimizer:
 
         ``lr`` overrides the schedule (used by the stateful path, where the
         paddle convention is that the user drives the scheduler's .step() —
-        typically per epoch — rather than the optimizer's iteration count)."""
+        typically per epoch — rather than the optimizer's iteration count).
+        The update's operations carry the scope ``<class>.update``
+        (``adamw.update``) in the trace and the lowered HLO."""
+        with jax.named_scope(type(self).__name__.lower() + ".update"):
+            return self._apply_gradients(grads, params, state, lr)
+
+    def _apply_gradients(self, grads, params, state, lr):
         step = state["step"] + 1
         lr_t = jnp.asarray(lr, jnp.float32) if lr is not None \
             else self._lr_at(step - 1)

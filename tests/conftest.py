@@ -27,8 +27,14 @@ jax.config.update("jax_default_matmul_precision", "highest")
 @pytest.fixture(autouse=True)
 def _seed_everything():
     import paddle_tpu as pt
+    from paddle_tpu.distributed.topology import set_hybrid_communicate_group
     pt.seed(1234)
     np.random.seed(1234)
+    # every test starts outside any fleet mesh: several files call
+    # fleet.init() and never clear it, and the order in which xdist deals
+    # files to a worker must not decide whether a later file's
+    # ServingEngine (single-host by design) can be built
+    set_hybrid_communicate_group(None)
     yield
 
 

@@ -252,3 +252,108 @@ class TestGPTGenerate:
         out2 = model.generate(prompt, max_new_tokens=20, temperature=1.0,
                               top_k=8, key=jax.random.key(0))
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+class TestGPTScopes:
+    """``jax.named_scope`` marks the owner of device operations in the
+    trace and in the lowered HLO (ISSUE 26): metadata only."""
+
+    @staticmethod
+    def lowered_names(hybrid: bool):
+        import re
+        from paddle_tpu import amp
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+        if hybrid:
+            strategy = fleet.DistributedStrategy()
+            strategy.hybrid_configs = {"dp_degree": 2, "mp_degree": 2,
+                                       "pp_degree": 1}
+            strategy.sharding = True
+            strategy.sharding_configs = {"stage": 1,
+                                         "shard_weight_update": True}
+            strategy.recompute = True
+            fleet.init(is_collective=True, strategy=strategy)
+        pt.seed(3)
+        cfg = GPTConfig(hidden_size=32, num_layers=2, num_heads=2,
+                        ffn_hidden_size=64, max_position_embeddings=64,
+                        vocab_size=128, hidden_dropout=0.0,
+                        attention_dropout=0.0)
+        model = GPTForCausalLM(cfg)
+        model.train()
+        opt = pt.optimizer.AdamW(learning_rate=1e-3, weight_decay=0.01)
+        if hybrid:
+            model = fleet.distributed_model(model)
+            opt = fleet.distributed_optimizer(opt)
+        params = model.state_dict()
+        state = opt.init(params)
+
+        def train_step(params, state, ids, key):
+            def loss_fn(p):
+                with fw_random.key_scope(key):
+                    with amp.auto_cast(level="O1", dtype="bfloat16"):
+                        loss, _ = model.apply(p, ids, labels=ids)
+                return loss
+            loss, grads = jax.value_and_grad(loss_fn)(params)
+            params, state = opt.apply_gradients(grads, params, state)
+            return loss, params, state
+
+        ids = jnp.zeros((4, 32), jnp.int32)
+        if hybrid:
+            ids = dist.shard_batch(ids)
+        text = jax.jit(train_step).lower(
+            params, state, ids, jax.random.PRNGKey(0)).as_text(
+                debug_info=True)
+        return set(re.findall(r'loc\("([^"]+)"', text))
+
+    @staticmethod
+    def has(names, *parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    def test_train_step_carries_the_scopes(self):
+        names = self.lowered_names(hybrid=False)
+        for scope in ("gpt.embed", "gpt.block", "gpt.ln_f",
+                      "gpt.head_loss", "adamw.update"):
+            assert self.has(names, "jit(train_step)/", scope), scope
+        for inner in ("ln", "attn", "mlp"):
+            assert self.has(names, f"jvp(gpt.block)/{inner}/"), inner
+        # backward names itself from the forward's scope
+        assert self.has(names, "transpose(jvp(gpt.block))/", "/attn/")
+        assert self.has(names, "transpose(jvp(gpt.head_loss))")
+        # the optimizer is no part of any model scope
+        assert not self.has(names, "gpt.", "adamw.update")
+
+    def test_hybrid_step_carries_the_parallel_scopes(self):
+        names = self.lowered_names(hybrid=True)
+        for scope in ("zero.pack", "zero.update", "zero.unpack"):
+            assert self.has(names, f"jit(train_step)/{scope}/"), scope
+        # a layer's scope sits inside the block part that calls it, through
+        # recompute; the contraction GSPMD completes with an all-reduce is
+        # the row-parallel one
+        assert self.has(names, "jvp(gpt.block)", "/attn/mp.column_parallel/")
+        assert self.has(names, "jvp(gpt.block)", "/mlp/mp.row_parallel/",
+                        "dot_general")
+        assert self.has(names, "checkpoint", "mp.row_parallel")
+
+    def test_serving_step_carries_the_scopes(self):
+        import re
+        from paddle_tpu.inference import ServingEngine
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+        from paddle_tpu.observability.registry import MetricsRegistry
+        pt.seed(3)
+        cfg = GPTConfig(hidden_size=32, num_layers=1, num_heads=2,
+                        ffn_hidden_size=64, max_position_embeddings=32,
+                        vocab_size=64, hidden_dropout=0.0,
+                        attention_dropout=0.0)
+        eng = ServingEngine(GPTForCausalLM(cfg), max_seqs=2,
+                            kv_block_size=4, registry=MetricsRegistry())
+        tables = np.zeros((2, 8), np.int32)
+        lens = np.ones((2,), np.int32)
+        slots = np.zeros((2, 1), np.int32)
+        caches = eng.cache.layer_caches(tables, lens, slots)
+        text = eng._build_step_fn().lower(
+            eng._params, jnp.zeros((2, 1), jnp.int32),
+            jnp.zeros((2,), jnp.int32), jnp.asarray(0, jnp.int32), caches,
+            jax.random.PRNGKey(0)).as_text(debug_info=True)
+        names = set(re.findall(r'loc\("([^"]+)"', text))
+        for scope in ("gpt.embed", "gpt.block/attn/", "gpt.block/mlp/",
+                      "gpt.head/"):
+            assert self.has(names, scope), scope

@@ -230,6 +230,95 @@ class TestTracing:
         tracing.reset_tracing()
         assert obs.span_tree_totals() == {}
         assert tracing.trace_events() == []
+        assert obs.spans_between(0.0, float("inf")) == []
+        assert obs.dropped() == 0
+
+    def test_a_child_cut_off_goes_with_its_parent(self):
+        # an asynchronous exception (the watchdog's StepTimeout) can land
+        # after a span's push and before its ``with``: no __exit__ follows
+        with obs.span("step"):
+            obs.span("child").__enter__()
+        assert tracing.current_span() is None
+        with obs.span("next"):
+            pass
+        tree = obs.span_tree_totals()
+        assert "next" in tree and "step" in tree
+        assert not [p for p in tree if p.endswith("/next")]
+
+    def test_spans_start_on_perf_counter(self):
+        before = time.perf_counter()
+        with obs.span("outer"):
+            with obs.span("inner"):
+                time.sleep(0.002)
+        after = time.perf_counter()
+        got = {p: (a, b) for p, a, b, _ in obs.spans_between(before, after)}
+        assert set(got) == {"outer", "outer/inner"}
+        (oa, ob), (ia, ib) = got["outer"], got["outer/inner"]
+        # the harness's clock, not the wall's: joinable without an offset
+        assert before <= oa <= ia < ib <= ob <= after
+        assert ib - ia >= 0.002
+        # the chrome export is the same spans on wall time
+        ev = {e["name"]: e for e in tracing.trace_events()}
+        assert abs(ev["outer"]["ts"] * 1e-6 - time.time()) < 60.0
+        assert abs(ev["outer"]["dur"] * 1e-6 - (ob - oa)) < 1e-6
+
+    def test_span_attributes_stay_with_the_record(self):
+        with obs.span("engine.step", step=7) as root:
+            with obs.span("schedule", step=7):
+                pass
+            root.set(kind="decode", rows=3)
+        spans = obs.spans_between(0.0, float("inf"))
+        by_path = {p: attrs for p, _, _, attrs in spans}
+        assert by_path["engine.step"] == {"step": 7, "kind": "decode",
+                                          "rows": 3}
+        assert by_path["engine.step/schedule"] == {"step": 7}
+        # oldest first: a child completes before its parent
+        assert [p for p, *_ in spans] == ["engine.step/schedule",
+                                          "engine.step"]
+        ev = {e["name"]: e for e in tracing.trace_events()}
+        assert ev["engine.step"]["args"]["kind"] == "decode"
+
+    def test_spans_between_keeps_what_overlaps(self):
+        marks = []
+        for name in ("a", "b", "c"):
+            with obs.span(name):
+                time.sleep(0.001)
+            marks.append(time.perf_counter())
+            time.sleep(0.001)       # every mark lies between two spans
+        names = lambda t0, t1: [p for p, *_ in obs.spans_between(t0, t1)]
+        assert names(0.0, float("inf")) == ["a", "b", "c"]
+        assert names(marks[0], marks[1]) == ["b"]
+        assert names(marks[2], float("inf")) == []
+        # overlap is enough: the window need not hold the whole span
+        (_, b0, b1, _), = obs.spans_between(marks[0], marks[1])
+        assert names((b0 + b1) / 2, marks[1]) == ["b"]
+
+    def test_dropped_says_when_a_window_is_no_longer_whole(self,
+                                                           monkeypatch):
+        from collections import deque
+        monkeypatch.setattr(tracing, "_buffer", deque(maxlen=4))
+        for i in range(4):
+            with obs.span(f"s{i}"):
+                pass
+        assert obs.dropped() == 0
+        t_mid = time.perf_counter()
+        for i in range(4, 7):
+            with obs.span(f"s{i}"):
+                pass
+        # s0..s2 went; all of them ended before t_mid
+        assert [p for p, *_ in obs.spans_between(0.0, float("inf"))] == \
+            ["s3", "s4", "s5", "s6"]
+        assert obs.dropped() == 3
+        assert obs.dropped(0.0) == 3          # a window from 0 lost spans
+        assert obs.dropped(t_mid) == 0        # a window from t_mid is whole
+        with obs.span("s7"):
+            pass                              # pushes s3 out, which ended
+        assert obs.dropped(t_mid) == 0        # ... before t_mid too
+        with obs.span("s8"):
+            pass                              # s4 ended after t_mid
+        assert obs.dropped(t_mid) == 5
+        # the tree is not bounded by the buffer
+        assert len(obs.span_tree_totals()) == 9
 
 
 class TestMetricsWriter:

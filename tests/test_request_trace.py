@@ -133,35 +133,6 @@ class TestKnobs:
         assert not [r for r in cap.records
                     if r["kind"].startswith("trace.")]
 
-    def test_emission_cost_meter(self):
-        reg, cap = capture_registry()
-        cost = requesttrace.emission_cost
-        # off by default: emits are free of accounting
-        assert not cost.enabled
-        requesttrace.emit_span(reg, "t1", "r1", "prefill", "prefill",
-                               1.0, 2.0, "replica-0")
-        assert cost.count == 0 and cost.seconds == 0.0
-        cost.start()
-        try:
-            requesttrace.emit_span(reg, "t1", "r1", "decode", "decode",
-                                   2.0, 3.0, "replica-0")
-            requesttrace.emit_decode_span(reg, [("r1", "t1")], 2,
-                                          3.0, 4.0, "replica-0")
-            # no-op calls (untraced) are metered too — they are still
-            # hot-path cost the serving loop pays
-            requesttrace.emit_span(reg, None, "r2", "decode", "decode",
-                                   2.0, 3.0, "replica-0")
-        finally:
-            cost.stop()
-        assert cost.count == 3
-        assert cost.seconds > 0.0
-        # start() resets the accumulator
-        cost.start()
-        cost.stop()
-        assert cost.count == 0 and cost.seconds == 0.0
-        assert len([r for r in cap.records
-                    if r["kind"] == "trace.span"]) == 3
-
 
 # ---------------------------------------------------------------------------
 # assembler units

@@ -420,6 +420,106 @@ class TestServingEngine:
 
 
 # ---------------------------------------------------------------------------
+# Spans inside engine.step (ISSUE 26)
+# ---------------------------------------------------------------------------
+PHASES = {"reap", "schedule", "tables", "h2d", "dispatch", "device_wait",
+          "logits_copy", "guard", "accept", "gauges"}
+
+
+class TestStepSpans:
+    @pytest.fixture()
+    def warm(self):
+        """A warm two-row engine (vocab 32, tables 8 wide) whose fault seam
+        sleeps, so that a step is long beside what its spans cost."""
+        from paddle_tpu.observability import tracing
+        reg = MetricsRegistry()
+        eng = ServingEngine(
+            tiny_model(), max_seqs=2, kv_block_size=4, registry=reg,
+            step_fault=lambda *a: time.sleep(0.05))
+        eng.generate([[1, 2, 3]], max_new_tokens=3)      # compiles both
+        tracing.reset_tracing()
+        return eng, reg, tracing
+
+    @staticmethod
+    def step_spans(tracing, step):
+        spans = [s for s in tracing.spans_between(0.0, float("inf"))
+                 if s[3].get("step") == step]
+        root, = [s for s in spans if s[0] == "engine.step"]
+        kids = [s for s in spans if s[0].startswith("engine.step/")]
+        return root, kids
+
+    @pytest.mark.parametrize("kind", ["prefill", "decode"])
+    def test_a_step_is_its_phases_and_nothing_else(self, warm, kind):
+        eng, reg, tracing = warm
+        eng.submit([1, 2, 3, 4, 5], max_new_tokens=4)
+        eng.step()                                       # the prefill
+        if kind == "decode":
+            eng.step()
+        step = eng.steps - 1
+        root, kids = self.step_spans(tracing, step)
+        assert {k[0].split("/", 1)[1] for k in kids} == PHASES
+        attrs = root[3]
+        assert attrs["kind"] == kind and attrs["rows"] == 1
+        assert attrs["bucket"] == (8 if kind == "prefill" else 0)
+        # children lie inside the root, in the order the step runs them
+        assert all(root[1] <= k[1] and k[2] <= root[2] for k in kids)
+        order = [k[0].split("/", 1)[1]
+                 for k in sorted(kids, key=lambda k: k[1])]
+        assert order == ["reap", "schedule", "dispatch", "tables", "h2d",
+                         "dispatch", "device_wait", "logits_copy", "guard",
+                         "accept", "accept", "gauges"]
+        # the phases' self times make up the step within 2%
+        total = root[2] - root[1]
+        covered = sum(k[2] - k[1] for k in kids)
+        assert total >= 0.05
+        assert 0.98 * total <= covered <= total
+
+    def test_stats_phases_and_byte_counters(self, warm):
+        eng, reg, tracing = warm
+        h2d, d2h = (reg.counter("serve.h2d_bytes"),
+                    reg.counter("serve.d2h_bytes"))
+        h0, d0 = h2d.value, d2h.value
+        eng.submit([1, 2, 3, 4, 5], max_new_tokens=3)
+        assert eng.run() == 3               # one prefill, two decode steps
+        phases = eng.stats()["phases"]
+        assert set(phases) == PHASES
+        tree = tracing.span_tree_totals()
+        for name, row in phases.items():
+            assert row == tree["engine.step/" + name]
+            want = {"dispatch": 6, "accept": 6}.get(name, 3)
+            assert row["count"] == want, name
+            assert 0 <= row["self_ms"] <= row["total_ms"]
+        root = tree["engine.step"]
+        assert root["self_ms"] <= 0.02 * root["total_ms"]
+        assert phases["guard"]["total_ms"] >= 3 * 50 * 0.99
+        # int32 everywhere: ids, positions, last index, tables (8 blocks a
+        # sequence), lengths, slots
+        i32 = 4
+        prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8)          # bucket 8, 1 row
+        decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2)       # 2 slots
+        assert h2d.value - h0 == prefill + 2 * decode
+        # next tokens (int32) and float32 logits over vocab 32
+        assert d2h.value - d0 == (4 + 4 * 32) + 2 * 2 * (4 + 4 * 32)
+
+    def test_a_quarantined_step_names_its_bisection(self):
+        from paddle_tpu.observability import tracing
+        inj = faults.poison_request(1, mode="raise", kinds=("decode",))
+        eng = ServingEngine(tiny_model(), max_seqs=4, kv_block_size=4,
+                            registry=MetricsRegistry(), step_fault=inj)
+        tracing.reset_tracing()
+        for p in ([1, 2, 3], [4, 5], [6, 7, 8]):
+            eng.submit(p, max_new_tokens=3)
+        eng.run(max_steps=50)
+        assert eng.stats()["resilience"]["poisoned"] == 1
+        tree = tracing.span_tree_totals()
+        assert tree["engine.step/quarantine"]["count"] == 1
+        # the probes' own phases nest under it, apart from the step's
+        assert tree["engine.step/quarantine/dispatch"]["count"] >= 1
+        assert "quarantine" in eng.stats()["phases"]
+        assert "recover" not in eng.stats()["phases"]
+
+
+# ---------------------------------------------------------------------------
 # Legacy facade routing
 # ---------------------------------------------------------------------------
 class TestLegacyFacadeRouting:
