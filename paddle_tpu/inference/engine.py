@@ -31,10 +31,12 @@ SLO telemetry rides the PR 3 registry: gauges ``serve.queue_depth`` /
 histograms ``serve.ttft_ms`` / ``serve.tpot_ms``, counters
 ``serve.tokens`` / ``serve.requests`` / ``serve.finished`` /
 ``serve.preemptions`` / ``serve.h2d_bytes`` / ``serve.d2h_bytes`` (what
-a step puts on the device and copies back).  Every ``step()`` is an
-``engine.step`` span of :mod:`observability.tracing` whose children name
-its phases (see :meth:`ServingEngine.step`); ``stats()["phases"]`` sums
-them.  ``start_status_server()`` exposes them on the
+a step puts on the device and copies back) / ``serve.pool_rebuilds``,
+gauge ``serve.kv_pool_bytes`` (the live page handles: one pool).  Every
+``step()`` is an ``engine.step`` span of :mod:`observability.tracing`
+whose children name its phases (see :meth:`ServingEngine.step`);
+``stats()["phases"]`` sums them.  ``start_status_server()`` exposes them
+on the
 PR 5 monitor (``/statusz`` serving section; ``/healthz`` goes 503 when
 the admission queue exceeds ``PTPU_SHED_QUEUE_DEPTH`` — load shedding).
 
@@ -57,6 +59,12 @@ same robustness treatment the training path earned:
   with ``reason="poisoned"`` plus a durable record under
   ``<run_dir>/serve_quarantine/``, and replays the step so every other
   request completes token-exact (decode rows are independent).  The
+  step program updates the KV pool in place (the pages are donated), so
+  a faulted or probed step leaves its page writes behind — harmless,
+  they land where the replay writes the same values — and a culprit's
+  blocks are zeroed before they are freed; a call that dies after
+  consuming the pool gets a zeroed pool and the running set goes back
+  through recompute-prefill (``serve.pool_rebuilds``).  The
   boundary only covers a step program that has run to completion at
   least once: an error from a program's first run (a lowering or
   compile failure, a missing device) is the engine's fault, not a
@@ -105,7 +113,8 @@ from ..observability.compilation import track_jit
 from ..observability.tracing import span, span_tree_totals
 from ..supervisor.watchdog import StepTimeout, Watchdog, guarded
 from ..utils import fsio
-from .kv_cache import PagedKVCache, default_kv_block_size
+from .kv_cache import (PagedKVCache, PagedLayerCache,
+                       default_kv_block_size)
 from .scheduler import (ContinuousBatchingScheduler, SequenceState,
                         StepPlan)
 
@@ -259,6 +268,7 @@ class ServingEngine:
         self._ids = itertools.count()
         self.steps = 0
         self.status_server = None
+        self._jit_step = None
         self._decode_tracked = None
         self._prefill_tracked: Dict[int, Callable] = {}
         # step programs ("decode", ("prefill", bucket)) that have run to
@@ -280,6 +290,7 @@ class ServingEngine:
         self._submit_order: List[str] = []
         self.quarantined: Dict[str, Dict[str, Any]] = {}
         self.watchdog_restarts = 0
+        self.pool_rebuilds = 0
         self.lifecycle_counts = {"deadline": 0, "cancelled": 0,
                                  "poisoned": 0, "spilled": 0}
         self._cb_dispatched = 0
@@ -328,10 +339,24 @@ class ServingEngine:
         return sub
 
     # -- jitted step functions --------------------------------------------
-    def _build_step_fn(self):
-        model, temperature = self.model, self.temperature
+    _STEP_ARGS = ("params", "ids", "positions", "last_index", "pages",
+                  "block_tables", "seq_lens", "slot_mapping", "key")
 
-        def fn(params, ids, positions, last_index, caches, key):
+    def _build_step_fn(self):
+        """The step program.  ``pages`` (per layer ``(k, v)``) is donated
+        and nothing else is: with token-major pages XLA scatters the new
+        tokens into the pool in place and every returned page array
+        aliases its input, so no step copies the pool.  The three small
+        per-step arrays arrive once; each layer's view is built here,
+        inside the trace."""
+        model, temperature = self.model, self.temperature
+        block_size = self.cache.block_size
+
+        def fn(params, ids, positions, last_index, pages, block_tables,
+               seq_lens, slot_mapping, key):
+            caches = [PagedLayerCache(k, v, block_tables, seq_lens,
+                                      slot_mapping, block_size=block_size)
+                      for (k, v) in pages]
             logits, new_caches = model.apply(
                 params, ids, caches, positions, last_index,
                 method="serving_step")
@@ -341,31 +366,29 @@ class ServingEngine:
             else:
                 nxt = jax.random.categorical(key, logits / temperature,
                                              axis=-1)
-            return nxt.astype(jnp.int32), logits, new_caches
+            return (nxt.astype(jnp.int32), logits,
+                    [(c.k_pages, c.v_pages) for c in new_caches])
 
-        return jax.jit(fn)
+        return jax.jit(fn, donate_argnames=("pages",))
+
+    def _tracked_step(self, name: str):
+        # one underlying jitted callable (jax caches per shape); a tracker
+        # name per program makes "one compile per bucket" directly
+        # observable and keeps retrace counts at zero
+        if self._jit_step is None:
+            self._jit_step = self._build_step_fn()
+        return track_jit(self._jit_step, name=name,
+                         arg_names=self._STEP_ARGS)
 
     def _decode_fn(self):
         if self._decode_tracked is None:
-            self._jit_step = getattr(self, "_jit_step", None) \
-                or self._build_step_fn()
-            self._decode_tracked = track_jit(
-                self._jit_step, name="serve_decode",
-                arg_names=("params", "ids", "positions", "last_index",
-                           "caches", "key"))
+            self._decode_tracked = self._tracked_step("serve_decode")
         return self._decode_tracked
 
     def _prefill_fn(self, bucket: int):
         fn = self._prefill_tracked.get(bucket)
         if fn is None:
-            # same underlying jitted callable (jax caches per shape);
-            # a per-bucket tracker name makes "one compile per bucket"
-            # directly observable and keeps retrace counts at zero
-            self._jit_step = getattr(self, "_jit_step", None) \
-                or self._build_step_fn()
-            fn = track_jit(self._jit_step, name=f"serve_prefill_b{bucket}",
-                           arg_names=("params", "ids", "positions",
-                                      "last_index", "caches", "key"))
+            fn = self._tracked_step(f"serve_prefill_b{bucket}")
             self._prefill_tracked[bucket] = fn
         return fn
 
@@ -581,15 +604,20 @@ class ServingEngine:
 
     def _recover_from_hang(self) -> List[Dict[str, Any]]:
         """Hung-step recovery: the watchdog already dumped every thread's
-        stack.  Device work in flight is abandoned — host state is still
-        consistent (marks/pages only mutate after a step returns) — so
-        rebuild the jitted fns and preempt the running set back to the
-        queue; recompute-prefill replays them token-exact."""
+        stack.  Device work in flight is abandoned.  The scheduler's marks
+        only move after a step returns, so they are consistent; the pool
+        is not protected that way any more — the step program consumes
+        the page handles, and a step cut between the call and
+        ``update_pages`` leaves them dead — so a lost pool is replaced by
+        a zeroed one.  Rebuild the jitted fns and preempt the running set
+        back to the queue; recompute-prefill rewrites their KV and replays
+        them token-exact, whichever pool they land in."""
         self._jit_step = None
         self._decode_tracked = None
         self._prefill_tracked = {}
         self._proven.clear()
         victims = self.sched.preempt_all()
+        self._rebuild_lost_pool()
         self.watchdog_restarts += 1
         reg = self._reg()
         reg.counter("serve.watchdog_restarts").inc()
@@ -617,10 +645,17 @@ class ServingEngine:
 
     # -- prefill / decode execution ---------------------------------------
     # The _apply_* helpers run the jitted step and read the result back
-    # to host WITHOUT mutating any host state (no update_pages, no
-    # scheduler marks) — that purity is what makes the quarantine
-    # bisection probes and the post-eviction replay safe: a failed or
-    # probed step leaves nothing behind.
+    # to host.  The step program consumes the page arrays (they are
+    # donated), so the cache takes the new ones the moment the call
+    # returns: a failed or probed step DOES leave its page writes behind.
+    # That is safe because a step writes only its own rows' slots at
+    # positions >= computed_len, which no row reads until mark_decoded /
+    # mark_prefilled advance the lengths, and a replay or a bisection
+    # probe of the same rows writes the same values to the same slots.
+    # So pages are adopted always, scheduler marks only on success (the
+    # _run_* callers).  What a culprit wrote is scrubbed when it is
+    # quarantined; a pool that a failed call consumed is rebuilt
+    # (_rebuild_lost_pool).
 
     def _apply_fault(self, kind: str, seqs: List[SequenceState],
                      logits_np: np.ndarray) -> np.ndarray:
@@ -644,32 +679,58 @@ class ServingEngine:
     def _device_step(self, fn, ids: np.ndarray, positions: np.ndarray,
                      last_index: int, tables: np.ndarray, lens: np.ndarray,
                      slots: np.ndarray, key):
-        """Host arrays in, host ``(next tokens, logits)`` and the device's
-        new caches out, one span a leg: ``h2d`` (the puts), ``dispatch``
-        (the jitted call until it returns; the step's PRNG-key split is
-        a ``dispatch`` span too), ``device_wait`` (until the
+        """Host arrays in, host ``(next tokens, logits)`` out, the pool
+        updated in place, one span a leg: ``h2d`` (the puts), ``dispatch``
+        (the jitted call until it returns, and the cache taking the new
+        page handles — the old ones are dead by then; the step's PRNG-key
+        split is a ``dispatch`` span too), ``device_wait`` (until the
         device is done — the copy below would wait for the same), and
         ``logits_copy`` (a pure device-to-host copy by then)."""
         reg = self._reg()
         with self._phase("h2d"):
             last = np.asarray(last_index, np.int32)
-            caches = self.cache.layer_caches(tables, lens, slots)
-            ids_d, positions_d, last_d = (jnp.asarray(ids),
-                                          jnp.asarray(positions),
-                                          jnp.asarray(last))
+            ids_d, positions_d, last_d, tables_d, lens_d, slots_d = (
+                jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(last),
+                jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(slots))
             reg.counter("serve.h2d_bytes").inc(
                 ids.nbytes + positions.nbytes + last.nbytes
                 + tables.nbytes + lens.nbytes + slots.nbytes)
         with self._phase("dispatch"):
-            nxt, logits, new_caches = fn(self._params, ids_d, positions_d,
-                                         last_d, caches, key)
+            nxt, logits, pages = fn(self._params, ids_d, positions_d,
+                                    last_d, self.cache.pages, tables_d,
+                                    lens_d, slots_d, key)
+            self.cache.update_pages(pages)
         with self._phase("device_wait"):
-            jax.block_until_ready((nxt, logits))
+            try:
+                jax.block_until_ready((nxt, logits))
+            except StepTimeout:
+                raise
+            except Exception:
+                # the program failed on the device: its page outputs are
+                # as dead as its logits
+                self.cache.drop_pages()
+                raise
         with self._phase("logits_copy"):
             nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
             reg.counter("serve.d2h_bytes").inc(
                 nxt_np.nbytes + logits_np.nbytes)
-        return nxt_np, logits_np, new_caches
+        return nxt_np, logits_np
+
+    def _rebuild_lost_pool(self) -> bool:
+        """If a step program consumed the pool and handed none back (the
+        call raised after its inputs were donated, or a hung step was cut
+        inside it), start over with a zeroed pool and send the running
+        set back through recompute-prefill.  True when it did."""
+        if not self.cache.pages_lost():
+            return False
+        self.cache.reset_pages()
+        victims = self.sched.preempt_all()
+        self.pool_rebuilds += 1
+        reg = self._reg()
+        reg.counter("serve.pool_rebuilds").inc()
+        reg.emit("serve.pool_rebuild", step=self.steps,
+                 victims=[s.request_id for s in victims])
+        return True
 
     def _apply_prefill(self, seq: SequenceState, bucket: int, key):
         with self._phase("tables"):
@@ -683,12 +744,12 @@ class ServingEngine:
             lens = np.asarray([L], np.int32)
             slots = self.cache.slot_array([seq.request_id], [0], bucket)
             fn = self._prefill_fn(bucket)
-        nxt_np, logits_np, new_caches = self._device_step(
+        nxt_np, logits_np = self._device_step(
             fn, ids, np.zeros((1,), np.int32), L - 1, tables, lens, slots,
             key)
         self._proven.add(("prefill", bucket))
         logits_np = self._apply_fault("prefill", [seq], logits_np)
-        return nxt_np, logits_np, new_caches
+        return nxt_np, logits_np
 
     def _apply_decode(self, seqs: List[SequenceState], key):
         with self._phase("tables"):
@@ -714,28 +775,28 @@ class ServingEngine:
                                             self.sched.max_blocks_per_seq)
             slots = self.cache.slot_array(sids, starts, 1)
             fn = self._decode_fn()
-        nxt_np, logits_np, new_caches = self._device_step(
+        nxt_np, logits_np = self._device_step(
             fn, ids, positions, 0, tables, lens, slots, key)
         self._proven.add("decode")
         logits_np = self._apply_fault("decode", seqs, logits_np)
-        return nxt_np, logits_np, new_caches
+        return nxt_np, logits_np
 
     def _run_prefill(self, plan: StepPlan) -> List[Dict[str, Any]]:
         seq = plan.seqs[0]
         key = self._next_key()
         t_prefill0 = float(self.clock())
         try:
-            nxt_np, logits_np, new_caches = self._apply_prefill(
-                seq, plan.bucket, key)
+            nxt_np, logits_np = self._apply_prefill(seq, plan.bucket, key)
         except StepTimeout:
             raise                      # the watchdog owns this one
         except Exception as e:
+            rebuilt = self._rebuild_lost_pool()
             if ("prefill", plan.bucket) not in self._proven:
                 raise                  # never ran: not a request's fault
-            self._quarantine_step("prefill", [seq], e, key)
+            if not rebuilt:            # else the engine's loss, not seq's
+                self._quarantine_step("prefill", [seq], e, key)
             return []
         with self._phase("accept"):
-            self.cache.update_pages(new_caches)
             self.sched.mark_prefilled(seq)
             reg = self._reg()
             reg.counter("serve.prefills").inc()
@@ -770,12 +831,15 @@ class ServingEngine:
         key = self._next_key()
         t0 = float(self.clock())
         try:
-            nxt_np, logits_np, new_caches = self._apply_decode(seqs, key)
+            nxt_np, logits_np = self._apply_decode(seqs, key)
         except StepTimeout:
             raise
         except Exception as e:
+            rebuilt = self._rebuild_lost_pool()
             if "decode" not in self._proven:
                 raise                  # never ran: not a request's fault
+            if rebuilt:
+                return []              # every row is queued for recompute
             survivors = self._quarantine_step("decode", seqs, e, key)
             if not survivors:
                 return []
@@ -785,7 +849,6 @@ class ServingEngine:
             # their tokens) identical to the un-faulted step
             return self._run_decode(StepPlan("decode", survivors))
         with self._phase("accept"):
-            self.cache.update_pages(new_caches)
             reg = self._reg()
             reg.counter("serve.decode_steps").inc()
             reg.histogram("serve.decode_batch").observe(float(len(seqs)))
@@ -804,13 +867,15 @@ class ServingEngine:
 
     # -- poisoned-request quarantine ---------------------------------------
     def _probe(self, seqs: List[SequenceState], key) -> bool:
-        """Re-run the decode step on a subset; True when it faults.
-        Pure — no host state mutates — so probing is free to repeat."""
+        """Re-run the decode step on a subset; True when it faults.  A
+        probe rewrites its rows' pending slots with the values already
+        there and moves no mark, so probing is free to repeat."""
         try:
             self._apply_decode(seqs, key)
         except StepTimeout:
             raise
         except Exception:
+            self._rebuild_lost_pool()
             return True
         return False
 
@@ -844,7 +909,12 @@ class ServingEngine:
             elif kind == "prefill" or len(seqs) == 1:
                 culprits = list(seqs)
             else:
+                rebuilds = self.pool_rebuilds
                 culprits = self._bisect(seqs, key)
+                if self.pool_rebuilds != rebuilds:
+                    # a probe lost the pool: every row is queued for
+                    # recompute, and the fault will show again there
+                    return []
             for seq in culprits:
                 self._quarantine(seq, error, kind)
             # the bisect stalls every row in the faulted batch — attribute
@@ -859,6 +929,9 @@ class ServingEngine:
 
     def _quarantine(self, seq: SequenceState, error: Exception,
                     kind: str) -> None:
+        # before the blocks go back to the allocator: whatever the culprit
+        # wrote (non-finite K/V, maybe) must not meet their next owner
+        self.cache.scrub_seq(seq.request_id)
         self.sched.evict(seq, "poisoned")
         self.lifecycle_counts["poisoned"] += 1
         record = {"request_id": seq.request_id, "reason": "poisoned",
@@ -1251,6 +1324,7 @@ class ServingEngine:
         reg.gauge("serve.kv_occupancy").set(self.cache.occupancy())
         reg.gauge("serve.kv_blocks_used").set(
             float(self.cache.allocator.num_used))
+        reg.gauge("serve.kv_pool_bytes").set(float(self.cache.pool_bytes()))
         reg.gauge("serve.shed").set(1.0 if self.should_shed() else 0.0)
 
     def stats(self) -> Dict[str, Any]:
@@ -1301,6 +1375,7 @@ class ServingEngine:
                 "poisoned": self.lifecycle_counts["poisoned"],
                 "spilled": self.lifecycle_counts["spilled"],
                 "watchdog_restarts": self.watchdog_restarts,
+                "pool_rebuilds": self.pool_rebuilds,
                 "quarantined": sorted(self.quarantined),
                 "callbacks": {"dispatched": self._cb_dispatched,
                               "errors": self._cb_errors,

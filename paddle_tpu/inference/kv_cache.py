@@ -18,7 +18,7 @@ Three layers in this module:
   ids: ``alloc / free / defrag`` plus occupancy accounting.  Pure python,
   no device traffic; the scheduler calls it every step.
 - :class:`PagedLayerCache` — the **device-side** view one decoder layer
-  sees inside a jitted step: ``(num_blocks, heads, block_size,
+  sees inside a jitted step: ``(num_blocks, block_size, heads,
   head_dim)`` key and value page arrays plus the batch's
   ``block_tables`` / ``seq_lens`` / ``slot_mapping`` int32 arrays.  It
   is a registered pytree, so it flows through ``jax.jit`` with fixed
@@ -27,26 +27,39 @@ Three layers in this module:
   arrays + the allocator + per-sequence tables, with the array-building
   helpers the engine uses to assemble fixed-shape step inputs.
 
-Page layout: ``pages[block, head, offset, :]`` — ``(block_size,
-head_dim)`` are the two minor dims because that is the tile the TPU
-decode kernel DMAs per block (Mosaic wants a block's minor dims to be
-the array's, or multiples of (8, 128)).  The layout is private to this
+Page layout: token-major, ``pages[block, offset, head, :]``.  One
+token's ``(heads, head_dim)`` slab is the minor tile (16 × 128 bf16 is
+exactly one 4 KB TPU tile) and a block is ``block_size`` of them,
+contiguous.  That is the layout XLA's scatter of new tokens wants, and
+one the decode kernel accepts (its block's two minor dims are the
+array's), so a step program that is handed the pool **donated** writes
+it in place: no relayout before the scatter, none after it, no second
+pool.  With heads ahead of the offset (the layout until PR 27) every
+step copied every page array twice.  The layout is private to this
 module and ``paged_attention``: models write through
 :meth:`PagedLayerCache.write`.
 
+Ownership: the serving step consumes the page arrays it is given, so
+:class:`PagedKVCache` holds the only live handles and takes the step's
+outputs through :meth:`PagedKVCache.update_pages` as soon as the call
+returns.  :meth:`PagedKVCache.pages_lost` says whether a failed call ate
+them, :meth:`PagedKVCache.reset_pages` starts over with a zeroed pool.
+
 Slots: a flat slot id addresses one token row, ``slot = block_table[pos
-// bs] * bs + pos % bs``, i.e. ``pages[slot // bs, :, slot % bs]``.
+// bs] * bs + pos % bs``, i.e. ``pages[slot // bs, slot % bs]``.
 ``slot_pad`` (== ``num_slots``, whose block id is out of bounds) marks
 padded positions — page writes use ``mode="drop"`` so padding never
 lands.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 import jax.tree_util as _tree_util
 
@@ -175,7 +188,7 @@ class BlockAllocator:
 class PagedLayerCache:
     """One decoder layer's jit-visible paged-cache view.
 
-    ``k_pages`` / ``v_pages``: ``(num_blocks, heads, block_size,
+    ``k_pages`` / ``v_pages``: ``(num_blocks, block_size, heads,
     head_dim)`` page arrays.
     ``block_tables``: ``(batch, max_blocks_per_seq)`` int32 block ids
     (padded rows/entries are 0 — masked out by ``seq_lens``).
@@ -212,9 +225,9 @@ class PagedLayerCache:
         out of bounds and dropped."""
         slots = self.slot_mapping.reshape(-1)
         blk, off = slots // self.block_size, slots % self.block_size
-        k_pages = self.k_pages.at[blk, :, off].set(
+        k_pages = self.k_pages.at[blk, off].set(
             new_k.astype(self.k_pages.dtype), mode="drop")
-        v_pages = self.v_pages.at[blk, :, off].set(
+        v_pages = self.v_pages.at[blk, off].set(
             new_v.astype(self.v_pages.dtype), mode="drop")
         return self.replace(k_pages=k_pages, v_pages=v_pages)
 
@@ -232,14 +245,22 @@ _tree_util.register_pytree_node(PagedLayerCache, _plc_flatten,
                                 _plc_unflatten)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _zero_blocks(pages, block_ids):
+    """Zeros into ``block_ids`` of every page array, in place (the pool
+    is donated); ids out of bounds are dropped."""
+    return [(k.at[block_ids].set(0, mode="drop"),
+             v.at[block_ids].set(0, mode="drop")) for (k, v) in pages]
+
+
 class PagedKVCache:
     """Whole-model paged KV store: per-layer page arrays + the allocator
     + per-sequence block tables.
 
     The engine owns one of these; the scheduler talks to ``allocator``
-    and the per-sequence helpers; the jitted step consumes the
-    fixed-shape arrays from :meth:`layer_caches` and hands back updated
-    page arrays through :meth:`update_pages`.
+    and the per-sequence helpers; the jitted step consumes :attr:`pages`
+    (donated: the handles are dead once it is called) and hands the
+    updated page arrays back through :meth:`update_pages`.
     """
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
@@ -257,10 +278,69 @@ class PagedKVCache:
         self.dtype = jnp.dtype(dtype)
         self.allocator = BlockAllocator(self.num_blocks, block_size)
         self._tables: Dict[object, List[int]] = {}
-        shape = (self.num_blocks, self.num_heads, block_size, self.head_dim)
-        self._pages: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
+        self.reset_pages()
+
+    # -- the page arrays ---------------------------------------------------
+    @property
+    def pages(self) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
+        """Per layer ``(k_pages, v_pages)``, each ``(num_blocks,
+        block_size, heads, head_dim)``: the step program's donated
+        argument."""
+        return self._pages
+
+    def update_pages(self, pages: Sequence[Tuple]) -> None:
+        """Take the page arrays a step program returned in place of the
+        ones it consumed."""
+        enforce(len(pages) == self.num_layers,
+                f"{len(pages)} page pairs for {self.num_layers} layers")
+        self._pages = [(k, v) for (k, v) in pages]
+
+    def reset_pages(self) -> None:
+        """A zeroed pool.  The old handles, if any are left, are let go
+        first, so the pool is never held twice."""
+        self._pages: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
+        shape = (self.num_blocks, self.block_size, self.num_heads,
+                 self.head_dim)
+        self._pages = [
             (jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype))
             for _ in range(self.num_layers)]
+
+    def _live_handles(self) -> List[jnp.ndarray]:
+        return [a for kv in self._pages for a in kv if not a.is_deleted()]
+
+    def pages_lost(self) -> bool:
+        """True when a page handle is dead: a step program consumed the
+        pool and did not hand one back."""
+        return len(self._live_handles()) < 2 * self.num_layers
+
+    def drop_pages(self) -> None:
+        """Delete every page handle: what they hold cannot be trusted (the
+        outputs of a program that failed on the device)."""
+        for a in self._live_handles():
+            a.delete()
+
+    def pool_bytes(self) -> int:
+        """Device bytes behind the live page handles: one pool.  (Every
+        page array has the same size; ``Array.nbytes`` costs 2 us a call,
+        and this is read in every step.)"""
+        return len(self._live_handles()) * (
+            self.num_slots * self.num_heads * self.head_dim
+            * self.dtype.itemsize)
+
+    def scrub_seq(self, seq_id) -> None:
+        """Zero ``seq_id``'s blocks in every layer (one small donated
+        program): a quarantined sequence may have left non-finite K/V
+        behind, and the decode kernel's ``p * v`` turns a masked ``0 *
+        NaN`` into NaN for the block's next owner."""
+        table = self._tables.get(seq_id)
+        if not table:
+            return
+        # padded to a power of two with an out-of-bounds id, so tables of
+        # any length share a handful of programs
+        width = 1 << (len(table) - 1).bit_length()
+        ids = np.full((width,), self.num_blocks, np.int32)
+        ids[:len(table)] = table
+        self._pages = _zero_blocks(self._pages, jnp.asarray(ids))
 
     # -- per-sequence table management ------------------------------------
     def table(self, seq_id) -> List[int]:
@@ -347,18 +427,15 @@ class PagedKVCache:
 
     def layer_caches(self, block_tables: np.ndarray, seq_lens: np.ndarray,
                      slot_mapping: np.ndarray) -> List[PagedLayerCache]:
+        """The per-layer views a model's ``serving_step`` takes, over the
+        live pages (the engine's step program builds the same views
+        inside its trace)."""
         bt = jnp.asarray(block_tables, jnp.int32)
         sl = jnp.asarray(seq_lens, jnp.int32)
         sm = jnp.asarray(slot_mapping, jnp.int32)
         return [PagedLayerCache(k, v, bt, sl, sm,
                                 block_size=self.block_size)
                 for (k, v) in self._pages]
-
-    def update_pages(self, new_caches: Sequence[PagedLayerCache]) -> None:
-        enforce(len(new_caches) == self.num_layers,
-                f"{len(new_caches)} layer caches for {self.num_layers} "
-                "layers")
-        self._pages = [(c.k_pages, c.v_pages) for c in new_caches]
 
     # -- defrag ------------------------------------------------------------
     def defrag(self) -> bool:
@@ -369,6 +446,8 @@ class PagedKVCache:
         if perm is None:
             return False
         idx = jnp.asarray(perm)
-        self._pages = [(jnp.take(k, idx, axis=0), jnp.take(v, idx, axis=0))
-                       for (k, v) in self._pages]
+        # a layer at a time, so at most one layer's pages are held twice
+        for i, (k, v) in enumerate(self._pages):
+            self._pages[i] = (jnp.take(k, idx, axis=0),
+                              jnp.take(v, idx, axis=0))
         return True

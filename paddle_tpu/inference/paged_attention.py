@@ -16,17 +16,23 @@ Two implementations behind one routing entry point:
 
 - :func:`paged_attention_pallas` — the kernel, built on the same Pallas
   surface as ``ops/flash_attention.py`` (lane-broadcast statistics,
-  online-softmax recurrence).  Pages are ``(num_blocks, heads,
-  block_size, head_dim)`` (``inference/kv_cache.py``); the grid is
-  ``(batch, max_blocks)`` with the block table and sequence lengths as
-  **scalar-prefetch** operands, so the k/v BlockSpec index maps
-  dereference the table and Mosaic DMAs exactly one KV block — all
-  heads of it — per grid step.  Per-step VMEM residency is
-  O(heads · block_size · head_dim) regardless of pool size, and a block
-  past ``seq_lens[b]`` is skipped (its flash state update is predicated
-  off; the redundant page-0 DMA it still costs is the ragged tax also
-  paid by the upstream TPU kernel).  The one-row-per-head products run
-  on the VPU in f32.
+  online-softmax recurrence).  Pages are token-major, ``(num_blocks,
+  block_size, heads, head_dim)`` (``inference/kv_cache.py``): one
+  token's ``(heads, head_dim)`` slab is the minor tile, which is what
+  lets the step program scatter new tokens into the pool in place.  The
+  grid is ``(batch, max_blocks)`` with the block table and sequence
+  lengths as **scalar-prefetch** operands, so the k/v BlockSpec index
+  maps dereference the table and Mosaic DMAs exactly one KV block —
+  ``(block_size, heads, head_dim)``, contiguous in HBM — per grid step.
+  Per-step VMEM residency is one such block of K and one of V (double
+  buffered: 4 · block_size · heads · head_dim elements, 256 KB at 16 ×
+  16 × 128 bf16) plus the ``(heads, head_dim)`` f32 state, regardless of
+  pool size.  A block past ``seq_lens[b]`` is skipped (its flash state
+  update is predicated off; the redundant page-0 DMA it still costs is
+  the ragged tax also paid by the upstream TPU kernel).  The
+  one-row-per-head products run on the VPU in f32, and the block's
+  tokens lie along the leading axis, so the max / sum / accumulate over
+  them are elementwise across vregs.
 - :func:`paged_attention_reference` — a pure ``jax.numpy``/``lax``
   gather-softmax with identical semantics.  It is the default off-TPU
   (interpret-mode Pallas is orders slower than XLA CPU), which is what
@@ -67,7 +73,7 @@ def _check_shapes(q, k_pages, v_pages, block_tables, seq_lens,
     b, h, d = q.shape
     enforce(k_pages.ndim == 4 and k_pages.shape == v_pages.shape,
             f"page shape mismatch: k={k_pages.shape} v={v_pages.shape}")
-    enforce(k_pages.shape[1:] == (h, block_size, d),
+    enforce(k_pages.shape[1:] == (block_size, h, d),
             f"pages {k_pages.shape} disagree with q {q.shape} at "
             f"block_size {block_size}")
     enforce(block_tables.shape[0] == b and seq_lens.shape == (b,),
@@ -82,7 +88,7 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
                               block_size: int,
                               scale: Optional[float] = None):
     """Pure-jax ragged paged attention over ``(batch, heads, head_dim)``
-    single-token queries against ``(num_blocks, heads, block_size,
+    single-token queries against ``(num_blocks, block_size, heads,
     head_dim)`` pages.  A row with ``seq_lens[b] == 0`` (a padding row of
     the decode batch) returns zeros."""
     _check_shapes(q, k_pages, v_pages, block_tables, seq_lens, block_size)
@@ -92,15 +98,13 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
     max_ctx = block_tables.shape[1] * block_size
 
     def per_seq(qb, table, ln):
-        # (T,) block ids -> gathered (T, h, bs, d) -> (h, T*bs, d)
-        k = jnp.take(k_pages, table, axis=0).transpose(1, 0, 2, 3)
-        v = jnp.take(v_pages, table, axis=0).transpose(1, 0, 2, 3)
-        k = k.reshape(h, max_ctx, d)
-        v = v.reshape(h, max_ctx, d)
+        # (T,) block ids -> gathered (T, bs, h, d) -> token rows (T*bs, h, d)
+        k = jnp.take(k_pages, table, axis=0).reshape(max_ctx, h, d)
+        v = jnp.take(v_pages, table, axis=0).reshape(max_ctx, h, d)
         # an oracle computes in f32 for real: on a TPU an f32 einsum at
         # the default precision rounds its operands (the probabilities
         # below among them) to bf16 first
-        s = jnp.einsum("hd,hld->hl", qb.astype(jnp.float32),
+        s = jnp.einsum("hd,lhd->hl", qb.astype(jnp.float32),
                        k.astype(jnp.float32),
                        precision=lax.Precision.HIGHEST) * scale
         valid = (jnp.arange(max_ctx) < ln)[None, :]
@@ -108,7 +112,7 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.where(valid, jnp.exp(s - m), 0.0)
         l = jnp.sum(p, axis=1, keepdims=True)
-        out = jnp.einsum("hl,hld->hd", p, v.astype(jnp.float32),
+        out = jnp.einsum("hl,lhd->hd", p, v.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         return (out / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
@@ -116,7 +120,8 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: one KV block (all heads) per grid step, table-driven DMA
+# Pallas kernel: one KV block (all its tokens' heads) per grid step,
+# table-driven DMA
 # ---------------------------------------------------------------------------
 def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, scale, block_size):
@@ -141,19 +146,21 @@ def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         # run on the VPU in f32 (exact products of the stored values, f32
         # accumulation) instead of padding a one-row operand onto the MXU
         q = q_ref[0].astype(jnp.float32)               # (h, d)
-        k = k_ref[0].astype(jnp.float32)               # (h, bs, d)
+        k = k_ref[0].astype(jnp.float32)               # (bs, h, d)
         v = v_ref[0].astype(jnp.float32)
-        s = jnp.sum(q[:, None, :] * k, axis=2) * scale     # (h, bs)
-        cols = t * block_size + lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(cols < kv_len, s, _NEG_INF)
+        # heads stay on the sublanes throughout (keepdims), so the
+        # per-head statistics below meet the (h, d) accumulator without a
+        # relayout; the block's tokens are the leading axis
+        s = jnp.sum(q[None] * k, axis=2, keepdims=True) * scale  # (bs, h, 1)
+        rows = t * block_size + lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        s = jnp.where(rows < kv_len, s, _NEG_INF)
         m_prev = m_scr[...]                            # (h, _LANES)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(cols < kv_len, jnp.exp(s - m_new[:, :1]), 0.0)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.where(rows < kv_len, jnp.exp(s - m_new[:, :1]), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = (acc_scr[...] * alpha[:, :1]
-                        + jnp.sum(p[:, :, None] * v, axis=1))
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0)
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jnp.sum(p * v, axis=0)
         m_scr[...] = m_new
 
     @pl.when(t == num_t - 1)
@@ -170,7 +177,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
                            interpret: Optional[bool] = None):
     """The table-driven Pallas kernel (interpret-mode off TPU).
 
-    Blocks are ``(1, heads, block_size, head_dim)`` page tiles and
+    Blocks are ``(1, block_size, heads, head_dim)`` page tiles and
     ``(1, heads, head_dim)`` q/out tiles: the two minor dims of every
     block equal the array's, which is what Mosaic's tiling rule asks of a
     block narrower than (8, 128)."""
@@ -180,7 +187,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
     max_blocks = block_tables.shape[1]
     if scale is None:
         scale = d ** -0.5
-    page_spec = pl.BlockSpec((1, h, block_size, d),
+    page_spec = pl.BlockSpec((1, block_size, h, d),
                              lambda bi, ti, lens, tbl:
                              (tbl[bi, ti], 0, 0, 0))
     row_spec = pl.BlockSpec((1, h, d), lambda bi, ti, lens, tbl: (bi, 0, 0))

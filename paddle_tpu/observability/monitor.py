@@ -184,6 +184,7 @@ class StatusServer:
                 "running": gauge("serve.running"),
                 "kv_occupancy": gauge("serve.kv_occupancy"),
                 "kv_blocks_used": gauge("serve.kv_blocks_used"),
+                "kv_pool_bytes": gauge("serve.kv_pool_bytes"),
                 "ttft_ms": hist("serve.ttft_ms"),
                 "tpot_ms": hist("serve.tpot_ms"),
                 # lifecycle-guard counters (ISSUE 15) — registry-derived
@@ -196,6 +197,7 @@ class StatusServer:
                     "spilled": counter("serve.spilled"),
                     "watchdog_restarts":
                         counter("serve.watchdog_restarts"),
+                    "pool_rebuilds": counter("serve.pool_rebuilds"),
                     "callback_errors": counter("serve.callback_errors"),
                 },
             }

@@ -348,10 +348,10 @@ class TestGPTScopes:
         tables = np.zeros((2, 8), np.int32)
         lens = np.ones((2,), np.int32)
         slots = np.zeros((2, 1), np.int32)
-        caches = eng.cache.layer_caches(tables, lens, slots)
         text = eng._build_step_fn().lower(
             eng._params, jnp.zeros((2, 1), jnp.int32),
-            jnp.zeros((2,), jnp.int32), jnp.asarray(0, jnp.int32), caches,
+            jnp.zeros((2,), jnp.int32), jnp.asarray(0, jnp.int32),
+            eng.cache.pages, tables, lens, slots,
             jax.random.PRNGKey(0)).as_text(debug_info=True)
         names = set(re.findall(r'loc\("([^"]+)"', text))
         for scope in ("gpt.embed", "gpt.block/attn/", "gpt.block/mlp/",

@@ -1,6 +1,7 @@
 """Serving subsystem (ISSUE 6): paged-KV cache invariants, scheduler
 policy under a tight block budget, ragged-vs-dense numerics, the compile
 contract, the slow-consumer fault drill, and the legacy facade routing."""
+import re
 import time
 
 import numpy as np
@@ -146,17 +147,72 @@ class TestPagedKVCache:
                                   np.ones((1,), np.int32),
                                   np.asarray([[slot_b]], np.int32))
         kv = jnp.full((1, 2, 4), 7.5)
-        c.update_pages([layer.write(kv, kv)])
+        new = layer.write(kv, kv)
+        c.update_pages([(new.k_pages, new.v_pages)])
         c.free_seq("a")
         assert c.defrag() is True
         # b's tables were renumbered to the compact prefix; its data moved
         assert sorted(c.table("b")) == [0, 1]
         blk, off = divmod(c.slot("b", 0), c.block_size)
         k_pages = np.asarray(c._pages[0][0])
-        assert (k_pages[blk, :, off] == 7.5).all()
+        assert k_pages.shape == (6, 4, 2, 4)    # (blocks, bs, heads, dim)
+        assert (k_pages[blk, off] == 7.5).all()
         assert (k_pages != 0).sum() == 2 * 4      # exactly that one row
         # idempotent when already compact
         assert c.defrag() is False
+
+
+    def test_write_lands_token_major_and_drops_padding(self):
+        c = PagedKVCache(num_layers=2, num_heads=2, head_dim=4,
+                         num_blocks=6, block_size=4)
+        c.ensure_capacity("a", 6)
+        slots = c.slot_array(["a"], [3], 4)          # positions 3..6
+        assert slots[0, 3] != c.slot_pad
+        slots[0, 3] = c.slot_pad                     # a padded position
+        layers = c.layer_caches(c.table_array(["a"], 2),
+                                np.asarray([6], np.int32), slots)
+        assert len(layers) == 2
+        k = jnp.arange(4 * 2 * 4, dtype=jnp.float32).reshape(4, 2, 4) + 1
+        new = layers[1].write(k, -k)
+        assert new.k_pages.shape == (6, 4, 2, 4)
+        kp, vp = np.asarray(new.k_pages), np.asarray(new.v_pages)
+        for j in range(3):
+            blk, off = divmod(int(slots[0, j]), 4)
+            np.testing.assert_array_equal(kp[blk, off], np.asarray(k[j]))
+            np.testing.assert_array_equal(vp[blk, off], -np.asarray(k[j]))
+        # the padded token went nowhere
+        assert (kp != 0).sum() == 3 * 2 * 4
+        # layer 0's view shares the step's tables but has its own pages
+        assert layers[0].block_tables is layers[1].block_tables
+        assert not np.asarray(layers[0].k_pages).any()
+
+    def test_scrub_seq_zeroes_only_that_table(self):
+        c = self.make(blocks=8, bs=4)
+        c.ensure_capacity("a", 12)                   # 3 blocks: pads to 4
+        c.ensure_capacity("b", 4)
+        ones = jnp.ones((8, 4, 2, 4))
+        c.update_pages([(ones, ones * jnp.nan)])
+        c.scrub_seq("a")
+        c.scrub_seq("nobody")                        # no table: no-op
+        kp, vp = (np.asarray(a) for a in c.pages[0])
+        for b in range(8):
+            if b in c.table("a"):
+                assert not kp[b].any() and not vp[b].any()
+            else:
+                assert (kp[b] == 1).all() and np.isnan(vp[b]).all()
+
+    def test_pool_handles_lost_and_reset(self):
+        c = self.make()
+        assert not c.pages_lost()
+        assert c.pool_bytes() == 2 * 6 * 4 * 2 * 4 * 4
+        c.pages[0][1].delete()
+        assert c.pages_lost()
+        assert c.pool_bytes() == 6 * 4 * 2 * 4 * 4   # live handles only
+        c.reset_pages()
+        assert not c.pages_lost()
+        assert not np.asarray(c.pages[0][0]).any()
+        c.drop_pages()
+        assert c.pages_lost() and c.pool_bytes() == 0
 
 
 # ---------------------------------------------------------------------------
@@ -167,8 +223,8 @@ class TestPagedAttention:
         rng = np.random.RandomState(0)
         B, H, D, bs, nb, T = 4, 2, 8, 4, 12, 5
         q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
-        kp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
+        kp = jnp.asarray(rng.randn(nb, bs, H, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(nb, bs, H, D).astype(np.float32))
         tbl = jnp.asarray(rng.randint(0, nb, (B, T)), jnp.int32)
         lens = jnp.asarray([7, 0, 20, 1], jnp.int32)
         ref = paged_attention_reference(q, kp, vp, tbl, lens, bs)
@@ -178,19 +234,51 @@ class TestPagedAttention:
                                    atol=1e-5)
         assert float(jnp.max(jnp.abs(ref[1]))) == 0.0   # len-0 row
 
+    @pytest.mark.parametrize("h,d,dtype,tol", [
+        (12, 64, jnp.float32, 1e-5), (16, 128, jnp.float32, 1e-5),
+        (12, 64, jnp.bfloat16, 2.0 ** -6), (16, 128, jnp.bfloat16, 2.0 ** -6)])
+    def test_pallas_matches_reference_at_the_hardware_tests_lengths(
+            self, h, d, dtype, tol):
+        # tests/test_tpu_hw.py's case in interpret mode: an empty row, one
+        # token, a non-multiple of the block, an exact multiple, a full
+        # table; GPT-125M and GPT-1.3B head shapes
+        bs, nb, T = 16, 24, 12
+        lens = jnp.asarray([0, 1, 37, 64, T * bs, 100], jnp.int32)
+        B = lens.shape[0]
+        rng = np.random.RandomState(0)
+        q = jnp.asarray(rng.randn(B, h, d), dtype)
+        kp = jnp.asarray(rng.randn(nb, bs, h, d), dtype)
+        vp = jnp.asarray(rng.randn(nb, bs, h, d), dtype)
+        tbl = jnp.asarray(rng.randint(0, nb, (B, T)), jnp.int32)
+        ref = paged_attention_reference(q, kp, vp, tbl, lens, bs).astype(
+            jnp.float32)
+        out = paged_attention_pallas(q, kp, vp, tbl, lens, bs,
+                                     interpret=True).astype(jnp.float32)
+        assert bool(jnp.isfinite(out).all())
+        assert float(jnp.max(jnp.abs(out - ref))) <= tol
+        assert float(jnp.max(jnp.abs(out[0]))) == 0.0      # the empty row
+
+    def test_head_major_pages_are_refused(self):
+        q = jnp.zeros((1, 2, 8))
+        old = jnp.zeros((3, 2, 4, 8))          # (blocks, heads, bs, dim)
+        with pytest.raises(Exception, match="pages"):
+            paged_attention_reference(q, old, old,
+                                      jnp.zeros((1, 2), jnp.int32),
+                                      jnp.ones((1,), jnp.int32), 4)
+
     def test_reference_matches_dense_gather(self):
         rng = np.random.RandomState(1)
         H, D, bs, nb = 3, 16, 4, 8
         q = jnp.asarray(rng.randn(1, H, D).astype(np.float32))
-        kp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(nb, H, bs, D).astype(np.float32))
+        kp = jnp.asarray(rng.randn(nb, bs, H, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(nb, bs, H, D).astype(np.float32))
         tbl = jnp.asarray([[5, 2, 7, 0]], jnp.int32)
         ln = 11
         out = paged_attention_reference(q, kp, vp, tbl,
                                         jnp.asarray([ln], jnp.int32), bs)
-        # (T, H, bs, D) blocks in table order -> (T*bs, H, D) token rows
-        gather = lambda p: np.asarray(p)[np.asarray(tbl[0])].transpose(  # noqa: E731
-            0, 2, 1, 3).reshape(-1, H, D)[:ln]
+        # (T, bs, H, D) blocks in table order -> (T*bs, H, D) token rows
+        gather = lambda p: np.asarray(p)[np.asarray(tbl[0])].reshape(  # noqa: E731
+            -1, H, D)[:ln]
         k, v = gather(kp), gather(vp)
         s = np.einsum("hd,lhd->hl", np.asarray(q[0]), k) * D ** -0.5
         p = np.exp(s - s.max(1, keepdims=True))
@@ -417,6 +505,67 @@ class TestServingEngine:
         eng.run(max_steps=300)
         code, _ = srv.healthz()
         assert code == 200
+
+
+# ---------------------------------------------------------------------------
+# The step program updates the pool in place (ISSUE 27)
+# ---------------------------------------------------------------------------
+class TestPoolInPlace:
+    def engine(self, **kw):
+        kw.setdefault("registry", MetricsRegistry())
+        return ServingEngine(tiny_model(), max_seqs=4, kv_block_size=4,
+                             **kw)
+
+    @pytest.mark.parametrize("rows,chunk", [(4, 1), (1, 8)],
+                             ids=["decode", "prefill_b8"])
+    def test_every_page_array_aliases_its_input(self, rows, chunk):
+        import jax
+        eng = self.engine()
+        pool = eng.cache.pool_bytes()
+        compiled = eng._build_step_fn().lower(
+            eng._params, jnp.zeros((rows, chunk), jnp.int32),
+            jnp.zeros((rows,), jnp.int32), jnp.asarray(0, jnp.int32),
+            eng.cache.pages,
+            np.zeros((rows, eng.sched.max_blocks_per_seq), np.int32),
+            np.ones((rows,), np.int32), np.zeros((rows, chunk), np.int32),
+            jax.random.PRNGKey(0)).compile()
+        header = compiled.as_text().split("input_output_alias={", 1)[1]
+        header = header.split("entry_computation_layout", 1)[0]
+        aliased = [int(p) for p in re.findall(r"\((\d+), \{\}", header)]
+        flat, _ = jax.tree_util.tree_flatten(eng._params)
+        first = len(flat) + 3                # params, ids, positions, last
+        n = 2 * eng.cache.num_layers
+        assert sorted(aliased) == list(range(first, first + n))
+        assert compiled.memory_analysis().alias_size_in_bytes == pool
+
+    def test_a_step_consumes_the_old_handles_and_holds_one_pool(self):
+        eng = self.engine()
+        pool = eng.cache.pool_bytes()
+        eng.submit([1, 2, 3], max_new_tokens=3)
+        for _ in range(3):                   # a prefill, then decodes
+            old = [a for kv in eng.cache.pages for a in kv]
+            eng.step()
+            assert all(a.is_deleted() for a in old)
+            assert not eng.cache.pages_lost()
+            snap = eng._reg().snapshot()
+            assert snap["serve.kv_pool_bytes"]["value"] == pool
+        assert eng.stats()["resilience"]["pool_rebuilds"] == 0
+
+    def test_statusz_shows_the_pool_and_its_rebuilds(self):
+        import json
+        from urllib.request import urlopen
+        eng = self.engine()
+        eng.submit([1, 2, 3], max_new_tokens=2)
+        eng.run(max_steps=20)
+        srv = eng.start_status_server(port=0, host="127.0.0.1")
+        try:
+            with urlopen(f"http://127.0.0.1:{srv.port}/statusz",
+                         timeout=10) as r:
+                serving = json.loads(r.read())["serving"]
+        finally:
+            eng.stop()
+        assert serving["kv_pool_bytes"] == eng.cache.pool_bytes()
+        assert serving["resilience"]["pool_rebuilds"] == 0
 
 
 # ---------------------------------------------------------------------------

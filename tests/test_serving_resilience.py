@@ -45,6 +45,18 @@ def make_engine(model=None, **kw):
     return ServingEngine(model, **kw)
 
 
+def run_traffic(model, n=4, max_new=6, prepare=None, **kw):
+    """``n`` short requests through a fresh engine (``prepare(engine)``
+    first, to plant a fault); the engine, the ids, the token lists."""
+    eng = make_engine(model, max_seqs=n, kv_block_size=4, **kw)
+    if prepare is not None:
+        prepare(eng)
+    prompts = [[1 + i, 2, 3 + i] for i in range(n)]
+    rids = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
+    eng.run(max_steps=500)
+    return eng, rids, [eng.collect(r)["tokens"] for r in rids]
+
+
 # ---------------------------------------------------------------------------
 # deadlines & cancellation
 # ---------------------------------------------------------------------------
@@ -134,12 +146,7 @@ class TestLifecycleGuard:
 # poisoned-request quarantine
 # ---------------------------------------------------------------------------
 class TestQuarantine:
-    def _traffic(self, model, n=4, max_new=6, **kw):
-        eng = make_engine(model, max_seqs=n, kv_block_size=4, **kw)
-        prompts = [[1 + i, 2, 3 + i] for i in range(n)]
-        rids = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
-        eng.run(max_steps=500)
-        return eng, rids, [eng.collect(r)["tokens"] for r in rids]
+    _traffic = staticmethod(run_traffic)
 
     def test_decode_raise_bisects_to_culprit(self, tmp_path):
         model = tiny_model()
@@ -233,6 +240,178 @@ class TestQuarantine:
         assert eng.stats()["resilience"]["poisoned"] == 1
         assert eng.stats()["resilience"]["quarantined"] == \
             [eng._submit_order[2]]
+
+
+# ---------------------------------------------------------------------------
+# a pool that the step program consumes (ISSUE 27)
+# ---------------------------------------------------------------------------
+class TestConsumedPool:
+    """The step program is handed the KV pool donated, so a step that
+    fails cannot be undone by keeping the old arrays: its page writes
+    stay (harmless), a culprit's blocks are scrubbed, and a pool that a
+    failed call ate is rebuilt."""
+
+    @staticmethod
+    def _traffic(model, **kw):
+        eng, _, outs = run_traffic(model, **kw)
+        return eng, outs
+
+    @pytest.mark.parametrize("mode,guard", [("raise", False),
+                                            ("nan", True)])
+    def test_culprits_blocks_are_scrubbed_before_they_are_freed(
+            self, mode, guard):
+        model = tiny_model()
+        _, clean = self._traffic(model)
+        seen = {}
+
+        def prepare(eng):
+            scrub = eng.cache.scrub_seq
+
+            def checked(seq_id):
+                blocks = list(eng.cache.table(seq_id))
+                before = [np.asarray(a)[blocks] for kv in eng.cache.pages
+                          for a in kv]
+                scrub(seq_id)
+                after = [np.asarray(a)[blocks] for kv in eng.cache.pages
+                         for a in kv]
+                seen[seq_id] = (blocks, before, after)
+            eng.cache.scrub_seq = checked
+
+        injector = faults.poison_request(1, mode=mode, kinds=("decode",))
+        eng, outs = self._traffic(model, prepare=prepare, nan_guard=guard,
+                                  step_fault=injector)
+        bad = eng._submit_order[1]
+        assert list(eng.quarantined) == [bad] and list(seen) == [bad]
+        blocks, before, after = seen[bad]
+        assert blocks                              # it still owned them
+        assert all(a.any() for a in before)        # and had written K/V
+        assert not any(a.any() for a in after)     # every layer, K and V
+        for i in (0, 2, 3):                        # survivors token-exact
+            assert outs[i] == clean[i], (i, outs[i], clean[i])
+        assert eng.pool_rebuilds == 0 and not eng.cache.pages_lost()
+        assert eng.cache.allocator.num_used == 0
+
+    @pytest.mark.parametrize("kind,nth", [("decode", 2), ("decode", 4),
+                                          ("prefill", 3)])
+    def test_a_call_that_dies_with_the_pool_rebuilds_it(self, kind, nth):
+        """The jitted call raises after it consumed its inputs: a zeroed
+        pool, every running row back through recompute-prefill, nobody
+        quarantined, every request token-exact."""
+        model = tiny_model()
+        _, clean = self._traffic(model)
+        calls = {"n": 0, "died": 0}
+
+        def prepare(eng):
+            real = eng._build_step_fn()
+
+            def flaky(params, ids, positions, last, pages, *rest):
+                if (ids.shape[1] == 1) == (kind == "decode"):
+                    calls["n"] += 1
+                    if calls["n"] == nth:
+                        calls["died"] += 1
+                        for kv in pages:
+                            for a in kv:
+                                a.delete()
+                        raise RuntimeError("device lost mid-step")
+                return real(params, ids, positions, last, pages, *rest)
+            eng._jit_step = flaky
+
+        eng, outs = self._traffic(model, prepare=prepare)
+        assert calls["died"] == 1
+        assert outs == clean
+        assert not eng.quarantined
+        res = eng.stats()["resilience"]
+        assert res["pool_rebuilds"] == 1 and res["poisoned"] == 0
+        snap = eng._reg().snapshot()
+        assert snap["serve.pool_rebuilds"]["value"] == 1
+        assert snap["serve.kv_pool_bytes"]["value"] == \
+            eng.cache.pool_bytes() > 0
+        assert eng.cache.allocator.num_used == 0
+
+    def test_a_first_run_that_dies_with_the_pool_still_propagates(self):
+        eng = make_engine(tiny_model(), max_seqs=2, kv_block_size=4)
+
+        def dies(params, ids, positions, last, pages, *rest):
+            pages[0][0].delete()
+            raise RuntimeError("device lost on the first step")
+        eng._jit_step = dies
+        eng.submit([1, 2, 3], max_new_tokens=2)
+        with pytest.raises(RuntimeError, match="first step"):
+            eng.step()
+        # not a request's fault, and the engine is not left without a pool
+        assert not eng.quarantined and eng.pool_rebuilds == 1
+        assert not eng.cache.pages_lost()
+
+    def test_outputs_of_a_program_that_failed_on_the_device_are_dropped(
+            self, monkeypatch):
+        import jax
+        model = tiny_model()
+        _, clean = self._traffic(model)
+        real, state = jax.block_until_ready, {"n": 0}
+
+        def flaky(x):
+            state["n"] += 1
+            if state["n"] == 6:          # the second decode step
+                raise RuntimeError("async device error")
+            return real(x)
+
+        def prepare(eng):
+            monkeypatch.setattr(jax, "block_until_ready", flaky)
+        eng, outs = self._traffic(model, prepare=prepare)
+        assert outs == clean and not eng.quarantined
+        assert eng.pool_rebuilds == 1
+
+    def test_a_probe_that_loses_the_pool_blames_nobody(self):
+        model = tiny_model()
+        _, clean = self._traffic(model)
+        state = {"armed": False, "died": 0}
+
+        def fault(engine, kind, rids, logits):
+            # the first full decode batch faults once; its first probe
+            # then dies inside the jitted call
+            if kind == "decode" and len(rids) == 4 and not state["died"]:
+                state["armed"] = True
+                raise RuntimeError("transient step fault")
+
+        def prepare(eng):
+            real = eng._build_step_fn()
+
+            def flaky(params, ids, positions, last, pages, *rest):
+                if state["armed"] and not state["died"]:
+                    state["died"] = 1
+                    pages[0][0].delete()
+                    raise RuntimeError("device lost in a probe")
+                return real(params, ids, positions, last, pages, *rest)
+            eng._jit_step = flaky
+
+        eng, outs = self._traffic(model, prepare=prepare, step_fault=fault)
+        assert state["died"] == 1 and eng.pool_rebuilds == 1
+        assert not eng.quarantined and outs == clean
+
+    def test_hang_recovery_replaces_a_consumed_pool(self):
+        model = tiny_model()
+        _, clean = self._traffic(model)
+        eng = make_engine(model, max_seqs=4, kv_block_size=4)
+        rids = [eng.submit([1 + i, 2, 3 + i], max_new_tokens=6)
+                for i in range(4)]
+        for _ in range(6):                   # four prefills, two decodes
+            eng.step()
+        eng.cache.drop_pages()               # a step cut inside the call
+        eng._recover_from_hang()
+        assert eng.pool_rebuilds == 1 and eng.watchdog_restarts == 1
+        assert not eng.cache.pages_lost()
+        eng.run(max_steps=500)
+        assert [eng.collect(r)["tokens"] for r in rids] == clean
+
+    def test_hang_recovery_keeps_a_live_pool(self):
+        eng = make_engine(tiny_model(), max_seqs=2, kv_block_size=4)
+        eng.submit([1, 2, 3], max_new_tokens=4)
+        eng.step()
+        handles = [a for kv in eng.cache.pages for a in kv]
+        eng._recover_from_hang()
+        assert eng.pool_rebuilds == 0
+        assert all(x is y for x, y in zip(
+            handles, [a for kv in eng.cache.pages for a in kv]))
 
 
 # ---------------------------------------------------------------------------

@@ -10,9 +10,11 @@ owns it.  Without a TPU every test skips, after at most one cached probe.
 Run on the chip with ``python -m pytest tests/test_tpu_hw.py`` (no
 ``JAX_PLATFORMS`` in the environment).
 
-The last two tests need no chip: they pin what keeps the chip free for the
-one process that should own it (package imports initialise no backend) and
-that ``chip_smoke.py`` cannot pass without one.
+The tests at the end need no chip: they pin what keeps the chip free for the
+one process that should own it (package imports initialise no backend), that
+``chip_smoke.py`` cannot pass without one, and — compiled for a v5e ahead of
+time, from whatever machine has libtpu — that the serving step of ``gpt3-xl``
+updates its KV pool in place.
 """
 import functools
 import json
@@ -274,7 +276,8 @@ from paddle_tpu.inference.paged_attention import (paged_attention_pallas,
                                                   paged_attention_reference)
 
 # the decode kernel at the head shapes of gpt-125M and gpt-1.3B, default
-# block size, ragged lengths: an empty row, one token, a non-multiple of
+# block size, token-major pages (blocks, block_size, heads, head_dim),
+# ragged lengths: an empty row, one token, a non-multiple of
 # the block, an exact multiple, and a full table
 bs, nb, T = 16, 96, 12
 lens = jnp.asarray([0, 1, 37, 64, T * bs, 5, 100, 17], jnp.int32)
@@ -287,8 +290,8 @@ for h, d in ((12, 64), (16, 128)):
     # boundary land one bf16 ulp apart, 2**-6 for the largest |x| < 4 here.
     for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 2.0 ** -6)):
         q = jnp.asarray(rng.randn(B, h, d), dtype)
-        kp = jnp.asarray(rng.randn(nb, h, bs, d), dtype)
-        vp = jnp.asarray(rng.randn(nb, h, bs, d), dtype)
+        kp = jnp.asarray(rng.randn(nb, bs, h, d), dtype)
+        vp = jnp.asarray(rng.randn(nb, bs, h, d), dtype)
         tbl = jnp.asarray(rng.randint(0, nb, (B, T)), jnp.int32)
         run = jax.jit(paged_attention_pallas, static_argnames=("block_size",))
         assert "tpu_custom_call" in run.lower(
@@ -422,3 +425,110 @@ def test_chip_smoke_fails_without_a_tpu_and_rehearses_with_tiny():
     assert not any(k in text for k in ("_ms", "_s\"", "tpot", "ttft"))
     assert summary["serve"]["positions_compared"] > 0
     assert summary["serve"]["leaked_blocks"] == 0
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the serving step of gpt3-xl, compiled for a v5e ahead of time
+# ---------------------------------------------------------------------------
+_AOT_SERVE_SCRIPT = r"""
+import importlib, json, re
+import numpy as np, jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    dev = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+except Exception as e:                 # no libtpu here, or its lock is held
+    print("aot-skip:", repr(e)[:300])
+    raise SystemExit(0)
+print("aot-topology-ok", flush=True)
+
+# off the chip the Pallas entry points default to interpret mode
+import paddle_tpu.ops.flash_attention as fa
+fa._interpret = lambda: False
+importlib.import_module(
+    "paddle_tpu.inference.paged_attention")._interpret = lambda: False
+from paddle_tpu.inference import ServingEngine
+from paddle_tpu.models import GPTConfig, GPTForCausalLM
+from paddle_tpu.observability.registry import MetricsRegistry
+
+# perfbench/configs/gpt3-xl.json and the engine of both serving cells
+LAYERS, HEADS, DIM, BLOCKS, BS, SEQS, LEN = 24, 16, 128, 1856, 16, 128, 2048
+model = GPTForCausalLM(GPTConfig(
+    hidden_size=HEADS * DIM, num_layers=LAYERS, num_heads=HEADS,
+    ffn_hidden_size=4 * HEADS * DIM, max_position_embeddings=LEN,
+    vocab_size=50304, hidden_dropout=0.0, attention_dropout=0.0,
+    dtype="bfloat16"))
+model.astype("bfloat16")
+# the pool is only ever abstract here: the engine's own stays tiny
+eng = ServingEngine(model, max_seqs=SEQS, max_model_len=LEN,
+                    kv_block_size=BS, num_kv_blocks=8,
+                    registry=MetricsRegistry())
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+pool = (BLOCKS, BS, HEADS, DIM)
+pages = [(S(pool, jnp.bfloat16), S(pool, jnp.bfloat16))] * LAYERS
+width = LEN // BS
+for name, rows, chunk in (("serve_decode", SEQS, 1),
+                          ("serve_prefill_b512", 1, 512)):
+    c = eng._build_step_fn().lower(
+        abstract(eng._params), S((rows, chunk), jnp.int32),
+        S((rows,), jnp.int32), S((), jnp.int32), pages,
+        S((rows, width), jnp.int32), S((rows,), jnp.int32),
+        S((rows, chunk), jnp.int32),
+        abstract(jax.random.PRNGKey(0))).compile()
+    text, ma = c.as_text(), c.memory_analysis()
+    shape = r"= bf16\[%d,%d,%d,%d\]" % pool
+    header = text.split("input_output_alias={", 1)[1].split(
+        "entry_computation_layout", 1)[0]
+    print("aot-program", json.dumps({
+        "name": name,
+        "pool_copies": len(re.findall(shape + r"\S* copy(-start)?\(", text)),
+        "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+        "alias_bytes": ma.alias_size_in_bytes,
+        "pool_bytes": 2 * LAYERS * int(np.prod(pool)) * 2,
+        "beside_args_bytes": ma.temp_size_in_bytes
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+        "paged_decode_calls": len(re.findall(
+            r"custom_call_target=\"tpu_custom_call\"[^\n]*paged_decode|"
+            r"paged_decode[^\n]*custom_call_target=\"tpu_custom_call\"",
+            text))}), flush=True)
+print("aot-serve-ok")
+"""
+
+
+@functools.lru_cache(maxsize=1)
+def _aot_serve_programs():
+    """One child compiles both programs (building the 1.3B model on the
+    CPU is most of its minute); None when libtpu cannot give a topology."""
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_SERVE_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu", PTPU_PAGED_KERNEL="pallas",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=1200)
+    if "aot-topology-ok" not in out.stdout:
+        return None, (out.stdout + out.stderr)[-600:]
+    assert out.returncode == 0 and "aot-serve-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    rows = [json.loads(line.split(" ", 1)[1])
+            for line in out.stdout.splitlines()
+            if line.startswith("aot-program ")]
+    return {r["name"]: r for r in rows}, ""
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b512"])
+def test_serving_step_compiled_for_v5e_updates_the_pool_in_place(program):
+    """ISSUE 27: in the real program of ``gpt3-xl`` (24 layers, 1,856
+    blocks, 128 rows) no operation copies a pool-shaped array, every page
+    array's output aliases its input, and the plan holds one pool."""
+    programs, why = _aot_serve_programs()
+    if programs is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    p = programs[program]
+    assert p["pool_copies"] == 0, p
+    assert p["aliased"] == 48 and p["alias_bytes"] == p["pool_bytes"], p
+    # what the program needs beside its arguments (logits, activations) is
+    # nowhere near a second pool
+    assert p["beside_args_bytes"] < p["pool_bytes"] // 10, p
+    assert (p["paged_decode_calls"] == 24) == (program == "serve_decode"), p
