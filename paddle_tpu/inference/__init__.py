@@ -15,6 +15,7 @@ ISSUE 6 grows this package into a real serving subsystem for decoder
 models: :mod:`.engine` (ServingEngine: continuous batching over a paged
 KV cache), :mod:`.kv_cache` (block allocator + page arrays),
 :mod:`.paged_attention` (ragged decode kernel + lax fallback),
+:mod:`.latent_attention` (the same over latent pages, absorbed form),
 :mod:`.scheduler` (admission/preemption policy).  The legacy Config
 routes onto it via ``enable_continuous_batching`` +
 ``set_decoder_model`` — see docs/ARCHITECTURE.md "Serving"."""
@@ -280,12 +281,13 @@ __all__ += ["DataType", "PlaceType", "PrecisionType", "Tensor",
 # -- the serving subsystem (ISSUE 6) ----------------------------------------
 from .engine import CollectTimeout, ServingEngine  # noqa: E402
 from .kv_cache import BlockAllocator, PagedKVCache  # noqa: E402
+from .latent_attention import latent_attention  # noqa: E402
 from .paged_attention import paged_attention  # noqa: E402
 from .scheduler import ContinuousBatchingScheduler  # noqa: E402
 
 __all__ += ["ServingEngine", "CollectTimeout", "PagedKVCache",
             "BlockAllocator", "ContinuousBatchingScheduler",
-            "paged_attention", "EnginePredictor"]
+            "paged_attention", "latent_attention", "EnginePredictor"]
 
 # -- the serving fleet (ISSUE 16) -------------------------------------------
 from . import fleet  # noqa: E402

@@ -188,11 +188,19 @@ class _NonfiniteLogits(RuntimeError):
 class ServingEngine:
     """Paged-KV continuous-batching serving engine over a decoder model.
 
-    ``model`` must expose the ``GPTForCausalLM`` serving surface:
-    ``.config`` (num_layers / num_heads / head_dim /
-    max_position_embeddings / dtype), ``.state_dict()``, ``.eval()`` and
-    an ``apply(..., method="serving_step")`` entry point returning
-    ``(logits, new_caches)`` over ``PagedLayerCache`` lists.
+    ``model`` must expose the serving surface of ``GPTForCausalLM`` and
+    ``DeepseekV2ForCausalLM``: ``.config`` (max_position_embeddings /
+    dtype), ``.state_dict()``, ``.eval()``, ``kv_cache_layout()`` (per
+    layer, the per-token shape of each page array: the pool is built from
+    it) and an ``apply(..., method="serving_step")`` entry point
+    returning ``(logits, new_caches)`` over ``PagedLayerCache`` lists, or
+    ``(logits, new_caches, aux)``.  ``aux`` rides out with the step's
+    outputs and its meaning is the model's: ``aux["counts"]`` (small
+    arrays, copied to the host with the logits) goes to the model's
+    ``serving_counts(counts, kind)``, which names the counters to add to
+    and the gauges to set; ``aux["per_token"]`` (arrays of ``(rows, chunk,
+    ...)``) stays on the device unless a request captures logits, and is
+    then handed out by ``collect()`` a token at a time.
 
     ``temperature`` is engine-level (it is baked into the jitted step;
     per-request temperatures would multiply the compile set).
@@ -252,8 +260,7 @@ class ServingEngine:
             num_kv_blocks = self.max_seqs * blocks_per_seq
         dtype = (jnp.dtype(cfg.dtype) if cfg.dtype != "float32"
                  else jnp.float32)
-        self.cache = PagedKVCache(cfg.num_layers, cfg.num_heads,
-                                  cfg.head_dim, num_kv_blocks,
+        self.cache = PagedKVCache(model.kv_cache_layout(), num_kv_blocks,
                                   block_size=block_size, dtype=dtype)
         self.sched = ContinuousBatchingScheduler(
             self.cache, self.max_seqs, self.max_model_len, clock=clock)
@@ -263,6 +270,12 @@ class ServingEngine:
             shed_queue_depth if shed_queue_depth is not None
             else default_shed_queue_depth())
         self._registry = registry
+        # what the model wants shown beside the engine's own gauges (a
+        # latent cache's bytes a token, say): constants, set once
+        self._model_gauges: Dict[str, float] = dict(
+            getattr(model, "serving_gauges", dict)())
+        for name, value in self._model_gauges.items():
+            self._reg().gauge(name).set(value)
         self.clock = clock
         self._key = jax.random.PRNGKey(seed)
         self._ids = itertools.count()
@@ -314,6 +327,13 @@ class ServingEngine:
         # padding sink) so padded rows stop inflating tokens/s and MFU
         self._pad_real_tokens = 0
         self._pad_slot_tokens = 0
+        # a step's aux outputs (host copies of the counts; the per-token
+        # arrays stay device handles unless a captured request wants
+        # them), the step's root span, and what the model booked so far
+        self._step_aux: Dict[str, Any] = {}
+        self._step_root: Optional[span] = None
+        self._model_counts: Dict[str, Dict[str, Any]] = {
+            "counters": {}, "gauges": {}}
 
     # -- plumbing ----------------------------------------------------------
     def serve_dir(self) -> Optional[str]:
@@ -343,21 +363,21 @@ class ServingEngine:
                   "block_tables", "seq_lens", "slot_mapping", "key")
 
     def _build_step_fn(self):
-        """The step program.  ``pages`` (per layer ``(k, v)``) is donated
-        and nothing else is: with token-major pages XLA scatters the new
-        tokens into the pool in place and every returned page array
-        aliases its input, so no step copies the pool.  The three small
-        per-step arrays arrive once; each layer's view is built here,
-        inside the trace."""
+        """The step program.  ``pages`` (per layer its page arrays, as
+        the model declared them) is donated and nothing else is: with
+        token-major pages XLA scatters the new tokens into the pool in
+        place and every returned page array aliases its input, so no step
+        copies the pool.  The three small per-step arrays arrive once;
+        each layer's view is built here, inside the trace."""
         model, temperature = self.model, self.temperature
         block_size = self.cache.block_size
 
         def fn(params, ids, positions, last_index, pages, block_tables,
                seq_lens, slot_mapping, key):
-            caches = [PagedLayerCache(k, v, block_tables, seq_lens,
+            caches = [PagedLayerCache(layer, block_tables, seq_lens,
                                       slot_mapping, block_size=block_size)
-                      for (k, v) in pages]
-            logits, new_caches = model.apply(
+                      for layer in pages]
+            logits, new_caches, *aux = model.apply(
                 params, ids, caches, positions, last_index,
                 method="serving_step")
             logits = logits.astype(jnp.float32)
@@ -367,7 +387,7 @@ class ServingEngine:
                 nxt = jax.random.categorical(key, logits / temperature,
                                              axis=-1)
             return (nxt.astype(jnp.int32), logits,
-                    [(c.k_pages, c.v_pages) for c in new_caches])
+                    [c.pages for c in new_caches], aux[0] if aux else {})
 
         return jax.jit(fn, donate_argnames=("pages",))
 
@@ -537,6 +557,7 @@ class ServingEngine:
         ``accept``, ``gauges``, and the rare ``quarantine`` /
         ``recover``; ``stats()["phases"]`` sums them."""
         with self._phase("engine.step") as root:
+            self._step_root = root
             with self._phase("reap"):
                 events = self._reap()
             try:
@@ -696,9 +717,9 @@ class ServingEngine:
                 ids.nbytes + positions.nbytes + last.nbytes
                 + tables.nbytes + lens.nbytes + slots.nbytes)
         with self._phase("dispatch"):
-            nxt, logits, pages = fn(self._params, ids_d, positions_d,
-                                    last_d, self.cache.pages, tables_d,
-                                    lens_d, slots_d, key)
+            nxt, logits, pages, aux = fn(self._params, ids_d, positions_d,
+                                         last_d, self.cache.pages, tables_d,
+                                         lens_d, slots_d, key)
             self.cache.update_pages(pages)
         with self._phase("device_wait"):
             try:
@@ -712,8 +733,14 @@ class ServingEngine:
                 raise
         with self._phase("logits_copy"):
             nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
-            reg.counter("serve.d2h_bytes").inc(
-                nxt_np.nbytes + logits_np.nbytes)
+            nbytes = nxt_np.nbytes + logits_np.nbytes
+            if aux:     # the counts come with the outputs; the rest stays
+                counts = jax.tree_util.tree_map(np.asarray,
+                                                aux.get("counts", {}))
+                self._step_aux = dict(aux, counts=counts)
+                nbytes += sum(v.nbytes
+                              for v in jax.tree_util.tree_leaves(counts))
+            reg.counter("serve.d2h_bytes").inc(nbytes)
         return nxt_np, logits_np
 
     def _rebuild_lost_pool(self) -> bool:
@@ -797,6 +824,7 @@ class ServingEngine:
                 self._quarantine_step("prefill", [seq], e, key)
             return []
         with self._phase("accept"):
+            self._note_aux("prefill", [seq])
             self.sched.mark_prefilled(seq)
             reg = self._reg()
             reg.counter("serve.prefills").inc()
@@ -849,6 +877,7 @@ class ServingEngine:
             # their tokens) identical to the un-faulted step
             return self._run_decode(StepPlan("decode", survivors))
         with self._phase("accept"):
+            self._note_aux("decode", seqs)
             reg = self._reg()
             reg.counter("serve.decode_steps").inc()
             reg.histogram("serve.decode_batch").observe(float(len(seqs)))
@@ -864,6 +893,43 @@ class ServingEngine:
                 reg, [(s.request_id, s.trace_id) for s in seqs], len(seqs),
                 t0, float(self.clock()), self._proc)
         return events
+
+    def _note_aux(self, kind: str, seqs: List[SequenceState]) -> None:
+        """Book what the step that just ran handed out beside its logits
+        (nothing for a model without ``aux``).  The model's
+        ``serving_counts`` says which counters the counts add to and
+        which gauges they set; the engine books them in the registry,
+        sums them for ``stats()["model_counts"]`` and sets the counters
+        on the step's span under the name after their last dot.  A
+        captured request keeps its rows of the per-token arrays."""
+        aux, self._step_aux = self._step_aux, {}
+        if not aux:
+            return
+        if aux.get("counts"):
+            booked = self.model.serving_counts(aux["counts"], kind)
+            reg, mine = self._reg(), self._model_counts
+            for name, n in booked.get("counters", {}).items():
+                reg.counter(name).inc(n)
+                mine["counters"][name] = mine["counters"].get(name, 0) + n
+            for name, v in booked.get("gauges", {}).items():
+                reg.gauge(name).set(v)
+                g = mine["gauges"].setdefault(
+                    name, {"last": None, "sum": 0.0, "steps": 0})
+                g.update(last=v, sum=g["sum"] + v, steps=g["steps"] + 1)
+            if self._step_root is not None:
+                self._step_root.set(**{
+                    name.rsplit(".", 1)[-1]: n
+                    for name, n in booked.get("counters", {}).items()})
+        captured = [(i, s) for i, s in enumerate(seqs) if s.capture_logits]
+        if aux.get("per_token") and captured:
+            host = {k: np.asarray(v) for k, v in aux["per_token"].items()}
+            for i, s in captured:
+                if kind == "prefill":      # a re-prefill covers it all again
+                    n = len(s.context())
+                    s.per_token = [{k: v[i, :n] for k, v in host.items()}]
+                else:
+                    s.per_token.append({k: v[i, :1]
+                                        for k, v in host.items()})
 
     # -- poisoned-request quarantine ---------------------------------------
     def _probe(self, seqs: List[SequenceState], key) -> bool:
@@ -1107,6 +1173,11 @@ class ServingEngine:
                "tpot_ms": None if tpot is None else tpot * 1e3}
         if seq.capture_logits:
             out["logits"] = list(seq.logits)
+            if seq.per_token:
+                # what the model hands out a token, (cached tokens, ...)
+                out["per_token"] = {
+                    k: np.concatenate([c[k] for c in seq.per_token])
+                    for k in seq.per_token[0]}
         return out
 
     def generate(self, prompts: Sequence[Sequence[int]],
@@ -1351,6 +1422,12 @@ class ServingEngine:
             "max_seqs": self.max_seqs,
             "max_model_len": self.max_model_len,
             "kv_block_size": self.cache.block_size,
+            "kv_bytes_per_token": self.cache.bytes_per_token(),
+            "model_gauges": dict(self._model_gauges),
+            "model_counts": {
+                "counters": dict(self._model_counts["counters"]),
+                "gauges": {k: dict(v) for k, v
+                           in self._model_counts["gauges"].items()}},
             "kv_blocks": {"total": self.cache.num_blocks,
                           "used": self.cache.allocator.num_used,
                           "occupancy": self.cache.occupancy(),

@@ -18,8 +18,8 @@ Three layers in this module:
   ids: ``alloc / free / defrag`` plus occupancy accounting.  Pure python,
   no device traffic; the scheduler calls it every step.
 - :class:`PagedLayerCache` — the **device-side** view one decoder layer
-  sees inside a jitted step: ``(num_blocks, block_size, heads,
-  head_dim)`` key and value page arrays plus the batch's
+  sees inside a jitted step: its page arrays, ``(num_blocks,
+  block_size) + per-token shape`` each, plus the batch's
   ``block_tables`` / ``seq_lens`` / ``slot_mapping`` int32 arrays.  It
   is a registered pytree, so it flows through ``jax.jit`` with fixed
   structure — the decode step never retraces on cache state.
@@ -27,10 +27,15 @@ Three layers in this module:
   arrays + the allocator + per-sequence tables, with the array-building
   helpers the engine uses to assemble fixed-shape step inputs.
 
-Page layout: token-major, ``pages[block, offset, head, :]``.  One
-token's ``(heads, head_dim)`` slab is the minor tile (16 × 128 bf16 is
-exactly one 4 KB TPU tile) and a block is ``block_size`` of them,
-contiguous.  That is the layout XLA's scatter of new tokens wants, and
+What a token keeps in a layer is the MODEL's to declare (``layout``,
+from its ``kv_cache_layout()``): a multi-head model keeps two arrays of
+``(heads, head_dim)``, keys and values; a latent-attention model one row
+shared by all heads (ISSUE 28).  Allocator, tables and slot arithmetic
+are the same for every layout.
+
+Page layout: token-major, ``pages[block, offset, ...]``.  One token's
+slab is the minor tile (a ``(16, 128)`` bf16 slab is exactly one 4 KB
+TPU tile) and a block is ``block_size`` of them, contiguous.  That is the layout XLA's scatter of new tokens wants, and
 one the decode kernel accepts (its block's two minor dims are the
 array's), so a step program that is handed the pool **donated** writes
 it in place: no relayout before the scatter, none after it, no second
@@ -54,6 +59,7 @@ lands.
 from __future__ import annotations
 
 import functools
+import itertools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -188,8 +194,9 @@ class BlockAllocator:
 class PagedLayerCache:
     """One decoder layer's jit-visible paged-cache view.
 
-    ``k_pages`` / ``v_pages``: ``(num_blocks, block_size, heads,
-    head_dim)`` page arrays.
+    ``pages``: this layer's page arrays, ``(num_blocks, block_size) +
+    per-token shape`` each, in the order the model declared them
+    (``k_pages`` / ``v_pages`` name the two of a keys-and-values layout).
     ``block_tables``: ``(batch, max_blocks_per_seq)`` int32 block ids
     (padded rows/entries are 0 — masked out by ``seq_lens``).
     ``seq_lens``: ``(batch,)`` int32 context length *including* the
@@ -202,13 +209,12 @@ class PagedLayerCache:
     as a compile-time constant (the attention kernel's grid needs it).
     """
 
-    __slots__ = ("k_pages", "v_pages", "block_tables", "seq_lens",
-                 "slot_mapping", "block_size")
+    __slots__ = ("pages", "block_tables", "seq_lens", "slot_mapping",
+                 "block_size")
 
-    def __init__(self, k_pages, v_pages, block_tables, seq_lens,
-                 slot_mapping, block_size: int):
-        self.k_pages = k_pages
-        self.v_pages = v_pages
+    def __init__(self, pages, block_tables, seq_lens, slot_mapping,
+                 block_size: int):
+        self.pages = tuple(pages)
         self.block_tables = block_tables
         self.seq_lens = seq_lens
         self.slot_mapping = slot_mapping
@@ -219,22 +225,30 @@ class PagedLayerCache:
         fields.update(kw)
         return PagedLayerCache(**fields)
 
-    def write(self, new_k, new_v) -> "PagedLayerCache":
-        """Scatter this call's ``(batch * chunk, heads, head_dim)`` keys
-        and values into the pages at ``slot_mapping``; padded slots are
-        out of bounds and dropped."""
+    @property
+    def k_pages(self):
+        return self.pages[0]
+
+    @property
+    def v_pages(self):
+        return self.pages[1]
+
+    def write(self, *new) -> "PagedLayerCache":
+        """Scatter this call's ``(batch * chunk,) + per-token shape``
+        arrays, one per page array, into the pages at ``slot_mapping``;
+        padded slots are out of bounds and dropped."""
+        enforce(len(new) == len(self.pages),
+                f"{len(new)} arrays for {len(self.pages)} page arrays")
         slots = self.slot_mapping.reshape(-1)
         blk, off = slots // self.block_size, slots % self.block_size
-        k_pages = self.k_pages.at[blk, off].set(
-            new_k.astype(self.k_pages.dtype), mode="drop")
-        v_pages = self.v_pages.at[blk, off].set(
-            new_v.astype(self.v_pages.dtype), mode="drop")
-        return self.replace(k_pages=k_pages, v_pages=v_pages)
+        return self.replace(pages=tuple(
+            p.at[blk, off].set(x.astype(p.dtype), mode="drop")
+            for p, x in zip(self.pages, new)))
 
 
 def _plc_flatten(c: PagedLayerCache):
-    return ((c.k_pages, c.v_pages, c.block_tables, c.seq_lens,
-             c.slot_mapping), c.block_size)
+    return ((c.pages, c.block_tables, c.seq_lens, c.slot_mapping),
+            c.block_size)
 
 
 def _plc_unflatten(block_size, children):
@@ -249,8 +263,8 @@ _tree_util.register_pytree_node(PagedLayerCache, _plc_flatten,
 def _zero_blocks(pages, block_ids):
     """Zeros into ``block_ids`` of every page array, in place (the pool
     is donated); ids out of bounds are dropped."""
-    return [(k.at[block_ids].set(0, mode="drop"),
-             v.at[block_ids].set(0, mode="drop")) for (k, v) in pages]
+    return [tuple(a.at[block_ids].set(0, mode="drop") for a in layer)
+            for layer in pages]
 
 
 class PagedKVCache:
@@ -263,14 +277,23 @@ class PagedKVCache:
     updated page arrays back through :meth:`update_pages`.
     """
 
-    def __init__(self, num_layers: int, num_heads: int, head_dim: int,
+    def __init__(self, layout: Sequence[Sequence[Sequence[int]]],
                  num_blocks: int, block_size: Optional[int] = None,
                  dtype=jnp.float32):
+        """``layout``: per layer, the per-token shape of each of its page
+        arrays, as the model's ``kv_cache_layout()`` declares them (keys
+        and values of 2 heads of 4: ``[((2, 4), (2, 4))]``)."""
         block_size = (default_kv_block_size() if block_size is None
                       else int(block_size))
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        self.layout = [tuple(tuple(int(d) for d in shape) for shape in layer)
+                       for layer in layout]
+        enforce(self.layout and all(self.layout), "empty cache layout")
+        self.num_layers = len(self.layout)
+        self._arity = [len(layer) for layer in self.layout]
+        self.num_arrays = sum(self._arity)
+        # values a token keeps in each page array, in the pool's order
+        self._token_sizes = [int(np.prod(shape)) for layer in self.layout
+                             for shape in layer]
         self.block_size = block_size
         self.num_blocks = int(num_blocks)
         self.num_slots = self.num_blocks * block_size
@@ -282,36 +305,35 @@ class PagedKVCache:
 
     # -- the page arrays ---------------------------------------------------
     @property
-    def pages(self) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
-        """Per layer ``(k_pages, v_pages)``, each ``(num_blocks,
-        block_size, heads, head_dim)``: the step program's donated
-        argument."""
+    def pages(self) -> List[Tuple[jnp.ndarray, ...]]:
+        """Per layer its page arrays, each ``(num_blocks, block_size) +
+        per-token shape``: the step program's donated argument."""
         return self._pages
 
     def update_pages(self, pages: Sequence[Tuple]) -> None:
         """Take the page arrays a step program returned in place of the
         ones it consumed."""
-        enforce(len(pages) == self.num_layers,
-                f"{len(pages)} page pairs for {self.num_layers} layers")
-        self._pages = [(k, v) for (k, v) in pages]
+        enforce([len(layer) for layer in pages] == self._arity,
+                f"page arrays handed back do not match the layout of "
+                f"{self.num_layers} layers")
+        self._pages = [tuple(layer) for layer in pages]
 
     def reset_pages(self) -> None:
         """A zeroed pool.  The old handles, if any are left, are let go
         first, so the pool is never held twice."""
-        self._pages: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
-        shape = (self.num_blocks, self.block_size, self.num_heads,
-                 self.head_dim)
-        self._pages = [
-            (jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype))
-            for _ in range(self.num_layers)]
+        self._pages: List[Tuple[jnp.ndarray, ...]] = []
+        lead = (self.num_blocks, self.block_size)
+        self._pages = [tuple(jnp.zeros(lead + shape, self.dtype)
+                             for shape in layer) for layer in self.layout]
 
     def _live_handles(self) -> List[jnp.ndarray]:
-        return [a for kv in self._pages for a in kv if not a.is_deleted()]
+        return [a for layer in self._pages for a in layer
+                if not a.is_deleted()]
 
     def pages_lost(self) -> bool:
         """True when a page handle is dead: a step program consumed the
         pool and did not hand one back."""
-        return len(self._live_handles()) < 2 * self.num_layers
+        return len(self._live_handles()) < self.num_arrays
 
     def drop_pages(self) -> None:
         """Delete every page handle: what they hold cannot be trusted (the
@@ -320,12 +342,18 @@ class PagedKVCache:
             a.delete()
 
     def pool_bytes(self) -> int:
-        """Device bytes behind the live page handles: one pool.  (Every
-        page array has the same size; ``Array.nbytes`` costs 2 us a call,
-        and this is read in every step.)"""
-        return len(self._live_handles()) * (
-            self.num_slots * self.num_heads * self.head_dim
-            * self.dtype.itemsize)
+        """Device bytes behind the live page handles: one pool.  (From
+        the shapes: ``Array.nbytes`` costs 2 us a call, and this is read
+        in every step.)"""
+        arrays = itertools.chain.from_iterable(self._pages)
+        return self.num_slots * self.dtype.itemsize * sum(
+            n for a, n in zip(arrays, self._token_sizes)
+            if not a.is_deleted())
+
+    def bytes_per_token(self) -> int:
+        """What one cached token takes over all layers, padding of the
+        declared shapes included."""
+        return self.dtype.itemsize * sum(self._token_sizes)
 
     def scrub_seq(self, seq_id) -> None:
         """Zero ``seq_id``'s blocks in every layer (one small donated
@@ -433,9 +461,9 @@ class PagedKVCache:
         bt = jnp.asarray(block_tables, jnp.int32)
         sl = jnp.asarray(seq_lens, jnp.int32)
         sm = jnp.asarray(slot_mapping, jnp.int32)
-        return [PagedLayerCache(k, v, bt, sl, sm,
+        return [PagedLayerCache(layer, bt, sl, sm,
                                 block_size=self.block_size)
-                for (k, v) in self._pages]
+                for layer in self._pages]
 
     # -- defrag ------------------------------------------------------------
     def defrag(self) -> bool:
@@ -447,7 +475,6 @@ class PagedKVCache:
             return False
         idx = jnp.asarray(perm)
         # a layer at a time, so at most one layer's pages are held twice
-        for i, (k, v) in enumerate(self._pages):
-            self._pages[i] = (jnp.take(k, idx, axis=0),
-                              jnp.take(v, idx, axis=0))
+        for i, layer in enumerate(self._pages):
+            self._pages[i] = tuple(jnp.take(a, idx, axis=0) for a in layer)
         return True

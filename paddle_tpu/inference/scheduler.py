@@ -108,6 +108,9 @@ class SequenceState:
     pending: Optional[int] = None       # sampled, KV not yet cached
     computed_len: int = 0
     logits: List = dataclasses.field(default_factory=list)
+    # under capture_logits, what the model hands out a token beside the
+    # logits (an expert layer's choices, say), a step at a time
+    per_token: List = dataclasses.field(default_factory=list)
     first_token_time: Optional[float] = None
     last_token_time: Optional[float] = None
     finish_reason: Optional[str] = None

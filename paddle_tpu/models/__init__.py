@@ -3,6 +3,8 @@ of BASELINE.json; vision models live in paddle_tpu.vision.models)."""
 from .gpt import (GPTConfig, GPTModel, GPTForCausalLM,  # noqa: F401
                   gpt_tiny, gpt_125m, gpt_350m, gpt_1p3b, gpt_6p7b)
 from .gpt_pipeline import GPTPipeline  # noqa: F401
+from .deepseek_v2 import (DeepseekV2Config,  # noqa: F401
+                          DeepseekV2ForCausalLM, deepseek_v2_tiny)
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertForSequenceClassification, bert_tiny,
                    bert_base, bert_large)
@@ -10,5 +12,6 @@ from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
 __all__ = ["BertConfig", "BertModel", "BertForPretraining",
            "BertForSequenceClassification", "bert_tiny", "bert_base",
            "bert_large",
+           "DeepseekV2Config", "DeepseekV2ForCausalLM", "deepseek_v2_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPipeline", "gpt_tiny",
            "gpt_125m", "gpt_350m", "gpt_1p3b", "gpt_6p7b"]

@@ -598,6 +598,13 @@ class GPTForCausalLM(Layer):
             logits = jnp.einsum("bh,vh->bv", h_last, table)
         return logits, new_caches
 
+    def kv_cache_layout(self):
+        """What a token keeps in each layer's pages, for the serving
+        engine's pool: keys and values of ``(heads, head_dim)``."""
+        c = self.config
+        slab = (c.num_heads, c.head_dim)
+        return [(slab, slab)] * c.num_layers
+
     def make_caches(self, batch_size: int, max_length: int):
         """Fixed-shape KV caches (one (k_buf, v_buf, used) triple per
         layer) for jitted decoding — preallocated so every decode step has

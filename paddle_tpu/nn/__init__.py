@@ -22,6 +22,7 @@ from .layers import (AdaptiveMaxPool2D, AvgPool1D, Conv1D, Conv3D,  # noqa: F401
                      SpectralNorm, Transformer, TransformerDecoder,
                      TransformerDecoderLayer, TripletMarginLoss, Unflatten,
                      Upsample, UpsamplingBilinear2D, UpsamplingNearest2D)
+from .dropless_moe import DroplessMoE, SwiGLU  # noqa: F401
 from .rnn import (GRU, LSTM, RNN, BiRNN, GRUCell, LSTMCell,  # noqa: F401
                   SimpleRNN, SimpleRNNCell)
 from .layers_ext import *  # noqa: F401,F403,E402  (long-tail layer classes)

@@ -6,6 +6,7 @@ idiomatic JAX signature — wrapped in a tiny class for paddle-shaped API parity
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -55,6 +56,24 @@ class Normal(Initializer):
     def __call__(self, key, shape, dtype=jnp.float32):
         x = self.mean + self.std * jax.random.normal(key, shape, dtype=jnp.float32)
         return x.astype(dtype)
+
+
+class NormalInDtype(Initializer):
+    """``Normal(0, std)`` drawn and cast in ONE jitted program, a tensor
+    at a time, so a float32 copy of the tensor never sits on the device
+    beside it (ISSUE 28: a 5-billion-parameter model served in bf16 has
+    no room for its float32 self)."""
+
+    def __init__(self, std=1.0):
+        self.std = std
+
+    def __call__(self, key, shape, dtype=jnp.float32):
+        return _normal_in_dtype(key, self.std, tuple(shape), jnp.dtype(dtype))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _normal_in_dtype(key, std, shape, dtype):
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
 class TruncatedNormal(Initializer):

@@ -1,0 +1,4 @@
+"""``guard`` + ``accept`` + ``gauges``, percent of the program's
+``engine.step`` spans that ended in the window."""
+from perfbench.harness.phase_reads import (  # noqa: F401
+    accept_share as read)

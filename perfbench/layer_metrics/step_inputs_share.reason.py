@@ -1,0 +1,4 @@
+"""``tables`` + ``h2d``, percent of the program's ``engine.step`` spans that
+ended in the window."""
+from perfbench.harness.phase_reads import (  # noqa: F401
+    inputs_share as read)

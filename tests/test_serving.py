@@ -104,8 +104,8 @@ class TestBlockAllocator:
 # ---------------------------------------------------------------------------
 class TestPagedKVCache:
     def make(self, blocks=6, bs=4):
-        return PagedKVCache(num_layers=1, num_heads=2, head_dim=4,
-                            num_blocks=blocks, block_size=bs)
+        return PagedKVCache([((2, 4), (2, 4))], num_blocks=blocks,
+                            block_size=bs)
 
     def test_capacity_growth_and_slots(self):
         c = self.make()
@@ -163,8 +163,8 @@ class TestPagedKVCache:
 
 
     def test_write_lands_token_major_and_drops_padding(self):
-        c = PagedKVCache(num_layers=2, num_heads=2, head_dim=4,
-                         num_blocks=6, block_size=4)
+        c = PagedKVCache([((2, 4), (2, 4))] * 2, num_blocks=6,
+                         block_size=4)
         c.ensure_capacity("a", 6)
         slots = c.slot_array(["a"], [3], 4)          # positions 3..6
         assert slots[0, 3] != c.slot_pad
@@ -292,8 +292,8 @@ class TestPagedAttention:
 # ---------------------------------------------------------------------------
 class TestScheduler:
     def make(self, blocks=4, bs=4, max_seqs=3, max_len=16):
-        cache = PagedKVCache(num_layers=1, num_heads=1, head_dim=4,
-                             num_blocks=blocks, block_size=bs)
+        cache = PagedKVCache([((1, 4), (1, 4))], num_blocks=blocks,
+                             block_size=bs)
         return cache, ContinuousBatchingScheduler(cache, max_seqs, max_len)
 
     @staticmethod

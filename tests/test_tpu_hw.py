@@ -532,3 +532,116 @@ def test_serving_step_compiled_for_v5e_updates_the_pool_in_place(program):
     # nowhere near a second pool
     assert p["beside_args_bytes"] < p["pool_bytes"] // 10, p
     assert (p["paged_decode_calls"] == 24) == (program == "serve_decode"), p
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the serving step of deepseek-v2-ep4-l5 (ISSUE 28), compiled
+# for a v5e ahead of time: the latent kernel and the grouped products lower
+# through Mosaic at the published widths, and the latent pool stays in place
+# ---------------------------------------------------------------------------
+_AOT_DEEPSEEK_SCRIPT = r"""
+import json, re
+import numpy as np, jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    dev = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+except Exception as e:                 # no libtpu here, or its lock is held
+    print("aot-skip:", repr(e)[:300])
+    raise SystemExit(0)
+print("aot-topology-ok", flush=True)
+
+# a 5-billion-parameter model is only ever abstract here
+from paddle_tpu.nn import initializer as I
+I.NormalInDtype.__call__ = lambda self, key, shape, dtype=jnp.float32: \
+    jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+# off the chip the kernels' entry points take their CPU routes
+import paddle_tpu.inference.latent_attention as la
+import paddle_tpu.ops.grouped_matmul as gm
+la._interpret = gm._interpret = lambda: False
+jax.default_backend = lambda: "tpu"
+from paddle_tpu.inference import ServingEngine
+from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                           DeepseekV2ForCausalLM)
+from paddle_tpu.observability.registry import MetricsRegistry
+
+# perfbench/configs/deepseek-v2-ep4-l5.json + traffic/reason-backlog.json
+traffic = json.load(open("perfbench/traffic/reason-backlog.json"))["engine"]
+SEQS, LEN = traffic["max_seqs"], traffic["max_model_len"]
+BS, BLOCKS = traffic["kv_block_size"], traffic["num_kv_blocks"]
+model = DeepseekV2ForCausalLM(DeepseekV2Config(
+    vocab_size=25600, num_layers=5, dtype="bfloat16", ep_degree=4,
+    ep_rank=0))
+eng = ServingEngine(model, max_seqs=SEQS, max_model_len=LEN,
+                    kv_block_size=BS, num_kv_blocks=8,
+                    registry=MetricsRegistry())
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+pool = (BLOCKS, BS, 640)
+pages = [(S(pool, jnp.bfloat16),)] * 5
+params = abstract(eng._params)
+n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+for name, rows, chunk in (("serve_decode", SEQS, 1),
+                          ("serve_prefill_b1024", 1, 1024)):
+    c = eng._build_step_fn().lower(
+        params, S((rows, chunk), jnp.int32), S((rows,), jnp.int32),
+        S((), jnp.int32), pages, S((rows, LEN // BS), jnp.int32),
+        S((rows,), jnp.int32), S((rows, chunk), jnp.int32),
+        abstract(jax.random.PRNGKey(0))).compile()
+    text, ma = c.as_text(), c.memory_analysis()
+    header = text.split("input_output_alias={", 1)[1].split(
+        "entry_computation_layout", 1)[0]
+    calls = lambda k: len(re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
+        + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    print("aot-program", json.dumps({
+        "name": name, "n_params": n_params,
+        "pool_copies": len(re.findall(
+            r"= bf16\[%d,%d,%d\]\S* copy(-start)?\(" % pool, text)),
+        "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+        "alias_bytes": ma.alias_size_in_bytes,
+        "pool_bytes": 5 * int(np.prod(pool)) * 2,
+        "plan_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+        "latent_calls": calls("mla_latent_attn"),
+        "grouped_calls": calls("moe_grouped")}), flush=True)
+print("aot-serve-ok")
+"""
+
+
+@functools.lru_cache(maxsize=1)
+def _aot_deepseek_programs():
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_DEEPSEEK_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=1200)
+    if "aot-topology-ok" not in out.stdout:
+        return None, (out.stdout + out.stderr)[-600:]
+    assert out.returncode == 0 and "aot-serve-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    rows = [json.loads(line.split(" ", 1)[1])
+            for line in out.stdout.splitlines()
+            if line.startswith("aot-program ")]
+    return {r["name"]: r for r in rows}, ""
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b1024"])
+def test_deepseek_v2_step_compiled_for_v5e_fits_and_stays_in_place(program):
+    """ISSUE 28: the cell's own step programs (published widths, 40 held
+    experts, 256 rows, the traffic file's pool) compile for a v5e: the
+    kernels lower, the 5 latent page arrays alias their inputs with no
+    pool-shaped copy, and weights + pool + temporaries fit one chip."""
+    programs, why = _aot_deepseek_programs()
+    if programs is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    p = programs[program]
+    assert p["n_params"] == 5_163_975_680, p          # 10.33 GB in bf16
+    assert p["pool_copies"] == 0, p
+    assert p["aliased"] == 5 and p["alias_bytes"] == p["pool_bytes"], p
+    assert p["plan_bytes"] < 15.75e9, p
+    decode = program == "serve_decode"
+    assert p["latent_calls"] == (5 if decode else 0), p
+    assert p["grouped_calls"] == 8, p                 # 4 layers x (up, down)
