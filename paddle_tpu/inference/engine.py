@@ -31,7 +31,9 @@ SLO telemetry rides the PR 3 registry: gauges ``serve.queue_depth`` /
 histograms ``serve.ttft_ms`` / ``serve.tpot_ms``, counters
 ``serve.tokens`` / ``serve.requests`` / ``serve.finished`` /
 ``serve.preemptions`` / ``serve.h2d_bytes`` / ``serve.d2h_bytes`` (what
-a step puts on the device and copies back) / ``serve.pool_rebuilds``,
+a step puts on the device and copies back) / ``serve.pool_rebuilds`` /
+``serve.paged_blocks_live`` / ``serve.paged_blocks_table`` (the pages a
+decode step's rows hold, and rows launched x table width),
 gauge ``serve.kv_pool_bytes`` (the live page handles: one pool).  Every
 ``step()`` is an ``engine.step`` span of :mod:`observability.tracing`
 whose children name its phases (see :meth:`ServingEngine.step`);
@@ -334,6 +336,7 @@ class ServingEngine:
         self._step_root: Optional[span] = None
         self._model_counts: Dict[str, Dict[str, Any]] = {
             "counters": {}, "gauges": {}}
+        self._paged_blocks = {"live": 0, "table": 0}
 
     # -- plumbing ----------------------------------------------------------
     def serve_dir(self) -> Optional[str]:
@@ -551,7 +554,8 @@ class ServingEngine:
         produced; empty when idle AND no queued work remains.
 
         The step is one ``engine.step`` span (attributes ``step``,
-        ``kind``, ``rows``, ``bucket``) whose children name where its
+        ``kind``, ``rows``, ``bucket``; a decode step also
+        ``kv_blocks_live``, ``kv_blocks_table``) whose children name where its
         host time goes — ``reap``, ``schedule``, ``tables``, ``h2d``,
         ``dispatch``, ``device_wait``, ``logits_copy``, ``guard``,
         ``accept``, ``gauges``, and the rare ``quarantine`` /
@@ -801,6 +805,7 @@ class ServingEngine:
             tables = self.cache.table_array(sids,
                                             self.sched.max_blocks_per_seq)
             slots = self.cache.slot_array(sids, starts, 1)
+            self._note_paged_blocks(lens, tables)
             fn = self._decode_fn()
         nxt_np, logits_np = self._device_step(
             fn, ids, positions, 0, tables, lens, slots, key)
@@ -893,6 +898,20 @@ class ServingEngine:
                 reg, [(s.request_id, s.trace_id) for s in seqs], len(seqs),
                 t0, float(self.clock()), self._proc)
         return events
+
+    def _note_paged_blocks(self, lens: np.ndarray, tables: np.ndarray):
+        """How much of a decode step's block tables is live: the pages
+        its rows hold against rows launched x table width, which is what
+        a kernel that walked the whole table would visit."""
+        live = int(np.sum(-(-lens // self.cache.block_size)))
+        reg = self._reg()
+        reg.counter("serve.paged_blocks_live").inc(live)
+        reg.counter("serve.paged_blocks_table").inc(tables.size)
+        self._paged_blocks["live"] += live
+        self._paged_blocks["table"] += tables.size
+        if self._step_root is not None:
+            self._step_root.set(kv_blocks_live=live,
+                                kv_blocks_table=tables.size)
 
     def _note_aux(self, kind: str, seqs: List[SequenceState]) -> None:
         """Book what the step that just ran handed out beside its logits
@@ -1428,6 +1447,7 @@ class ServingEngine:
                 "counters": dict(self._model_counts["counters"]),
                 "gauges": {k: dict(v) for k, v
                            in self._model_counts["gauges"].items()}},
+            "paged_blocks": dict(self._paged_blocks),
             "kv_blocks": {"total": self.cache.num_blocks,
                           "used": self.cache.allocator.num_used,
                           "occupancy": self.cache.occupancy(),

@@ -29,7 +29,9 @@ Three layers in this module:
 
 What a token keeps in a layer is the MODEL's to declare (``layout``,
 from its ``kv_cache_layout()``): a multi-head model keeps two arrays of
-``(heads, head_dim)``, keys and values; a latent-attention model one row
+``(heads, head_dim)``, keys and values, rounded up to whole tiles where
+the decode kernel reads them (``paged_attention.page_token_shape``: the
+writes fill the rest with zeros); a latent-attention model one row
 shared by all heads (ISSUE 28).  Allocator, tables and slot arithmetic
 are the same for every layout.
 
@@ -236,13 +238,22 @@ class PagedLayerCache:
     def write(self, *new) -> "PagedLayerCache":
         """Scatter this call's ``(batch * chunk,) + per-token shape``
         arrays, one per page array, into the pages at ``slot_mapping``;
-        padded slots are out of bounds and dropped."""
+        padded slots are out of bounds and dropped.  An array narrower
+        than its pages' per-token shape (a pool kept in whole tiles) is
+        filled up with zeros."""
         enforce(len(new) == len(self.pages),
                 f"{len(new)} arrays for {len(self.pages)} page arrays")
         slots = self.slot_mapping.reshape(-1)
         blk, off = slots // self.block_size, slots % self.block_size
+
+        def fit(x, p):
+            x = x.astype(p.dtype)
+            if x.shape[1:] == p.shape[2:]:
+                return x
+            return jnp.pad(x, [(0, 0)] + [
+                (0, n - m) for n, m in zip(p.shape[2:], x.shape[1:])])
         return self.replace(pages=tuple(
-            p.at[blk, off].set(x.astype(p.dtype), mode="drop")
+            p.at[blk, off].set(fit(x, p), mode="drop")
             for p, x in zip(self.pages, new)))
 
 

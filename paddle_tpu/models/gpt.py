@@ -600,9 +600,11 @@ class GPTForCausalLM(Layer):
 
     def kv_cache_layout(self):
         """What a token keeps in each layer's pages, for the serving
-        engine's pool: keys and values of ``(heads, head_dim)``."""
+        engine's pool: keys and values of ``(heads, head_dim)``, in whole
+        tiles where the decode kernel reads them."""
+        from ..inference.paged_attention import page_token_shape
         c = self.config
-        slab = (c.num_heads, c.head_dim)
+        slab = page_token_shape(c.num_heads, c.head_dim, c.dtype)
         return [(slab, slab)] * c.num_layers
 
     def make_caches(self, batch_size: int, max_length: int):

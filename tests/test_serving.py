@@ -1,6 +1,7 @@
 """Serving subsystem (ISSUE 6): paged-KV cache invariants, scheduler
 policy under a tight block budget, ragged-vs-dense numerics, the compile
 contract, the slow-consumer fault drill, and the legacy facade routing."""
+import importlib
 import re
 import time
 
@@ -12,7 +13,8 @@ import jax.numpy as jnp
 import paddle_tpu as pt
 from paddle_tpu.inference import (BlockAllocator, Config, PagedKVCache,
                                   ServingEngine, create_predictor)
-from paddle_tpu.inference.paged_attention import (paged_attention_pallas,
+from paddle_tpu.inference.paged_attention import (_pages_per_wave,
+                                                  paged_attention_pallas,
                                                   paged_attention_reference)
 from paddle_tpu.inference.scheduler import (ContinuousBatchingScheduler,
                                             SequenceState, prefill_bucket)
@@ -218,6 +220,15 @@ class TestPagedKVCache:
 # ---------------------------------------------------------------------------
 # Ragged paged attention numerics
 # ---------------------------------------------------------------------------
+def _in_whole_tiles(pages):
+    """Pages as the pool keeps them on the chip (``page_token_shape``):
+    the token slab in whole bf16 tiles, zeros in the added heads and
+    dims."""
+    h, d = pages.shape[2:]
+    return jnp.asarray(np.pad(pages, [(0, 0), (0, 0), (0, -h % 16),
+                                      (0, -d % 128)]), jnp.bfloat16)
+
+
 class TestPagedAttention:
     def test_pallas_matches_reference_incl_empty_rows(self):
         rng = np.random.RandomState(0)
@@ -257,6 +268,165 @@ class TestPagedAttention:
         assert bool(jnp.isfinite(out).all())
         assert float(jnp.max(jnp.abs(out - ref))) <= tol
         assert float(jnp.max(jnp.abs(out[0]))) == 0.0      # the empty row
+
+    @pytest.mark.parametrize("h,d,bs,poison", [
+        (h, d, bs, False) for h, d in ((12, 64), (16, 128))
+        for bs in (4, 8, 16, 32)] + [(12, 64, 4, True), (16, 128, 16, True)])
+    def test_pallas_walks_live_pages_only(self, h, d, bs, poison):
+        """ISSUE 29: a row's loop ends at its own length.  Lengths 0, 1,
+        a page, a wave, a wave and a token, something ragged and the
+        whole table, empty rows between live ones, a table whose width
+        is no multiple of the wave.  Poisoned: every page that no live
+        table entry names is NaN and the dead part of each table holds
+        an id outside the pool, so an entry past a row's length is
+        neither read nor dereferenced."""
+        # the pool keeps both head shapes as 16 x 128 bf16 tiles, as on
+        # the chip: 128 tokens a wave
+        wave = _pages_per_wave(bs, 16, 128, jnp.bfloat16, 10 ** 6)
+        assert wave * bs == 128
+        T = 2 * wave + 3
+        lens = np.asarray([0, 1, bs, 0, wave * bs, wave * bs + 1, 0,
+                           2 * wave * bs - bs // 2, T * bs, 0], np.int32)
+        B = lens.shape[0]
+        used = -(-lens // bs)
+        nb = int(used.sum()) + 5
+        rng = np.random.RandomState(bs + h)
+        q = jnp.asarray(rng.randn(B, h, d), jnp.bfloat16)
+        kp = rng.randn(nb, bs, h, d).astype(np.float32)
+        vp = rng.randn(nb, bs, h, d).astype(np.float32)
+        tbl = np.zeros((B, T), np.int32)         # what the engine pads with
+        ids = rng.permutation(nb)[:used.sum()]   # every live entry its own
+        for b in range(B):
+            tbl[b, :used[b]], ids = ids[:used[b]], ids[used[b]:]
+        ref = paged_attention_reference(
+            q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16),
+            jnp.asarray(tbl), jnp.asarray(lens), bs).astype(jnp.float32)
+        if poison:
+            named = np.zeros(nb, bool)
+            for b in range(B):
+                named[tbl[b, :used[b]]] = True
+                tbl[b, used[b]:] = nb + 1000
+            kp[~named] = np.nan
+            vp[~named] = np.nan
+        out = paged_attention_pallas(
+            q, _in_whole_tiles(kp), _in_whole_tiles(vp),
+            jnp.asarray(tbl), jnp.asarray(lens), bs,
+            interpret=True).astype(jnp.float32)
+        assert out.shape == q.shape
+        assert bool(jnp.isfinite(out).all())
+        assert float(jnp.max(jnp.abs(out - ref))) <= 2.0 ** -6
+        assert not np.asarray(out)[lens == 0].any()     # the empty rows
+
+    @pytest.mark.parametrize("h,d,bs", [(16, 128, 16), (12, 64, 4)])
+    def test_pallas_keeps_a_rows_nan_to_itself(self, h, d, bs):
+        """A row whose own keys and values went non-finite this step (they
+        are written to its pages before attention) returns NaN and no
+        other row does: a shorter row's partial wave leaves the rest of
+        the VMEM buffer as the long row's waves filled it, and the tail
+        of a row's last page is its last owner's.  Both are masked, keys
+        by the score's select and values before ``p @ v``."""
+        wave = _pages_per_wave(bs, 16, 128, jnp.bfloat16, 10 ** 6)
+        T = 3 * wave
+        # the poisoned row fills both halves of the double buffer; after
+        # it come one token, a page and a token, nothing, a wave and a token
+        lens = np.asarray([5, 3 * wave * bs, 1, bs + 1, 0, wave * bs + 1],
+                          np.int32)
+        sick = 1
+        B = lens.shape[0]
+        used = -(-lens // bs)
+        nb = int(used.sum())
+        rng = np.random.RandomState(h)
+        q = jnp.asarray(rng.randn(B, h, d), jnp.bfloat16)
+        kp = rng.randn(nb, bs, h, d).astype(np.float32)
+        vp = rng.randn(nb, bs, h, d).astype(np.float32)
+        tbl = np.zeros((B, T), np.int32)
+        ids = rng.permutation(nb)
+        for b in range(B):
+            tbl[b, :used[b]], ids = ids[:used[b]], ids[used[b]:]
+        args = (jnp.asarray(tbl), jnp.asarray(lens), bs)
+        ref = paged_attention_reference(
+            q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16),
+            *args).astype(jnp.float32)
+        kp[tbl[sick, :used[sick]]] = np.nan
+        vp[tbl[sick, :used[sick]]] = np.nan
+        for b in range(B):                   # what a page's last owner left
+            if b != sick and lens[b] % bs:
+                kp[tbl[b, used[b] - 1], lens[b] % bs:] = np.nan
+                vp[tbl[b, used[b] - 1], lens[b] % bs:] = np.nan
+        out = np.asarray(paged_attention_pallas(
+            q, _in_whole_tiles(kp), _in_whole_tiles(vp),
+            *args, interpret=True).astype(jnp.float32))
+        well = np.arange(B) != sick
+        assert np.isnan(out[sick]).all()
+        assert np.isfinite(out[well]).all()
+        assert np.abs(out[well] - np.asarray(ref)[well]).max() <= 2.0 ** -6
+
+    @pytest.mark.parametrize("q_dtype,page_dtype,tol", [
+        (jnp.float32, jnp.bfloat16, 1e-4), (jnp.bfloat16, jnp.float32,
+                                            2.0 ** -6)])
+    def test_pallas_takes_a_query_of_another_type_than_the_pages(
+            self, q_dtype, page_dtype, tol):
+        # the products are those of the stored values in f32, as the
+        # reference's, whatever the two types are
+        rng = np.random.RandomState(3)
+        B, H, D, bs, nb, T = 3, 16, 128, 16, 12, 4
+        q = jnp.asarray(rng.randn(B, H, D), q_dtype)
+        kp = jnp.asarray(rng.randn(nb, bs, H, D), page_dtype)
+        vp = jnp.asarray(rng.randn(nb, bs, H, D), page_dtype)
+        tbl = jnp.asarray(rng.randint(0, nb, (B, T)), jnp.int32)
+        lens = jnp.asarray([T * bs, 0, 19], jnp.int32)
+        ref = paged_attention_reference(q, kp, vp, tbl, lens, bs)
+        out = paged_attention_pallas(q, kp, vp, tbl, lens, bs, interpret=True)
+        assert out.dtype == ref.dtype == q.dtype
+        assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                     - ref.astype(jnp.float32)))) <= tol
+
+    @pytest.mark.parametrize("route,compiled,dtype,want", [
+        ("pallas", True, "bfloat16", (16, 128)),
+        ("pallas", True, "float32", (16, 128)),
+        ("pallas", False, "bfloat16", (12, 64)),    # interpret mode
+        ("reference", True, "bfloat16", (12, 64)),
+        ("", False, "bfloat16", (12, 64))])         # a CPU's own choice
+    def test_pages_are_whole_tiles_where_the_kernel_runs_compiled(
+            self, monkeypatch, route, compiled, dtype, want):
+        pa = importlib.import_module("paddle_tpu.inference.paged_attention")
+        monkeypatch.setenv(pa.PAGED_KERNEL_ENV, route)
+        monkeypatch.setattr(pa, "_interpret", lambda: not compiled)
+        assert pa.page_token_shape(12, 64, dtype) == want
+        assert pa.page_token_shape(16, 128, dtype) == (16, 128)
+        cfg = GPTConfig(vocab_size=32, hidden_size=12 * 64, num_layers=2,
+                        num_heads=12, max_position_embeddings=32, dtype=dtype)
+        assert (GPTForCausalLM.kv_cache_layout(type("M", (), {"config": cfg}))
+                == [(want, want)] * 2)
+
+    def test_compiled_kernel_refuses_pages_that_are_not_whole_tiles(self):
+        q = jnp.zeros((2, 12, 64), jnp.bfloat16)
+        pages = jnp.zeros((3, 4, 12, 64), jnp.bfloat16)
+        with pytest.raises(Exception, match="whole tiles"):
+            paged_attention_pallas(q, pages, pages,
+                                   jnp.zeros((2, 2), jnp.int32),
+                                   jnp.ones((2,), jnp.int32), 4,
+                                   interpret=False)
+
+    def test_engine_on_a_pool_of_whole_tiles_says_the_same(self, monkeypatch):
+        """What the chip's engine does at a head shape that is not whole
+        tiles: the pool is wider than the model's heads, writes fill the
+        rest with zeros, attention widens ``q`` and cuts the output."""
+        pa = importlib.import_module("paddle_tpu.inference.paged_attention")
+        prompts = [[1, 2, 3, 4, 5], [6, 7], [8, 9, 10]]
+        model = tiny_model()
+
+        def tokens():
+            eng = ServingEngine(model, max_seqs=4, kv_block_size=4,
+                                registry=MetricsRegistry())
+            return eng, eng.generate(prompts, max_new_tokens=6)
+        _, plain = tokens()
+        monkeypatch.setattr(pa, "page_token_shape", pa._whole_tiles)
+        eng, tiled = tokens()
+        assert eng.cache.pages[0][0].shape[2:] == (8, 128)
+        assert tiled == plain
+        assert not np.asarray(eng.cache.pages[0][0])[:, :, 2:].any()
+        assert not np.asarray(eng.cache.pages[0][1])[:, :, :, 16:].any()
 
     def test_head_major_pages_are_refused(self):
         q = jnp.zeros((1, 2, 8))
@@ -649,6 +819,38 @@ class TestStepSpans:
         assert h2d.value - h0 == prefill + 2 * decode
         # next tokens (int32) and float32 logits over vocab 32
         assert d2h.value - d0 == (4 + 4 * 32) + 2 * 2 * (4 + 4 * 32)
+
+    def test_paged_block_counters_and_span_attributes(self, warm):
+        """ISSUE 29: the share of a decode step's block tables that is
+        live.  Tables are 8 wide (32 positions / 4), 2 rows launched."""
+        eng, reg, tracing = warm
+        live, table = (reg.counter("serve.paged_blocks_live"),
+                       reg.counter("serve.paged_blocks_table"))
+        l0, t0 = live.value, table.value
+        before = dict(eng.stats()["paged_blocks"])
+        eng.submit([1, 2, 3, 4, 5], max_new_tokens=5)
+        eng.submit([6, 7], max_new_tokens=2)
+        steps0 = eng.steps
+        eng.run()
+        want_live = want_table = decodes = 0
+        for step in range(steps0, eng.steps):
+            attrs = self.step_spans(tracing, step)[0][3]
+            if attrs["kind"] != "decode":
+                assert "kv_blocks_live" not in attrs
+                continue
+            decodes += 1
+            assert attrs["kv_blocks_table"] == 2 * 8
+            assert 1 <= attrs["kv_blocks_live"] <= 2 * 3
+            want_live += attrs["kv_blocks_live"]
+            want_table += attrs["kv_blocks_table"]
+        # row one decodes at lengths 6..9 (2, 2, 2, 3 pages of 4), row two
+        # at length 3 (1 page): its second token is its last
+        assert decodes == 4 and want_live == 2 + 2 + 2 + 3 + 1
+        assert live.value - l0 == want_live
+        assert table.value - t0 == want_table == decodes * 2 * 8
+        after = eng.stats()["paged_blocks"]
+        assert after["live"] - before["live"] == want_live
+        assert after["table"] - before["table"] == want_table
 
     def test_a_quarantined_step_names_its_bisection(self):
         from paddle_tpu.observability import tracing

@@ -272,7 +272,8 @@ def test_flash_matrix_on_tpu():
 
 
 _PAGED_SCRIPT = r"""
-from paddle_tpu.inference.paged_attention import (paged_attention_pallas,
+from paddle_tpu.inference.paged_attention import (page_token_shape,
+                                                  paged_attention_pallas,
                                                   paged_attention_reference)
 
 # the decode kernel at the head shapes of gpt-125M and gpt-1.3B, default
@@ -290,8 +291,13 @@ for h, d in ((12, 64), (16, 128)):
     # boundary land one bf16 ulp apart, 2**-6 for the largest |x| < 4 here.
     for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 2.0 ** -6)):
         q = jnp.asarray(rng.randn(B, h, d), dtype)
-        kp = jnp.asarray(rng.randn(nb, bs, h, d), dtype)
-        vp = jnp.asarray(rng.randn(nb, bs, h, d), dtype)
+        # the pool as a model allocates it here: whole tiles, zeros in
+        # the heads and dims that 12 x 64 does not fill
+        hp, dp = page_token_shape(h, d, dtype)
+        assert hp % 8 == 0 and dp % 128 == 0, (hp, dp)
+        room = [(0, 0), (0, 0), (0, hp - h), (0, dp - d)]
+        kp = jnp.asarray(np.pad(rng.randn(nb, bs, h, d), room), dtype)
+        vp = jnp.asarray(np.pad(rng.randn(nb, bs, h, d), room), dtype)
         tbl = jnp.asarray(rng.randint(0, nb, (B, T)), jnp.int32)
         run = jax.jit(paged_attention_pallas, static_argnames=("block_size",))
         assert "tpu_custom_call" in run.lower(
@@ -303,6 +309,63 @@ for h, d in ((12, 64), (16, 128)):
         assert bool(jnp.isfinite(out).all()) and err <= tol, (h, d, dtype, err)
         assert float(jnp.max(jnp.abs(out[0]))) == 0.0      # the empty row
         print("paged", h, d, jnp.dtype(dtype).name, err, flush=True)
+
+# ISSUE 29: the serving cells' table (2048 positions wide) at every block
+# size: a full-table row, a wave (128 tokens) to the token and one more,
+# empty rows between the live ones, and a NaN page under every table
+# entry past a row's length, which the kernel must never read
+h, d, nb = 16, 128, 160
+for bs in (4, 8, 16, 32):
+    T = 2048 // bs
+    lens = np.asarray([0, 2048, 0, 128, 129, 0, 1, 700], np.int32)
+    B = lens.shape[0]
+    q = jnp.asarray(rng.randn(B, h, d), jnp.bfloat16)
+    kp = rng.randn(nb, bs, h, d).astype(np.float32)
+    vp = rng.randn(nb, bs, h, d).astype(np.float32)
+    kp[nb - 1] = vp[nb - 1] = np.nan
+    tbl = rng.randint(0, nb - 1, (B, T)).astype(np.int32)
+    clean = tbl.copy()
+    for b in range(B):
+        tbl[b, -(-lens[b] // bs):] = nb - 1
+    kp, vp = jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16)
+    out = run(q, kp, vp, jnp.asarray(tbl), jnp.asarray(lens),
+              block_size=bs).astype(jnp.float32)
+    ref = paged_attention_reference(
+        q, kp, vp, jnp.asarray(clean), jnp.asarray(lens), bs).astype(
+        jnp.float32)
+    err = float(jnp.max(jnp.abs(out - ref)))
+    assert bool(jnp.isfinite(out).all()) and err <= 2.0 ** -6, (bs, err)
+    assert not np.asarray(out)[lens == 0].any()
+    print("paged-table", bs, err, flush=True)
+
+# a row whose own pages went NaN (three waves: both halves of the double
+# buffer) keeps it to itself: the shorter rows after it fill part of a
+# wave's buffer, and the tail of their last page is NaN as well
+bs, lens = 16, np.asarray([5, 300, 1, 17, 0, 129], np.int32)
+B, used = lens.shape[0], -(-lens // bs)
+kp = rng.randn(nb, bs, h, d).astype(np.float32)
+vp = rng.randn(nb, bs, h, d).astype(np.float32)
+tbl, ids = np.zeros((B, 2048 // bs), np.int32), rng.permutation(nb)
+for b in range(B):
+    tbl[b, :used[b]], ids = ids[:used[b]], ids[used[b]:]
+q = jnp.asarray(rng.randn(B, h, d), jnp.bfloat16)
+args = (jnp.asarray(tbl), jnp.asarray(lens))
+ref = np.asarray(paged_attention_reference(
+    q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16), *args,
+    bs).astype(jnp.float32))
+for b in range(B):
+    if b == 1:
+        kp[tbl[b, :used[b]]] = vp[tbl[b, :used[b]]] = np.nan
+    elif lens[b] % bs:
+        kp[tbl[b, used[b] - 1], lens[b] % bs:] = np.nan
+        vp[tbl[b, used[b] - 1], lens[b] % bs:] = np.nan
+out = np.asarray(run(q, jnp.asarray(kp, jnp.bfloat16),
+                     jnp.asarray(vp, jnp.bfloat16), *args,
+                     block_size=bs).astype(jnp.float32))
+well = np.arange(B) != 1
+assert np.isnan(out[1]).all() and np.isfinite(out[well]).all()
+assert np.abs(out[well] - ref[well]).max() <= 2.0 ** -6
+print("paged-nan-row-ok", flush=True)
 print("paged-hw-ok")
 """
 
@@ -449,59 +512,84 @@ fa._interpret = lambda: False
 importlib.import_module(
     "paddle_tpu.inference.paged_attention")._interpret = lambda: False
 from paddle_tpu.inference import ServingEngine
+from paddle_tpu.inference.paged_attention import (page_token_shape,
+                                                  paged_attention_pallas)
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.observability.registry import MetricsRegistry
 
-# perfbench/configs/gpt3-xl.json and the engine of both serving cells
-LAYERS, HEADS, DIM, BLOCKS, BS, SEQS, LEN = 24, 16, 128, 1856, 16, 128, 2048
-model = GPTForCausalLM(GPTConfig(
-    hidden_size=HEADS * DIM, num_layers=LAYERS, num_heads=HEADS,
-    ffn_hidden_size=4 * HEADS * DIM, max_position_embeddings=LEN,
-    vocab_size=50304, hidden_dropout=0.0, attention_dropout=0.0,
-    dtype="bfloat16"))
-model.astype("bfloat16")
-# the pool is only ever abstract here: the engine's own stays tiny
-eng = ServingEngine(model, max_seqs=SEQS, max_model_len=LEN,
-                    kv_block_size=BS, num_kv_blocks=8,
-                    registry=MetricsRegistry())
 sh = SingleDeviceSharding(dev)
 S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+# the paged kernel alone (ISSUE 29): both head shapes, block sizes 4-32,
+# the cells' table of 2048 positions and one no wave divides
+for heads, dim in ((12, 64), (16, 128)):
+    for bs, width in ((4, 512), (8, 256), (16, 128), (32, 64), (16, 13)):
+        page = (64, bs) + page_token_shape(heads, dim, jnp.bfloat16)
+        text = jax.jit(paged_attention_pallas,
+                       static_argnames=("block_size",)).lower(
+            S((128, heads, dim), jnp.bfloat16), S(page, jnp.bfloat16),
+            S(page, jnp.bfloat16), S((128, width), jnp.int32),
+            S((128,), jnp.int32), block_size=bs).compile().as_text()
+        print("aot-kernel", json.dumps({
+            "name": "%dx%d-bs%d-w%d" % (heads, dim, bs, width),
+            "paged_decode_calls": len(re.findall(
+                r"custom_call_target=\"tpu_custom_call\"", text))}),
+            flush=True)
+
+# perfbench/configs/gpt3-xl.json and the engine of both serving cells,
+# then GPT-125M's head shape, whose pool is kept in whole tiles
 abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
-pool = (BLOCKS, BS, HEADS, DIM)
-pages = [(S(pool, jnp.bfloat16), S(pool, jnp.bfloat16))] * LAYERS
-width = LEN // BS
-for name, rows, chunk in (("serve_decode", SEQS, 1),
-                          ("serve_prefill_b512", 1, 512)):
-    c = eng._build_step_fn().lower(
-        abstract(eng._params), S((rows, chunk), jnp.int32),
-        S((rows,), jnp.int32), S((), jnp.int32), pages,
-        S((rows, width), jnp.int32), S((rows,), jnp.int32),
-        S((rows, chunk), jnp.int32),
-        abstract(jax.random.PRNGKey(0))).compile()
-    text, ma = c.as_text(), c.memory_analysis()
-    shape = r"= bf16\[%d,%d,%d,%d\]" % pool
-    header = text.split("input_output_alias={", 1)[1].split(
-        "entry_computation_layout", 1)[0]
-    print("aot-program", json.dumps({
-        "name": name,
-        "pool_copies": len(re.findall(shape + r"\S* copy(-start)?\(", text)),
-        "aliased": len(re.findall(r"\(\d+, \{\}", header)),
-        "alias_bytes": ma.alias_size_in_bytes,
-        "pool_bytes": 2 * LAYERS * int(np.prod(pool)) * 2,
-        "beside_args_bytes": ma.temp_size_in_bytes
-        + ma.output_size_in_bytes - ma.alias_size_in_bytes,
-        "paged_decode_calls": len(re.findall(
-            r"custom_call_target=\"tpu_custom_call\"[^\n]*paged_decode|"
-            r"paged_decode[^\n]*custom_call_target=\"tpu_custom_call\"",
-            text))}), flush=True)
+for tag, LAYERS, HEADS, DIM, BLOCKS, BS, SEQS, LEN in (
+        ("", 24, 16, 128, 1856, 16, 128, 2048),
+        ("_125m", 12, 12, 64, 1024, 16, 8, 2048)):
+    model = GPTForCausalLM(GPTConfig(
+        hidden_size=HEADS * DIM, num_layers=LAYERS, num_heads=HEADS,
+        ffn_hidden_size=4 * HEADS * DIM, max_position_embeddings=LEN,
+        vocab_size=50304, hidden_dropout=0.0, attention_dropout=0.0,
+        dtype="bfloat16"))
+    model.astype("bfloat16")
+    # the pool is only ever abstract here: the engine's own stays tiny
+    eng = ServingEngine(model, max_seqs=SEQS, max_model_len=LEN,
+                        kv_block_size=BS, num_kv_blocks=8,
+                        registry=MetricsRegistry())
+    pool = (BLOCKS,) + eng.cache.pages[0][0].shape[1:]
+    pages = [(S(pool, jnp.bfloat16), S(pool, jnp.bfloat16))] * LAYERS
+    width = LEN // BS
+    for name, rows, chunk in (("serve_decode", SEQS, 1),
+                              ("serve_prefill_b512", 1, 512)):
+        c = eng._build_step_fn().lower(
+            abstract(eng._params), S((rows, chunk), jnp.int32),
+            S((rows,), jnp.int32), S((), jnp.int32), pages,
+            S((rows, width), jnp.int32), S((rows,), jnp.int32),
+            S((rows, chunk), jnp.int32),
+            abstract(jax.random.PRNGKey(0))).compile()
+        text, ma = c.as_text(), c.memory_analysis()
+        # an operation whose result is a whole page array, other than the
+        # in-place write and what it is fused into
+        whole = r"= bf16\[%d,%d,\d+,\d+\]\S* (copy|copy-start|pad)\(" % (
+            BLOCKS, BS)
+        header = text.split("input_output_alias={", 1)[1].split(
+            "entry_computation_layout", 1)[0]
+        print("aot-program", json.dumps({
+            "name": name + tag, "pool": pool,
+            "pool_copies": len(re.findall(whole, text)),
+            "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+            "alias_bytes": ma.alias_size_in_bytes,
+            "pool_bytes": 2 * LAYERS * int(np.prod(pool)) * 2,
+            "beside_args_bytes": ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+            "paged_decode_calls": len(re.findall(
+                r"custom_call_target=\"tpu_custom_call\"[^\n]*paged_decode|"
+                r"paged_decode[^\n]*custom_call_target=\"tpu_custom_call\"",
+                text))}), flush=True)
 print("aot-serve-ok")
 """
 
 
 @functools.lru_cache(maxsize=1)
 def _aot_serve_programs():
-    """One child compiles both programs (building the 1.3B model on the
-    CPU is most of its minute); None when libtpu cannot give a topology."""
+    """One child compiles the paged kernel's shapes and both programs
+    (building the 1.3B model on the CPU is most of its minute); None when
+    libtpu cannot give a topology."""
     out = subprocess.run(
         [sys.executable, "-c", _AOT_SERVE_SCRIPT], cwd=str(REPO),
         env=dict(_sub_env(), JAX_PLATFORMS="cpu", PTPU_PAGED_KERNEL="pallas",
@@ -513,25 +601,46 @@ def _aot_serve_programs():
         f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
     rows = [json.loads(line.split(" ", 1)[1])
             for line in out.stdout.splitlines()
-            if line.startswith("aot-program ")]
+            if line.startswith(("aot-program ", "aot-kernel "))]
     return {r["name"]: r for r in rows}, ""
 
 
-@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b512"])
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b512",
+                                     "serve_decode_125m",
+                                     "serve_prefill_b512_125m"])
 def test_serving_step_compiled_for_v5e_updates_the_pool_in_place(program):
     """ISSUE 27: in the real program of ``gpt3-xl`` (24 layers, 1,856
     blocks, 128 rows) no operation copies a pool-shaped array, every page
-    array's output aliases its input, and the plan holds one pool."""
+    array's output aliases its input, and the plan holds one pool.  The
+    same at GPT-125M's 12 x 64 heads, whose pool is kept as 16 x 128
+    tiles: no copy between XLA's layout and the kernel's, and no pad."""
     programs, why = _aot_serve_programs()
     if programs is None:
         pytest.skip(f"no v5e topology from libtpu here: {why}")
     p = programs[program]
+    layers = 12 if program.endswith("_125m") else 24
+    assert p["pool"][2:] == [16, 128], p
     assert p["pool_copies"] == 0, p
-    assert p["aliased"] == 48 and p["alias_bytes"] == p["pool_bytes"], p
+    assert p["aliased"] == 2 * layers, p
+    assert p["alias_bytes"] == p["pool_bytes"], p
     # what the program needs beside its arguments (logits, activations) is
     # nowhere near a second pool
     assert p["beside_args_bytes"] < p["pool_bytes"] // 10, p
-    assert (p["paged_decode_calls"] == 24) == (program == "serve_decode"), p
+    assert (p["paged_decode_calls"] == layers) == ("decode" in program), p
+
+
+@pytest.mark.parametrize("heads,dim", [(12, 64), (16, 128)])
+@pytest.mark.parametrize("bs,width", [(4, 512), (8, 256), (16, 128),
+                                      (32, 64), (16, 13)])
+def test_paged_kernel_compiled_for_v5e(heads, dim, bs, width):
+    """ISSUE 29: the kernel whose loop follows the live pages lowers
+    through Mosaic at both GPT head shapes, block sizes 4 to 32 and a
+    table that no wave divides, as one custom call."""
+    programs, why = _aot_serve_programs()
+    if programs is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    p = programs["%dx%d-bs%d-w%d" % (heads, dim, bs, width)]
+    assert p["paged_decode_calls"] == 1, p
 
 
 # ---------------------------------------------------------------------------
