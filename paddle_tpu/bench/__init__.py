@@ -1,58 +1,40 @@
-"""Performance observatory (ISSUE 13; ROADMAP 5b).
+"""The scenario matrix (ISSUE 13): a CPU smoke that prints rows.
 
-The scenario matrix that replaces bench.py's monolith: every workload
-family the repo claims speed on (GPT pretrain fused/unfused, MoE,
+Every workload family of the matrix (GPT pretrain fused/unfused, MoE,
 long-context sequence-parallel, ResNet/MNIST vision, serve-mode decode)
 runs under one measurement discipline and emits ONE schema-versioned
-row into the append-only ``benchmarks/ledger.jsonl``.
+row to stdout.  Nothing here keeps a history or judges a number: the
+benchmark is ``BENCHMARK.json`` + ``perfbench/``, its record
+``PERF_LEDGER.jsonl`` (``PERF.md``).
 
 Layout::
 
-    schema.py     row schema v1: fingerprint, phase breakdown, validate
-    ledger.py     append-only ledger + golden + series view + --compact
+    schema.py     row schema: fingerprint, phase breakdown, validate
     harness.py    phase-timed step loop, compile window, bytes-on-wire
     scenarios.py  the registered workload matrix
-    runner.py     scenario → row assembly → ledger append
-    diff.py       perfdiff: row-vs-row / golden / trailing-median
-    gate.py       the CI perf tier (noise-aware; --write-golden)
-    trends.py     series model: noise floors, changepoints, drift (14)
-    report.py     self-contained HTML dashboard (inline SVG) (14)
+    runner.py     scenario → row assembly
 
-Entry points::
+Entry point::
 
-    python -m paddle_tpu.bench --all --smoke     # run matrix, append rows
-    python -m paddle_tpu.bench.diff              # attribute a regression
-    python -m paddle_tpu.bench.gate              # enforce, noise-aware
-    python -m paddle_tpu.bench.trends            # series report
-    python -m paddle_tpu.bench.report            # HTML dashboard
-    python -m paddle_tpu.bench.ledger --compact  # bound history
+    python -m paddle_tpu.bench (--all | --scenario NAME) [--smoke]
 """
 from __future__ import annotations
 
-from . import harness, ledger, schema
-from .ledger import (DEFAULT_LEDGER_KEEP, DEFAULT_THRESHOLDS, append_row,
-                     compact_ledger, default_golden_path,
-                     default_ledger_path, latest_rows, load_golden,
-                     read_ledger, read_series, threshold, write_golden)
+from . import harness, schema
 from .schema import (KNOWN_SCHEMA_VERSIONS, METRICS, PHASES,
                      SCHEMA_VERSION, fingerprint_key, metric_value,
                      new_row, validate_row)
 
 __all__ = [
-    "schema", "ledger", "harness",
+    "schema", "harness",
     "SCHEMA_VERSION", "KNOWN_SCHEMA_VERSIONS", "PHASES", "METRICS",
     "new_row", "validate_row", "fingerprint_key", "metric_value",
-    "append_row", "read_ledger", "latest_rows", "read_series",
-    "compact_ledger", "load_golden",
-    "write_golden", "threshold", "default_ledger_path",
-    "default_golden_path", "DEFAULT_THRESHOLDS", "DEFAULT_LEDGER_KEEP",
     "run_scenarios",
 ]
 
 
 def run_scenarios(*args, **kwargs):
     """Lazy forward to :func:`runner.run_scenarios` (the runner imports
-    jax-heavy scenario code; keep ``import paddle_tpu.bench`` light for
-    tooling that only reads the ledger)."""
+    jax-heavy scenario code; keep ``import paddle_tpu.bench`` light)."""
     from .runner import run_scenarios as _run
     return _run(*args, **kwargs)

@@ -1,8 +1,8 @@
 """``python -m paddle_tpu.bench`` — run the scenario matrix.
 
-Each selected scenario emits one validated row: appended to the ledger
-(unless ``--no-append``) and printed to stdout as JSONL (stdout carries
-only rows; diagnostics go to stderr, same contract as bench.py).
+Each selected scenario emits one validated row, printed to stdout as
+JSONL (stdout carries only rows; diagnostics go to stderr).  No file is
+written.
 """
 from __future__ import annotations
 
@@ -18,8 +18,8 @@ from .runner import run_scenarios
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.bench",
-        description="performance observatory: run the scenario matrix "
-                    "and append one ledger row per scenario")
+        description="run the scenario matrix and print one row per "
+                    "scenario")
     ap.add_argument("--all", action="store_true",
                     help="run every registered scenario")
     ap.add_argument("--scenario", action="append", default=[],
@@ -28,10 +28,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="CPU-sized smoke shapes (default)")
     ap.add_argument("--full", action="store_true",
                     help="the real BASELINE shapes (TPU-sized)")
-    ap.add_argument("--ledger", default=None,
-                    help="ledger path override")
-    ap.add_argument("--no-append", action="store_true",
-                    help="print rows without touching the ledger")
     ap.add_argument("--list", action="store_true",
                     help="list registered scenarios and exit")
     args = ap.parse_args(argv)
@@ -46,8 +42,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         ap.error("pick --all or at least one --scenario NAME "
                  "(see --list)")
     mode = "full" if args.full else "smoke"
-    rows = run_scenarios(names, mode=mode, ledger_path=args.ledger,
-                         append=not args.no_append)
+    rows = run_scenarios(names, mode=mode)
     for row in rows:
         sys.stdout.write(json.dumps(row) + "\n")
     sys.stdout.flush()

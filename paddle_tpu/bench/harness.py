@@ -6,17 +6,16 @@ One measurement discipline for every scenario, so rows are comparable:
   the ledger's phase axes: **data** (host batch production), **compute**
   (the dispatch call), **readback** (the host readback of the loss —
   dispatch is asynchronous, so this is where the host waits for the
-  device; see bench.py's module note).  The
-  **collective** phase comes from the ``collective.<op>.ms`` histogram
-  deltas the comm layer records across the timed window.
+  device).  The **collective** phase comes from the
+  ``collective.<op>.ms`` histogram deltas the comm layer records across
+  the timed window.
 - :class:`CompileWindow` — brackets a scenario with a compile-tracker
   reset and registry-counter baselines, yielding the row's ``compile``
   stats (wall, traces, retraces, in-process cache hits, persistent
   disk-cache hits/requests from ``observability/compilecache``).
 - :func:`peak_hbm` — PJRT ``memory_stats()`` peak when the backend
   exposes it, else the compiled program's memory analysis
-  (temp+argument+output bytes), the platform-independent proxy bench.py
-  has always used.
+  (temp+argument+output bytes), the platform-independent proxy.
 """
 from __future__ import annotations
 

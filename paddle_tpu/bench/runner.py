@@ -1,9 +1,9 @@
-"""Scenario runner (ISSUE 13): registry entry → one validated ledger row.
+"""Scenario runner (ISSUE 13): registry entry → one validated row.
 
 ``run_scenario`` is the assembly point — it brackets the scenario with
-the compile window and bytes-on-wire baselines, stamps device
-provenance, and validates + appends the row.  Scenario code never
-touches the ledger; the runner never touches model code.
+the compile window and bytes-on-wire baselines and stamps device
+provenance; ``run_scenarios`` validates each row.  The runner never
+touches model code.
 """
 from __future__ import annotations
 
@@ -12,7 +12,7 @@ import sys
 import traceback
 from typing import Any, Dict, List, Optional
 
-from . import harness, ledger, scenarios, schema
+from . import harness, scenarios, schema
 
 __all__ = ["run_scenario", "run_scenarios", "ensure_devices"]
 
@@ -25,9 +25,9 @@ def _emit_diag(msg: str) -> None:
 def ensure_devices() -> str:
     """Decide what the matrix runs on; returns the platform.
 
-    Mirrors bench.py's doctrine — ``BENCH_CPU=1`` or ``JAX_PLATFORMS=cpu``
-    asks for the virtual CPU mesh (8-wide so the meshed scenarios —
-    long_context's dp×sp axes — have devices to shard over); otherwise
+    ``BENCH_CPU=1`` or ``JAX_PLATFORMS=cpu`` asks for the virtual CPU
+    mesh (8-wide so the meshed scenarios — long_context's dp×sp axes —
+    have devices to shard over); otherwise
     the matrix runs on the TPU this process finds, and finding anything
     else is an error: a run that was not told to use a CPU never
     continues on one.
@@ -97,7 +97,7 @@ def run_scenario(name: str, mode: str = "smoke",
         extra=payload.get("extra"),
     )
     # mirror the headline figures into the live registry so /statusz and
-    # the doctor see the freshest matrix without re-reading the ledger
+    # the doctor see the freshest matrix
     p50 = row["step_time_ms"]["p50"]
     if p50 is not None:
         registry.gauge(f"perf.step_time_ms[scenario={name}]").set(p50)
@@ -172,13 +172,12 @@ def run_scenario(name: str, mode: str = "smoke",
     return row
 
 
-def run_scenarios(names: Optional[List[str]] = None, mode: str = "smoke",
-                  ledger_path: Optional[str] = None,
-                  append: bool = True) -> List[Dict[str, Any]]:
-    """Run the matrix; each scenario's row is validated and appended as
-    it lands (a later scenario crashing never loses earlier rows).
-    Scenario failures are reported and skipped, not fatal — the matrix
-    must degrade scenario-by-scenario, like the doctor's checks.
+def run_scenarios(names: Optional[List[str]] = None,
+                  mode: str = "smoke") -> List[Dict[str, Any]]:
+    """Run the matrix; each scenario's row is validated as it lands.
+    Scenario failures (an invalid row is one) are reported and skipped,
+    not fatal — the matrix must degrade scenario-by-scenario, like the
+    doctor's checks.
     """
     ensure_devices()
     rows: List[Dict[str, Any]] = []
@@ -186,12 +185,14 @@ def run_scenarios(names: Optional[List[str]] = None, mode: str = "smoke",
         _emit_diag(f"[bench] {name} ({mode}) ...")
         try:
             row = run_scenario(name, mode)
+            errors = schema.validate_row(row)
+            if errors:
+                raise ValueError(f"invalid row for scenario {name!r}: "
+                                 + "; ".join(errors))
         except Exception:
             _emit_diag(f"[bench] scenario {name!r} failed:\n"
                        + traceback.format_exc())
             continue
-        if append:
-            ledger.append_row(row, path=ledger_path)
         rows.append(row)
         _emit_diag(f"[bench] {name}: p50={row['step_time_ms']['p50']:.2f}ms"
                    f" compile={row['compile'].get('wall_ms', 0):.0f}ms"

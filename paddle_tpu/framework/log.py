@@ -26,12 +26,25 @@ _cached_level = None
 _cached_version = -1
 
 
+class _StderrHandler(logging.StreamHandler):
+    """Writes to ``sys.stderr`` as it is at emit time, not as it was when
+    the first caller logged: a process that swaps the stream later (a
+    test's capture, a daemon's redirect) still sees the lines."""
+
+    def __init__(self):
+        logging.Handler.__init__(self)
+
+    @property
+    def stream(self):
+        return sys.stderr
+
+
 def get_logger() -> logging.Logger:
     global _logger
     if _logger is None:
         logger = logging.getLogger("paddle_tpu")
         if not logger.handlers:
-            h = logging.StreamHandler(sys.stderr)
+            h = _StderrHandler()
             h.setFormatter(logging.Formatter(
                 "%(asctime)s [paddle_tpu] %(levelname)s %(message)s"))
             logger.addHandler(h)

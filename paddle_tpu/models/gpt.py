@@ -97,7 +97,7 @@ class GPTConfig:
     moe_every: int = 2
     # memory-efficient LM loss (ops/fused.py linear_softmax_cross_entropy):
     # never materializes the [B, S, V] logits/softmax — measured on v5e this
-    # is the top HLO temp of the naive path (benchmarks/batch_scan_125m.json)
+    # is the top HLO temp of the naive path (a July reading, to re-measure)
     fused_lm_loss: bool = True
 
     def is_moe_layer(self, index: int) -> bool:

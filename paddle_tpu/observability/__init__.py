@@ -3,7 +3,7 @@
 Before this package, run health lived in four unrelated channels — VLOG
 lines (``framework/log.py``), the XPlane profiler wrapper, the
 supervisor's JSON report, heartbeat files — and the only MFU number came
-from ``bench.py``'s one-shot harness.  This package is the shared spine
+from a one-shot harness.  This package is the shared spine
 they all report through:
 
 - :mod:`registry` — process-wide counters / gauges / bounded-reservoir
@@ -15,8 +15,8 @@ they all report through:
 - :mod:`sinks` — the run-scoped JSONL ``MetricsWriter`` (fsync'd via
   ``utils/fsio``), a periodic stderr summary line, and a Prometheus
   textfile exporter;
-- :mod:`mfu` — the peak-TFLOPs table and FLOPs-per-token math shared by
-  ``bench.py`` and the live per-step MFU in ``hapi.Model.fit``;
+- :mod:`mfu` — the peak-TFLOPs table and FLOPs-per-token math behind
+  the live per-step MFU in ``hapi.Model.fit``;
 - :mod:`aggregate` — merges ``<run_dir>/metrics/worker-*.jsonl`` into
   ``summary.json`` (driven by ``launch --run_dir``), including the
   cross-worker straggler skew stats;

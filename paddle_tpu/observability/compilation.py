@@ -162,7 +162,7 @@ class CompileTracker:
                 wall_ms: Optional[float] = None) -> Optional[dict]:
         """Classify one call; returns the emitted ``compile`` record on a
         miss, None on a hit.  Called by the :func:`track_jit` wrapper —
-        or directly by code that times its own compiles (bench.py)."""
+        or directly by code that times its own compiles."""
         return self.observe_signatures([arg_signature(a) for a in args],
                                        name=name, arg_names=arg_names,
                                        wall_ms=wall_ms)

@@ -36,11 +36,6 @@ evidence lines):
                        step; the verdict names the dominant (op, axis)
                        and its efficiency vs the ICI cost model.
 - ``data_starved``   — data-wait dominates the step-time breakdown.
-- ``perf_trend``     — the ledger *series* for a benched scenario shows
-                       an upward step-time changepoint (named by git-sha
-                       range and dominant phase, via ``bench.trends``)
-                       or a flagged upward drift — the multi-commit
-                       creep a pairwise golden comparison can't see.
 - ``unstable``       — the supervisor logged rollbacks / watchdog
                        timeouts / step failures (corroborating context,
                        ranked below the causes above).
@@ -79,8 +74,7 @@ from .sinks import metrics_dir
 
 __all__ = ["diagnose", "render_report", "main", "check_compilation",
            "check_memory", "check_straggler", "check_data_starved",
-           "check_comm_bound", "check_supervisor",
-           "check_perf_regression", "check_perf_trend", "check_serving",
+           "check_comm_bound", "check_supervisor", "check_serving",
            "check_fleet", "check_fleet_flapping",
            "check_fleet_slo_burn", "check_tail_latency",
            "check_mfu_gap", "check_comm_budget"]
@@ -431,132 +425,6 @@ def check_comm_bound(workers, frac: Optional[float] = None
              "bfloat16) or shard the weight update (ShardedOptimizer) — "
              "see docs/ARCHITECTURE.md 'Communication'"],
             op=op, **{k: v for k, v in info.items() if k != "op"}))
-    return findings
-
-
-def check_perf_regression(workers, golden=None) -> List[Dict[str, Any]]:
-    """ISSUE 13: ``bench.row`` records in the telemetry window vs the
-    checked-in ``benchmarks/golden.json`` — a row whose step p50 sits
-    more than the golden's ``step_time_regression_frac`` above the
-    blessed row becomes a ``perf_regression`` finding that NAMES the
-    dominant mover (the perfdiff attribution), so /statusz and the
-    post-run report say *which phase* slowed, not just "slower"."""
-    from ..bench import diff as perfdiff
-    from ..bench import ledger as bench_ledger
-    if golden is None:
-        golden = bench_ledger.load_golden()
-    if not golden:
-        return []
-    thr = bench_ledger.threshold(golden, "step_time_regression_frac")
-    latest: Dict[str, Dict[str, Any]] = {}
-    for records in workers.values():
-        for r in records:
-            if r.get("kind") != "bench.row":
-                continue
-            name = r.get("scenario")
-            if isinstance(name, str):
-                latest[name] = r   # newest record per scenario wins
-    findings = []
-    for name, rec in sorted(latest.items()):
-        base = (golden.get("scenarios") or {}).get(name)
-        p50 = rec.get("step_time_p50_ms")
-        if not base or not isinstance(p50, (int, float)):
-            continue
-        # reshape the telemetry record into a row-alike for perfdiff
-        cur = {"scenario": name, "step_time_ms": {"p50": p50, "p99": p50},
-               "phases_ms": rec.get("phases_ms") or {},
-               "compile": {"wall_ms": rec.get("compile_wall_ms")},
-               "device_kind": rec.get("device_kind")}
-        report = perfdiff.diff_rows(base, cur, thr)
-        if not report["regression"]:
-            continue
-        att = report["attribution"]
-        dom = att["dominant"] or "unattributed"
-        mover = next((m for m in att["movers"]
-                      if m["phase"] == att["dominant"]), None)
-        ev = [f"step p50 {report['base_p50_ms']:.2f}ms (golden) -> "
-              f"{report['cur_p50_ms']:.2f}ms "
-              f"({report['ratio']:.2f}x, threshold "
-              f"{1.0 + thr:.2f}x)"]
-        if mover:
-            ev.append(f"dominant mover: {dom} "
-                      f"{mover['base_ms']:.2f}ms -> {mover['cur_ms']:.2f}ms "
-                      f"({mover['delta_ms']:+.2f}ms/step)")
-        ev.append("full attribution: python -m paddle_tpu.bench.diff "
-                  f"--golden --scenario {name}")
-        ratio = report["ratio"] or 1.0
-        findings.append(_finding(
-            "perf_regression", 40 + 40 * min(1.0, ratio - 1.0 - thr),
-            f"perf regression in {name}: {dom} moved "
-            f"({ratio:.2f}x step time vs golden)",
-            ev, scenario=name, dominant=dom, ratio=ratio,
-            base_p50_ms=report["base_p50_ms"],
-            cur_p50_ms=report["cur_p50_ms"]))
-    return findings
-
-
-def check_perf_trend(workers, rows=None) -> List[Dict[str, Any]]:
-    """ISSUE 14: series-aware verdicts over the perf ledger, gated on
-    ``bench.row`` records in the telemetry window (a run that benched
-    nothing gets no trend findings — the global ledger is someone else's
-    history).  For each benched scenario, ``bench.trends`` analyzes its
-    sha-deduped series; the newest upward step-time changepoint (named
-    by git-sha range and dominant phase) and/or a flagged upward drift
-    become one ``perf_trend`` finding with the drift magnitude."""
-    scenarios = set()
-    for records in workers.values():
-        for r in records:
-            if (r.get("kind") == "bench.row"
-                    and isinstance(r.get("scenario"), str)):
-                scenarios.add(r["scenario"])
-    if not scenarios:
-        return []
-    from ..bench import trends
-    findings: List[Dict[str, Any]] = []
-    for a in trends.scan_ledger(rows=rows,
-                                scenario_names=sorted(scenarios)):
-        step = a["metrics"].get("step_p50") or {}
-        ups = [cp for cp in (step.get("changepoints") or [])
-               if cp["direction"] == "up"]
-        cp = ups[-1] if ups else None
-        drift = step.get("drift")
-        drifting = bool(drift and drift.get("flagged")
-                        and drift["direction"] == "up")
-        if cp is None and not drifting:
-            continue
-        ev: List[str] = []
-        magnitude = 0.0
-        title_bits: List[str] = []
-        if cp is not None:
-            before, at = cp.get("sha_range") or (None, None)
-            dom = cp.get("dominant_phase") or "unattributed"
-            ev.append(
-                f"step p50 shifted {cp['delta_frac']:+.1%} at sha range "
-                f"{(before or '?')[:8]}..{(at or '?')[:8]} "
-                f"({cp['before_median']:.2f}ms -> "
-                f"{cp['after_median']:.2f}ms), dominant phase: {dom}")
-            magnitude = max(magnitude, cp["delta_frac"])
-            title_bits.append(f"{cp['delta_frac']:+.1%} shift "
-                              f"ending at {(at or '?')[:8]} ({dom})")
-        if drifting:
-            ev.append(
-                f"step p50 drifting {drift['total_frac']:+.1%} across "
-                f"{step.get('n')} commits "
-                f"({drift['slope_per_point']:+.3g}ms/commit, residual "
-                f"noise ±{drift['residual_sigma_frac']:.1%})")
-            magnitude = max(magnitude, drift["total_frac"])
-            title_bits.append(f"{drift['total_frac']:+.1%} drift")
-        ev.append("series report: python -m paddle_tpu.bench.trends "
-                  f"--scenario {a['scenario']}")
-        findings.append(_finding(
-            "perf_trend", 35 + 45 * min(1.0, magnitude / 0.5),
-            f"perf trend in {a['scenario']}: " + ", ".join(title_bits),
-            ev, scenario=a["scenario"], mode=a["mode"],
-            delta_frac=magnitude,
-            sha_range=(cp.get("sha_range") if cp else None),
-            dominant=(cp.get("dominant_phase") if cp else None),
-            drift_frac=(drift.get("total_frac") if drifting else None),
-            flakiness=a.get("flakiness")))
     return findings
 
 
@@ -1041,8 +909,6 @@ def diagnose(run_dir: str, write: bool = True) -> Optional[Dict[str, Any]]:
     findings += check_straggler(workers, summary)
     findings += check_data_starved(workers)
     findings += check_comm_bound(workers)
-    findings += check_perf_regression(workers)
-    findings += check_perf_trend(workers)
     findings += check_integrity(events)
     findings += check_serving(workers)
     findings += check_fleet(workers)

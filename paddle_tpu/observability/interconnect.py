@@ -343,124 +343,3 @@ def unattributed_ms(block: Dict[str, Any]) -> float:
         if e.get("op") == UNATTRIBUTED:
             return float(e.get("measured_ms") or 0.0)
     return 0.0
-
-
-# --------------------------------------------------------------------------
-# CLI: ledger reconciliation check (the CI roofline-tier gate)
-# --------------------------------------------------------------------------
-
-def _format_table(by_scenario: Dict[str, Dict[str, Any]]) -> str:
-    lines = ["Interconnect sub-budgets (newest row per scenario, "
-             "ms/step):"]
-    for name in sorted(by_scenario):
-        ic = by_scenario[name]
-        dev = ic.get("device") or {}
-        hdr = ("  %-14s comm=%.3fms  unattributed=%.3fms  gen=%s"
-               % (name, float(ic.get("comm_bucket_ms") or 0.0),
-                  unattributed_ms(ic), dev.get("gen") or "unknown"))
-        if ic.get("overlapped_ms") is not None:
-            hdr += "  overlapped=%.3fms" % float(ic["overlapped_ms"])
-        if ic.get("injected"):
-            hdr += "  [injected drill]"
-        if ic.get("degraded"):
-            hdr += "  [degraded: %s]" % ic["degraded"]
-        lines.append(hdr)
-        for e in ic.get("entries") or []:
-            if e.get("op") == UNATTRIBUTED:
-                continue
-            eff = e.get("efficiency")
-            lines.append(
-                "    %-18s axis=%-9s n=%-4s measured=%8.3fms "
-                "modeled=%s eff=%s"
-                % (e.get("op"), e.get("axis"),
-                   e.get("participants") or "?",
-                   float(e.get("measured_ms") or 0.0),
-                   ("%8.3fms" % e["modeled_ms"]
-                    if isinstance(e.get("modeled_ms"), (int, float))
-                    else "      --"),
-                   ("%.2f" % eff if isinstance(eff, (int, float))
-                    else "--")))
-    return "\n".join(lines)
-
-
-def main(argv: Optional[List[str]] = None) -> int:
-    """``python -m paddle_tpu.observability.interconnect`` — print the
-    per-collective sub-budget for the newest ledger row per scenario
-    and fail when any row's entries don't sum to its roofline ``comm``
-    bucket (or the row lacks an interconnect block entirely)."""
-    import argparse
-
-    from ..bench import ledger as bench_ledger
-
-    p = argparse.ArgumentParser(
-        prog="python -m paddle_tpu.observability.interconnect",
-        description="per-collective comm sub-budget reconciliation "
-                    "over the ledger")
-    p.add_argument("--ledger", default=None, help="ledger path "
-                   "(default benchmarks/ledger.jsonl)")
-    p.add_argument("--mode", default="smoke", choices=("smoke", "full"))
-    p.add_argument("--max-unattributed-frac", type=float, default=None,
-                   help="bound on the (unattributed) share of a nonzero "
-                        "comm bucket (default from golden thresholds)")
-    args = p.parse_args(argv)
-    drops: Dict[str, int] = {}
-    rows = bench_ledger.read_ledger(args.ledger, drops=drops)
-    if any(drops.values()):
-        print("ledger drops: %s" % drops)  # noqa: print — CLI report
-    frac = args.max_unattributed_frac
-    if frac is None:
-        frac = bench_ledger.threshold(bench_ledger.load_golden(),
-                                      "interconnect_max_unattributed_frac")
-    newest: Dict[str, Dict[str, Any]] = {}
-    for row in rows:
-        if row.get("mode") != args.mode:
-            continue
-        if not isinstance(row.get("scenario"), str):
-            continue
-        newest[row["scenario"]] = row  # ledger order: newest last wins
-    if not newest:
-        print("no %s rows in ledger" % args.mode)  # noqa: print — CLI report
-        return 1
-    failures: List[str] = []
-    table: Dict[str, Dict[str, Any]] = {}
-    for name, row in sorted(newest.items()):
-        ic = row.get("interconnect")
-        if not isinstance(ic, dict):
-            failures.append("%s: no interconnect block (schema v%s row)"
-                            % (name, row.get("schema_version")))
-            continue
-        table[name] = ic
-        bucket = float(ic.get("comm_bucket_ms") or 0.0)
-        total = sum(float(e.get("measured_ms") or 0.0)
-                    for e in (ic.get("entries") or []))
-        tol = max(0.01, 0.005 * abs(bucket))
-        if abs(total - bucket) > tol:
-            failures.append(
-                "%s: entries sum %.4fms != comm bucket %.4fms"
-                % (name, total, bucket))
-        rl_comm = ((row.get("roofline") or {}).get("buckets_ms")
-                   or {}).get("comm")
-        if isinstance(rl_comm, (int, float)) and \
-                abs(float(rl_comm) - bucket) > tol:
-            failures.append(
-                "%s: comm bucket %.4fms != roofline comm %.4fms"
-                % (name, bucket, float(rl_comm)))
-        if bucket > 0:
-            un_frac = abs(unattributed_ms(ic)) / bucket
-            if un_frac > frac:
-                failures.append(
-                    "%s: unattributed %.0f%% of comm bucket exceeds "
-                    "%.0f%% bound" % (name, 100 * un_frac, 100 * frac))
-    print(_format_table(table))  # noqa: print — CLI report
-    if failures:
-        print("RECONCILIATION FAILURES:")  # noqa: print — CLI report
-        for f in failures:
-            print("  " + f)  # noqa: print — CLI report
-        return 1
-    print("reconciliation OK: %d scenario(s); entries sum to the comm "  # noqa: print — CLI report
-          "bucket exactly" % len(table))
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

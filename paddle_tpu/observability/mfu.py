@@ -1,6 +1,6 @@
-"""MFU accounting helpers (ISSUE 3) — extracted from ``bench.py`` so the
-one-shot benchmark and the live per-step telemetry share one definition
-of "model FLOPs utilization".
+"""MFU accounting helpers (ISSUE 3): one definition of "model FLOPs
+utilization" for the live per-step telemetry and every harness that
+reports one.
 
 Two halves:
 

@@ -307,68 +307,6 @@ class StatusServer:
                             is not None else None),
             })
         status["integrity"] = integrity or None
-        # performance observatory (ISSUE 13): present whenever the bench
-        # runner has mirrored matrix figures into the registry; carries
-        # the perf_regression verdict (dominant mover named) when a
-        # golden baseline exists to compare against
-        perf: Dict[str, Any] = {}
-        perf_gauges = {k: m for k, m in snap.items()
-                       if k.startswith("perf.") and m.get("type") == "gauge"}
-        if perf_gauges:
-            scen: Dict[str, Dict[str, Any]] = {}
-            for name, m in perf_gauges.items():
-                if "[scenario=" not in name:
-                    continue
-                metric, _, rest = name.partition("[scenario=")
-                label = rest[:-1]
-                if metric == "perf.phase_ms" and ",phase=" in label:
-                    sname, _, phase = label.partition(",phase=")
-                    scen.setdefault(sname, {}).setdefault(
-                        "phases_ms", {})[phase] = m["value"]
-                else:
-                    scen.setdefault(label, {})[
-                        metric[len("perf."):]] = m["value"]
-            perf["scenarios"] = scen
-            # row-alike records from the gauges → the doctor's verdict
-            recs = [{"kind": "bench.row", "scenario": sname,
-                     "step_time_p50_ms": v.get("step_time_ms"),
-                     "phases_ms": v.get("phases_ms") or {}}
-                    for sname, v in scen.items()]
-            try:
-                from .doctor import check_perf_regression
-                regressions = check_perf_regression({0: recs})
-            except Exception:  # noqa: swallow — statusz must render
-                regressions = []
-            perf["perf_regression"] = ([
-                {"scenario": f["data"].get("scenario"),
-                 "dominant": f["data"].get("dominant"),
-                 "title": f["title"]} for f in regressions] or None)
-            # trend engine (ISSUE 14): per-scenario direction vs the
-            # trailing median plus the last detected changepoint, from
-            # the ledger series (step-time axis only — statusz is a
-            # glance, the full report is `python -m paddle_tpu.bench
-            # .trends` / bench.report)
-            try:
-                from ..bench import trends as bench_trends
-                trend_info: Dict[str, Any] = {}
-                for a in bench_trends.scan_ledger(
-                        scenario_names=sorted(scen),
-                        metrics=("step_p50",)):
-                    cp = a.get("last_changepoint")
-                    trend_info[f"{a['scenario']}/{a['mode']}"] = {
-                        "trend": a.get("trend"),
-                        "flakiness": a.get("flakiness"),
-                        "last_changepoint": ({
-                            "sha_range": cp.get("sha_range"),
-                            "delta_frac": cp.get("delta_frac"),
-                            "direction": cp.get("direction"),
-                            "dominant_phase": cp.get("dominant_phase"),
-                        } if cp else None),
-                    }
-                perf["trends"] = trend_info or None
-            except Exception:  # noqa: swallow — statusz must render
-                perf["trends"] = None
-        status["perf"] = perf or None
         # MFU microscope (ISSUE 19): the bench runner mirrors each row's
         # roofline gap budget into `roofline.*` gauges — statusz shows
         # the per-scenario buckets, coverage, and the doctor's mfu_gap
@@ -734,8 +672,6 @@ class LiveAggregator:
         findings += doctor.check_straggler(workers)
         findings += doctor.check_data_starved(workers)
         findings += doctor.check_comm_bound(workers)
-        findings += doctor.check_perf_regression(workers)
-        findings += doctor.check_perf_trend(workers)
         findings += doctor.check_serving(workers)
         findings += doctor.check_fleet(workers)
         findings += doctor.check_fleet_flapping(workers)

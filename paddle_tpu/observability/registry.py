@@ -17,7 +17,7 @@ always, events flow only when someone is listening.
 
 The process-global registry (:func:`get_registry`) auto-attaches a JSONL
 :class:`~paddle_tpu.observability.sinks.MetricsWriter` when
-``PTPU_METRICS_DIR`` is set, so any entry point — ``bench.py``, a user
+``PTPU_METRICS_DIR`` is set, so any entry point — a user
 script, a launcher-spawned worker — lands on the same
 ``<dir>/worker-<i>.jsonl`` stream without plumbing.
 """

@@ -840,96 +840,3 @@ def _gc_dumps(dump_dir: str, keep: int) -> None:
                 os.remove(p)
             except OSError:
                 pass
-
-
-# --------------------------------------------------------------------------
-# CLI: ledger reconciliation check (the CI perf-tier gate)
-# --------------------------------------------------------------------------
-
-def _format_gap_table(by_scenario: Dict[str, Dict[str, Any]]) -> str:
-    lines = ["MFU-gap budgets (newest row per scenario, ms/step):"]
-    cols = [s for s in SINKS]
-    header = "  %-14s %9s " % ("scenario", "measured")
-    header += " ".join("%12s" % c for c in cols)
-    header += "  %8s %s" % ("coverage", "dominant")
-    lines.append(header)
-    for name in sorted(by_scenario):
-        rl = by_scenario[name]
-        b = rl.get("buckets_ms") or {}
-        line = "  %-14s %9.2f " % (name, rl.get("measured_step_ms") or 0.0)
-        line += " ".join("%12.3f" % float(b.get(c) or 0.0) for c in cols)
-        line += "  %8.3f %s" % (float(rl.get("coverage") or 0.0),
-                                rl.get("dominant_sink"))
-        if rl.get("injected"):
-            line += "  [injected drill]"
-        lines.append(line)
-    return "\n".join(lines)
-
-
-def main(argv: Optional[List[str]] = None) -> int:
-    """``python -m paddle_tpu.observability.roofline`` — print the gap
-    table for the newest ledger row per scenario and fail when any
-    row's reconciliation residual exceeds the bound (or lacks a
-    roofline block entirely)."""
-    import argparse
-
-    from ..bench import ledger as bench_ledger
-
-    p = argparse.ArgumentParser(
-        prog="python -m paddle_tpu.observability.roofline",
-        description="modeled-vs-measured reconciliation over the ledger")
-    p.add_argument("--ledger", default=None, help="ledger path "
-                   "(default benchmarks/ledger.jsonl)")
-    p.add_argument("--mode", default="smoke", choices=("smoke", "full"))
-    p.add_argument("--max-residual-frac", type=float, default=None,
-                   help="|residual| bound as a fraction of measured "
-                        "step time (default from golden thresholds)")
-    args = p.parse_args(argv)
-    drops: Dict[str, int] = {}
-    rows = bench_ledger.read_ledger(args.ledger, drops=drops)
-    if any(drops.values()):
-        print("ledger drops: %s" % drops)  # noqa: print — CLI report
-    frac = args.max_residual_frac
-    if frac is None:
-        frac = bench_ledger.threshold(bench_ledger.load_golden(),
-                                      "roofline_max_residual_frac")
-    newest: Dict[str, Dict[str, Any]] = {}
-    for row in rows:
-        if row.get("mode") != args.mode:
-            continue
-        if not isinstance(row.get("scenario"), str):
-            continue
-        newest[row["scenario"]] = row  # ledger order: newest last wins
-    if not newest:
-        print("no %s rows in ledger" % args.mode)  # noqa: print — CLI report
-        return 1
-    failures: List[str] = []
-    table: Dict[str, Dict[str, Any]] = {}
-    for name, row in newest.items():
-        rl = row.get("roofline")
-        if not isinstance(rl, dict):
-            failures.append("%s: no roofline block (schema v%s row)"
-                            % (name, row.get("schema_version")))
-            continue
-        table[name] = rl
-        measured = float(rl.get("measured_step_ms") or 0.0)
-        residual = float((rl.get("buckets_ms") or {}).get("residual")
-                         or 0.0)
-        if measured > 0 and abs(residual) > frac * measured:
-            failures.append(
-                "%s: |residual| %.3fms exceeds %.0f%% of measured "
-                "%.3fms" % (name, abs(residual), 100 * frac, measured))
-    print(_format_gap_table(table))  # noqa: print — CLI report
-    if failures:
-        print("RECONCILIATION FAILURES (bound %.0f%%):"  # noqa: print — CLI report
-              % (100 * frac))
-        for f in failures:
-            print("  " + f)  # noqa: print — CLI report
-        return 1
-    print("reconciliation OK: %d scenario(s) within %.0f%% residual"  # noqa: print — CLI report
-          % (len(table), 100 * frac))
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

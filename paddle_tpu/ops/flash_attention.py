@@ -19,7 +19,7 @@ Backward is recompute-based (no probability tensor saved): a dkdv kernel on a
 and a dq kernel over Q blocks, both replaying p = exp(qk - lse).  Backward
 VMEM residency is O(block), so sequence length is bounded by HBM, not the
 16MB scoped-vmem limit (S=8192 fwd+bwd measured 30ms vs 737ms for XLA
-attention on v5e; benchmarks/flash_seqlen_ab.json).
+attention on v5e in July; a claim to re-measure).
 
 Causal masking is block-skipped: programs never visit KV blocks strictly
 above the diagonal, so the causal fwd does ~half the FLOPs — the fusion
@@ -95,7 +95,7 @@ def _stat_tile(x, width):
 
 def _block_sizes(seq_q: int, seq_k: int):
     # swept on v5e (3D-grid kernels, bh·S·d with d=64, best-of-3 fwd+bwd;
-    # benchmarks/flash_block_sweep.json): at S=2048, 512/512 = 13.9ms vs
+    # July figures, to re-measure): at S=2048, 512/512 = 13.9ms vs
     # 19.5ms for 1024 and 46ms for 128 (small blocks starve the MXU when
     # the contraction dim is only 64); at S>=4096 the longer grid favors
     # 1024/1024 (S=4096: 23.1 vs 25.6ms; S=8192: 30.1 vs 35.2ms).

@@ -144,7 +144,7 @@ def rotary_position_embedding(q, k, position_ids=None, base: float = 10000.0):
 # [B, S, V] logits **twice** (bf16 matmul output + the f32 softmax
 # probabilities XLA saves for backward) — measured on v5e at GPT-125M
 # B=8/S=2048 that is ~4.5GB of HLO temps, and B=32 OOMs outright
-# (benchmarks/batch_scan_125m.json).  This op never materializes more than
+# (a July reading, to re-measure).  This op never materializes more than
 # one [B, chunk, V] block: forward scans over sequence chunks saving only
 # the per-token logsumexp; backward recomputes each chunk's logits and
 # fuses softmax-grad into the dW / dh matmuls.

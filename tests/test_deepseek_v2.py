@@ -57,12 +57,14 @@ def reference_params(params, layers):
                             0).reference_params(params)
 
 
-@pytest.fixture(scope="module")
-def served():
-    """A tiny model (rank 1 of 4, a sliced vocabulary) served through the
-    engine: prompts, the collected results and the engine."""
+@pytest.fixture(scope="module", params=[0, 1], ids=["rank0", "rank1"])
+def served(request):
+    """A tiny model (one rank of 4, a sliced vocabulary) served through
+    the engine: prompts, the collected results and the engine.  Rank 0 is
+    the rank the benchmark's cell runs."""
     pt.seed(28)
-    cfg = deepseek_v2_tiny(ep_degree=4, ep_rank=1, initializer_range=0.2)
+    cfg = deepseek_v2_tiny(ep_degree=4, ep_rank=request.param,
+                           initializer_range=0.2)
     model = DeepseekV2ForCausalLM(cfg)
     eng = ServingEngine(model, max_seqs=4, kv_block_size=8, max_model_len=64,
                         capture_logits=True, registry=MetricsRegistry())
@@ -150,7 +152,7 @@ BROKEN = {
     "wrong_group_limit": {"topk_group": 4},
     "missing_mscale_squared": {"rope_scaling": dict(
         ROPE, mscale_all_dim=0.0)},
-    "wrong_rank": {"ep_rank": 2},
+    "wrong_rank": {"ep_rank": 2},              # the fixture's is 0 or 1
 }
 
 
@@ -188,10 +190,11 @@ def test_expert_counters_ride_out_with_the_steps(served):
         assert snap[name]["value"] == total
     assert counters["serve.moe_experts_touched"] > 0
     # every pair computed here is a (token, held expert) pair the router
-    # chose: count them from the captured choices (rank 1 holds 4..7)
+    # chose: count them from the captured choices (rank r holds 4r..4r+3)
     chosen = [r["per_token"]["moe_topk"] for r in results]
+    lo = 4 * cfg.ep_rank
     assert counters["serve.moe_pairs"] == sum(
-        int(((c >= 4) & (c < 8)).sum()) for c in chosen)
+        int(((c >= lo) & (c < lo + 4)).sum()) for c in chosen)
     load = booked["gauges"]["serve.moe_load_max_over_mean"]
     assert load["steps"] == 5 and load["last"] >= 1.0
     assert load["sum"] >= load["steps"]

@@ -7,12 +7,10 @@ and modeled wire times are asserted against hand-computed figures, so
 a silent change to the model is a test failure, not a drifting
 dashboard.
 """
-import json
-import os
 
 import pytest
 
-from paddle_tpu.bench import ledger, schema
+from paddle_tpu.bench import schema
 from paddle_tpu.observability import interconnect as ic
 from paddle_tpu.observability import doctor
 from paddle_tpu.observability.registry import split_labels
@@ -287,49 +285,6 @@ class TestSchemaV3:
                 == blk["modeled_ms_total"])
         assert (schema.metric_value(row, "comm_overlapped_ms")
                 == blk["overlapped_ms"])
-
-
-# -- CLI reconciliation gate ------------------------------------------------
-class TestCLI:
-    def _ledger(self, tmp_path, rows):
-        path = str(tmp_path / "ledger.jsonl")
-        with open(path, "w", encoding="utf-8") as f:
-            for r in rows:
-                f.write(json.dumps(r) + "\n")
-        return path
-
-    def test_ok_on_valid_rows(self, tmp_path, capsys):
-        path = self._ledger(tmp_path, [_mk_row()])
-        rc = ic.main(["--ledger", path, "--mode", "smoke"])
-        out = capsys.readouterr().out
-        assert rc == 0, out
-        assert "reconciliation OK" in out
-
-    def test_fails_on_sum_violation(self, tmp_path, capsys):
-        row = _mk_row()
-        row["interconnect"]["entries"][0]["measured_ms"] += 5.0
-        path = self._ledger(tmp_path, [row])
-        rc = ic.main(["--ledger", path, "--mode", "smoke"])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "RECONCILIATION FAILURES" in out
-
-    def test_fails_on_missing_block(self, tmp_path, capsys):
-        row = _mk_row()
-        row.pop("interconnect")
-        path = self._ledger(tmp_path, [row])
-        rc = ic.main(["--ledger", path, "--mode", "smoke"])
-        assert rc == 1
-        assert "no interconnect block" in capsys.readouterr().out
-
-    def test_unattributed_bound(self, tmp_path, capsys):
-        path = self._ledger(tmp_path, [_mk_row()])
-        # the synthesized degraded block is 100% unattributed — a tight
-        # bound must flag it, the default (1.0) must not
-        rc = ic.main(["--ledger", path, "--mode", "smoke",
-                      "--max-unattributed-frac", "0.5"])
-        assert rc == 1
-        assert "unattributed" in capsys.readouterr().out
 
 
 # -- doctor verdict ---------------------------------------------------------

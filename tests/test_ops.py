@@ -400,7 +400,7 @@ class TestFusionEvidence:
 class TestLinearCrossEntropy:
     """ops/fused.py linear_softmax_cross_entropy — the memory-efficient LM
     loss (c_softmax_with_cross_entropy objective without materialized
-    logits; see benchmarks/batch_scan_125m.json for the motivating OOM)."""
+    logits; B=32 at GPT-125M went out of memory without it)."""
 
     def _ref(self, hid, W, lab, ignore=-100):
         logits = jnp.einsum("bsh,vh->bsv", hid, W).astype(jnp.float32)

@@ -9,8 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.bench import diff as perfdiff
-from paddle_tpu.bench import harness, ledger, schema, trends
+from paddle_tpu.bench import harness, schema
 from paddle_tpu.observability import doctor, roofline
 from paddle_tpu.observability.compilation import get_tracker, track_jit
 from paddle_tpu.observability.mfu import DEVICE_SPECS, device_spec
@@ -211,7 +210,7 @@ def test_validate_row_rejects_broken_roofline():
     assert any("roofline" in e for e in schema.validate_row(bad))
 
 
-def test_v1_rows_stay_readable_and_gap_metrics_none(tmp_path):
+def test_v1_rows_stay_readable_and_gap_metrics_none():
     row = _mk_row()
     v1 = {k: v for k, v in row.items() if k != "roofline"}
     v1["schema_version"] = 1
@@ -221,50 +220,12 @@ def test_v1_rows_stay_readable_and_gap_metrics_none(tmp_path):
     assert schema.metric_value(row, "gap_host_ms") is not None
     assert schema.metric_value(
         row, "roofline_coverage") == row["roofline"]["coverage"]
-    # a mixed-version ledger round-trips: v1 rows are not rejected
-    path = str(tmp_path / "ledger.jsonl")
-    ledger.append_row(v1, path)
-    ledger.append_row(row, path)
-    assert len(ledger.read_ledger(path)) == 2
 
 
 def test_gap_metrics_are_trendable_axes():
     assert "gap_host_ms" in schema.METRICS
     assert "roofline_coverage" in schema.METRICS
     assert "gap_mxu_ms" not in schema.METRICS  # mxu is work, not gap
-
-
-# -- perfdiff / trends integration ------------------------------------------
-def test_diff_attribution_gains_gap_movers():
-    base = _mk_row()
-    cur = json.loads(json.dumps(base))
-    cur["roofline"]["buckets_ms"]["comm"] += 2.0
-    att = perfdiff.attribute(base, cur)
-    assert att["gap_dominant"] == "comm"
-    sinks = [m["sink"] for m in att["gap_movers"]]
-    assert "mxu" not in sinks
-    text = perfdiff.render(perfdiff.diff_rows(base, cur))
-    assert "MFU-gap sinks" in text and "comm" in text
-
-
-def test_diff_attribution_guards_missing_roofline():
-    base = _mk_row()
-    v1 = {k: v for k, v in base.items() if k != "roofline"}
-    att = perfdiff.attribute(v1, base)
-    assert "gap_movers" not in att
-    perfdiff.render(perfdiff.diff_rows(v1, base))  # must not raise
-
-
-def test_median_row_carries_roofline_medians():
-    rows = [_mk_row(p50=10.0), _mk_row(p50=12.0), _mk_row(p50=14.0)]
-    med = trends.median_row(rows)
-    assert med["roofline"] is not None
-    assert set(med["roofline"]["buckets_ms"]) == set(schema.GAP_SINKS)
-    att = perfdiff.attribute(med, rows[-1])
-    assert "gap_movers" in att
-    # v1-only windows produce no pseudo-roofline
-    v1s = [{k: v for k, v in r.items() if k != "roofline"} for r in rows]
-    assert trends.median_row(v1s)["roofline"] is None
 
 
 # -- track_jit -> observatory -> block (e2e on CPU) -------------------------
@@ -417,22 +378,3 @@ def test_statusz_roofline_section_from_gauges():
     # no roofline gauges at all -> section absent, statusz still renders
     st2 = StatusServer(port=0, registry=MetricsRegistry()).statusz()
     assert st2["roofline"] is None
-
-
-# -- CLI --------------------------------------------------------------------
-def test_roofline_cli_residual_bound(tmp_path, capsys):
-    path = str(tmp_path / "ledger.jsonl")
-    ledger.append_row(_mk_row(scenario="moe"), path)
-    assert roofline.main(["--ledger", path, "--mode", "smoke"]) == 0
-    out = capsys.readouterr().out
-    assert "moe" in out and "residual" in out
-    # a row whose residual busts the bound fails the check
-    row = _mk_row(scenario="moe")
-    row["roofline"]["buckets_ms"] = {s: 0.0 for s in schema.GAP_SINKS}
-    row["roofline"]["buckets_ms"]["residual"] = row["roofline"][
-        "measured_step_ms"]
-    bad_path = str(tmp_path / "bad.jsonl")
-    with open(bad_path, "w") as fh:
-        fh.write(json.dumps(row) + "\n")
-    assert roofline.main(["--ledger", bad_path,
-                          "--max-residual-frac", "0.35"]) != 0

@@ -7,39 +7,18 @@ import json
 import os
 import time
 
-import numpy as np
 import pytest
+from serving_families import (dense_continuation, family,  # noqa: F401
+                              tiny_model)
 
-import jax.numpy as jnp
-
-import paddle_tpu as pt
 from paddle_tpu.inference import ServingEngine
 from paddle_tpu.inference.fleet import (DispatchExhausted, FleetOverloaded,
                                         LocalReplica, ReplicaManager,
                                         Router)
-from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.observability.registry import MetricsRegistry
 from paddle_tpu.testing import faults
 
 pytestmark = pytest.mark.serving
-
-
-def tiny_model(max_pos=64):
-    pt.seed(7)
-    cfg = GPTConfig(vocab_size=32, hidden_size=32, num_layers=2,
-                    num_heads=2, ffn_hidden_size=64,
-                    max_position_embeddings=max_pos, hidden_dropout=0.0,
-                    attention_dropout=0.0)
-    m = GPTForCausalLM(cfg)
-    m.eval()
-    return m
-
-
-def dense_continuation(model, prompt, max_new, eos=None):
-    out = model.generate(jnp.asarray([prompt], jnp.int32),
-                         max_new_tokens=max_new, temperature=0.0,
-                         eos_token_id=eos)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 def local_fleet(n=2, registry=None, max_pos=64, **engine_kw):
@@ -172,8 +151,9 @@ class TestDispatchPolicy:
 # journal replay: token-exact failover, in-process
 # ---------------------------------------------------------------------------
 class TestFailoverInProcess:
+    @pytest.mark.usefixtures("family")
     def test_failover_token_exact_vs_dense(self):
-        model = tiny_model()
+        model = tiny_model(64)
         want = {i: dense_continuation(model, [1, 2, 3 + i], 10)
                 for i in range(3)}
         reps, reg = local_fleet(2, max_seqs=4, kv_block_size=4)
@@ -198,6 +178,7 @@ class TestFailoverInProcess:
                 assert rep.engine.cache.leak_report()["leaked_blocks"] \
                     == 0
 
+    @pytest.mark.usefixtures("family")
     def test_journal_record_is_spill_format(self):
         reps, reg = local_fleet(1, max_seqs=2, kv_block_size=4)
         router = Router(reps, registry=reg)
@@ -211,12 +192,13 @@ class TestFailoverInProcess:
         assert rec["max_new_tokens"] == 8
         assert rec["eos_token_id"] == 9
         # and it round-trips through a fresh engine's admit_record
-        fresh = ServingEngine(tiny_model(), max_seqs=2,
+        fresh = ServingEngine(tiny_model(64), max_seqs=2,
                               registry=MetricsRegistry())
         assert fresh.admit_record(rec) == rid
 
+    @pytest.mark.usefixtures("family")
     def test_drain_migration_token_exact(self, tmp_path):
-        model = tiny_model()
+        model = tiny_model(64)
         want = {i: dense_continuation(model, [1, 2, 3 + i], 12)
                 for i in range(4)}
         # both replicas share one run_dir — the ISSUE 16 namespacing
@@ -240,6 +222,7 @@ class TestFailoverInProcess:
             assert reg.snapshot()["fleet.migrations"]["value"] \
                 == float(moved)
 
+    @pytest.mark.usefixtures("family")
     def test_statusz_fleet_section(self):
         from paddle_tpu.observability.monitor import StatusServer
         reps, reg = local_fleet(2, max_seqs=2, kv_block_size=4)
@@ -312,7 +295,7 @@ class TestMultiProcessDrills:
             outs = [router.collect(r, timeout=120) for r in rids]
             assert router.failovers >= 1
             # token-exact vs an uninterrupted single-engine reference
-            model = tiny_model()
+            model = tiny_model(64)
             ref = ServingEngine(model, max_seqs=4,
                                 registry=MetricsRegistry())
             ref_out = ref.generate([[1, 2, 3 + i] for i in range(6)],
@@ -341,7 +324,7 @@ class TestMultiProcessDrills:
             outs = [router.collect(r, timeout=120) for r in rids]
             # zero dropped or truncated streams
             assert all(len(o["tokens"]) == 48 for o in outs)
-            model = tiny_model()
+            model = tiny_model(64)
             ref = ServingEngine(model, max_seqs=4,
                                 registry=MetricsRegistry())
             assert [o["tokens"] for o in outs] == ref.generate(

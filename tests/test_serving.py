@@ -1,16 +1,19 @@
 """Serving subsystem (ISSUE 6): paged-KV cache invariants, scheduler
 policy under a tight block budget, ragged-vs-dense numerics, the compile
-contract, the slow-consumer fault drill, and the legacy facade routing."""
+contract, the slow-consumer fault drill, and the legacy facade routing.
+The engine's own classes run once a model family: a GPT and a
+DeepSeek-V2 (a latent pool, an expert layer, aux outputs a step)."""
 import importlib
 import re
 import time
 
 import numpy as np
 import pytest
+from serving_families import (VOCAB, dense_continuation,  # noqa: F401
+                              dense_forward, family, tiny_model)
 
 import jax.numpy as jnp
 
-import paddle_tpu as pt
 from paddle_tpu.inference import (BlockAllocator, Config, PagedKVCache,
                                   ServingEngine, create_predictor)
 from paddle_tpu.inference.paged_attention import (_pages_per_wave,
@@ -24,24 +27,6 @@ from paddle_tpu.observability.registry import MetricsRegistry
 from paddle_tpu.testing import faults
 
 pytestmark = pytest.mark.serving
-
-
-def tiny_model(max_pos=32):
-    pt.seed(7)
-    cfg = GPTConfig(vocab_size=32, hidden_size=32, num_layers=2,
-                    num_heads=2, ffn_hidden_size=64,
-                    max_position_embeddings=max_pos, hidden_dropout=0.0,
-                    attention_dropout=0.0)
-    m = GPTForCausalLM(cfg)
-    m.eval()
-    return m
-
-
-def dense_continuation(model, prompt, max_new, eos=None):
-    out = model.generate(jnp.asarray([prompt], jnp.int32),
-                         max_new_tokens=max_new, temperature=0.0,
-                         eos_token_id=eos)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 def assert_no_block_aliasing(cache: PagedKVCache):
@@ -547,6 +532,7 @@ class TestScheduler:
 # ---------------------------------------------------------------------------
 # Engine end-to-end
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestServingEngine:
     def test_ragged_decode_matches_dense_logits(self):
         model = tiny_model()
@@ -562,7 +548,7 @@ class TestServingEngine:
             assert r["tokens"] == want, (p, r["tokens"], want)
             # logits through the paged path == dense no-cache forward
             full = p + r["tokens"]
-            ref = np.asarray(model(jnp.asarray([full], jnp.int32)))[0]
+            ref = dense_forward(model)(full)
             for i, row in enumerate(r["logits"]):
                 np.testing.assert_allclose(
                     row, ref[len(p) - 1 + i], atol=1e-4)
@@ -680,6 +666,7 @@ class TestServingEngine:
 # ---------------------------------------------------------------------------
 # The step program updates the pool in place (ISSUE 27)
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestPoolInPlace:
     def engine(self, **kw):
         kw.setdefault("registry", MetricsRegistry())
@@ -688,7 +675,8 @@ class TestPoolInPlace:
 
     @pytest.mark.parametrize("rows,chunk", [(4, 1), (1, 8)],
                              ids=["decode", "prefill_b8"])
-    def test_every_page_array_aliases_its_input(self, rows, chunk):
+    def test_every_page_array_aliases_its_input(self, family, rows,
+                                                chunk):
         import jax
         eng = self.engine()
         pool = eng.cache.pool_bytes()
@@ -704,7 +692,8 @@ class TestPoolInPlace:
         aliased = [int(p) for p in re.findall(r"\((\d+), \{\}", header)]
         flat, _ = jax.tree_util.tree_flatten(eng._params)
         first = len(flat) + 3                # params, ids, positions, last
-        n = 2 * eng.cache.num_layers
+        n = sum(len(layer) for layer in eng.cache.pages)
+        assert n == {"gpt": 2, "deepseek_v2": 1}[family] * 2    # layers
         assert sorted(aliased) == list(range(first, first + n))
         assert compiled.memory_analysis().alias_size_in_bytes == pool
 
@@ -745,10 +734,11 @@ PHASES = {"reap", "schedule", "tables", "h2d", "dispatch", "device_wait",
           "logits_copy", "guard", "accept", "gauges"}
 
 
+@pytest.mark.usefixtures("family")
 class TestStepSpans:
     @pytest.fixture()
     def warm(self):
-        """A warm two-row engine (vocab 32, tables 8 wide) whose fault seam
+        """A warm two-row engine (tables 8 wide) whose fault seam
         sleeps, so that a step is long beside what its spans cost."""
         from paddle_tpu.observability import tracing
         reg = MetricsRegistry()
@@ -793,7 +783,7 @@ class TestStepSpans:
         assert total >= 0.05
         assert 0.98 * total <= covered <= total
 
-    def test_stats_phases_and_byte_counters(self, warm):
+    def test_stats_phases_and_byte_counters(self, family, warm):
         eng, reg, tracing = warm
         h2d, d2h = (reg.counter("serve.h2d_bytes"),
                     reg.counter("serve.d2h_bytes"))
@@ -817,8 +807,12 @@ class TestStepSpans:
         prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8)          # bucket 8, 1 row
         decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2)       # 2 slots
         assert h2d.value - h0 == prefill + 2 * decode
-        # next tokens (int32) and float32 logits over vocab 32
-        assert d2h.value - d0 == (4 + 4 * 32) + 2 * 2 * (4 + 4 * 32)
+        # next tokens (int32) and float32 logits over the vocabulary; with
+        # them a step's counts, where the model books any (DeepSeek-V2:
+        # ``moe_load`` of 1 expert layer x 4 held experts, ``moe_dropped``)
+        row = 4 + 4 * VOCAB[family]
+        counts = {"gpt": 0, "deepseek_v2": i32 * (1 * 4 + 1)}[family]
+        assert d2h.value - d0 == row + 2 * 2 * row + 3 * counts
 
     def test_paged_block_counters_and_span_attributes(self, warm):
         """ISSUE 29: the share of a decode step's block tables that is

@@ -2,18 +2,19 @@
 cancellation), poisoned-request quarantine with batch bisection,
 watchdog-supervised steps, graceful drain/resume, collect timeouts,
 callback-error accounting, KV-block leak-freedom, and the doctor /
-healthz surfaces."""
+healthz surfaces.  Every engine-level test runs once a model family: a
+GPT (two page arrays of ``(heads, head_dim)`` a layer) and a DeepSeek-V2
+(one latent row a layer, one dense and one expert layer)."""
+import importlib
 import json
 import os
 
 import numpy as np
 import pytest
+from serving_families import (dense_continuation, family,  # noqa: F401
+                              tiny_model)
 
-import jax.numpy as jnp
-
-import paddle_tpu as pt
 from paddle_tpu.inference import CollectTimeout, ServingEngine
-from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.observability import doctor
 from paddle_tpu.observability.registry import MetricsRegistry
 from paddle_tpu.testing import faults
@@ -21,22 +22,11 @@ from paddle_tpu.testing import faults
 pytestmark = [pytest.mark.serving, pytest.mark.faults]
 
 
-def tiny_model(max_pos=32):
-    pt.seed(7)
-    cfg = GPTConfig(vocab_size=32, hidden_size=32, num_layers=2,
-                    num_heads=2, ffn_hidden_size=64,
-                    max_position_embeddings=max_pos, hidden_dropout=0.0,
-                    attention_dropout=0.0)
-    m = GPTForCausalLM(cfg)
-    m.eval()
-    return m
-
-
-def dense_continuation(model, prompt, max_new, eos=None):
-    out = model.generate(jnp.asarray([prompt], jnp.int32),
-                         max_new_tokens=max_new, temperature=0.0,
-                         eos_token_id=eos)
-    return np.asarray(out)[0, len(prompt):].tolist()
+# the module each family's decode step calls for attention over its pages
+ATTENTION = {"gpt": ("paddle_tpu.inference.paged_attention",
+                     "paged_attention"),
+             "deepseek_v2": ("paddle_tpu.inference.latent_attention",
+                             "latent_attention")}
 
 
 def make_engine(model=None, **kw):
@@ -60,6 +50,7 @@ def run_traffic(model, n=4, max_new=6, prepare=None, **kw):
 # ---------------------------------------------------------------------------
 # deadlines & cancellation
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestLifecycleGuard:
     def test_deadline_eviction(self):
         clk = faults.expire_clock()
@@ -145,6 +136,7 @@ class TestLifecycleGuard:
 # ---------------------------------------------------------------------------
 # poisoned-request quarantine
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestQuarantine:
     _traffic = staticmethod(run_traffic)
 
@@ -184,18 +176,18 @@ class TestQuarantine:
         assert eng.quarantined[bad]["step_kind"] == "prefill"
         assert eng.collect(rids[1])["tokens"] == []
 
-    def test_first_run_failure_is_not_a_poisoned_request(self, monkeypatch):
+    def test_first_run_failure_is_not_a_poisoned_request(self, family,
+                                                         monkeypatch):
         """A step program that has never run to completion cannot poison
         a request: a lowering/compile failure on the first decode (here a
         raising kernel stub) propagates out of run() instead of ending
         with every request 'poisoned' and exit 0."""
-        import importlib
         # (the package re-exports the function under the module's name)
-        pa = importlib.import_module("paddle_tpu.inference.paged_attention")
+        module, name = ATTENTION[family]
 
         def boom(*a, **kw):
             raise NotImplementedError("Mosaic could not lower this block")
-        monkeypatch.setattr(pa, "paged_attention", boom)
+        monkeypatch.setattr(importlib.import_module(module), name, boom)
         eng = make_engine(tiny_model(), max_seqs=2, kv_block_size=4)
         rids = [eng.submit([1, 2, 3], max_new_tokens=4) for _ in range(2)]
         with pytest.raises(NotImplementedError, match="Mosaic"):
@@ -245,6 +237,7 @@ class TestQuarantine:
 # ---------------------------------------------------------------------------
 # a pool that the step program consumes (ISSUE 27)
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestConsumedPool:
     """The step program is handed the KV pool donated, so a step that
     fails cannot be undone by keeping the old arrays: its page writes
@@ -417,6 +410,7 @@ class TestConsumedPool:
 # ---------------------------------------------------------------------------
 # watchdog supervision
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestWatchdogRecovery:
     # step_timeout must cover a COLD compile (the watchdog cannot tell
     # XLA compiling from a wedged device) — these tests warm the shape
@@ -472,6 +466,7 @@ class TestWatchdogRecovery:
 # ---------------------------------------------------------------------------
 # graceful drain / resume
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestDrainResume:
     def test_drain_finishes_running_spills_waiting(self, tmp_path):
         model = tiny_model()
@@ -590,6 +585,7 @@ class TestDrainResume:
 # ---------------------------------------------------------------------------
 # collect timeout / stuck-run diagnostics
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestCollectTimeout:
     def test_collect_timeout_names_scheduler_state(self):
         eng = make_engine(max_seqs=1, kv_block_size=4)
@@ -612,6 +608,7 @@ class TestCollectTimeout:
 # ---------------------------------------------------------------------------
 # callback-error accounting
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestCallbackErrors:
     def test_consumer_exception_counted_not_fatal(self):
         eng = make_engine(max_seqs=2, kv_block_size=4)
@@ -646,6 +643,7 @@ class TestCallbackErrors:
 # ---------------------------------------------------------------------------
 # KV-block leak freedom (property-style)
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestLeakFreedom:
     def test_any_interleaving_returns_to_baseline(self, tmp_path):
         """Finish / cancel / deadline-evict / preempt / quarantine, all
@@ -695,6 +693,7 @@ class TestLeakFreedom:
 # ---------------------------------------------------------------------------
 # observability surfaces: /healthz, /statusz, doctor
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
 class TestSurfaces:
     def test_healthz_draining_then_stopped(self):
         from paddle_tpu.observability.monitor import StatusServer
@@ -721,21 +720,22 @@ class TestSurfaces:
         assert res["state"] == "serving"
         assert res["callbacks"]["errors"] == 0
 
-    def test_doctor_check_serving(self):
-        workers = {0: [
-            {"kind": "serve.quarantine", "request_id": "req-7",
-             "step_kind": "decode", "error": "RuntimeError('boom')"},
-            {"kind": "serve.deadline_miss", "request_id": "req-8",
-             "miss": "ttft"},
-            {"kind": "serve.deadline_miss", "request_id": "req-9",
-             "miss": "total"},
-        ]}
-        findings = doctor.check_serving(workers)
-        kinds = {f["kind"]: f for f in findings}
-        assert set(kinds) == {"serve_poisoned", "serve_deadline_misses"}
-        assert kinds["serve_poisoned"]["data"]["count"] == 1
-        assert kinds["serve_deadline_misses"]["data"]["count"] == 2
-        assert kinds["serve_deadline_misses"]["data"]["ttft_misses"] == 1
-        assert kinds["serve_poisoned"]["severity"] \
-            > kinds["serve_deadline_misses"]["severity"]
-        assert doctor.check_serving({0: []}) == []
+
+def test_doctor_check_serving():
+    workers = {0: [
+        {"kind": "serve.quarantine", "request_id": "req-7",
+         "step_kind": "decode", "error": "RuntimeError('boom')"},
+        {"kind": "serve.deadline_miss", "request_id": "req-8",
+         "miss": "ttft"},
+        {"kind": "serve.deadline_miss", "request_id": "req-9",
+         "miss": "total"},
+    ]}
+    findings = doctor.check_serving(workers)
+    kinds = {f["kind"]: f for f in findings}
+    assert set(kinds) == {"serve_poisoned", "serve_deadline_misses"}
+    assert kinds["serve_poisoned"]["data"]["count"] == 1
+    assert kinds["serve_deadline_misses"]["data"]["count"] == 2
+    assert kinds["serve_deadline_misses"]["data"]["ttft_misses"] == 1
+    assert kinds["serve_poisoned"]["severity"] \
+        > kinds["serve_deadline_misses"]["severity"]
+    assert doctor.check_serving({0: []}) == []

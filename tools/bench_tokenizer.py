@@ -1,15 +1,13 @@
 """Host-side tokenizer throughput benchmark: native C core vs python
 oracle (paddle_tpu/text/tokenizer.py; the faster_tokenizer analog).
 
-Unlike the device benches in bench.py, CPU numbers are the CORRECT kind
-of evidence here — tokenization is host-side work in both the reference
-and this framework — so this tool records benchmarks/tokenizer_host.json
-directly, labelled host_side.
+CPU numbers are the CORRECT kind of evidence here — tokenization is
+host-side work in both the reference and this framework — so this tool
+prints its row, labelled host_side, and writes no file.
 
 Run: python tools/bench_tokenizer.py
 """
 import json
-import os
 import pathlib
 import sys
 import time
@@ -80,17 +78,12 @@ def main():
         "native_mb_per_s": n_bytes / 1e6 / t_native,
         "python_mb_per_s": n_bytes / 1e6 / t_python,
         "speedup_native_over_python": t_python / t_native,
-        "_meta": {"recorded_unix": time.time(),
-                  "note": "host-side component; CPU is the right platform"},
     }
-    out = ROOT / "benchmarks" / "tokenizer_host.json"
-    out.parent.mkdir(exist_ok=True)
-    out.write_text(json.dumps(row, indent=2))
     print(f"[tokenizer] {n_bytes / 1e6:.1f}MB corpus, {len(ids_n)} tokens: "
           f"native {row['native_mb_per_s']:.1f}MB/s vs python "
           f"{row['python_mb_per_s']:.1f}MB/s "
           f"({row['speedup_native_over_python']:.1f}x)", file=sys.stderr)
-    print(json.dumps({k: v for k, v in row.items() if k != "_meta"}))
+    print(json.dumps(row))
 
 
 if __name__ == "__main__":
