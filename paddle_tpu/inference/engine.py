@@ -31,7 +31,11 @@ SLO telemetry rides the PR 3 registry: gauges ``serve.queue_depth`` /
 histograms ``serve.ttft_ms`` / ``serve.tpot_ms``, counters
 ``serve.tokens`` / ``serve.requests`` / ``serve.finished`` /
 ``serve.preemptions`` / ``serve.h2d_bytes`` / ``serve.d2h_bytes`` (what
-a step puts on the device and copies back) / ``serve.pool_rebuilds`` /
+a step puts on the device and copies back: its int32 inputs in one
+buffer; the next tokens, a finite flag a row and the model's counts) /
+``serve.logits_fetch_steps`` (the steps whose ``[rows, vocab]`` float32
+logits crossed too: only a captured request or the fault seam asks for
+them) / ``serve.pool_rebuilds`` /
 ``serve.paged_blocks_live`` / ``serve.paged_blocks_table`` (the pages a
 decode step's rows hold, and rows launched x table width),
 gauge ``serve.kv_pool_bytes`` (the live page handles: one pool).  Every
@@ -56,8 +60,9 @@ same robustness treatment the training path earned:
   terminal reason (``deadline`` / ``cancelled``) through ``collect()``
   and the callback path;
 - **poisoned-request quarantine** — the jitted step runs inside a fault
-  boundary; a step exception (or a nonfinite logits row under
-  ``PTPU_SERVE_NAN_GUARD``) bisects the batch, evicts the culprit(s)
+  boundary; a step exception bisects the batch (a nonfinite logits row
+  under ``PTPU_SERVE_NAN_GUARD`` names its request itself, from a flag
+  a row that the step program computes), evicts the culprit(s)
   with ``reason="poisoned"`` plus a durable record under
   ``<run_dir>/serve_quarantine/``, and replays the step so every other
   request completes token-exact (decode rows are independent).  The
@@ -124,7 +129,7 @@ __all__ = ["MAX_SEQS_ENV", "SHED_QUEUE_DEPTH_ENV", "NAN_GUARD_ENV",
            "DEADLINE_MS_ENV", "DRAIN_SECS_ENV", "default_max_seqs",
            "default_shed_queue_depth", "default_nan_guard",
            "default_deadline_ms", "default_drain_secs", "CollectTimeout",
-           "ServingEngine"]
+           "ServingEngine", "pack_step_inputs", "unpack_step_inputs"]
 
 MAX_SEQS_ENV = "PTPU_MAX_SEQS"
 SHED_QUEUE_DEPTH_ENV = "PTPU_SHED_QUEUE_DEPTH"
@@ -178,6 +183,35 @@ class CollectTimeout(TimeoutError):
     message names the request's current scheduler state."""
 
 
+def pack_step_inputs(ids, positions, last_index, block_tables, seq_lens,
+                     slot_mapping) -> np.ndarray:
+    """A step's int32 inputs end to end in one host buffer, so that they
+    cross to the device in one put: token ids ``(rows, chunk)``,
+    positions ``(rows,)``, the index of the position sampled, block
+    tables ``(rows, width)``, sequence lengths ``(rows,)``, write slots
+    ``(rows, chunk)``."""
+    return np.concatenate([
+        np.asarray(a, np.int32).reshape(-1)
+        for a in (ids, positions, last_index, block_tables, seq_lens,
+                  slot_mapping)])
+
+
+def unpack_step_inputs(packed, rows: int, chunk: int):
+    """Cut :func:`pack_step_inputs`'s buffer apart again (inside a trace:
+    static slices).  The table width is whatever is left of the length."""
+    width, rest = divmod(packed.shape[0] - 2 * rows * (chunk + 1) - 1, rows)
+    enforce(width > 0 and rest == 0,
+            f"{packed.shape[0]} packed step inputs do not hold {rows} rows "
+            f"of {chunk}")
+    out, at = [], 0
+    for shape in ((rows, chunk), (rows,), (), (rows, width), (rows,),
+                  (rows, chunk)):
+        n = int(np.prod(shape, dtype=np.int64))
+        out.append(packed[at:at + n].reshape(shape))
+        at += n
+    return out
+
+
 class _NonfiniteLogits(RuntimeError):
     """NaN-guard verdict: the named rows came back nonfinite — unlike a
     raised step error this carries the culprits, no bisection needed."""
@@ -198,7 +232,7 @@ class ServingEngine:
     returning ``(logits, new_caches)`` over ``PagedLayerCache`` lists, or
     ``(logits, new_caches, aux)``.  ``aux`` rides out with the step's
     outputs and its meaning is the model's: ``aux["counts"]`` (small
-    arrays, copied to the host with the logits) goes to the model's
+    arrays, copied to the host with the next tokens) goes to the model's
     ``serving_counts(counts, kind)``, which names the counters to add to
     and the gauges to set; ``aux["per_token"]`` (arrays of ``(rows, chunk,
     ...)``) stays on the device unless a request captures logits, and is
@@ -207,7 +241,13 @@ class ServingEngine:
     ``temperature`` is engine-level (it is baked into the jitted step;
     per-request temperatures would multiply the compile set).
     ``capture_logits=True`` keeps every sampled position's logits row on
-    the host per request — the numerics-equality hook for tests.
+    the host per request — the numerics-equality hook for tests.  It is
+    read at ``submit()``, so it can be set for some requests only.  A
+    step moves over PCIe what the host reads: the next tokens, one
+    finite flag a row and the model's counts.  The ``[rows, vocab]``
+    float32 logits stay on the device unless a row of the step belongs
+    to a capturing request or ``step_fault`` is set
+    (``serve.logits_fetch_steps`` counts those steps).
 
     Resilience knobs (ISSUE 15): ``nan_guard`` enables the per-step
     nonfinite-logits check (env ``PTPU_SERVE_NAN_GUARD``);
@@ -218,7 +258,10 @@ class ServingEngine:
     records and the drain spill file land; ``step_fault`` is the test
     seam the ``testing/faults.poison_request`` injector plugs into — it
     is called as ``fault(engine, kind, request_ids, logits)`` on every
-    executed step, bisection probes included.
+    executed step, bisection probes included; while it is set the
+    engine fetches every step's logits for it, and the NaN guard checks
+    on the host what the hook hands back.  Without it the guard reads
+    the flags and no logits cross for its sake.
     """
 
     def __init__(self, model, *, max_seqs: Optional[int] = None,
@@ -337,6 +380,7 @@ class ServingEngine:
         self._model_counts: Dict[str, Dict[str, Any]] = {
             "counters": {}, "gauges": {}}
         self._paged_blocks = {"live": 0, "table": 0}
+        self._logits_fetch_steps = 0
 
     # -- plumbing ----------------------------------------------------------
     def serve_dir(self) -> Optional[str]:
@@ -362,21 +406,28 @@ class ServingEngine:
         return sub
 
     # -- jitted step functions --------------------------------------------
-    _STEP_ARGS = ("params", "ids", "positions", "last_index", "pages",
-                  "block_tables", "seq_lens", "slot_mapping", "key")
+    _STEP_ARGS = ("params", "packed", "pages", "key")
 
     def _build_step_fn(self):
-        """The step program.  ``pages`` (per layer its page arrays, as
-        the model declared them) is donated and nothing else is: with
-        token-major pages XLA scatters the new tokens into the pool in
-        place and every returned page array aliases its input, so no step
-        copies the pool.  The three small per-step arrays arrive once;
-        each layer's view is built here, inside the trace."""
+        """The step program, ``fn(params, packed, pages, key, *, rows,
+        chunk)``.  ``packed`` is the step's int32 inputs in one buffer
+        (:func:`pack_step_inputs`), cut apart here at offsets that
+        ``rows`` and ``chunk`` fix for each program (decode; each prefill
+        bucket).  ``pages`` (per layer its page arrays, as the model
+        declared them) is donated and nothing else is: with token-major
+        pages XLA scatters the new tokens into the pool in place and
+        every returned page array aliases its input, so no step copies
+        the pool.  Each layer's view is built here, inside the trace.
+
+        Returns ``(next tokens, finite, logits, pages, aux)``: the float32
+        logits stay on the device unless the host asks for them, so the
+        program says itself which rows of them are finite."""
         model, temperature = self.model, self.temperature
         block_size = self.cache.block_size
 
-        def fn(params, ids, positions, last_index, pages, block_tables,
-               seq_lens, slot_mapping, key):
+        def fn(params, packed, pages, key, *, rows, chunk):
+            (ids, positions, last_index, block_tables, seq_lens,
+             slot_mapping) = unpack_step_inputs(packed, rows, chunk)
             caches = [PagedLayerCache(layer, block_tables, seq_lens,
                                       slot_mapping, block_size=block_size)
                       for layer in pages]
@@ -389,10 +440,12 @@ class ServingEngine:
             else:
                 nxt = jax.random.categorical(key, logits / temperature,
                                              axis=-1)
-            return (nxt.astype(jnp.int32), logits,
-                    [c.pages for c in new_caches], aux[0] if aux else {})
+            return (nxt.astype(jnp.int32), jnp.isfinite(logits).all(-1),
+                    logits, [c.pages for c in new_caches],
+                    aux[0] if aux else {})
 
-        return jax.jit(fn, donate_argnames=("pages",))
+        return jax.jit(fn, donate_argnames=("pages",),
+                       static_argnames=("rows", "chunk"))
 
     def _tracked_step(self, name: str):
         # one underlying jitted callable (jax caches per shape); a tracker
@@ -554,8 +607,8 @@ class ServingEngine:
         produced; empty when idle AND no queued work remains.
 
         The step is one ``engine.step`` span (attributes ``step``,
-        ``kind``, ``rows``, ``bucket``; a decode step also
-        ``kv_blocks_live``, ``kv_blocks_table``) whose children name where its
+        ``kind``, ``rows``, ``bucket``, ``logits_fetched``; a decode step
+        also ``kv_blocks_live``, ``kv_blocks_table``) whose children name where its
         host time goes — ``reap``, ``schedule``, ``tables``, ``h2d``,
         ``dispatch``, ``device_wait``, ``logits_copy``, ``guard``,
         ``accept``, ``gauges``, and the rare ``quarantine`` /
@@ -682,11 +735,20 @@ class ServingEngine:
     # quarantined; a pool that a failed call consumed is rebuilt
     # (_rebuild_lost_pool).
 
+    def _wants_logits(self, seqs: List[SequenceState]) -> bool:
+        """Whether this step's logits have a reader on the host: the
+        fault seam, or a row whose request captures them."""
+        return (self.step_fault is not None
+                or any(s.capture_logits for s in seqs))
+
     def _apply_fault(self, kind: str, seqs: List[SequenceState],
-                     logits_np: np.ndarray) -> np.ndarray:
+                     finite: np.ndarray,
+                     logits_np: Optional[np.ndarray]):
         """Fault seam + NaN guard, applied to every executed step
         (bisection probes included — injected faults must re-fire on the
-        subset that still contains the target)."""
+        subset that still contains the target).  The guard reads the
+        flags the step program computed, a row each; where the seam is
+        set it reads what the hook handed back instead."""
         with self._phase("guard"):
             if self.step_fault is not None:
                 out = self.step_fault(self, kind,
@@ -695,36 +757,46 @@ class ServingEngine:
                 if out is not None:
                     logits_np = np.asarray(out)
             if self.nan_guard:
-                bad = [s.request_id for i, s in enumerate(seqs)
-                       if not np.isfinite(logits_np[i]).all()]
+                if self.step_fault is not None:
+                    finite = np.isfinite(logits_np[:len(seqs)]).all(axis=-1)
+                bad = [s.request_id for s, ok in zip(seqs, finite)
+                       if not ok]
                 if bad:
                     raise _NonfiniteLogits(bad)
         return logits_np
 
-    def _device_step(self, fn, ids: np.ndarray, positions: np.ndarray,
-                     last_index: int, tables: np.ndarray, lens: np.ndarray,
-                     slots: np.ndarray, key):
-        """Host arrays in, host ``(next tokens, logits)`` out, the pool
-        updated in place, one span a leg: ``h2d`` (the puts), ``dispatch``
-        (the jitted call until it returns, and the cache taking the new
-        page handles — the old ones are dead by then; the step's PRNG-key
-        split is a ``dispatch`` span too), ``device_wait`` (until the
+    def _device_step(self, fn, rows: int, chunk: int, inputs,
+                     key, fetch_logits: bool):
+        """Host arrays in (``inputs`` in :func:`pack_step_inputs`'s
+        order), host ``(next tokens, finite flags, logits or None)`` out,
+        the pool updated in place, one span a leg: ``h2d`` (the inputs
+        packed into one buffer and put once), ``dispatch`` (the jitted
+        call until it returns, and the cache taking the new page handles
+        — the old ones are dead by then; the step's PRNG-key split is a
+        ``dispatch`` span too; the copies to the host are asked for here,
+        so that they follow the program out), ``device_wait`` (until the
         device is done — the copy below would wait for the same), and
-        ``logits_copy`` (a pure device-to-host copy by then)."""
+        ``logits_copy`` (what is left of the device-to-host copy by
+        then: the next tokens, a flag a row, the model's counts — and
+        the ``[rows, vocab]`` float32 logits only under ``fetch_logits``;
+        otherwise they stay where they are and go with the step's
+        outputs)."""
         reg = self._reg()
         with self._phase("h2d"):
-            last = np.asarray(last_index, np.int32)
-            ids_d, positions_d, last_d, tables_d, lens_d, slots_d = (
-                jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(last),
-                jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(slots))
-            reg.counter("serve.h2d_bytes").inc(
-                ids.nbytes + positions.nbytes + last.nbytes
-                + tables.nbytes + lens.nbytes + slots.nbytes)
+            packed = pack_step_inputs(*inputs)
+            packed_d = jax.device_put(packed)
+            reg.counter("serve.h2d_bytes").inc(packed.nbytes)
         with self._phase("dispatch"):
-            nxt, logits, pages, aux = fn(self._params, ids_d, positions_d,
-                                         last_d, self.cache.pages, tables_d,
-                                         lens_d, slots_d, key)
+            nxt, finite, logits, pages, aux = fn(
+                self._params, packed_d, self.cache.pages, key, rows=rows,
+                chunk=chunk)
             self.cache.update_pages(pages)
+            # the counts come with the outputs; the rest of aux stays.
+            # What the host reads sets out as soon as the program is done
+            out = ([nxt, finite, aux.get("counts", {})]
+                   + ([logits] if fetch_logits else []))
+            for a in jax.tree_util.tree_leaves(out):
+                a.copy_to_host_async()
         with self._phase("device_wait"):
             try:
                 jax.block_until_ready((nxt, logits))
@@ -736,16 +808,18 @@ class ServingEngine:
                 self.cache.drop_pages()
                 raise
         with self._phase("logits_copy"):
-            nxt_np, logits_np = np.asarray(nxt), np.asarray(logits)
-            nbytes = nxt_np.nbytes + logits_np.nbytes
-            if aux:     # the counts come with the outputs; the rest stays
-                counts = jax.tree_util.tree_map(np.asarray,
-                                                aux.get("counts", {}))
+            out = jax.device_get(out)
+            nxt_np, finite_np, counts, *fetched = out
+            if aux:
                 self._step_aux = dict(aux, counts=counts)
-                nbytes += sum(v.nbytes
-                              for v in jax.tree_util.tree_leaves(counts))
-            reg.counter("serve.d2h_bytes").inc(nbytes)
-        return nxt_np, logits_np
+            reg.counter("serve.d2h_bytes").inc(sum(
+                v.nbytes for v in jax.tree_util.tree_leaves(out)))
+            if fetch_logits:
+                reg.counter("serve.logits_fetch_steps").inc()
+                self._logits_fetch_steps += 1
+        if self._step_root is not None:
+            self._step_root.set(logits_fetched=fetch_logits)
+        return nxt_np, finite_np, fetched[0] if fetched else None
 
     def _rebuild_lost_pool(self) -> bool:
         """If a step program consumed the pool and handed none back (the
@@ -775,11 +849,12 @@ class ServingEngine:
             lens = np.asarray([L], np.int32)
             slots = self.cache.slot_array([seq.request_id], [0], bucket)
             fn = self._prefill_fn(bucket)
-        nxt_np, logits_np = self._device_step(
-            fn, ids, np.zeros((1,), np.int32), L - 1, tables, lens, slots,
-            key)
+        nxt_np, finite, logits_np = self._device_step(
+            fn, 1, bucket,
+            (ids, np.zeros((1,), np.int32), L - 1, tables, lens, slots),
+            key, self._wants_logits([seq]))
         self._proven.add(("prefill", bucket))
-        logits_np = self._apply_fault("prefill", [seq], logits_np)
+        logits_np = self._apply_fault("prefill", [seq], finite, logits_np)
         return nxt_np, logits_np
 
     def _apply_decode(self, seqs: List[SequenceState], key):
@@ -807,10 +882,11 @@ class ServingEngine:
             slots = self.cache.slot_array(sids, starts, 1)
             self._note_paged_blocks(lens, tables)
             fn = self._decode_fn()
-        nxt_np, logits_np = self._device_step(
-            fn, ids, positions, 0, tables, lens, slots, key)
+        nxt_np, finite, logits_np = self._device_step(
+            fn, B, 1, (ids, positions, 0, tables, lens, slots), key,
+            self._wants_logits(seqs))
         self._proven.add("decode")
-        logits_np = self._apply_fault("decode", seqs, logits_np)
+        logits_np = self._apply_fault("decode", seqs, finite, logits_np)
         return nxt_np, logits_np
 
     def _run_prefill(self, plan: StepPlan) -> List[Dict[str, Any]]:
@@ -856,8 +932,9 @@ class ServingEngine:
                 # already sampled (and streamed) before eviction — only
                 # the KV was rebuilt; nothing new to emit
                 return []
-            return [self._accept_token(seq, int(nxt_np[0]),
-                                       logits_np[0], first=True)]
+            return [self._accept_token(
+                seq, int(nxt_np[0]),
+                logits_np[0] if seq.capture_logits else None, first=True)]
 
     def _run_decode(self, plan: StepPlan) -> List[Dict[str, Any]]:
         seqs = plan.seqs
@@ -889,9 +966,10 @@ class ServingEngine:
             events = []
             for i, s in enumerate(seqs):
                 self.sched.mark_decoded(s)
-                events.append(self._accept_token(s, int(nxt_np[i]),
-                                                 logits_np[i],
-                                                 first=False))
+                events.append(self._accept_token(
+                    s, int(nxt_np[i]),
+                    logits_np[i] if s.capture_logits else None,
+                    first=False))
             # one batch-level decode span; the assembler amortizes the
             # step across its residents to produce per-request decode time
             requesttrace.emit_decode_span(
@@ -1061,8 +1139,9 @@ class ServingEngine:
             self._tpot_ms.append(tpot)
         seq.last_token_time = now
         reg.counter("serve.tokens").inc()
-        if seq.capture_logits:
-            seq.logits.append(np.asarray(logits_row))
+        if logits_row is not None:
+            # a copy: a view would keep the whole batch's array alive
+            seq.logits.append(np.array(logits_row))
         reason = seq.should_finish()
         if reason is not None:
             self.sched.complete(seq, reason)
@@ -1432,6 +1511,7 @@ class ServingEngine:
         return {
             "steps": self.steps,
             "phases": phases,
+            "logits_fetch_steps": self._logits_fetch_steps,
             "replica_id": self.replica_id,
             "queue_depth": self.sched.queue_depth,
             "waiting": c["waiting"],

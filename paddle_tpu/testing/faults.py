@@ -377,7 +377,9 @@ class poison_request:
     """Step-fault injector for the ServingEngine quarantine drill: plug
     into ``ServingEngine(step_fault=...)``; the engine calls it as
     ``fault(engine, kind, request_ids, logits)`` on every executed step
-    — bisection probes included.
+    — bisection probes included.  The logits otherwise stay on the
+    device: while a hook is set the engine fetches them for it, every
+    step, as a host ``[rows, vocab]`` float32 array.
 
     ``target`` is a request id (str) or a submit-order index (int,
     resolved lazily against ``engine._submit_order``).  Modes:

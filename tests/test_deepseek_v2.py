@@ -14,6 +14,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.inference import ServingEngine
+from paddle_tpu.inference.engine import pack_step_inputs
 from paddle_tpu.inference.kv_cache import PagedKVCache
 from paddle_tpu.inference.latent_attention import (
     latent_attention_pallas, latent_attention_reference)
@@ -219,12 +220,12 @@ def test_named_scopes_of_the_new_device_parts(served):
     tables = np.zeros((4, eng.sched.max_blocks_per_seq), np.int32)
 
     def names(rows, chunk):
+        packed = pack_step_inputs(
+            np.zeros((rows, chunk)), np.zeros((rows,)), 0, tables[:rows],
+            np.ones((rows,)), np.zeros((rows, chunk)))
         text = eng._build_step_fn().lower(
-            eng._params, jnp.zeros((rows, chunk), jnp.int32),
-            jnp.zeros((rows,), jnp.int32), jnp.asarray(0, jnp.int32),
-            eng.cache.pages, tables[:rows], np.ones((rows,), np.int32),
-            np.zeros((rows, chunk), np.int32),
-            jax.random.PRNGKey(0)).as_text(debug_info=True)
+            eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
+            rows=rows, chunk=chunk).as_text(debug_info=True)
         return text
     decode, prefill = names(4, 1), names(1, 8)
     for scope in ("mla.q", "mla.kv_write", "mla.decode", "moe.route",

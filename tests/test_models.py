@@ -336,6 +336,7 @@ class TestGPTScopes:
     def test_serving_step_carries_the_scopes(self):
         import re
         from paddle_tpu.inference import ServingEngine
+        from paddle_tpu.inference.engine import pack_step_inputs
         from paddle_tpu.models import GPTConfig, GPTForCausalLM
         from paddle_tpu.observability.registry import MetricsRegistry
         pt.seed(3)
@@ -348,11 +349,11 @@ class TestGPTScopes:
         tables = np.zeros((2, 8), np.int32)
         lens = np.ones((2,), np.int32)
         slots = np.zeros((2, 1), np.int32)
+        packed = pack_step_inputs(np.zeros((2, 1)), np.zeros((2,)), 0,
+                                  tables, lens, slots)
         text = eng._build_step_fn().lower(
-            eng._params, jnp.zeros((2, 1), jnp.int32),
-            jnp.zeros((2,), jnp.int32), jnp.asarray(0, jnp.int32),
-            eng.cache.pages, tables, lens, slots,
-            jax.random.PRNGKey(0)).as_text(debug_info=True)
+            eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
+            rows=2, chunk=1).as_text(debug_info=True)
         names = set(re.findall(r'loc\("([^"]+)"', text))
         for scope in ("gpt.embed", "gpt.block/attn/", "gpt.block/mlp/",
                       "gpt.head/"):

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference import (BlockAllocator, Config, PagedKVCache,
                                   ServingEngine, create_predictor)
+from paddle_tpu.inference.engine import (pack_step_inputs,
+                                         unpack_step_inputs)
 from paddle_tpu.inference.paged_attention import (_pages_per_wave,
                                                   paged_attention_pallas,
                                                   paged_attention_reference)
@@ -680,18 +682,18 @@ class TestPoolInPlace:
         import jax
         eng = self.engine()
         pool = eng.cache.pool_bytes()
+        packed = pack_step_inputs(
+            np.zeros((rows, chunk)), np.zeros((rows,)), 0,
+            np.zeros((rows, eng.sched.max_blocks_per_seq)),
+            np.ones((rows,)), np.zeros((rows, chunk)))
         compiled = eng._build_step_fn().lower(
-            eng._params, jnp.zeros((rows, chunk), jnp.int32),
-            jnp.zeros((rows,), jnp.int32), jnp.asarray(0, jnp.int32),
-            eng.cache.pages,
-            np.zeros((rows, eng.sched.max_blocks_per_seq), np.int32),
-            np.ones((rows,), np.int32), np.zeros((rows, chunk), np.int32),
-            jax.random.PRNGKey(0)).compile()
+            eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
+            rows=rows, chunk=chunk).compile()
         header = compiled.as_text().split("input_output_alias={", 1)[1]
         header = header.split("entry_computation_layout", 1)[0]
         aliased = [int(p) for p in re.findall(r"\((\d+), \{\}", header)]
         flat, _ = jax.tree_util.tree_flatten(eng._params)
-        first = len(flat) + 3                # params, ids, positions, last
+        first = len(flat) + 1                # params, the packed inputs
         n = sum(len(layer) for layer in eng.cache.pages)
         assert n == {"gpt": 2, "deepseek_v2": 1}[family] * 2    # layers
         assert sorted(aliased) == list(range(first, first + n))
@@ -807,10 +809,11 @@ class TestStepSpans:
         prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8)          # bucket 8, 1 row
         decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2)       # 2 slots
         assert h2d.value - h0 == prefill + 2 * decode
-        # next tokens (int32) and float32 logits over the vocabulary; with
-        # them a step's counts, where the model books any (DeepSeek-V2:
+        # next tokens (int32), a finite flag a row and, since this engine's
+        # fault seam is set, float32 logits over the vocabulary; with them
+        # a step's counts, where the model books any (DeepSeek-V2:
         # ``moe_load`` of 1 expert layer x 4 held experts, ``moe_dropped``)
-        row = 4 + 4 * VOCAB[family]
+        row = 4 + 1 + 4 * VOCAB[family]
         counts = {"gpt": 0, "deepseek_v2": i32 * (1 * 4 + 1)}[family]
         assert d2h.value - d0 == row + 2 * 2 * row + 3 * counts
 
@@ -862,6 +865,201 @@ class TestStepSpans:
         assert tree["engine.step/quarantine/dispatch"]["count"] >= 1
         assert "quarantine" in eng.stats()["phases"]
         assert "recover" not in eng.stats()["phases"]
+
+
+# ---------------------------------------------------------------------------
+# What crosses the host-device boundary in a step (ISSUE 31)
+# ---------------------------------------------------------------------------
+def _fetch_all(engine, kind, request_ids, logits):
+    """An identity fault hook: an engine whose seam is set fetches every
+    step's logits, as every engine did before ISSUE 31: the oracle."""
+    return None
+
+
+# what a DeepSeek-V2 step's counts take (``moe_load`` of 1 expert layer x
+# 4 held experts, ``moe_dropped``, int32); a GPT books none
+COUNTS_BYTES = {"gpt": 0, "deepseek_v2": 4 * (1 * 4 + 1)}
+
+
+@pytest.mark.usefixtures("family")
+class TestWhatCrossesTheBoundary:
+    PROMPTS = ([1, 2, 3], [4, 5], [6, 7, 8, 9, 10, 11, 12, 13, 14], [15])
+
+    def engine(self, **kw):
+        kw.setdefault("registry", MetricsRegistry())
+        return ServingEngine(tiny_model(), max_seqs=4, kv_block_size=4,
+                             **kw)
+
+    def test_a_plain_step_moves_tokens_and_flags_and_no_logits(
+            self, family):
+        from paddle_tpu.observability import tracing
+        eng = self.engine()
+        reg = eng._reg()
+        tracing.reset_tracing()
+        for p in self.PROMPTS:
+            eng.submit(p, max_new_tokens=5)
+        steps = eng.run()
+        prefills = reg.counter("serve.prefills").value
+        decodes = reg.counter("serve.decode_steps").value
+        assert prefills == 4 and prefills + decodes == steps
+        # a token (int32) and a flag a row launched, and the counts:
+        # nothing that grows with the vocabulary
+        want = (prefills * (1 * 5 + COUNTS_BYTES[family])
+                + decodes * (4 * 5 + COUNTS_BYTES[family]))
+        assert reg.counter("serve.d2h_bytes").value == want
+        assert reg.counter("serve.logits_fetch_steps").value == 0
+        assert eng.stats()["logits_fetch_steps"] == 0
+        roots = [s for s in tracing.spans_between(0.0, float("inf"))
+                 if s[0] == "engine.step"]
+        assert len(roots) == steps
+        assert all(s[3]["logits_fetched"] is False for s in roots)
+
+    def test_a_captured_row_gets_its_logits_and_nobody_elses_cross(self):
+        oracle = self.engine(step_fault=_fetch_all, capture_logits=True)
+        want = [oracle.submit(p, max_new_tokens=n)
+                for p, n in zip(self.PROMPTS[:2], (3, 6))]
+        oracle.run()
+        eng = self.engine()
+        eng.capture_logits = True           # for this request only
+        captured = eng.submit(self.PROMPTS[0], max_new_tokens=3)
+        eng.capture_logits = False
+        plain = eng.submit(self.PROMPTS[1], max_new_tokens=6)
+        steps = eng.run()
+        got, ref = eng.collect(captured), oracle.collect(want[0])
+        assert got["tokens"] == ref["tokens"]
+        assert len(got["logits"]) == len(ref["logits"]) == 3
+        for a, b in zip(got["logits"], ref["logits"]):
+            assert a.dtype == np.float32 and np.array_equal(a, b)
+        other = eng.collect(plain)
+        assert "logits" not in other
+        assert other["tokens"] == oracle.collect(want[1])["tokens"]
+        # the captured request's prefill and its two decode steps, which
+        # the other request shared; the other's own five steps fetch none
+        assert steps == 2 + 5
+        assert eng.stats()["logits_fetch_steps"] == 3
+        reg = eng._reg()
+        assert reg.counter("serve.logits_fetch_steps").value == 3
+        assert oracle.stats()["logits_fetch_steps"] == oracle.steps
+
+    @pytest.mark.parametrize("route", ["page", "hook"])
+    def test_the_guard_names_a_nonfinite_row(self, route):
+        """The guard reads the flags the step computed.  ``page``: the
+        NaN arises on the device (one request's cached page), nothing is
+        fetched and nothing bisected.  ``hook``: the seam overwrites the
+        row on the host, and the guard reads what it handed back."""
+        from paddle_tpu.observability import tracing
+        clean = self.engine()
+        want = clean.generate(self.PROMPTS, max_new_tokens=6)
+        hook = (faults.poison_request(1, mode="nan", kinds=("decode",))
+                if route == "hook" else None)
+        eng = self.engine(nan_guard=True, step_fault=hook)
+        tracing.reset_tracing()
+        rids = [eng.submit(p, max_new_tokens=6) for p in self.PROMPTS]
+        for _ in rids:
+            eng.step()                       # the four prefills
+        if route == "page":
+            block = eng.cache.table(rids[1])[0]
+            pages = [list(layer) for layer in eng.cache.pages]
+            pages[0][0] = pages[0][0].at[block].set(np.nan)
+            eng.cache.update_pages(pages)
+        eng.run()
+        assert list(eng.quarantined) == [rids[1]]
+        assert "nonfinite" in eng.quarantined[rids[1]]["error"]
+        assert eng.collect(rids[1])["finish_reason"] == "poisoned"
+        for i in (0, 2, 3):
+            assert eng.collect(rids[i])["tokens"] == want[i]
+        tree = tracing.span_tree_totals()
+        assert tree["engine.step/quarantine"]["count"] == 1
+        assert "engine.step/quarantine/dispatch" not in tree    # no probe
+        # with the seam set every executed step program fetches: each
+        # step, and the replay of the faulted one on its survivors
+        fetched = eng.stats()["logits_fetch_steps"]
+        assert fetched == (0 if route == "page" else eng.steps + 1)
+        assert eng.cache.allocator.num_used == 0
+
+    def test_the_packed_inputs_unpack_to_what_went_in(self):
+        rng = np.random.default_rng(0)
+        rows, chunk, width = 3, 5, 7
+        parts = [rng.integers(0, 99, shape).astype(np.int32)
+                 for shape in ((rows, chunk), (rows,), (), (rows, width),
+                               (rows,), (rows, chunk))]
+        packed = pack_step_inputs(*parts)
+        assert packed.dtype == np.int32 and packed.ndim == 1
+        assert packed.nbytes == sum(a.nbytes for a in parts)
+        for got, want in zip(unpack_step_inputs(packed, rows, chunk), parts):
+            assert got.shape == want.shape and np.array_equal(got, want)
+        with pytest.raises(Exception, match="packed step inputs"):
+            unpack_step_inputs(packed[:-1], rows, chunk)
+
+    @pytest.mark.parametrize("rows,chunk", [(4, 1), (1, 8), (1, 16),
+                                            (1, 32)],
+                             ids=["decode", "prefill_b8", "prefill_b16",
+                                  "prefill_b32"])
+    def test_a_packed_step_is_the_unpacked_step(self, rows, chunk):
+        """The step program against a reference that takes the six
+        arrays apart, as the program did before ISSUE 31: the same
+        tokens, finite flags that agree with the logits."""
+        import jax
+        from paddle_tpu.inference.kv_cache import PagedLayerCache
+        eng = self.engine()
+        model, bs = eng.model, eng.cache.block_size
+        rng = np.random.default_rng(chunk)
+        eng.cache.update_pages([
+            tuple(jnp.asarray(rng.normal(size=a.shape) * 0.3, a.dtype)
+                  for a in layer) for layer in eng.cache.pages])
+        sids = [f"s{i}" for i in range(rows)]
+        if chunk == 1:                       # rows that hold 3..6 tokens
+            lens = np.arange(3, 3 + rows, dtype=np.int32)
+            starts, last = list(lens - 1), 0
+            positions = lens - 1
+        else:                                # a prompt that fills its bucket
+            lens = np.asarray([chunk - 2], np.int32)
+            starts, last = [0], chunk - 3
+            positions = np.zeros((1,), np.int32)
+        for sid, n in zip(sids, lens):
+            assert eng.cache.ensure_capacity(sid, int(n))
+        ids = rng.integers(0, 30, (rows, chunk)).astype(np.int32)
+        tables = eng.cache.table_array(sids, eng.sched.max_blocks_per_seq)
+        slots = eng.cache.slot_array(sids, starts, chunk)
+
+        @jax.jit
+        def reference(params, ids, positions, last, pages, tables, lens,
+                      slots):
+            caches = [PagedLayerCache(layer, tables, lens, slots,
+                                      block_size=bs) for layer in pages]
+            logits = model.apply(params, ids, caches, positions, last,
+                                 method="serving_step")[0]
+            return jnp.argmax(logits, axis=-1), logits
+
+        want, want_logits = reference(
+            eng._params, ids, positions, np.asarray(last, np.int32),
+            eng.cache.pages, tables, lens, slots)
+        nxt, finite, logits, pages, _ = eng._build_step_fn()(
+            eng._params,
+            pack_step_inputs(ids, positions, last, tables, lens, slots),
+            eng.cache.pages, jax.random.PRNGKey(0), rows=rows, chunk=chunk)
+        eng.cache.update_pages(pages)
+        assert np.asarray(nxt).tolist() == np.asarray(want).tolist()
+        np.testing.assert_allclose(np.asarray(logits),
+                                   np.asarray(want_logits, np.float32),
+                                   atol=1e-5)
+        assert finite.dtype == jnp.bool_ and finite.shape == (rows,)
+        assert np.asarray(finite).all()
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    def test_generate_is_token_for_token_what_the_fetching_engine_gives(
+            self, temperature):
+        """Same seed, same traffic (a pool tight enough to preempt):
+        keeping the logits on the device changes no token, greedy or
+        sampled."""
+        kw = dict(temperature=temperature, seed=11, num_kv_blocks=7)
+        oracle = self.engine(step_fault=_fetch_all, **kw)
+        eng = self.engine(**kw)
+        want = oracle.generate(self.PROMPTS, max_new_tokens=7)
+        got = eng.generate(self.PROMPTS, max_new_tokens=7)
+        assert got == want and all(len(t) == 7 for t in got)
+        assert eng.sched.preemptions == oracle.sched.preemptions > 0
+        assert eng.stats()["logits_fetch_steps"] == 0
 
 
 # ---------------------------------------------------------------------------

@@ -297,8 +297,8 @@ class TestConsumedPool:
         def prepare(eng):
             real = eng._build_step_fn()
 
-            def flaky(params, ids, positions, last, pages, *rest):
-                if (ids.shape[1] == 1) == (kind == "decode"):
+            def flaky(params, packed, pages, key, *, rows, chunk):
+                if (chunk == 1) == (kind == "decode"):
                     calls["n"] += 1
                     if calls["n"] == nth:
                         calls["died"] += 1
@@ -306,7 +306,8 @@ class TestConsumedPool:
                             for a in kv:
                                 a.delete()
                         raise RuntimeError("device lost mid-step")
-                return real(params, ids, positions, last, pages, *rest)
+                return real(params, packed, pages, key, rows=rows,
+                            chunk=chunk)
             eng._jit_step = flaky
 
         eng, outs = self._traffic(model, prepare=prepare)
@@ -324,7 +325,7 @@ class TestConsumedPool:
     def test_a_first_run_that_dies_with_the_pool_still_propagates(self):
         eng = make_engine(tiny_model(), max_seqs=2, kv_block_size=4)
 
-        def dies(params, ids, positions, last, pages, *rest):
+        def dies(params, packed, pages, key, **program):
             pages[0][0].delete()
             raise RuntimeError("device lost on the first step")
         eng._jit_step = dies
@@ -369,12 +370,13 @@ class TestConsumedPool:
         def prepare(eng):
             real = eng._build_step_fn()
 
-            def flaky(params, ids, positions, last, pages, *rest):
+            def flaky(params, packed, pages, key, *, rows, chunk):
                 if state["armed"] and not state["died"]:
                     state["died"] = 1
                     pages[0][0].delete()
                     raise RuntimeError("device lost in a probe")
-                return real(params, ids, positions, last, pages, *rest)
+                return real(params, packed, pages, key, rows=rows,
+                            chunk=chunk)
             eng._jit_step = flaky
 
         eng, outs = self._traffic(model, prepare=prepare, step_fault=fault)

@@ -556,12 +556,13 @@ for tag, LAYERS, HEADS, DIM, BLOCKS, BS, SEQS, LEN in (
     width = LEN // BS
     for name, rows, chunk in (("serve_decode", SEQS, 1),
                               ("serve_prefill_b512", 1, 512)):
+        # ids, positions, last index, tables, lengths, slots: one buffer
+        packed = S((2 * rows * chunk + 2 * rows + 1 + rows * width,),
+                   jnp.int32)
         c = eng._build_step_fn().lower(
-            abstract(eng._params), S((rows, chunk), jnp.int32),
-            S((rows,), jnp.int32), S((), jnp.int32), pages,
-            S((rows, width), jnp.int32), S((rows,), jnp.int32),
-            S((rows, chunk), jnp.int32),
-            abstract(jax.random.PRNGKey(0))).compile()
+            abstract(eng._params), packed, pages,
+            abstract(jax.random.PRNGKey(0)), rows=rows,
+            chunk=chunk).compile()
         text, ma = c.as_text(), c.memory_analysis()
         # an operation whose result is a whole page array, other than the
         # in-place write and what it is fused into
@@ -694,11 +695,12 @@ params = abstract(eng._params)
 n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
 for name, rows, chunk in (("serve_decode", SEQS, 1),
                           ("serve_prefill_b1024", 1, 1024)):
+    # ids, positions, last index, tables, lengths, slots: one buffer
+    packed = S((2 * rows * chunk + 2 * rows + 1 + rows * (LEN // BS),),
+               jnp.int32)
     c = eng._build_step_fn().lower(
-        params, S((rows, chunk), jnp.int32), S((rows,), jnp.int32),
-        S((), jnp.int32), pages, S((rows, LEN // BS), jnp.int32),
-        S((rows,), jnp.int32), S((rows, chunk), jnp.int32),
-        abstract(jax.random.PRNGKey(0))).compile()
+        params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=rows,
+        chunk=chunk).compile()
     text, ma = c.as_text(), c.memory_analysis()
     header = text.split("input_output_alias={", 1)[1].split(
         "entry_computation_layout", 1)[0]
