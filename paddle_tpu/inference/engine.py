@@ -236,7 +236,10 @@ class ServingEngine:
     ``serving_counts(counts, kind)``, which names the counters to add to
     and the gauges to set; ``aux["per_token"]`` (arrays of ``(rows, chunk,
     ...)``) stays on the device unless a request captures logits, and is
-    then handed out by ``collect()`` a token at a time.
+    then handed out by ``collect()`` a token at a time; ``aux["per_logit"]``
+    (arrays of ``(rows, ...)``: what belongs to the ONE position a row
+    whose logits the step returns, too large to keep for a chunk's every
+    token) likewise, one entry a captured logits row.
 
     ``temperature`` is engine-level (it is baked into the jitted step;
     per-request temperatures would multiply the compile set).
@@ -1027,6 +1030,14 @@ class ServingEngine:
                 else:
                     s.per_token.append({k: v[i, :1]
                                         for k, v in host.items()})
+        # a re-prefill after preemption emits no logits row (the token
+        # was sampled before eviction): nothing of its own to keep
+        emits = [(i, s) for i, s in captured
+                 if kind != "prefill" or s.pending is None]
+        if aux.get("per_logit") and emits:
+            host = {k: np.asarray(v) for k, v in aux["per_logit"].items()}
+            for i, s in emits:
+                s.per_logit.append({k: v[i] for k, v in host.items()})
 
     # -- poisoned-request quarantine ---------------------------------------
     def _probe(self, seqs: List[SequenceState], key) -> bool:
@@ -1276,6 +1287,11 @@ class ServingEngine:
                 out["per_token"] = {
                     k: np.concatenate([c[k] for c in seq.per_token])
                     for k in seq.per_token[0]}
+            if seq.per_logit:
+                # what the model hands out a logits row, (rows kept, ...)
+                out["per_logit"] = {
+                    k: np.stack([c[k] for c in seq.per_logit])
+                    for k in seq.per_logit[0]}
         return out
 
     def generate(self, prompts: Sequence[Sequence[int]],

@@ -26,6 +26,10 @@ MXU, near the chip's ridge (2 * heads FLOPs a byte read).
   ``jax.numpy``, float32 at ``HIGHEST``: the route off the TPU and the
   kernel's oracle.
 
+Its neighbours, for a model with an indexer, are ``dsa_index_scores`` and
+``dsa_sparse_attn`` in ``sparse_attention.py`` (scores over every live
+index key; this kernel's body over the selected rows only).
+
 ``width`` is a multiple of 128 (a 576-value row is stored in 640): the
 page's minor dimension is then whole lane tiles, which is what keeps XLA
 from giving the donated pool another layout than the kernel's (PERF.md
@@ -123,7 +127,10 @@ def _latent_kernel(lens_ref, table_ref, q_ref, kv_ref, o_ref,
 
 def latent_attention_pallas(q, pages, block_tables, seq_lens,
                             value_width: int, scale: float,
-                            interpret: Optional[bool] = None):
+                            interpret: Optional[bool] = None,
+                            name: str = "mla_latent_attn"):
+    """``name`` is the kernel's name in a trace: ``sparse_attention.py``
+    runs this body over gathered rows as ``dsa_sparse_attn``."""
     from jax.experimental.pallas import tpu as pltpu
     _check_shapes(q, pages, block_tables, seq_lens, value_width)
     b, h, w = q.shape
@@ -148,13 +155,13 @@ def latent_attention_pallas(q, pages, block_tables, seq_lens,
             pltpu.VMEM((h, value_width), jnp.float32),   # acc
         ],
     )
-    kernel = functools.partial(_latent_kernel, scale=float(scale),
-                               block_size=int(block_size),
-                               value_width=int(value_width))
+    kernel = functools.partial(_latent_kernel, scale=scale,
+                               block_size=block_size,
+                               value_width=value_width)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
-        name="mla_latent_attn",
+        name=name,
         interpret=_interpret() if interpret is None else interpret,
     )(jnp.asarray(seq_lens, jnp.int32),
       jnp.asarray(block_tables, jnp.int32), q, pages)

@@ -111,6 +111,9 @@ class SequenceState:
     # under capture_logits, what the model hands out a token beside the
     # logits (an expert layer's choices, say), a step at a time
     per_token: List = dataclasses.field(default_factory=list)
+    # and what it hands out a logits row (the positions the one query
+    # behind the row attended, say), an entry a row of `logits`
+    per_logit: List = dataclasses.field(default_factory=list)
     first_token_time: Optional[float] = None
     last_token_time: Optional[float] = None
     finish_reason: Optional[str] = None

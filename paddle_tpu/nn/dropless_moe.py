@@ -6,10 +6,14 @@ its experts spread over a mesh axis.  This one is the serving-side layer
 of a DeepSeekMoE block and lives in ``nn/`` because it holds no
 collective: it is given ``ep_degree`` and ``ep_rank``, holds experts
 ``[held * ep_rank, held * (ep_rank + 1))`` of ``num_experts``, routes over
-ALL of them (softmax scores in float32, group-limited top-k, weights not
-renormalised unless asked, times ``routed_scaling_factor``), computes its
-own experts' part of the result for the tokens routed to them and adds the
-shared experts.  What absent experts would add is left out: on a mesh the
+ALL of them, computes its own experts' part of the result for the tokens
+routed to them and adds the shared experts.  Two published routers
+(``scoring_func``), both scored in float32: ``"softmax"`` (DeepSeek-V2:
+group-limited top-k, weights renormalised if asked and otherwise times
+``routed_scaling_factor``) and ``"sigmoid"`` (``noaux_tc`` with one group,
+DeepSeek-V3 and GLM-5: the top-k of ``sigmoid + router_bias`` chooses, the
+unbiased sigmoid weighs, renormalised if asked AND times
+``routed_scaling_factor``).  What absent experts would add is left out: on a mesh the
 exchange goes around this layer, and on one chip nothing stands in for it.
 
 Dropless: every (token, held expert) pair the router chose is computed.
@@ -74,8 +78,13 @@ class DroplessMoE(Layer):
                  topk_group: int = 1, n_shared_experts: int = 0,
                  routed_scaling_factor: float = 1.0,
                  norm_topk_prob: bool = False, ep_degree: int = 1,
-                 ep_rank: int = 0, dtype="float32", std: float = 0.02):
+                 ep_rank: int = 0, dtype="float32", std: float = 0.02,
+                 scoring_func: str = "softmax"):
         super().__init__()
+        enforce(scoring_func in ("softmax", "sigmoid"),
+                f"scoring_func {scoring_func!r}")
+        enforce(scoring_func == "softmax" or n_group == 1,
+                "the sigmoid router is built for one group")
         enforce(num_experts % ep_degree == 0 and 0 <= ep_rank < ep_degree,
                 f"{num_experts} experts over ep_degree {ep_degree}, "
                 f"rank {ep_rank}")
@@ -88,9 +97,16 @@ class DroplessMoE(Layer):
         self.norm_topk_prob = bool(norm_topk_prob)
         self.ep_degree, self.ep_rank = int(ep_degree), int(ep_rank)
         self.held = self.num_experts // self.ep_degree
+        self.scoring_func = scoring_func
         init = I.NormalInDtype(std)
         self.router = self.create_parameter(
             (hidden_size, num_experts), dtype, init)
+        if scoring_func == "sigmoid":
+            # the published correction bias (float32; trained by the
+            # balancing rule, here drawn N(0, 0.02): enough to change
+            # some choices of a sigmoid in (0, 1), whatever `std` is)
+            self.router_bias = self.create_parameter(
+                (num_experts,), "float32", I.NormalInDtype(0.02))
         self.w_gate = self.create_parameter(
             (self.held, hidden_size, expert_width), dtype, init)
         self.w_up = self.create_parameter(
@@ -108,6 +124,13 @@ class DroplessMoE(Layer):
         logits = jnp.dot(h.astype(jnp.float32),
                          self.router.value.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
+        if self.scoring_func == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = lax.top_k(scores + self.router_bias.value, self.top_k)
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+            if self.norm_topk_prob:
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            return w * self.routed_scaling_factor, idx
         scores = jax.nn.softmax(logits, axis=-1)
         w, idx = group_limited_topk(scores, self.n_group, self.topk_group,
                                     self.top_k)

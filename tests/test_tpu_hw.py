@@ -756,3 +756,85 @@ def test_deepseek_v2_step_compiled_for_v5e_fits_and_stays_in_place(program):
     decode = program == "serve_decode"
     assert p["latent_calls"] == (5 if decode else 0), p
     assert p["grouped_calls"] == 8, p                 # 4 layers x (up, down)
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the decode step of glm-5-ep16-l5 (ISSUE 32), compiled for a
+# v5e ahead of time: the index and sparse-attention kernels lower through
+# Mosaic at the published widths, and both page arrays of a layer stay in place
+# ---------------------------------------------------------------------------
+_AOT_GLM5_SCRIPT = _AOT_DEEPSEEK_SCRIPT.split("from paddle_tpu.inference import ServingEngine")[0] + r"""
+import paddle_tpu.inference.sparse_attention as sa
+sa._interpret = lambda: False
+from paddle_tpu.inference import ServingEngine
+from paddle_tpu.models.glm5 import Glm5Config, Glm5ForCausalLM
+from paddle_tpu.observability.registry import MetricsRegistry
+
+# perfbench/configs/glm-5-ep16-l5.json + traffic/longctx-backlog.json
+traffic = json.load(open("perfbench/traffic/longctx-backlog.json"))["engine"]
+SEQS, LEN = traffic["max_seqs"], traffic["max_model_len"]
+BS, BLOCKS = traffic["kv_block_size"], traffic["num_kv_blocks"]
+model = Glm5ForCausalLM(Glm5Config(
+    vocab_size=19360, num_layers=5, first_k_dense_replace=1,
+    dtype="bfloat16", ep_degree=16, ep_rank=0))
+eng = ServingEngine(model, max_seqs=SEQS, max_model_len=LEN,
+                    kv_block_size=BS, num_kv_blocks=8,
+                    registry=MetricsRegistry())
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+pages = [(S((BLOCKS, BS, 640), jnp.bfloat16),
+          S((BLOCKS, BS, 128), jnp.bfloat16))] * 5
+params = abstract(eng._params)
+packed = S((2 * SEQS + 2 * SEQS + 1 + SEQS * (LEN // BS),), jnp.int32)
+c = eng._build_step_fn().lower(
+    params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=SEQS,
+    chunk=1).compile()
+text, ma = c.as_text(), c.memory_analysis()
+header = text.split("input_output_alias={", 1)[1].split(
+    "entry_computation_layout", 1)[0]
+calls = lambda k: len(re.findall(
+    r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
+    + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+print("aot-program", json.dumps({
+    "n_params": sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)),
+    "pool_copies": len(re.findall(
+        r"= bf16\[%d,%d,(640|128)\]\S* copy(-start)?\(" % (BLOCKS, BS),
+        text)),
+    "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+    "alias_bytes": ma.alias_size_in_bytes,
+    "pool_bytes": 5 * BLOCKS * BS * (640 + 128) * 2,
+    "plan_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+    "index_calls": calls("dsa_index_scores"),
+    "sparse_calls": calls("dsa_sparse_attn"),
+    "latent_calls": calls("mla_latent_attn"),
+    "grouped_calls": calls("moe_grouped")}), flush=True)
+print("aot-serve-ok")
+"""
+
+
+def test_glm5_decode_step_compiled_for_v5e_selects_and_stays_in_place():
+    """ISSUE 32: the cell's decode program (published widths, 16 held
+    experts, 16 rows, the traffic file's pool) compiles for a v5e: both
+    new kernels lower, a layer, and the dense latent kernel is not there;
+    the 10 page arrays alias their inputs with no pool-shaped copy."""
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_GLM5_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=1200)
+    if "aot-topology-ok" not in out.stdout:
+        pytest.skip("no v5e topology from libtpu here: "
+                    + (out.stdout + out.stderr)[-600:])
+    assert out.returncode == 0 and "aot-serve-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    p = json.loads(next(line for line in out.stdout.splitlines()
+                        if line.startswith("aot-program ")).split(" ", 1)[1])
+    assert p["n_params"] == 3_909_632_768, p           # 7.82 GB in bf16
+    assert p["pool_copies"] == 0, p
+    assert p["aliased"] == 10 and p["alias_bytes"] == p["pool_bytes"], p
+    assert p["plan_bytes"] < 15.75e9, p
+    assert (p["index_calls"], p["sparse_calls"], p["latent_calls"]) \
+        == (5, 5, 0), p
+    assert p["grouped_calls"] == 8, p                  # 4 layers x (up, down)
