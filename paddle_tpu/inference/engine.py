@@ -184,28 +184,29 @@ class CollectTimeout(TimeoutError):
 
 
 def pack_step_inputs(ids, positions, last_index, block_tables, seq_lens,
-                     slot_mapping) -> np.ndarray:
+                     slot_mapping, step: int = 0) -> np.ndarray:
     """A step's int32 inputs end to end in one host buffer, so that they
     cross to the device in one put: token ids ``(rows, chunk)``,
     positions ``(rows,)``, the index of the position sampled, block
     tables ``(rows, width)``, sequence lengths ``(rows,)``, write slots
-    ``(rows, chunk)``."""
+    ``(rows, chunk)``, and the step's number (what a sampling program
+    folds into the engine's key; a greedy one reads past it)."""
     return np.concatenate([
         np.asarray(a, np.int32).reshape(-1)
         for a in (ids, positions, last_index, block_tables, seq_lens,
-                  slot_mapping)])
+                  slot_mapping, step)])
 
 
 def unpack_step_inputs(packed, rows: int, chunk: int):
     """Cut :func:`pack_step_inputs`'s buffer apart again (inside a trace:
     static slices).  The table width is whatever is left of the length."""
-    width, rest = divmod(packed.shape[0] - 2 * rows * (chunk + 1) - 1, rows)
+    width, rest = divmod(packed.shape[0] - 2 * rows * (chunk + 1) - 2, rows)
     enforce(width > 0 and rest == 0,
             f"{packed.shape[0]} packed step inputs do not hold {rows} rows "
             f"of {chunk}")
     out, at = [], 0
     for shape in ((rows, chunk), (rows,), (), (rows, width), (rows,),
-                  (rows, chunk)):
+                  (rows, chunk), ()):
         n = int(np.prod(shape, dtype=np.int64))
         out.append(packed[at:at + n].reshape(shape))
         at += n
@@ -325,6 +326,7 @@ class ServingEngine:
         for name, value in self._model_gauges.items():
             self._reg().gauge(name).set(value)
         self.clock = clock
+        # the one key: a sampling step program folds its step number in
         self._key = jax.random.PRNGKey(seed)
         self._ids = itertools.count()
         self.steps = 0
@@ -402,12 +404,6 @@ class ServingEngine:
         from ..observability.registry import get_registry
         return get_registry()
 
-    def _next_key(self):
-        # a small device program of its own, so it is timed as dispatch
-        with self._phase("dispatch"):
-            self._key, sub = jax.random.split(self._key)
-        return sub
-
     # -- jitted step functions --------------------------------------------
     _STEP_ARGS = ("params", "packed", "pages", "key")
 
@@ -424,13 +420,16 @@ class ServingEngine:
 
         Returns ``(next tokens, finite, logits, pages, aux)``: the float32
         logits stay on the device unless the host asks for them, so the
-        program says itself which rows of them are finite."""
+        program says itself which rows of them are finite.  ``key`` is
+        the engine's one key, the same array every step: a sampling
+        program folds the step's number (the last packed word) into it,
+        a greedy one reads neither."""
         model, temperature = self.model, self.temperature
         block_size = self.cache.block_size
 
         def fn(params, packed, pages, key, *, rows, chunk):
             (ids, positions, last_index, block_tables, seq_lens,
-             slot_mapping) = unpack_step_inputs(packed, rows, chunk)
+             slot_mapping, step) = unpack_step_inputs(packed, rows, chunk)
             caches = [PagedLayerCache(layer, block_tables, seq_lens,
                                       slot_mapping, block_size=block_size)
                       for layer in pages]
@@ -441,8 +440,9 @@ class ServingEngine:
             if temperature <= 0.0:
                 nxt = jnp.argmax(logits, axis=-1)
             else:
-                nxt = jax.random.categorical(key, logits / temperature,
-                                             axis=-1)
+                nxt = jax.random.categorical(
+                    jax.random.fold_in(key, step), logits / temperature,
+                    axis=-1)
             return (nxt.astype(jnp.int32), jnp.isfinite(logits).all(-1),
                     logits, [c.pages for c in new_caches],
                     aux[0] if aux else {})
@@ -769,15 +769,15 @@ class ServingEngine:
         return logits_np
 
     def _device_step(self, fn, rows: int, chunk: int, inputs,
-                     key, fetch_logits: bool):
+                     fetch_logits: bool):
         """Host arrays in (``inputs`` in :func:`pack_step_inputs`'s
         order), host ``(next tokens, finite flags, logits or None)`` out,
         the pool updated in place, one span a leg: ``h2d`` (the inputs
-        packed into one buffer and put once), ``dispatch`` (the jitted
-        call until it returns, and the cache taking the new page handles
-        — the old ones are dead by then; the step's PRNG-key split is a
-        ``dispatch`` span too; the copies to the host are asked for here,
-        so that they follow the program out), ``device_wait`` (until the
+        packed into one buffer with the step's number and put once),
+        ``dispatch`` (the jitted call until it returns, and the cache
+        taking the new page handles — the old ones are dead by then; the
+        copies to the host are asked for here, so that they follow the
+        program out), ``device_wait`` (until the
         device is done — the copy below would wait for the same), and
         ``logits_copy`` (what is left of the device-to-host copy by
         then: the next tokens, a flag a row, the model's counts — and
@@ -786,13 +786,15 @@ class ServingEngine:
         outputs)."""
         reg = self._reg()
         with self._phase("h2d"):
-            packed = pack_step_inputs(*inputs)
+            # a replay or a probe of a faulted step runs inside the same
+            # step(), so it carries the same number and draws the same
+            packed = pack_step_inputs(*inputs, step=self.steps % 2 ** 31)
             packed_d = jax.device_put(packed)
             reg.counter("serve.h2d_bytes").inc(packed.nbytes)
         with self._phase("dispatch"):
             nxt, finite, logits, pages, aux = fn(
-                self._params, packed_d, self.cache.pages, key, rows=rows,
-                chunk=chunk)
+                self._params, packed_d, self.cache.pages, self._key,
+                rows=rows, chunk=chunk)
             self.cache.update_pages(pages)
             # the counts come with the outputs; the rest of aux stays.
             # What the host reads sets out as soon as the program is done
@@ -840,7 +842,7 @@ class ServingEngine:
                  victims=[s.request_id for s in victims])
         return True
 
-    def _apply_prefill(self, seq: SequenceState, bucket: int, key):
+    def _apply_prefill(self, seq: SequenceState, bucket: int):
         with self._phase("tables"):
             ctx = seq.context()
             L = len(ctx)
@@ -855,12 +857,12 @@ class ServingEngine:
         nxt_np, finite, logits_np = self._device_step(
             fn, 1, bucket,
             (ids, np.zeros((1,), np.int32), L - 1, tables, lens, slots),
-            key, self._wants_logits([seq]))
+            self._wants_logits([seq]))
         self._proven.add(("prefill", bucket))
         logits_np = self._apply_fault("prefill", [seq], finite, logits_np)
         return nxt_np, logits_np
 
-    def _apply_decode(self, seqs: List[SequenceState], key):
+    def _apply_decode(self, seqs: List[SequenceState]):
         with self._phase("tables"):
             B = self.max_seqs
             enforce(len(seqs) <= B,
@@ -886,7 +888,7 @@ class ServingEngine:
             self._note_paged_blocks(lens, tables)
             fn = self._decode_fn()
         nxt_np, finite, logits_np = self._device_step(
-            fn, B, 1, (ids, positions, 0, tables, lens, slots), key,
+            fn, B, 1, (ids, positions, 0, tables, lens, slots),
             self._wants_logits(seqs))
         self._proven.add("decode")
         logits_np = self._apply_fault("decode", seqs, finite, logits_np)
@@ -894,10 +896,9 @@ class ServingEngine:
 
     def _run_prefill(self, plan: StepPlan) -> List[Dict[str, Any]]:
         seq = plan.seqs[0]
-        key = self._next_key()
         t_prefill0 = float(self.clock())
         try:
-            nxt_np, logits_np = self._apply_prefill(seq, plan.bucket, key)
+            nxt_np, logits_np = self._apply_prefill(seq, plan.bucket)
         except StepTimeout:
             raise                      # the watchdog owns this one
         except Exception as e:
@@ -905,7 +906,7 @@ class ServingEngine:
             if ("prefill", plan.bucket) not in self._proven:
                 raise                  # never ran: not a request's fault
             if not rebuilt:            # else the engine's loss, not seq's
-                self._quarantine_step("prefill", [seq], e, key)
+                self._quarantine_step("prefill", [seq], e)
             return []
         with self._phase("accept"):
             self._note_aux("prefill", [seq])
@@ -941,10 +942,9 @@ class ServingEngine:
 
     def _run_decode(self, plan: StepPlan) -> List[Dict[str, Any]]:
         seqs = plan.seqs
-        key = self._next_key()
         t0 = float(self.clock())
         try:
-            nxt_np, logits_np = self._apply_decode(seqs, key)
+            nxt_np, logits_np = self._apply_decode(seqs)
         except StepTimeout:
             raise
         except Exception as e:
@@ -953,13 +953,14 @@ class ServingEngine:
                 raise                  # never ran: not a request's fault
             if rebuilt:
                 return []              # every row is queued for recompute
-            survivors = self._quarantine_step("decode", seqs, e, key)
+            survivors = self._quarantine_step("decode", seqs, e)
             if not survivors:
                 return []
             # replay: the culprit rows are gone, every surviving row is
             # re-run with the same pending tokens — per-row paged
-            # attention makes the survivors' logits (and, greedy,
-            # their tokens) identical to the un-faulted step
+            # attention makes the survivors' logits identical to the
+            # un-faulted step's, and their tokens where they kept their
+            # rows (sampling draws a row's noise by its place in the batch)
             return self._run_decode(StepPlan("decode", survivors))
         with self._phase("accept"):
             self._note_aux("decode", seqs)
@@ -1040,12 +1041,12 @@ class ServingEngine:
                 s.per_logit.append({k: v[i] for k, v in host.items()})
 
     # -- poisoned-request quarantine ---------------------------------------
-    def _probe(self, seqs: List[SequenceState], key) -> bool:
+    def _probe(self, seqs: List[SequenceState]) -> bool:
         """Re-run the decode step on a subset; True when it faults.  A
         probe rewrites its rows' pending slots with the values already
         there and moves no mark, so probing is free to repeat."""
         try:
-            self._apply_decode(seqs, key)
+            self._apply_decode(seqs)
         except StepTimeout:
             raise
         except Exception:
@@ -1053,8 +1054,7 @@ class ServingEngine:
             return True
         return False
 
-    def _bisect(self, seqs: List[SequenceState],
-                key) -> List[SequenceState]:
+    def _bisect(self, seqs: List[SequenceState]) -> List[SequenceState]:
         """Find the faulting sequence(s) by halving.  A passing half is
         exonerated (faults here are deterministic per-row).  When the
         whole group faults but neither half does, the fault is an
@@ -1064,14 +1064,14 @@ class ServingEngine:
         mid = len(seqs) // 2
         left, right = seqs[:mid], seqs[mid:]
         culprits: List[SequenceState] = []
-        if self._probe(left, key):
-            culprits += self._bisect(left, key)
-        if self._probe(right, key):
-            culprits += self._bisect(right, key)
+        if self._probe(left):
+            culprits += self._bisect(left)
+        if self._probe(right):
+            culprits += self._bisect(right)
         return culprits or seqs
 
     def _quarantine_step(self, kind: str, seqs: List[SequenceState],
-                         error: Exception, key) -> List[SequenceState]:
+                         error: Exception) -> List[SequenceState]:
         """Fault-boundary handler: identify the culprit rows, evict each
         with ``reason="poisoned"`` and a durable record, return the
         surviving sequences for replay."""
@@ -1084,7 +1084,7 @@ class ServingEngine:
                 culprits = list(seqs)
             else:
                 rebuilds = self.pool_rebuilds
-                culprits = self._bisect(seqs, key)
+                culprits = self._bisect(seqs)
                 if self.pool_rebuilds != rebuilds:
                     # a probe lost the pool: every row is queued for
                     # recompute, and the fault will show again there

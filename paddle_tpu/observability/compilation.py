@@ -30,6 +30,12 @@ Instruments (per wrapped function ``<name>``):
 
 Event records: ``compile`` (one per miss, with ``changed`` naming the
 diffed arguments) and ``compile.retrace_storm``.
+
+What a hit costs (ISSUE 33): nothing that grows with the arguments.  A
+jitted callable says itself whether a call traced (its cache grew), so
+the wrapper builds signatures only on the calls that did, and on a
+name's first; ``stats(name)["walks"]`` counts them beside ``"calls"``.
+A retrace is therefore diffed against the previous TRACE of that name.
 """
 from __future__ import annotations
 
@@ -38,6 +44,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from . import roofline
 
 __all__ = ["arg_signature", "diff_signatures", "CompileTracker",
            "track_jit", "get_tracker", "reset_tracker"]
@@ -100,7 +108,7 @@ def diff_signatures(prev: Sequence[Tuple[str, Tuple[str, ...]]],
 
 class _FuncState:
     __slots__ = ("names", "seen", "last_sig", "traces", "retraces",
-                 "storms", "recent", "calls")
+                 "storms", "recent", "calls", "walks")
 
     def __init__(self, names: Sequence[str]):
         self.names = list(names)
@@ -110,6 +118,7 @@ class _FuncState:
         self.retraces = 0
         self.storms = 0
         self.calls = 0
+        self.walks = 0
         # (call index, changed-arg names) of recent retraces
         self.recent: deque = deque(maxlen=64)
 
@@ -144,9 +153,11 @@ class CompileTracker:
         with self._lock:
             st = self._funcs.get(name)
             if st is None:
-                return {"calls": 0, "traces": 0, "retraces": 0, "storms": 0}
-            return {"calls": st.calls, "traces": st.traces,
-                    "retraces": st.retraces, "storms": st.storms}
+                return {"calls": 0, "walks": 0, "traces": 0, "retraces": 0,
+                        "storms": 0}
+            return {"calls": st.calls, "walks": st.walks,
+                    "traces": st.traces, "retraces": st.retraces,
+                    "storms": st.storms}
 
     def functions(self) -> List[str]:
         with self._lock:
@@ -157,12 +168,25 @@ class CompileTracker:
             self._funcs.clear()
 
     # -- the observation path ----------------------------------------------
+    def note_hit(self, name: str) -> bool:
+        """Book a call that the jitted callable says it served from its
+        cache: no signature is built.  False, and nothing booked, for a
+        name this tracker has not walked yet (its first call, or the
+        first after :meth:`reset`): the caller walks that one."""
+        with self._lock:
+            st = self._funcs.get(name)
+            if st is None or st.last_sig is None:
+                return False
+            st.calls += 1
+        self._reg().counter(f"compile.cache_hit[fn={name}]").inc()
+        return True
+
     def observe(self, name: str, args: Sequence[Any],
                 arg_names: Optional[Sequence[str]] = None,
                 wall_ms: Optional[float] = None) -> Optional[dict]:
-        """Classify one call; returns the emitted ``compile`` record on a
-        miss, None on a hit.  Called by the :func:`track_jit` wrapper —
-        or directly by code that times its own compiles."""
+        """Classify one call by its arguments' signatures; returns the
+        emitted ``compile`` record on a miss, None on a hit.  For code
+        that times its own compiles."""
         return self.observe_signatures([arg_signature(a) for a in args],
                                        name=name, arg_names=arg_names,
                                        wall_ms=wall_ms)
@@ -172,9 +196,8 @@ class CompileTracker:
                            arg_names: Optional[Sequence[str]] = None,
                            wall_ms: Optional[float] = None
                            ) -> Optional[dict]:
-        """Like :meth:`observe` but with pre-computed signatures — the
-        wrapper computes them *before* the call so donated buffers
-        (``donate_argnums``) are described while still alive."""
+        """Like :meth:`observe` but with pre-computed signatures (a
+        walk: ``stats(name)["walks"]`` counts these)."""
         key = hash(tuple(sigs))
         names = list(arg_names or [])
         while len(names) < len(sigs):
@@ -185,6 +208,7 @@ class CompileTracker:
             if st is None:
                 st = self._funcs[name] = _FuncState(names)
             st.calls += 1
+            st.walks += 1
             if key in st.seen:
                 hit = True
             else:
@@ -269,59 +293,61 @@ def track_jit(fn: Callable, name: Optional[str] = None,
               tracker: Optional[CompileTracker] = None) -> Callable:
     """Wrap a jitted callable with compile/retrace accounting.
 
-    The wrapper is transparent (same args/result) and cheap on hits —
-    one signature walk over the arguments (linear in pytree leaves, no
-    device sync).  Misses additionally time the call: on a fresh
-    signature the call wall time is trace + XLA compile + first run,
-    the honest per-backend compile-cost proxy.
+    The wrapper is transparent (same args/result).  A hit costs two
+    reads of the jitted callable's cache size and a counter, whatever
+    the arguments hold: signatures are built (one walk, linear in pytree
+    leaves, no device sync) only when the cache grew during the call, on
+    a name's first call, while a roofline capture is open, and on every
+    call of a callable that has no such cache (a plain function).
+    Misses are timed: on a fresh signature the call wall time is trace +
+    XLA compile + first run, the honest per-backend compile-cost proxy.
 
     >>> step = track_jit(jax.jit(step), name="train_step",
     ...                  arg_names=("params", "batch"))
     """
     if name is None:
         name = getattr(fn, "__name__", None) or repr(fn)
+    # how many traces jax.jit holds for this callable: the call traced
+    # (or compiled anew) exactly when this grew
+    cache_size = getattr(fn, "_cache_size", None)
 
     @functools.wraps(fn)
     def tracked(*args, **kwargs):
         tr = tracker or get_tracker()
-        sigs = names = None
         abstract = None
-        try:
-            # signatures BEFORE the call: donated buffers are gone after
-            all_args = list(args) + [kwargs[k] for k in sorted(kwargs)]
-            sigs = [arg_signature(a) for a in all_args]
+        if roofline.capture_active():
+            try:
+                # abstract shapes BEFORE the call: the roofline
+                # observatory re-lowers this signature later, after any
+                # donated buffers are dead (ISSUE 19)
+                abstract = roofline.abstractify(args, kwargs)
+            except Exception:
+                abstract = None
+        before = None if cache_size is None else cache_size()
+        t0 = time.perf_counter()
+        result = fn(*args, **kwargs)
+        try:  # tracking must never break the call
+            wall_ms = (time.perf_counter() - t0) * 1e3
+            if (abstract is None and before is not None
+                    and cache_size() == before and tr.note_hit(name)):
+                return result
+            # described AFTER the call: a donated array has lost its
+            # buffer by now, not its shape and dtype
+            sigs = [arg_signature(a) for a in args]
+            sigs += [arg_signature(kwargs[k]) for k in sorted(kwargs)]
             names = list(arg_names) if arg_names else None
             if names is not None and kwargs:
                 names = names[:len(args)] + sorted(kwargs)
-        except Exception:
-            sigs = None  # tracking must never break the call
-        if sigs is not None:
-            try:
-                # abstract shapes too, and for the same reason: the
-                # roofline observatory re-lowers this signature later,
-                # after any donated buffers are dead (ISSUE 19)
-                from . import roofline
-                if roofline.capture_active():
-                    abstract = roofline.abstractify(args, kwargs)
-            except Exception:
-                abstract = None
-        t0 = time.perf_counter()
-        result = fn(*args, **kwargs)
-        if sigs is not None:
-            try:
-                wall_ms = (time.perf_counter() - t0) * 1e3
-                rec = tr.observe_signatures(sigs, name=name,
-                                            arg_names=names,
-                                            wall_ms=wall_ms)
-                if abstract is not None:
-                    roofline.get_observatory().record(
-                        name, fn, abstract[0], abstract[1],
-                        sig_key=hash(tuple(sigs)),
-                        miss=rec is not None)
-            except Exception as e:
-                from ..framework.log import vlog
-                vlog(1, "observability: compile tracking failed for %s: "
-                     "%r", name, e)
+            rec = tr.observe_signatures(sigs, name=name, arg_names=names,
+                                        wall_ms=wall_ms)
+            if abstract is not None:
+                roofline.get_observatory().record(
+                    name, fn, abstract[0], abstract[1],
+                    sig_key=hash(tuple(sigs)), miss=rec is not None)
+        except Exception as e:
+            from ..framework.log import vlog
+            vlog(1, "observability: compile tracking failed for %s: "
+                 "%r", name, e)
         return result
 
     tracked.__tracked_name__ = name

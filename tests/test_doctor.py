@@ -57,8 +57,8 @@ class TestCompileTracking:
         f(jnp.zeros((2, 4)))            # same signature → cache hit
         f(jnp.zeros((2, 5)))            # new shape → retrace
         stats = tr.stats("f")
-        assert stats == {"calls": 3, "traces": 2, "retraces": 1,
-                         "storms": 0}
+        assert stats == {"calls": 3, "walks": 2, "traces": 2,
+                         "retraces": 1, "storms": 0}
         compiles = [r for r in sink.records if r["kind"] == "compile"]
         assert len(compiles) == 2
         assert compiles[0]["retrace"] is False

@@ -321,6 +321,162 @@ class TestTracing:
         assert len(obs.span_tree_totals()) == 9
 
 
+class TestTrackJitHits:
+    """ISSUE 33: a call that lands on a trace jax already holds builds no
+    signature; a call that traced gives the record it always gave."""
+
+    @staticmethod
+    def tracked(fn, **kw):
+        import jax
+        from paddle_tpu.observability import compilation
+        reg = MetricsRegistry()
+        sink = _ListSink()
+        reg.add_sink(sink)
+        tr = compilation.CompileTracker(registry=reg)
+        jitted = jax.jit(fn, **kw.pop("jit", {}))
+        return compilation.track_jit(jitted, tracker=tr, **kw), tr, reg, sink
+
+    def test_n_calls_on_one_signature_walk_once(self, monkeypatch):
+        import jax.numpy as jnp
+        from paddle_tpu.observability import compilation
+        f, tr, reg, sink = self.tracked(
+            lambda tree, x: x + tree["a"].sum(), name="f",
+            arg_names=("tree", "x"))
+        seen = {"arg_signature": 0, "_describe_leaf": 0}
+        for fn_name in seen:
+            real = getattr(compilation, fn_name)
+
+            def spy(*a, _real=real, _name=fn_name):
+                seen[_name] += 1
+                return _real(*a)
+            monkeypatch.setattr(compilation, fn_name, spy)
+        tree = {"a": jnp.ones((3,)), "b": [jnp.zeros((2, 2))] * 5}
+        f(tree, jnp.zeros((4,)))
+        first = dict(seen)
+        assert first == {"arg_signature": 2, "_describe_leaf": 7}
+        for _ in range(19):
+            f(tree, jnp.ones((4,)))           # fresh arrays, same signature
+        assert seen == first                   # nothing described again
+        assert tr.stats("f") == {"calls": 20, "walks": 1, "traces": 1,
+                                 "retraces": 0, "storms": 0}
+        assert reg.counter("compile.cache_hit[fn=f]").value == 19
+        assert reg.counter("compile.count[fn=f]").value == 1
+        assert [r["kind"] for r in sink.records] == ["compile"]
+
+    MISSES = {
+        # case: (jit kwargs, first call, second call, changed)
+        "leaf_shape": (
+            {}, lambda z: (z(2, 8), {"w": z(3)}),
+            lambda z: (z(2, 12), {"w": z(3)}),
+            [{"arg": "data", "detail": "float32[2,8] -> float32[2,12]"}]),
+        "leaf_of_many": (
+            {}, lambda z: (z(2), {"w": z(3), "v": z(1)}),
+            lambda z: (z(2), {"w": z(4), "v": z(1)}),
+            [{"arg": "state",
+              "detail": "leaf 1: float32[3] -> float32[4]"}]),
+        "structure": (
+            {}, lambda z: (z(2), {"w": z(3)}),
+            lambda z: (z(2), {"w": z(3), "v": z(3)}),
+            [{"arg": "state", "detail": "structure changed"}]),
+        "static": (
+            {"static_argnums": (2,)}, lambda z: (z(2), {"w": z(3)}, 4),
+            lambda z: (z(2), {"w": z(3)}, 5),
+            [{"arg": "arg2", "detail": "4 -> 5"}]),
+        "donated": (
+            {"donate_argnums": (0,)}, lambda z: (z(2, 8), {"w": z(3)}),
+            lambda z: (z(2, 12), {"w": z(3)}),
+            [{"arg": "data", "detail": "float32[2,8] -> float32[2,12]"}]),
+    }
+
+    @pytest.mark.parametrize("case", sorted(MISSES))
+    def test_a_call_that_traced_gives_todays_record(self, case):
+        import jax.numpy as jnp
+        jit_kw, first, second, changed = self.MISSES[case]
+
+        def fn(data, state, *static):
+            return data * 2.0, state["w"].sum()
+        f, tr, reg, sink = self.tracked(fn, name="step", jit=jit_kw,
+                                        arg_names=("data", "state"))
+        z = lambda *shape: jnp.zeros(shape, jnp.float32)
+        args = first(z)
+        f(*args)
+        f(*first(z))                           # a hit between the traces
+        args = second(z)
+        f(*args)
+        if case == "donated":                  # described after it went
+            assert args[0].is_deleted()
+        one, two = [r for r in sink.records if r["kind"] == "compile"]
+        for rec in (one, two):
+            assert rec.pop("wall_ms") > 0 and rec.pop("ts") > 0
+            rec.pop("kind")
+        nargs = len(args)
+        assert one == {"function": "step", "trace": True, "retrace": False,
+                       "changed": [], "nargs": nargs}
+        assert two == {"function": "step", "trace": True, "retrace": True,
+                       "changed": changed, "nargs": nargs}
+        assert tr.stats("step") == {"calls": 3, "walks": 2, "traces": 2,
+                                    "retraces": 1, "storms": 0}
+        assert reg.counter("compile.retraces[fn=step]").value == 1
+        assert reg.counter("compile.cache_hit[fn=step]").value == 1
+
+    def test_three_retraces_in_sixteen_calls_are_a_storm(self):
+        import jax.numpy as jnp
+        f, tr, reg, sink = self.tracked(
+            lambda w, seq: (w * seq).sum(), name="step",
+            arg_names=("weights", "seq"))
+        w = jnp.ones((4,))
+        for call in range(16):                 # a new length every fifth
+            f(w, jnp.zeros((8 + call // 5, 4)))
+        assert tr.stats("step") == {"calls": 16, "walks": 4, "traces": 4,
+                                    "retraces": 3, "storms": 1}
+        storm, = [r for r in sink.records
+                  if r["kind"] == "compile.retrace_storm"]
+        assert storm["culprit"] == "seq" and storm["retraces"] == 3
+        assert storm["window"] == 16
+        assert reg.counter("compile.storms[fn=step]").value == 1
+
+    def test_a_capture_window_gets_a_program_warmed_before_it(self):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.observability import roofline
+        f, tr, reg, sink = self.tracked(lambda a: jnp.tanh(a).sum(),
+                                        name="warm_step")
+        a = jnp.ones((8, 4))
+        f(a)
+        roofline.reset_observatory()
+        try:
+            with roofline.capture_window():
+                f(a)                           # a hit, and still recorded
+                entry = roofline.get_observatory().entries()["warm_step"]
+        finally:
+            roofline.reset_observatory()
+        assert entry["args"] == (jax.ShapeDtypeStruct((8, 4), jnp.float32),)
+        f(a)
+        assert tr.stats("warm_step")["calls"] == 3
+        assert tr.stats("warm_step")["walks"] == 2     # first; the window's
+        assert tr.stats("warm_step")["traces"] == 1
+
+    @pytest.mark.parametrize("how", ["plain_callable", "after_reset"])
+    def test_where_jax_cannot_say_every_call_walks(self, how):
+        import jax.numpy as jnp
+        from paddle_tpu.observability import compilation
+        if how == "plain_callable":            # no cache to ask
+            tr = compilation.CompileTracker(registry=MetricsRegistry())
+            f = compilation.track_jit(lambda x: x * 2, name="f", tracker=tr)
+            for _ in range(3):
+                f(jnp.ones((2,)))
+            assert tr.stats("f")["walks"] == 3
+        else:                                  # the tracker forgot the name
+            f, tr, reg, sink = self.tracked(lambda x: x * 2, name="f")
+            f(jnp.ones((2,)))
+            f(jnp.ones((2,)))
+            tr.reset()
+            f(jnp.ones((2,)))
+            f(jnp.ones((2,)))
+            assert tr.stats("f") == {"calls": 2, "walks": 1, "traces": 1,
+                                     "retraces": 0, "storms": 0}
+
+
 class TestMetricsWriter:
     def test_writes_jsonl(self, tmp_path):
         w = MetricsWriter(str(tmp_path), worker_id=3, flush_every=2)

@@ -598,6 +598,8 @@ class TestServingEngine:
             st = tracker.stats(fn)
             assert st["traces"] == 1, (fn, st)      # one compile per shape
             assert st["retraces"] == 0 and st["storms"] == 0, (fn, st)
+            assert st["walks"] == 1, (fn, st)       # ... and one walk
+        assert tracker.stats("serve_decode")["calls"] > 1
 
     def test_eos_stops_early_and_frees(self):
         model = tiny_model()
@@ -776,9 +778,9 @@ class TestStepSpans:
         assert all(root[1] <= k[1] and k[2] <= root[2] for k in kids)
         order = [k[0].split("/", 1)[1]
                  for k in sorted(kids, key=lambda k: k[1])]
-        assert order == ["reap", "schedule", "dispatch", "tables", "h2d",
-                         "dispatch", "device_wait", "logits_copy", "guard",
-                         "accept", "accept", "gauges"]
+        assert order == ["reap", "schedule", "tables", "h2d", "dispatch",
+                         "device_wait", "logits_copy", "guard", "accept",
+                         "accept", "gauges"]
         # the phases' self times make up the step within 2%
         total = root[2] - root[1]
         covered = sum(k[2] - k[1] for k in kids)
@@ -797,17 +799,17 @@ class TestStepSpans:
         tree = tracing.span_tree_totals()
         for name, row in phases.items():
             assert row == tree["engine.step/" + name]
-            want = {"dispatch": 6, "accept": 6}.get(name, 3)
+            want = {"accept": 6}.get(name, 3)      # one dispatch a step
             assert row["count"] == want, name
             assert 0 <= row["self_ms"] <= row["total_ms"]
         root = tree["engine.step"]
         assert root["self_ms"] <= 0.02 * root["total_ms"]
         assert phases["guard"]["total_ms"] >= 3 * 50 * 0.99
         # int32 everywhere: ids, positions, last index, tables (8 blocks a
-        # sequence), lengths, slots
+        # sequence), lengths, slots, the step's number
         i32 = 4
-        prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8)          # bucket 8, 1 row
-        decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2)       # 2 slots
+        prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8 + 1)      # bucket 8, 1 row
+        decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2 + 1)   # 2 slots
         assert h2d.value - h0 == prefill + 2 * decode
         # next tokens (int32), a finite flag a row and, since this engine's
         # fault seam is set, float32 logits over the vocabulary; with them
@@ -982,7 +984,7 @@ class TestWhatCrossesTheBoundary:
         rows, chunk, width = 3, 5, 7
         parts = [rng.integers(0, 99, shape).astype(np.int32)
                  for shape in ((rows, chunk), (rows,), (), (rows, width),
-                               (rows,), (rows, chunk))]
+                               (rows,), (rows, chunk), ())]
         packed = pack_step_inputs(*parts)
         assert packed.dtype == np.int32 and packed.ndim == 1
         assert packed.nbytes == sum(a.nbytes for a in parts)
@@ -1060,6 +1062,115 @@ class TestWhatCrossesTheBoundary:
         assert got == want and all(len(t) == 7 for t in got)
         assert eng.sched.preemptions == oracle.sched.preemptions > 0
         assert eng.stats()["logits_fetch_steps"] == 0
+
+
+# ---------------------------------------------------------------------------
+# The sampling key is data of the step program (ISSUE 33)
+# ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("family")
+class TestTheKeyIsData:
+    PROMPTS = ([1, 2, 3], [4, 5], [6, 7, 8, 9, 10, 11, 12, 13, 14], [15])
+
+    def engine(self, **kw):
+        kw.setdefault("registry", MetricsRegistry())
+        return ServingEngine(tiny_model(), max_seqs=4, kv_block_size=4,
+                             **kw)
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8],
+                             ids=["greedy", "sampled"])
+    def test_n_steps_are_n_dispatches_of_the_step_program_alone(
+            self, monkeypatch, temperature):
+        """A warm engine's step opens one ``dispatch`` span, calls one
+        tracked program, puts one buffer and asks ``jax.random`` for
+        nothing on the host: no device program beside the step's own."""
+        import jax
+        import paddle_tpu.observability.compilation as comp
+        from paddle_tpu.observability import tracing
+        eng = self.engine(temperature=temperature, seed=5)
+        eng.generate(self.PROMPTS, max_new_tokens=3)     # every program
+        tracker = CompileTracker(registry=MetricsRegistry())
+        monkeypatch.setattr(comp, "get_tracker", lambda: tracker)
+        host_calls = []
+        for name in ("split", "fold_in", "PRNGKey", "key", "categorical"):
+            monkeypatch.setattr(
+                jax.random, name,
+                lambda *a, _n=name, **kw: host_calls.append(_n))
+        puts = []
+        real_put = jax.device_put
+        monkeypatch.setattr(jax, "device_put",
+                            lambda x, *a, **kw: (puts.append(x.shape),
+                                                 real_put(x, *a, **kw))[1])
+        tracing.reset_tracing()
+        for prompt in self.PROMPTS:
+            eng.submit(prompt, max_new_tokens=4)
+        steps = eng.run()
+        assert steps == 4 + 3
+        assert eng.stats()["phases"]["dispatch"]["count"] == steps
+        calls = {f: tracker.stats(f) for f in tracker.functions()}
+        assert sum(st["calls"] for st in calls.values()) == steps
+        # the private tracker meets each program warm: its first call is
+        # walked, the cache did not grow, and that is every walk there is
+        assert calls["serve_decode"]["calls"] == 3
+        assert all(st["walks"] == 1 and st["retraces"] == 0
+                   for st in calls.values())
+        assert host_calls == [] and len(puts) == steps
+
+    def test_one_seed_one_stream_and_another_seed_another(self):
+        streams = [self.engine(temperature=0.8, seed=seed).generate(
+            self.PROMPTS, max_new_tokens=8) for seed in (3, 3, 4)]
+        assert all(len(t) == 8 for s in streams for t in s)
+        assert streams[0] == streams[1]
+        assert streams[0] != streams[2]
+        greedy = self.engine().generate(self.PROMPTS, max_new_tokens=8)
+        assert streams[0] != greedy            # it does sample
+
+    def test_a_replayed_sampled_step_draws_what_it_would_have(self):
+        """A decode step that faults is bisected and replayed on its
+        survivors under the step's own number: the rows that stay where
+        they were sample what the un-faulted run samples, then and after
+        (the culprit was the last row, so nobody moved)."""
+        kw = dict(temperature=0.8, seed=9)
+        clean = self.engine(**kw)
+        want = clean.generate(self.PROMPTS, max_new_tokens=6)
+        inj = faults.poison_request(3, mode="raise", kinds=("decode",))
+        eng = self.engine(step_fault=inj, **kw)
+        rids = [eng.submit(p, max_new_tokens=6) for p in self.PROMPTS]
+        steps = eng.run()
+        assert inj.fired > 1                   # the probes met it again
+        assert list(eng.quarantined) == [rids[3]]
+        assert steps == clean.steps            # the replay is no new step
+        for rid, tokens in zip(rids[:3], want):
+            assert eng.collect(rid)["tokens"] == tokens
+        assert want != self.engine(temperature=0.0).generate(
+            self.PROMPTS, max_new_tokens=6)
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8],
+                             ids=["greedy", "sampled"])
+    def test_the_step_number_is_the_last_packed_word(self, temperature):
+        """A sampling program draws from (the engine's key, the word); a
+        greedy program reads neither."""
+        import jax
+        eng = self.engine(temperature=temperature)
+        fn = eng._build_step_fn()
+        parts = (np.arange(4).reshape(4, 1), np.zeros((4,)), 0,
+                 np.zeros((4, 8)), np.ones((4,)), np.zeros((4, 1)))
+        assert pack_step_inputs(*parts, step=77)[-1] == 77
+        assert pack_step_inputs(*parts)[-1] == 0
+
+        def tokens(key, step):
+            nxt, _, _, pages, _ = fn(
+                eng._params, pack_step_inputs(*parts, step=step),
+                eng.cache.pages, key, rows=4, chunk=1)
+            eng.cache.update_pages(pages)
+            return np.asarray(nxt).tolist()
+        key, other = jax.random.PRNGKey(0), jax.random.PRNGKey(1)
+        draws = [tokens(key, 5), tokens(key, 5), tokens(key, 6),
+                 tokens(other, 5)]
+        assert draws[0] == draws[1]
+        if temperature > 0:
+            assert draws[0] != draws[2] and draws[0] != draws[3]
+        else:
+            assert draws[0] == draws[2] == draws[3]
 
 
 # ---------------------------------------------------------------------------

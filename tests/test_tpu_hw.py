@@ -556,8 +556,9 @@ for tag, LAYERS, HEADS, DIM, BLOCKS, BS, SEQS, LEN in (
     width = LEN // BS
     for name, rows, chunk in (("serve_decode", SEQS, 1),
                               ("serve_prefill_b512", 1, 512)):
-        # ids, positions, last index, tables, lengths, slots: one buffer
-        packed = S((2 * rows * chunk + 2 * rows + 1 + rows * width,),
+        # ids, positions, last index, tables, lengths, slots, the step's
+        # number: one buffer
+        packed = S((2 * rows * chunk + 2 * rows + 2 + rows * width,),
                    jnp.int32)
         c = eng._build_step_fn().lower(
             abstract(eng._params), packed, pages,
@@ -695,8 +696,9 @@ params = abstract(eng._params)
 n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
 for name, rows, chunk in (("serve_decode", SEQS, 1),
                           ("serve_prefill_b1024", 1, 1024)):
-    # ids, positions, last index, tables, lengths, slots: one buffer
-    packed = S((2 * rows * chunk + 2 * rows + 1 + rows * (LEN // BS),),
+    # ids, positions, last index, tables, lengths, slots, the step's
+    # number: one buffer
+    packed = S((2 * rows * chunk + 2 * rows + 2 + rows * (LEN // BS),),
                jnp.int32)
     c = eng._build_step_fn().lower(
         params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=rows,
@@ -786,7 +788,7 @@ abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
 pages = [(S((BLOCKS, BS, 640), jnp.bfloat16),
           S((BLOCKS, BS, 128), jnp.bfloat16))] * 5
 params = abstract(eng._params)
-packed = S((2 * SEQS + 2 * SEQS + 1 + SEQS * (LEN // BS),), jnp.int32)
+packed = S((2 * SEQS + 2 * SEQS + 2 + SEQS * (LEN // BS),), jnp.int32)
 c = eng._build_step_fn().lower(
     params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=SEQS,
     chunk=1).compile()
