@@ -16,6 +16,7 @@ models: :mod:`.engine` (ServingEngine: continuous batching over a paged
 KV cache), :mod:`.kv_cache` (block allocator + page arrays),
 :mod:`.paged_attention` (ragged decode kernel + lax fallback),
 :mod:`.latent_attention` (the same over latent pages, absorbed form),
+:mod:`.gqa_attention` (grouped-query heads, a window and a sink),
 :mod:`.scheduler` (admission/preemption policy).  The legacy Config
 routes onto it via ``enable_continuous_batching`` +
 ``set_decoder_model`` — see docs/ARCHITECTURE.md "Serving"."""
@@ -280,14 +281,16 @@ __all__ += ["DataType", "PlaceType", "PrecisionType", "Tensor",
 
 # -- the serving subsystem (ISSUE 6) ----------------------------------------
 from .engine import CollectTimeout, ServingEngine  # noqa: E402
-from .kv_cache import BlockAllocator, PagedKVCache  # noqa: E402
+from .gqa_attention import gqa_decode  # noqa: E402
+from .kv_cache import BlockAllocator, PagedKVCache, WindowLayer  # noqa: E402
 from .latent_attention import latent_attention  # noqa: E402
 from .paged_attention import paged_attention  # noqa: E402
 from .scheduler import ContinuousBatchingScheduler  # noqa: E402
 
 __all__ += ["ServingEngine", "CollectTimeout", "PagedKVCache",
             "BlockAllocator", "ContinuousBatchingScheduler",
-            "paged_attention", "latent_attention", "EnginePredictor"]
+            "paged_attention", "latent_attention", "EnginePredictor",
+            "WindowLayer", "gqa_decode"]
 
 # -- the serving fleet (ISSUE 16) -------------------------------------------
 from . import fleet  # noqa: E402

@@ -37,7 +37,11 @@ buffer; the next tokens, a finite flag a row and the model's counts) /
 logits crossed too: only a captured request or the fault seam asks for
 them) / ``serve.pool_rebuilds`` /
 ``serve.paged_blocks_live`` / ``serve.paged_blocks_table`` (the pages a
-decode step's rows hold, and rows launched x table width),
+decode step's rows hold, and rows launched x table width); over a cache
+with a page pool a kind of layer (``kv_cache.py``: window layers beside
+full ones) also ``serve.kv_<kind>_blocks_live`` (each pool's blocks under
+a decode step's rows) and ``serve.kv_window_blocks_freed`` (blocks let go
+behind the window),
 gauge ``serve.kv_pool_bytes`` (the live page handles: one pool).  Every
 ``step()`` is an ``engine.step`` span of :mod:`observability.tracing`
 whose children name its phases (see :meth:`ServingEngine.step`);
@@ -107,7 +111,8 @@ import re
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
+from typing import (Any, Callable, Deque, Dict, List, Mapping, Optional,
+                    Sequence, Union)
 
 import numpy as np
 
@@ -121,7 +126,8 @@ from ..observability.tracing import span, span_tree_totals
 from ..supervisor.watchdog import StepTimeout, Watchdog, guarded
 from ..utils import fsio
 from .kv_cache import (PagedKVCache, PagedLayerCache,
-                       default_kv_block_size)
+                       default_kv_block_size, layout_kinds,
+                       window_table_width)
 from .scheduler import (ContinuousBatchingScheduler, SequenceState,
                         StepPlan)
 
@@ -190,27 +196,47 @@ def pack_step_inputs(ids, positions, last_index, block_tables, seq_lens,
     positions ``(rows,)``, the index of the position sampled, block
     tables ``(rows, width)``, sequence lengths ``(rows,)``, write slots
     ``(rows, chunk)``, and the step's number (what a sampling program
-    folds into the engine's key; a greedy one reads past it)."""
-    return np.concatenate([
-        np.asarray(a, np.int32).reshape(-1)
-        for a in (ids, positions, last_index, block_tables, seq_lens,
-                  slot_mapping, step)])
+    folds into the engine's key; a greedy one reads past it).  Over a
+    cache with a pool a kind of layer ``block_tables`` and
+    ``slot_mapping`` are lists, an array a kind: each list goes in where
+    its one array would."""
+    flat = []
+    for a in (ids, positions, last_index, block_tables, seq_lens,
+              slot_mapping, step):
+        flat += a if isinstance(a, (list, tuple)) else [a]
+    return np.concatenate([np.asarray(a, np.int32).reshape(-1)
+                           for a in flat])
 
 
-def unpack_step_inputs(packed, rows: int, chunk: int):
+def unpack_step_inputs(packed, rows: int, chunk: int,
+                       widths: Optional[Sequence[int]] = None):
     """Cut :func:`pack_step_inputs`'s buffer apart again (inside a trace:
-    static slices).  The table width is whatever is left of the length."""
-    width, rest = divmod(packed.shape[0] - 2 * rows * (chunk + 1) - 2, rows)
-    enforce(width > 0 and rest == 0,
+    static slices).  The table width is whatever is left of the length;
+    ``widths`` are the kinds' table widths where the tables and the slots
+    went in as lists, and they come out as lists."""
+    kinds = 1 if widths is None else len(widths)
+    width, rest = divmod(
+        packed.shape[0] - rows * ((kinds + 1) * chunk + 2) - 2, rows)
+    enforce(width > 0 and rest == 0
+            and (widths is None or width == sum(widths)),
             f"{packed.shape[0]} packed step inputs do not hold {rows} rows "
-            f"of {chunk}")
-    out, at = [], 0
-    for shape in ((rows, chunk), (rows,), (), (rows, width), (rows,),
-                  (rows, chunk), ()):
+            f"of {chunk}" + ("" if widths is None
+                             else f" with tables of {tuple(widths)}"))
+    at = 0
+
+    def cut(shape):
+        nonlocal at
         n = int(np.prod(shape, dtype=np.int64))
-        out.append(packed[at:at + n].reshape(shape))
         at += n
-    return out
+        return packed[at - n:at].reshape(shape)
+
+    ids, positions, last_index = cut((rows, chunk)), cut((rows,)), cut(())
+    tables = [cut((rows, w)) for w in (widths or (width,))]
+    lens = cut((rows,))
+    slots = [cut((rows, chunk)) for _ in range(kinds)]
+    if widths is None:
+        tables, slots = tables[0], slots[0]
+    return [ids, positions, last_index, tables, lens, slots, cut(())]
 
 
 class _NonfiniteLogits(RuntimeError):
@@ -270,7 +296,7 @@ class ServingEngine:
 
     def __init__(self, model, *, max_seqs: Optional[int] = None,
                  kv_block_size: Optional[int] = None,
-                 num_kv_blocks: Optional[int] = None,
+                 num_kv_blocks: Union[None, int, Mapping[str, int]] = None,
                  max_model_len: Optional[int] = None,
                  temperature: float = 0.0,
                  capture_logits: bool = False,
@@ -303,13 +329,23 @@ class ServingEngine:
         block_size = (default_kv_block_size() if kv_block_size is None
                       else int(kv_block_size))
         blocks_per_seq = -(-self.max_model_len // block_size)
+        layout = model.kv_cache_layout()
+        kinds = layout_kinds(layout)
+        # roomy default: every batch slot can hold a full-length
+        # sequence (tests pass tight pools to exercise preemption)
+        roomy = {kind: self.max_seqs * (
+            blocks_per_seq if window is None
+            else min(blocks_per_seq, window_table_width(window, block_size)))
+            for kind, window in kinds.items()}
         if num_kv_blocks is None:
-            # roomy default: every batch slot can hold a full-length
-            # sequence (tests pass tight pools to exercise preemption)
-            num_kv_blocks = self.max_seqs * blocks_per_seq
+            num_kv_blocks = roomy
+        elif isinstance(num_kv_blocks, Mapping):
+            num_kv_blocks = {**roomy, **num_kv_blocks}
+        if len(kinds) == 1 and isinstance(num_kv_blocks, Mapping):
+            num_kv_blocks = num_kv_blocks[next(iter(kinds))]
         dtype = (jnp.dtype(cfg.dtype) if cfg.dtype != "float32"
                  else jnp.float32)
-        self.cache = PagedKVCache(model.kv_cache_layout(), num_kv_blocks,
+        self.cache = PagedKVCache(layout, num_kv_blocks,
                                   block_size=block_size, dtype=dtype)
         self.sched = ContinuousBatchingScheduler(
             self.cache, self.max_seqs, self.max_model_len, clock=clock)
@@ -385,6 +421,8 @@ class ServingEngine:
         self._model_counts: Dict[str, Dict[str, Any]] = {
             "counters": {}, "gauges": {}}
         self._paged_blocks = {"live": 0, "table": 0}
+        # a kind's blocks under the rows of the decode steps so far
+        self._kv_live: Dict[str, int] = {}
         self._logits_fetch_steps = 0
 
     # -- plumbing ----------------------------------------------------------
@@ -426,13 +464,22 @@ class ServingEngine:
         a greedy one reads neither."""
         model, temperature = self.model, self.temperature
         block_size = self.cache.block_size
+        # a cache of one kind of layer: one table, one slot matrix, every
+        # layer's view over them.  Of several: one of each a kind
+        kind_of = [list(self.cache.pools).index(k)
+                   for k in self.cache.layer_kinds]
+        widths = (None if len(self.cache.pools) == 1 else
+                  self.cache.table_widths(self.sched.max_blocks_per_seq))
 
         def fn(params, packed, pages, key, *, rows, chunk):
             (ids, positions, last_index, block_tables, seq_lens,
-             slot_mapping, step) = unpack_step_inputs(packed, rows, chunk)
-            caches = [PagedLayerCache(layer, block_tables, seq_lens,
-                                      slot_mapping, block_size=block_size)
-                      for layer in pages]
+             slot_mapping, step) = unpack_step_inputs(packed, rows, chunk,
+                                                      widths)
+            if widths is None:
+                block_tables, slot_mapping = [block_tables], [slot_mapping]
+            caches = [PagedLayerCache(layer, block_tables[k], seq_lens,
+                                      slot_mapping[k], block_size=block_size)
+                      for layer, k in zip(pages, kind_of)]
             logits, new_caches, *aux = model.apply(
                 params, ids, caches, positions, last_index,
                 method="serving_step")
@@ -842,6 +889,12 @@ class ServingEngine:
                  victims=[s.request_id for s in victims])
         return True
 
+    def _tables_and_slots(self, sids, starts, chunk: int):
+        """A step's block tables and write slots, an array of each a kind
+        of layer (one, for every model whose layers are of one kind)."""
+        return (self.cache.step_tables(sids, self.sched.max_blocks_per_seq),
+                self.cache.step_slots(sids, starts, chunk))
+
     def _apply_prefill(self, seq: SequenceState, bucket: int):
         with self._phase("tables"):
             ctx = seq.context()
@@ -849,10 +902,9 @@ class ServingEngine:
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :L] = ctx
             self._note_padding(L, bucket)
-            tables = self.cache.table_array([seq.request_id],
-                                            self.sched.max_blocks_per_seq)
+            tables, slots = self._tables_and_slots([seq.request_id], [0],
+                                                   bucket)
             lens = np.asarray([L], np.int32)
-            slots = self.cache.slot_array([seq.request_id], [0], bucket)
             fn = self._prefill_fn(bucket)
         nxt_np, finite, logits_np = self._device_step(
             fn, 1, bucket,
@@ -882,10 +934,8 @@ class ServingEngine:
                 positions[i] = s.computed_len
                 lens[i] = s.computed_len + 1  # includes the written token
                 starts[i] = s.computed_len
-            tables = self.cache.table_array(sids,
-                                            self.sched.max_blocks_per_seq)
-            slots = self.cache.slot_array(sids, starts, 1)
-            self._note_paged_blocks(lens, tables)
+            tables, slots = self._tables_and_slots(sids, starts, 1)
+            self._note_paged_blocks(lens, tables, seqs)
             fn = self._decode_fn()
         nxt_np, finite, logits_np = self._device_step(
             fn, B, 1, (ids, positions, 0, tables, lens, slots),
@@ -981,12 +1031,25 @@ class ServingEngine:
                 t0, float(self.clock()), self._proc)
         return events
 
-    def _note_paged_blocks(self, lens: np.ndarray, tables: np.ndarray):
+    def _note_paged_blocks(self, lens: np.ndarray, tables: List[np.ndarray],
+                           seqs: List[SequenceState]):
         """How much of a decode step's block tables is live: the pages
         its rows hold against rows launched x table width, which is what
-        a kernel that walked the whole table would visit."""
+        a kernel that walked the whole table would visit.  Over a cache
+        with a pool a kind of layer that is the first kind's, and each
+        kind's blocks under the step's rows are counted beside it
+        (``serve.kv_<kind>_blocks_live``)."""
+        tables, reg = tables[0], self._reg()
+        if len(self.cache.pools) > 1:
+            for kind, pool in self.cache.pools.items():
+                held = sum(len(pool.tables.get(s.request_id, ()))
+                           for s in seqs)
+                reg.counter(f"serve.kv_{kind}_blocks_live").inc(held)
+                self._kv_live[kind] = self._kv_live.get(kind, 0) + held
+                if pool.window is not None:
+                    freed = reg.counter(f"serve.kv_{kind}_blocks_freed")
+                    freed.inc(pool.freed_behind - freed.value)
         live = int(np.sum(-(-lens // self.cache.block_size)))
-        reg = self._reg()
         reg.counter("serve.paged_blocks_live").inc(live)
         reg.counter("serve.paged_blocks_table").inc(tables.size)
         self._paged_blocks["live"] += live
@@ -1508,7 +1571,7 @@ class ServingEngine:
         reg.gauge("serve.running").set(float(c["running"]))
         reg.gauge("serve.kv_occupancy").set(self.cache.occupancy())
         reg.gauge("serve.kv_blocks_used").set(
-            float(self.cache.allocator.num_used))
+            float(self.cache.blocks_used()))
         reg.gauge("serve.kv_pool_bytes").set(float(self.cache.pool_bytes()))
         reg.gauge("serve.shed").set(1.0 if self.should_shed() else 0.0)
 
@@ -1544,12 +1607,20 @@ class ServingEngine:
                 "gauges": {k: dict(v) for k, v
                            in self._model_counts["gauges"].items()}},
             "paged_blocks": dict(self._paged_blocks),
-            "kv_blocks": {"total": self.cache.num_blocks,
-                          "used": self.cache.allocator.num_used,
+            "kv_blocks": {"total": leak["num_blocks"],
+                          "used": leak["num_used"],
                           "occupancy": self.cache.occupancy(),
                           "high_water": leak["high_water"],
                           "leaked": leak["leaked_blocks"],
                           "balanced": leak["balanced"]},
+            "kv_pools": {
+                kind: {"total": pool.num_blocks,
+                       "used": pool.allocator.num_used,
+                       "high_water": pool.allocator.high_water,
+                       "block_bytes": self.cache.block_bytes(kind),
+                       "freed_behind": pool.freed_behind,
+                       "blocks_live": self._kv_live.get(kind, 0)}
+                for kind, pool in self.cache.pools.items()},
             "load_shed": {"active": self.should_shed(),
                           "queue_threshold": self.shed_queue_depth},
             "padding": {"real_tokens": self._pad_real_tokens,

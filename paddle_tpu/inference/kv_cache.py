@@ -35,6 +35,18 @@ writes fill the rest with zeros); a latent-attention model one row
 shared by all heads (ISSUE 28).  Allocator, tables and slot arithmetic
 are the same for every layout.
 
+A layer is of a **kind** (ISSUE 35): ``full`` (a query may attend every
+cached token: the layer's entry in the layout is the tuple of its page
+arrays' shapes) or ``window`` (:class:`WindowLayer`: a query attends the
+last ``window`` tokens only).  Each kind has a pool of its own: an
+allocator, a block table a sequence, and page arrays of its own number of
+blocks.  The window kind holds, for each sequence, only the blocks a later
+query can still attend: growing a sequence past a block's reach frees it,
+a prefill writes a prompt's last blocks only, and the table the step is
+handed is a ring of ``window_table_width`` entries (block ``b`` of the
+sequence at column ``b % width``).  A layout of one kind is one pool, one
+table, one slot matrix: what it was before kinds.
+
 Page layout: token-major, ``pages[block, offset, ...]``.  One token's
 slab is the minor tile (a ``(16, 128)`` bf16 slab is exactly one 4 KB
 TPU tile) and a block is ``block_size`` of them, contiguous.  That is the layout XLA's scatter of new tokens wants, and
@@ -63,7 +75,8 @@ from __future__ import annotations
 import functools
 import itertools
 import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
+                    Tuple, Union)
 
 import numpy as np
 
@@ -74,13 +87,45 @@ import jax.tree_util as _tree_util
 from ..framework.errors import enforce
 
 __all__ = ["KV_BLOCK_SIZE_ENV", "default_kv_block_size", "BlockAllocator",
-           "PagedLayerCache", "PagedKVCache"]
+           "PagedLayerCache", "PagedKVCache", "WindowLayer", "layout_kinds",
+           "window_table_width", "FULL", "WINDOW"]
+
+FULL = "full"
+WINDOW = "window"
 
 KV_BLOCK_SIZE_ENV = "PTPU_KV_BLOCK_SIZE"
 
 
 def default_kv_block_size() -> int:
     return int(os.environ.get(KV_BLOCK_SIZE_ENV, "16"))
+
+
+class WindowLayer(NamedTuple):
+    """A layer's entry in ``kv_cache_layout()`` whose queries attend the
+    last ``window`` cached tokens only (the query's own among them):
+    ``shapes`` as a full layer's entry gives them."""
+    shapes: Tuple[Tuple[int, ...], ...]
+    window: int
+
+
+def layout_kinds(layout) -> Dict[str, Optional[int]]:
+    """The kinds of layer a layout holds, in the order they first appear,
+    each with its reach (None: everything)."""
+    kinds: Dict[str, Optional[int]] = {}
+    for layer in layout:
+        if isinstance(layer, WindowLayer):
+            enforce(layer.window >= 1 and kinds.get(WINDOW, layer.window)
+                    == layer.window, "window layers of one reach, >= 1")
+            kinds[WINDOW] = int(layer.window)
+        else:
+            kinds[FULL] = None
+    return kinds
+
+
+def window_table_width(window: int, block_size: int) -> int:
+    """Blocks that ``window`` consecutive tokens can lie in: the width of
+    a window layer's ring of a table."""
+    return (window + block_size - 2) // block_size + 1
 
 
 class BlockAllocator:
@@ -278,9 +323,119 @@ def _zero_blocks(pages, block_ids):
             for layer in pages]
 
 
+class _Pool:
+    """One kind's share of the cache: its allocator, a table a sequence
+    and, for the window kind, where each table starts.  ``tables[seq]``
+    are the blocks the sequence holds, in the order of its tokens;
+    ``first[seq]`` is the block of the sequence (``position //
+    block_size``) that the table's first entry holds: always 0 for the
+    full kind."""
+
+    def __init__(self, window: Optional[int], num_blocks: int,
+                 block_size: int, layers: List[int]):
+        self.window, self.layers = window, layers
+        self.block_size = block_size
+        self.num_blocks = int(num_blocks)
+        self.num_slots = self.slot_pad = self.num_blocks * block_size
+        self.allocator = BlockAllocator(self.num_blocks, block_size)
+        self.tables: Dict[object, List[int]] = {}
+        self.first: Dict[object, int] = {}
+        self.freed_behind = 0
+        self.table_width = (None if window is None
+                            else window_table_width(window, block_size))
+
+    def span(self, num_tokens: int) -> Tuple[int, int]:
+        """``[lo, hi)``: the blocks of a sequence that hold what a query
+        at ``num_tokens - 1`` (and any later one) can attend."""
+        n = max(0, int(num_tokens))
+        hi = -(-n // self.block_size)
+        if self.window is None:
+            return 0, hi
+        return max(0, n - self.window) // self.block_size, hi
+
+    def settle(self, seq_id, num_tokens: int) -> int:
+        """Free the blocks no query at or after ``num_tokens - 1`` can
+        attend; returns how many more the sequence needs to cover
+        ``num_tokens``."""
+        lo, hi = self.span(num_tokens)
+        table = self.tables.get(seq_id)
+        if not table:
+            return hi - lo
+        first = self.first[seq_id]
+        if lo > first:
+            drop = min(len(table), lo - first)
+            self.allocator.free(table[:drop])
+            del table[:drop]
+            first = self.first[seq_id] = first + drop
+            self.freed_behind += drop
+            if not table:
+                return hi - lo
+        return hi - first - len(table)
+
+    def grow(self, seq_id, num_tokens: int, need: int) -> None:
+        table = self.tables.setdefault(seq_id, [])
+        if not table:
+            self.first[seq_id] = self.span(num_tokens)[0]
+        if need > 0:
+            table.extend(self.allocator.alloc(need))
+
+    def free_seq(self, seq_id) -> None:
+        table = self.tables.pop(seq_id, None)
+        self.first.pop(seq_id, None)
+        if table:
+            self.allocator.free(table)
+
+    def report(self) -> Dict[str, object]:
+        report = self.allocator.stats()
+        tabled = sum(len(t) for t in self.tables.values())
+        report["live_seqs"] = len(self.tables)
+        report["tabled_blocks"] = tabled
+        report["leaked_blocks"] = int(report["num_used"]) - tabled
+        return report
+
+    # -- a step's arrays -----------------------------------------------------
+    def table_array(self, seq_ids, max_blocks: int) -> np.ndarray:
+        width = max_blocks if self.window is None else self.table_width
+        out = np.zeros((len(seq_ids), width), np.int32)
+        for i, sid in enumerate(seq_ids):
+            t = self.tables.get(sid)
+            if not t:
+                continue
+            enforce(len(t) <= width,
+                    f"{sid}: {len(t)} blocks > table width {width}")
+            if self.window is None:
+                out[i, :len(t)] = t
+            else:                  # a ring: block b at column b % width
+                at = self.first[sid]
+                for b in t:
+                    out[i, at % width] = b
+                    at += 1
+        return out
+
+    def slot_array(self, seq_ids, starts, chunk: int) -> np.ndarray:
+        bs = self.block_size
+        out = np.full((len(seq_ids), chunk), self.slot_pad, np.int32)
+        for i, (sid, start) in enumerate(zip(seq_ids, starts)):
+            table = self.tables.get(sid)
+            if start < 0 or not table:        # padding row
+                continue
+            first = self.first[sid]
+            if chunk == 1:                    # a decode row: one position
+                block = start // bs - first
+                if 0 <= block < len(table):
+                    out[i, 0] = table[block] * bs + start % bs
+                continue
+            pos = start + np.arange(chunk)
+            held = (pos >= first * bs) & (pos < (first + len(table)) * bs)
+            pos = pos[held]
+            out[i, held] = (np.asarray(table, np.int64)[pos // bs - first]
+                            * bs + pos % bs)
+        return out
+
+
 class PagedKVCache:
-    """Whole-model paged KV store: per-layer page arrays + the allocator
-    + per-sequence block tables.
+    """Whole-model paged KV store: per-layer page arrays and, for each
+    kind of layer, the allocator + per-sequence block tables.
 
     The engine owns one of these; the scheduler talks to ``allocator``
     and the per-sequence helpers; the jitted step consumes :attr:`pages`
@@ -288,16 +443,23 @@ class PagedKVCache:
     updated page arrays back through :meth:`update_pages`.
     """
 
-    def __init__(self, layout: Sequence[Sequence[Sequence[int]]],
-                 num_blocks: int, block_size: Optional[int] = None,
-                 dtype=jnp.float32):
+    def __init__(self, layout: Sequence, num_blocks: Union[int, Mapping],
+                 block_size: Optional[int] = None, dtype=jnp.float32):
         """``layout``: per layer, the per-token shape of each of its page
         arrays, as the model's ``kv_cache_layout()`` declares them (keys
-        and values of 2 heads of 4: ``[((2, 4), (2, 4))]``)."""
+        and values of 2 heads of 4: ``[((2, 4), (2, 4))]``), a window
+        layer's inside a :class:`WindowLayer`.  ``num_blocks``: the
+        pool's blocks, of a layout of several kinds a mapping from each
+        kind to its pool's."""
         block_size = (default_kv_block_size() if block_size is None
                       else int(block_size))
-        self.layout = [tuple(tuple(int(d) for d in shape) for shape in layer)
-                       for layer in layout]
+        layout = list(layout)
+        self.kinds = layout_kinds(layout)
+        self.layer_kinds = [WINDOW if isinstance(layer, WindowLayer)
+                            else FULL for layer in layout]
+        self.layout = [tuple(tuple(int(d) for d in shape) for shape in (
+            layer.shapes if isinstance(layer, WindowLayer) else layer))
+            for layer in layout]
         enforce(self.layout and all(self.layout), "empty cache layout")
         self.num_layers = len(self.layout)
         self._arity = [len(layer) for layer in self.layout]
@@ -306,19 +468,50 @@ class PagedKVCache:
         self._token_sizes = [int(np.prod(shape)) for layer in self.layout
                              for shape in layer]
         self.block_size = block_size
-        self.num_blocks = int(num_blocks)
-        self.num_slots = self.num_blocks * block_size
+        if not isinstance(num_blocks, Mapping):
+            enforce(len(self.kinds) == 1,
+                    f"a layout of the kinds {list(self.kinds)} needs a "
+                    f"number of blocks for each")
+            num_blocks = {kind: num_blocks for kind in self.kinds}
+        enforce(set(num_blocks) == set(self.kinds),
+                f"blocks for {sorted(num_blocks)}, layers of "
+                f"{sorted(self.kinds)}")
+        self.pools: Dict[str, _Pool] = {
+            kind: _Pool(window, num_blocks[kind], block_size,
+                        [i for i, k in enumerate(self.layer_kinds)
+                         if k == kind])
+            for kind, window in self.kinds.items()}
+        # the first kind's pool answers for "the" pool where one is asked
+        # for: all there is of a layout of one kind
+        self._pool_list = list(self.pools.values())
+        self._main = self._pool_list[0]
+        # the slots behind each page array, in the pool's order
+        self._array_slots = [self.pools[kind].num_slots
+                             for kind, n in zip(self.layer_kinds, self._arity)
+                             for _ in range(n)]
+        self.num_blocks = self._main.num_blocks
+        self.num_slots = self._main.num_slots
         self.slot_pad = self.num_slots          # OOB sentinel, mode="drop"
         self.dtype = jnp.dtype(dtype)
-        self.allocator = BlockAllocator(self.num_blocks, block_size)
-        self._tables: Dict[object, List[int]] = {}
         self.reset_pages()
+
+    @property
+    def allocator(self) -> BlockAllocator:
+        return self._main.allocator
+
+    def _pool(self, kind: Optional[str]) -> _Pool:
+        return self._main if kind is None else self.pools[kind]
+
+    @property
+    def _tables(self) -> Dict[object, List[int]]:
+        return self._main.tables
 
     # -- the page arrays ---------------------------------------------------
     @property
     def pages(self) -> List[Tuple[jnp.ndarray, ...]]:
-        """Per layer its page arrays, each ``(num_blocks, block_size) +
-        per-token shape``: the step program's donated argument."""
+        """Per layer its page arrays, each ``(its kind's blocks,
+        block_size) + per-token shape``: the step program's donated
+        argument."""
         return self._pages
 
     def update_pages(self, pages: Sequence[Tuple]) -> None:
@@ -333,9 +526,10 @@ class PagedKVCache:
         """A zeroed pool.  The old handles, if any are left, are let go
         first, so the pool is never held twice."""
         self._pages: List[Tuple[jnp.ndarray, ...]] = []
-        lead = (self.num_blocks, self.block_size)
-        self._pages = [tuple(jnp.zeros(lead + shape, self.dtype)
-                             for shape in layer) for layer in self.layout]
+        self._pages = [
+            tuple(jnp.zeros((self.pools[kind].num_blocks, self.block_size)
+                            + shape, self.dtype) for shape in layer)
+            for layer, kind in zip(self.layout, self.layer_kinds)]
 
     def _live_handles(self) -> List[jnp.ndarray]:
         return [a for layer in self._pages for a in layer
@@ -353,12 +547,13 @@ class PagedKVCache:
             a.delete()
 
     def pool_bytes(self) -> int:
-        """Device bytes behind the live page handles: one pool.  (From
-        the shapes: ``Array.nbytes`` costs 2 us a call, and this is read
-        in every step.)"""
+        """Device bytes behind the live page handles: one pool a kind.
+        (From the shapes: ``Array.nbytes`` costs 2 us a call, and this is
+        read in every step.)"""
         arrays = itertools.chain.from_iterable(self._pages)
-        return self.num_slots * self.dtype.itemsize * sum(
-            n for a, n in zip(arrays, self._token_sizes)
+        return self.dtype.itemsize * sum(
+            n * m for a, n, m in zip(arrays, self._token_sizes,
+                                     self._array_slots)
             if not a.is_deleted())
 
     def bytes_per_token(self) -> int:
@@ -366,126 +561,168 @@ class PagedKVCache:
         declared shapes included."""
         return self.dtype.itemsize * sum(self._token_sizes)
 
+    def block_bytes(self, kind: str) -> int:
+        """What one block of ``kind``'s pool takes over its layers."""
+        return self.dtype.itemsize * self.block_size * sum(
+            int(np.prod(shape)) for i in self.pools[kind].layers
+            for shape in self.layout[i])
+
     def scrub_seq(self, seq_id) -> None:
         """Zero ``seq_id``'s blocks in every layer (one small donated
-        program): a quarantined sequence may have left non-finite K/V
-        behind, and the decode kernel's ``p * v`` turns a masked ``0 *
+        program a kind): a quarantined sequence may have left non-finite
+        K/V behind, and the decode kernel's ``p * v`` turns a masked ``0 *
         NaN`` into NaN for the block's next owner."""
-        table = self._tables.get(seq_id)
-        if not table:
-            return
-        # padded to a power of two with an out-of-bounds id, so tables of
-        # any length share a handful of programs
-        width = 1 << (len(table) - 1).bit_length()
-        ids = np.full((width,), self.num_blocks, np.int32)
-        ids[:len(table)] = table
-        self._pages = _zero_blocks(self._pages, jnp.asarray(ids))
+        for pool in self.pools.values():
+            table = pool.tables.get(seq_id)
+            if not table:
+                continue
+            # padded to a power of two with an out-of-bounds id, so tables
+            # of any length share a handful of programs
+            width = 1 << (len(table) - 1).bit_length()
+            ids = np.full((width,), pool.num_blocks, np.int32)
+            ids[:len(table)] = table
+            zeroed = _zero_blocks([self._pages[i] for i in pool.layers],
+                                  jnp.asarray(ids))
+            for i, layer in zip(pool.layers, zeroed):
+                self._pages[i] = layer
 
     # -- per-sequence table management ------------------------------------
-    def table(self, seq_id) -> List[int]:
-        return self._tables.get(seq_id, [])
+    def table(self, seq_id, kind: Optional[str] = None) -> List[int]:
+        return self._pool(kind).tables.get(seq_id, [])
 
     def live_seqs(self) -> List[object]:
-        return list(self._tables)
+        return list(self._main.tables)
 
     def ensure_capacity(self, seq_id, num_tokens: int) -> bool:
-        """Grow ``seq_id``'s table to cover ``num_tokens`` cache slots;
-        False (nothing taken) when the pool cannot supply the growth."""
-        table = self._tables.setdefault(seq_id, [])
-        need = self.allocator.blocks_for_tokens(num_tokens) - len(table)
-        if need <= 0:
-            return True
-        got = self.allocator.alloc(need)
-        if got is None:
-            if not table:
-                del self._tables[seq_id]
-            return False
-        table.extend(got)
+        """Grow ``seq_id``'s tables to cover ``num_tokens`` cache slots,
+        letting go first of the window blocks that no query from there on
+        can attend; False (nothing taken) when a pool cannot supply the
+        growth."""
+        pools = self._pool_list
+        if len(pools) == 1 and self._main.window is None:
+            # a decode step's usual case in a cache of one kind: the table
+            # covers the token already (256 rows ask every step)
+            table = self._main.tables.get(seq_id)
+            if table is not None and \
+                    num_tokens <= len(table) * self.block_size:
+                return True
+        need = [pool.settle(seq_id, num_tokens) for pool in pools]
+        for pool, n in zip(pools, need):
+            if n > 0 and not pool.allocator.can_alloc(n):
+                return False
+        for pool, n in zip(pools, need):
+            if n > 0 or seq_id not in pool.tables:
+                pool.grow(seq_id, num_tokens, n)
         return True
 
-    def free_seq(self, seq_id) -> None:
-        table = self._tables.pop(seq_id, None)
-        if table:
-            self.allocator.free(table)
+    def holds(self, num_tokens: int) -> bool:
+        """Whether the pools, empty, could hold a sequence of
+        ``num_tokens``."""
+        return all(hi - lo <= pool.num_blocks for pool in self.pools.values()
+                   for lo, hi in [pool.span(num_tokens)])
 
-    def slot(self, seq_id, pos: int) -> int:
+    def free_seq(self, seq_id) -> None:
+        for pool in self.pools.values():
+            pool.free_seq(seq_id)
+
+    def slot(self, seq_id, pos: int, kind: Optional[str] = None) -> int:
         """Flat page slot of cache position ``pos`` for ``seq_id``."""
-        table = self._tables[seq_id]
-        block = pos // self.block_size
+        pool = self._pool(kind)
+        table = pool.tables[seq_id]
+        block = pos // self.block_size - pool.first.get(seq_id, 0)
         enforce(0 <= block < len(table),
                 f"pos {pos} outside {seq_id}'s {len(table)}-block table")
         return table[block] * self.block_size + pos % self.block_size
 
     def occupancy(self) -> float:
-        return self.allocator.occupancy()
+        return max(p.allocator.occupancy() for p in self.pools.values())
+
+    def blocks_used(self) -> int:
+        return sum(p.allocator.num_used for p in self.pools.values())
 
     def leak_report(self) -> Dict[str, object]:
         """Eviction-accounting view (ISSUE 15): allocator lifetime
         counters plus the table-coverage cross-check.  A nonzero
         ``leaked_blocks`` means some blocks are marked used but no
         sequence's table covers them — exactly the state a missed
-        eviction path (cancel/deadline/quarantine) would leave."""
-        report = self.allocator.stats()
-        tabled = sum(len(t) for t in self._tables.values())
-        report["live_seqs"] = len(self._tables)
-        report["tabled_blocks"] = tabled
-        report["leaked_blocks"] = int(report["num_used"]) - tabled
-        return report
+        eviction path (cancel/deadline/quarantine) would leave.  Of
+        several kinds the sums (``high_water``: of the pools' own), and
+        each pool's own report under ``pools``."""
+        reports = {kind: pool.report() for kind, pool in self.pools.items()}
+        if len(reports) == 1:
+            return next(iter(reports.values()))
+        total = {k: sum(int(r[k]) for r in reports.values())
+                 for k in next(iter(reports.values()))
+                 if k not in ("balanced", "live_seqs")}
+        total["balanced"] = all(r["balanced"] for r in reports.values())
+        total["live_seqs"] = max(r["live_seqs"] for r in reports.values())
+        total["pools"] = reports
+        return total
 
     # -- fixed-shape step inputs ------------------------------------------
-    def table_array(self, seq_ids: Sequence[object],
-                    max_blocks: int) -> np.ndarray:
+    def table_array(self, seq_ids: Sequence[object], max_blocks: int,
+                    kind: Optional[str] = None) -> np.ndarray:
         """``(len(seq_ids), max_blocks)`` int32 block-table matrix; rows
-        of absent/short tables are 0-padded (masked by seq_lens)."""
-        out = np.zeros((len(seq_ids), max_blocks), np.int32)
-        for i, sid in enumerate(seq_ids):
-            t = self._tables.get(sid, [])
-            enforce(len(t) <= max_blocks,
-                    f"{sid}: {len(t)} blocks > table width {max_blocks}")
-            out[i, :len(t)] = t
-        return out
+        of absent/short tables are 0-padded (masked by seq_lens).  Of the
+        window kind ``(len(seq_ids), its table width)``, block ``b`` of a
+        sequence at column ``b % width``."""
+        return self._pool(kind).table_array(seq_ids, max_blocks)
 
     def slot_array(self, seq_ids: Sequence[object],
-                   starts: Sequence[int], chunk: int) -> np.ndarray:
+                   starts: Sequence[int], chunk: int,
+                   kind: Optional[str] = None) -> np.ndarray:
         """``(len(seq_ids), chunk)`` write-slot matrix for tokens at
-        positions ``starts[i] .. starts[i]+chunk-1``; positions past the
-        sequence's table get the OOB pad sentinel."""
-        out = np.full((len(seq_ids), chunk), self.slot_pad, np.int32)
-        for i, (sid, start) in enumerate(zip(seq_ids, starts)):
-            if start < 0:        # padding row
-                continue
-            table = self._tables.get(sid, [])
-            cap = len(table) * self.block_size
-            for j in range(chunk):
-                pos = start + j
-                if pos < cap:
-                    out[i, j] = (table[pos // self.block_size]
-                                 * self.block_size
-                                 + pos % self.block_size)
-        return out
+        positions ``starts[i] .. starts[i]+chunk-1``; positions outside
+        the sequence's table (past it, or of the window kind behind it)
+        get the OOB pad sentinel."""
+        return self._pool(kind).slot_array(seq_ids, starts, chunk)
 
-    def layer_caches(self, block_tables: np.ndarray, seq_lens: np.ndarray,
-                     slot_mapping: np.ndarray) -> List[PagedLayerCache]:
+    def step_tables(self, seq_ids, max_blocks: int) -> List[np.ndarray]:
+        """:meth:`table_array` of each kind, in the kinds' order."""
+        return [p.table_array(seq_ids, max_blocks)
+                for p in self.pools.values()]
+
+    def step_slots(self, seq_ids, starts, chunk: int) -> List[np.ndarray]:
+        """:meth:`slot_array` of each kind, in the kinds' order."""
+        return [p.slot_array(seq_ids, starts, chunk)
+                for p in self.pools.values()]
+
+    def table_widths(self, max_blocks: int) -> Tuple[int, ...]:
+        return tuple(max_blocks if p.window is None else p.table_width
+                     for p in self.pools.values())
+
+    def layer_caches(self, block_tables, seq_lens: np.ndarray,
+                     slot_mapping) -> List[PagedLayerCache]:
         """The per-layer views a model's ``serving_step`` takes, over the
         live pages (the engine's step program builds the same views
-        inside its trace)."""
-        bt = jnp.asarray(block_tables, jnp.int32)
+        inside its trace).  ``block_tables`` and ``slot_mapping``: one
+        array, or of several kinds one a kind in the kinds' order."""
+        if len(self.pools) == 1 and not isinstance(block_tables,
+                                                   (list, tuple)):
+            block_tables, slot_mapping = [block_tables], [slot_mapping]
         sl = jnp.asarray(seq_lens, jnp.int32)
-        sm = jnp.asarray(slot_mapping, jnp.int32)
-        return [PagedLayerCache(layer, bt, sl, sm,
-                                block_size=self.block_size)
-                for layer in self._pages]
+        by_kind = {kind: (jnp.asarray(bt, jnp.int32),
+                          jnp.asarray(sm, jnp.int32))
+                   for kind, bt, sm in zip(self.pools, block_tables,
+                                           slot_mapping)}
+        return [PagedLayerCache(layer, by_kind[kind][0], sl,
+                                by_kind[kind][1], block_size=self.block_size)
+                for layer, kind in zip(self._pages, self.layer_kinds)]
 
     # -- defrag ------------------------------------------------------------
     def defrag(self) -> bool:
-        """Compact the pool (see :meth:`BlockAllocator.defrag`) and
-        permute the device page arrays to match.  Returns True when a
+        """Compact each pool (see :meth:`BlockAllocator.defrag`) and
+        permute its layers' page arrays to match.  Returns True when a
         permutation was applied."""
-        perm = self.allocator.defrag(self._tables)
-        if perm is None:
-            return False
-        idx = jnp.asarray(perm)
-        # a layer at a time, so at most one layer's pages are held twice
-        for i, layer in enumerate(self._pages):
-            self._pages[i] = tuple(jnp.take(a, idx, axis=0) for a in layer)
-        return True
+        moved = False
+        for pool in self.pools.values():
+            perm = pool.allocator.defrag(pool.tables)
+            if perm is None:
+                continue
+            moved = True
+            idx = jnp.asarray(perm)
+            # a layer at a time, so at most one layer's pages are held twice
+            for i in pool.layers:
+                self._pages[i] = tuple(jnp.take(a, idx, axis=0)
+                                       for a in self._pages[i])
+        return moved

@@ -31,7 +31,9 @@ lifecycle single-exit.
 
 - **Admission** is by KV-block budget: a sequence is admitted only when
   the allocator can hold its whole prefill context *now* (all-or-nothing
-  — partial holds deadlock a full pool).  Preempted sequences re-admit
+  — partial holds deadlock a full pool).  A cache with a pool a kind of
+  layer (``kv_cache.py``) admits, grows and preempts on all of them: a
+  sequence takes its blocks in every pool or in none, and gives all back.  Preempted sequences re-admit
   ahead of new arrivals (front of queue) so preemption cannot starve a
   request forever.
 - **Preemption** frees the victim's entire table (recompute-style: its
@@ -193,8 +195,7 @@ class ContinuousBatchingScheduler:
                 f"{seq.request_id}: prompt {len(seq.prompt)} + "
                 f"max_new {seq.max_new_tokens} exceeds max_model_len "
                 f"{self.max_model_len}")
-        enforce(self.cache.allocator.blocks_for_tokens(worst)
-                <= self.cache.num_blocks,
+        enforce(self.cache.holds(worst),
                 f"{seq.request_id}: needs more KV blocks than the whole "
                 f"pool holds ({self.cache.num_blocks})")
         enforce(len(seq.prompt) >= 1, f"{seq.request_id}: empty prompt")

@@ -63,16 +63,12 @@ from jax import lax
 
 from ..framework.errors import enforce
 from ..nn import initializer as I
-from ..nn.dropless_moe import DroplessMoE, SwiGLU
-from ..nn.layer import Layer, LayerList
+from ..nn.layer import Layer
+from .decoder_stack import (DecoderForCausalLM, DecoderLayer,
+                            RMSNorm as _Norm, plain_rotary)
 
 __all__ = ["LatentShape", "LatentAttention", "LatentDecoderLayer",
            "LatentDecoderForCausalLM", "plain_rotary"]
-
-# an expert layer's row buffer holds every (token, choice) pair and a dense
-# layer's gate and up products are twice the stream's width, so a long chunk
-# goes through a feed-forward this many tokens at a time
-_FFN_TOKENS = 4096
 
 # heads a pass of the blocked prefill
 _PREFILL_HEADS = 16
@@ -94,19 +90,6 @@ class LatentShape:
     def latent_row(self) -> int:
         """The latent row as it is stored: whole lane tiles."""
         return -(-self.latent_width // 128) * 128
-
-
-def plain_rotary(positions, dim: int, theta: float):
-    """``cos, sin`` of ``positions x theta^(-2i/dim)``."""
-    inv = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    angle = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(angle), jnp.sin(angle)
-
-
-def _rms_norm(x, weight, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return weight.astype(x.dtype) * y.astype(x.dtype)
 
 
 def _rotary(x, cos, sin):
@@ -155,16 +138,6 @@ def _causal_attention(q, k, v, lens, scale):
 
     out = lax.map(heads, (split(q), split(k), split(v)))
     return jnp.moveaxis(out, 0, 2).reshape(b, s, h, -1)
-
-
-class _Norm(Layer):
-    def __init__(self, width: int, eps: float, dtype):
-        super().__init__()
-        self.eps = eps
-        self.weight = self.create_parameter((width,), dtype, I.Constant(1.0))
-
-    def forward(self, x):
-        return _rms_norm(x, self.weight.value, self.eps)
 
 
 class _LayerNorm(Layer):
@@ -397,88 +370,22 @@ class LatentAttention(Layer):
                        "scored": jnp.sum(cache.seq_lens)}
 
 
-class LatentDecoderLayer(Layer):
+class LatentDecoderLayer(DecoderLayer):
     def __init__(self, config, index: int):
-        super().__init__()
-        c = self.config = config
-        self.input_norm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
-        self.attn = LatentAttention(c)
-        self.post_attn_norm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
-        self.is_moe = index >= c.first_k_dense_replace
-        if self.is_moe:
-            self.mlp = DroplessMoE(
-                c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
-                c.num_experts_per_tok, c.n_group, c.topk_group,
-                c.n_shared_experts, c.routed_scaling_factor,
-                c.norm_topk_prob, c.ep_degree, c.ep_rank, c.dtype,
-                c.initializer_range, scoring_func=c.scoring_func)
-        else:
-            self.mlp = SwiGLU(c.hidden_size, c.intermediate_size, c.dtype,
-                              c.initializer_range)
-
-    def forward(self, x, positions, cache=None, valid=None, last_index=None):
-        """-> ``(x, cache, aux, dsa)``; ``aux`` is None for a dense
-        layer, ``dsa`` without an indexer."""
-        b, s, hidden = x.shape
-        a, cache, dsa = self.attn(self.input_norm(x), positions, cache,
-                                  last_index=last_index)
-        x = x + a
-        h = self.post_attn_norm(x)
-        y, aux = self._feed_forward(
-            h.reshape(b * s, hidden),
-            None if valid is None else valid.reshape(-1))
-        if aux is not None:
-            aux["topk"] = aux["topk"].reshape(b, s, -1)
-        return x + y.reshape(b, s, hidden), cache, aux, dsa
-
-    def _feed_forward(self, h, valid):
-        """The layer's feed-forward on ``h (tokens, hidden)`` -> ``(y,
-        aux or None)``.  A long chunk goes through ``_FFN_TOKENS`` at a
-        time (a 16,384-token chunk's pairs would be gigabytes of rows),
-        and only the parts that hold a valid token: a chunk is padded to
-        its bucket, and a padding part's result stays zero."""
-        one = ((lambda a, v: self.mlp(a, v)) if self.is_moe
-               else (lambda a, v: (self.mlp(a), None)))
-        t = h.shape[0]
-        if t <= _FFN_TOKENS or t % _FFN_TOKENS:
-            return one(h, valid)
-        parts = t // _FFN_TOKENS
-        if valid is None:
-            valid = jnp.ones((t,), bool)
-        hs = h.reshape(parts, _FFN_TOKENS, -1)
-        vs = valid.reshape(parts, _FFN_TOKENS)
-        zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
-                             jax.eval_shape(one, hs[0], vs[0]))
-        ys, auxes = lax.map(
-            lambda a: lax.cond(jnp.any(a[1]), one, lambda *_: zeros, *a),
-            (hs, vs))
-        if auxes is None:
-            return ys.reshape(t, -1), None
-        return ys.reshape(t, -1), {
-            "load": jnp.sum(auxes["load"], axis=0),
-            "dropped": jnp.sum(auxes["dropped"]),
-            "topk": auxes["topk"].reshape(t, -1)}
+        super().__init__(config, lambda: LatentAttention(config),
+                         index >= config.first_k_dense_replace)
 
 
-class LatentDecoderForCausalLM(Layer):
-    """Embedding, decoder stack, final RMSNorm, untied head — all of
-    ``vocab_size`` rows (the slice held here)."""
+class LatentDecoderForCausalLM(DecoderForCausalLM):
+    """The stack of :mod:`decoder_stack` over latent-attention layers."""
 
     _head_scope = "latent.head"
 
     def __init__(self, config):
-        super().__init__()
-        c = self.config = config
+        c = config
         enforce(c.num_layers > c.first_k_dense_replace >= 0,
                 "no expert layer in this depth")
-        init = I.NormalInDtype(c.initializer_range)
-        self.embed = self.create_parameter(
-            (c.vocab_size, c.hidden_size), c.dtype, init)
-        self.layers = LayerList([LatentDecoderLayer(c, i)
-                                 for i in range(c.num_layers)])
-        self.norm = _Norm(c.hidden_size, c.rms_norm_eps, c.dtype)
-        self.head = self.create_parameter(
-            (c.hidden_size, c.vocab_size), c.dtype, init)
+        super().__init__(c, lambda i: LatentDecoderLayer(c, i))
 
     # -- the serving engine's surface ----------------------------------------
     def kv_cache_layout(self):
@@ -500,23 +407,10 @@ class LatentDecoderForCausalLM(Layer):
         return out
 
     def serving_counts(self, counts, kind: str):
-        """What a step's counts (host copies of ``aux["counts"]``) add to
-        the engine's registry: the pairs computed here, the held experts
-        that saw a token (summed over the expert layers), the pairs
-        dropped (0: the layer is dropless), and after a decode step its
-        busiest held expert over the mean, averaged over the layers; with
-        an indexer, after a decode step, the cache entries attended and
-        the entries scored, summed over rows and layers."""
-        load = counts["moe_load"]
-        pairs = int(load.sum())
-        out = {"counters": {
-            "serve.moe_pairs": pairs,
-            "serve.moe_experts_touched": int((load > 0).sum()),
-            "serve.moe_pairs_dropped": int(counts["moe_dropped"])},
-            "gauges": {}}
-        if kind == "decode" and pairs:
-            out["gauges"]["serve.moe_load_max_over_mean"] = float(
-                (load.max(axis=1) / load.mean(axis=1).clip(1e-9)).mean())
+        """The expert layers' counts (:mod:`decoder_stack`) and, with an
+        indexer, after a decode step, the cache entries attended and the
+        entries scored, summed over rows and layers."""
+        out = super().serving_counts(counts, kind)
         if kind == "decode" and "dsa_kept" in counts:
             out["counters"]["serve.dsa_selected_tokens"] = int(
                 counts["dsa_kept"])
@@ -524,61 +418,16 @@ class LatentDecoderForCausalLM(Layer):
                 counts["dsa_scored"])
         return out
 
-    def _stack(self, input_ids, positions, caches, valid, last_index=None):
-        x = jnp.take(self.embed.value, input_ids, axis=0)
-        new_caches, auxes, dsas = [], [], []
-        for i, layer in enumerate(self.layers):
-            x, cache, aux, dsa = layer(
-                x, positions, None if caches is None else caches[i], valid,
-                last_index)
-            new_caches.append(cache)
-            if aux is not None:
-                auxes.append(aux)
-            if dsa is not None:
-                dsas.append(dsa)
-        return self.norm(x), new_caches, auxes, dsas
-
-    def forward(self, input_ids):
-        """Logits ``(b, s, vocab)`` of whole sequences, no cache: the
-        plain form of attention throughout."""
-        b, s = input_ids.shape
-        pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-        hidden = self._stack(input_ids, pos, None, None)[0]
-        return hidden @ self.head.value
-
-    def serving_step(self, input_ids, caches, position_offset, last_index):
-        """One engine step over latent paged caches: ``(logits (b, vocab),
-        new_caches, aux)``.  ``aux["counts"]`` are the layers' counts for
-        :meth:`serving_counts` (``moe_load (expert layers, held)``,
-        ``moe_dropped``; with an indexer ``dsa_kept`` / ``dsa_scored`` of
-        a decode step), ``aux["per_token"]`` the experts chosen
-        (``moe_topk (b, s, expert layers, top_k)``) and
-        ``aux["per_logit"]``, with an indexer, the positions selected by
-        the one query a row whose logits the step returns
+    def _aux(self, aux, dsas, caches):
+        """With an indexer: ``aux["per_logit"]``, the positions selected
+        by the one query a row whose logits the step returns
         (``dsa_selected (b, layers, index_topk)``, -1 where none): a
-        chunk's every query would be gigabytes.  Rows and positions past
-        ``seq_lens`` are padding and reach no expert."""
-        b, s = input_ids.shape
-        off = jnp.asarray(position_offset)
-        pos = jnp.broadcast_to(
-            (off[:, None] if off.ndim else off) + jnp.arange(s), (b, s))
-        lens = caches[0].seq_lens
-        valid = ((jnp.arange(s)[None, :] < lens[:, None]) if s > 1
-                 else (lens > 0)[:, None])
-        idx = jnp.broadcast_to(jnp.asarray(last_index, jnp.int32), (b,))
-        hidden, new_caches, auxes, dsas = self._stack(
-            input_ids, pos, caches, valid, idx)
-        with jax.named_scope(self._head_scope):
-            logits = hidden[jnp.arange(b), idx] @ self.head.value
-        aux = {"counts": {
-            "moe_load": jnp.stack([a["load"] for a in auxes]),
-            "moe_dropped": sum(a["dropped"] for a in auxes)},
-            "per_token": {
-                "moe_topk": jnp.stack([a["topk"] for a in auxes], axis=2)}}
+        chunk's every query would be gigabytes; and of a decode step the
+        counts ``dsa_kept`` / ``dsa_scored``."""
         if dsas:
             aux["per_logit"] = {"dsa_selected": jnp.stack(
                 [d["selected"] for d in dsas], axis=1)}
             if "kept" in dsas[0]:
                 aux["counts"]["dsa_kept"] = sum(d["kept"] for d in dsas)
                 aux["counts"]["dsa_scored"] = sum(d["scored"] for d in dsas)
-        return logits, new_caches, aux
+        return aux

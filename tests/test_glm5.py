@@ -16,7 +16,7 @@ from paddle_tpu.inference import ServingEngine
 from paddle_tpu.inference import sparse_attention as sa
 from paddle_tpu.inference.engine import pack_step_inputs
 from paddle_tpu.inference.latent_attention import latent_attention_reference
-from paddle_tpu.models import latent_decoder
+from paddle_tpu.models import decoder_stack, latent_decoder
 from paddle_tpu.models.glm5 import Glm5Config, Glm5ForCausalLM, glm5_tiny
 from paddle_tpu.nn.dropless_moe import DroplessMoE
 from paddle_tpu.observability.registry import MetricsRegistry
@@ -540,7 +540,7 @@ def test_feed_forwards_in_chunks_of_tokens_read_the_same(monkeypatch):
     params = model.state_dict()
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 96, (1, 64)))
     whole = np.asarray(model.apply(params, ids))
-    monkeypatch.setattr(latent_decoder, "_FFN_TOKENS", 16)
+    monkeypatch.setattr(decoder_stack, "_FFN_TOKENS", 16)
     parts = np.asarray(model.apply(params, ids))
     np.testing.assert_allclose(parts, whole, atol=3e-5)
 
@@ -550,7 +550,7 @@ def test_padding_blocks_and_parts_of_a_bucket_are_skipped_and_change_nothing(
     """A prompt of 19 tokens in a bucket of 64: the blocked prefill visits
     the 3 blocks of 8 queries that hold a real token and the feed-forwards
     the 2 parts of 16, and the logits are the whole-sequence ones."""
-    monkeypatch.setattr(latent_decoder, "_FFN_TOKENS", 16)
+    monkeypatch.setattr(decoder_stack, "_FFN_TOKENS", 16)
     pt.seed(6)
     cfg = glm5_tiny(initializer_range=0.2)
     model = Glm5ForCausalLM(cfg)

@@ -32,12 +32,30 @@ pytestmark = pytest.mark.serving
 
 
 def assert_no_block_aliasing(cache: PagedKVCache):
-    seen = {}
-    for sid in cache.live_seqs():
-        for b in cache.table(sid):
-            assert b not in seen, \
-                f"block {b} aliased by {sid} and {seen[b]}"
-            seen[b] = sid
+    for kind in cache.pools:
+        seen = {}
+        for sid in cache.live_seqs():
+            for b in cache.table(sid, kind):
+                assert b not in seen, \
+                    f"{kind} block {b} aliased by {sid} and {seen[b]}"
+                seen[b] = sid
+
+
+# a cache of one kind of layer (every model until ISSUE 35), and one with a
+# window layer beside the full one: a pool, an allocator and a table a kind
+KINDS = ["one_kind", "two_kinds"]
+WINDOW = 6
+
+
+def kinds_layout(kinds, shapes, layers=1):
+    from paddle_tpu.inference.kv_cache import WindowLayer
+    return [shapes] * layers + (
+        [WindowLayer(shapes, WINDOW)] if kinds == "two_kinds" else [])
+
+
+def kinds_blocks(kinds, blocks, window_blocks=8):
+    return (blocks if kinds == "one_kind"
+            else {"full": blocks, "window": window_blocks})
 
 
 # ---------------------------------------------------------------------------
@@ -92,9 +110,23 @@ class TestBlockAllocator:
 # PagedKVCache
 # ---------------------------------------------------------------------------
 class TestPagedKVCache:
-    def make(self, blocks=6, bs=4):
-        return PagedKVCache([((2, 4), (2, 4))], num_blocks=blocks,
-                            block_size=bs)
+    @pytest.fixture(autouse=True, params=KINDS)
+    def _kinds(self, request):
+        self.kinds = request.param
+
+    def make(self, blocks=6, bs=4, layers=1, window_blocks=8):
+        return PagedKVCache(
+            kinds_layout(self.kinds, ((2, 4), (2, 4)), layers),
+            num_blocks=kinds_blocks(self.kinds, blocks, window_blocks),
+            block_size=bs)
+
+    def step_arrays(self, c, sids, starts, chunk, width):
+        """A step's tables and slots as the engine hands them on: an
+        array each, or of two kinds a list of them."""
+        if self.kinds == "one_kind":
+            return (c.table_array(sids, width),
+                    c.slot_array(sids, starts, chunk))
+        return c.step_tables(sids, width), c.step_slots(sids, starts, chunk)
 
     def test_capacity_growth_and_slots(self):
         c = self.make()
@@ -106,8 +138,18 @@ class TestPagedKVCache:
         t = c.table("a")
         assert c.slot("a", 0) == t[0] * 4
         assert c.slot("a", 6) == t[1] * 4 + 2
+        if self.kinds == "two_kinds":
+            # of 9 tokens a window of 6 reaches 3..8: blocks 0, 1 and 2
+            w = list(c.table("a", "window"))
+            assert len(w) == 3
+            assert c.slot("a", 6, "window") == w[1] * 4 + 2
+            assert c.ensure_capacity("a", 10)  # reaches 4..9: block 0 goes
+            assert c.table("a", "window") == w[1:]
+            with pytest.raises(Exception, match="outside"):
+                c.slot("a", 3, "window")
+            assert len(c.table("a")) == 3      # the full kind keeps all
         c.free_seq("a")
-        assert c.allocator.num_used == 0
+        assert c.allocator.num_used == 0 and c.blocks_used() == 0
 
     def test_no_aliasing_across_live_seqs(self):
         c = self.make(blocks=8)
@@ -121,46 +163,133 @@ class TestPagedKVCache:
     def test_oom_takes_nothing(self):
         c = self.make(blocks=2)
         assert c.ensure_capacity("a", 8)       # both blocks
+        used = c.blocks_used()
         assert not c.ensure_capacity("b", 5)   # needs 2, has 0
-        assert c.table("b") == []
-        assert c.allocator.num_used == 2
+        assert c.table("b") == [] and "b" not in c.live_seqs()
+        assert c.allocator.num_used == 2 and c.blocks_used() == used
+        if self.kinds == "two_kinds":
+            assert c.table("b", "window") == []
+
+    def test_growth_past_the_window_frees_exactly_the_blocks_behind_it(
+            self):
+        """A sequence grown a token at a time (a decode step each) to 40
+        tokens: of the window kind it holds, at every length, exactly the
+        blocks that hold positions ``n - window .. n - 1``, never more
+        than the ring's width; of the full kind every block."""
+        if self.kinds == "one_kind":
+            c = self.make(blocks=10)
+            for n in range(1, 41):
+                assert c.ensure_capacity("a", n)
+                assert len(c.table("a")) == -(-n // 4)
+            assert c.leak_report()["total_frees"] == 0
+            return
+        from paddle_tpu.inference.kv_cache import window_table_width
+        c = self.make(blocks=10, window_blocks=3)
+        width = window_table_width(WINDOW, 4)
+        assert width == 3 == c.table_widths(99)[1]
+        pool, freed = c.pools["window"], []
+        for n in range(1, 41):
+            before = list(c.table("a", "window"))
+            assert c.ensure_capacity("a", n)
+            held = c.table("a", "window")
+            lo, hi = max(0, n - WINDOW) // 4, -(-n // 4)
+            assert pool.first["a"] == lo and len(held) == hi - lo <= width
+            freed += [b for b in before if b not in held]
+            assert len(c.table("a")) == hi
+            # the step's table is a ring: block b at column b % width
+            ring = c.table_array(["a"], 99, "window")
+            assert ring.shape == (1, width)
+            for j, b in enumerate(range(lo, hi)):
+                assert ring[0, b % width] == held[j]
+            # slots of the positions the window holds, pad behind them
+            slots = c.slot_array(["a"], [0], n, "window")[0]
+            assert (slots[:lo * 4] == pool.slot_pad).all()
+            assert slots[lo * 4:n].tolist() == [
+                held[p // 4 - lo] * 4 + p % 4 for p in range(lo * 4, n)]
+        assert len(freed) == pool.freed_behind == 40 // 4 - 2
+        assert pool.allocator.num_used == 2 and pool.allocator.high_water \
+            <= width
+
+    def test_a_prefill_takes_only_a_prompts_last_window_blocks(self):
+        c = self.make(blocks=10, window_blocks=3)
+        assert c.ensure_capacity("a", 27)         # a prompt of 27 tokens
+        assert len(c.table("a")) == 7
+        if self.kinds == "two_kinds":
+            # a window of 6 reaches 21..26: blocks 5 and 6
+            assert len(c.table("a", "window")) == 2
+            assert c.pools["window"].first["a"] == 5
+            slots = c.slot_array(["a"], [0], 32, "window")[0]
+            assert (slots[:20] == c.pools["window"].slot_pad).all()
+            assert (slots[20:28] < c.pools["window"].num_slots).all()
+            assert (slots[28:] == c.pools["window"].slot_pad).all()
+
+    def test_free_scrub_and_leak_report_account_for_every_pool(self):
+        c = self.make(blocks=6, window_blocks=4)
+        c.ensure_capacity("a", 11)
+        c.ensure_capacity("b", 3)
+        c.update_pages([tuple(jnp.ones_like(a) for a in layer)
+                        for layer in c.pages])
+        report = c.leak_report()
+        per_pool = report.get("pools", {"full": report})
+        assert report["leaked_blocks"] == 0 and report["balanced"]
+        assert report["num_used"] == c.blocks_used() == sum(
+            r["tabled_blocks"] for r in per_pool.values())
+        assert set(per_pool) == set(c.pools)
+        held = {kind: list(c.table("a", kind)) for kind in c.pools}
+        c.scrub_seq("a")
+        for layer, kind in zip(c.pages, c.layer_kinds):
+            for a in layer:
+                a = np.asarray(a)
+                assert not a[held[kind]].any()          # a's blocks zeroed
+                assert a[c.table("b", kind)].all()      # b's untouched
+        c.free_seq("a")
+        c.free_seq("b")
+        report = c.leak_report()
+        assert report["num_used"] == 0 and report["balanced"]
+        assert report["total_allocs"] == report["total_frees"] > 0
+        assert c.pool_bytes() == sum(
+            np.asarray(a).nbytes for layer in c.pages for a in layer)
 
     def test_defrag_preserves_page_data(self):
         c = self.make(blocks=6, bs=4)
         c.ensure_capacity("a", 8)
         c.ensure_capacity("b", 8)
-        # write a recognizable value into b's first slot, the way a model
-        # does: through the layer view's write()
-        slot_b = c.slot("b", 0)
-        (layer,) = c.layer_caches(np.zeros((1, 2), np.int32),
-                                  np.ones((1,), np.int32),
-                                  np.asarray([[slot_b]], np.int32))
+        # write a recognizable value into b's first slot of every kind,
+        # the way a model does: through the layer view's write()
+        tables, _ = self.step_arrays(c, ["b"], [0], 1, 2)
+        slots = [np.asarray([[c.slot("b", 4, kind)]], np.int32)
+                 for kind in c.pools]
+        layers = c.layer_caches(
+            tables, np.ones((1,), np.int32),
+            slots[0] if self.kinds == "one_kind" else slots)
         kv = jnp.full((1, 2, 4), 7.5)
-        new = layer.write(kv, kv)
-        c.update_pages([(new.k_pages, new.v_pages)])
+        c.update_pages([layer.write(kv, kv).pages for layer in layers])
         c.free_seq("a")
         assert c.defrag() is True
         # b's tables were renumbered to the compact prefix; its data moved
-        assert sorted(c.table("b")) == [0, 1]
-        blk, off = divmod(c.slot("b", 0), c.block_size)
-        k_pages = np.asarray(c._pages[0][0])
-        assert k_pages.shape == (6, 4, 2, 4)    # (blocks, bs, heads, dim)
-        assert (k_pages[blk, off] == 7.5).all()
-        assert (k_pages != 0).sum() == 2 * 4      # exactly that one row
+        for i, kind in enumerate(c.layer_kinds):
+            assert sorted(c.table("b", kind)) == [0, 1]
+            blk, off = divmod(c.slot("b", 4, kind), c.block_size)
+            k_pages = np.asarray(c._pages[i][0])
+            assert k_pages.shape == (c.pools[kind].num_blocks, 4, 2, 4)
+            assert (k_pages[blk, off] == 7.5).all()
+            assert (k_pages != 0).sum() == 2 * 4      # exactly that one row
         # idempotent when already compact
         assert c.defrag() is False
 
-
     def test_write_lands_token_major_and_drops_padding(self):
-        c = PagedKVCache([((2, 4), (2, 4))] * 2, num_blocks=6,
-                         block_size=4)
+        c = self.make(layers=2)
         c.ensure_capacity("a", 6)
-        slots = c.slot_array(["a"], [3], 4)          # positions 3..6
+        tables, slots = self.step_arrays(c, ["a"], [3], 4, 2)
+        every = [slots] if self.kinds == "one_kind" else slots
+        slots = every[0]                             # positions 3..6
         assert slots[0, 3] != c.slot_pad
-        slots[0, 3] = c.slot_pad                     # a padded position
-        layers = c.layer_caches(c.table_array(["a"], 2),
-                                np.asarray([6], np.int32), slots)
-        assert len(layers) == 2
+        for a, pool in zip(every, c.pools.values()):
+            a[0, 3] = pool.slot_pad                  # a padded position
+        layers = c.layer_caches(tables, np.asarray([6], np.int32),
+                                slots if self.kinds == "one_kind"
+                                else every)
+        assert len(layers) == 2 + (self.kinds == "two_kinds")
         k = jnp.arange(4 * 2 * 4, dtype=jnp.float32).reshape(4, 2, 4) + 1
         new = layers[1].write(k, -k)
         assert new.k_pages.shape == (6, 4, 2, 4)
@@ -180,23 +309,28 @@ class TestPagedKVCache:
         c.ensure_capacity("a", 12)                   # 3 blocks: pads to 4
         c.ensure_capacity("b", 4)
         ones = jnp.ones((8, 4, 2, 4))
-        c.update_pages([(ones, ones * jnp.nan)])
+        c.update_pages([(ones + 0, ones * jnp.nan)     # a buffer each
+                        for _ in range(c.num_layers)])
         c.scrub_seq("a")
         c.scrub_seq("nobody")                        # no table: no-op
-        kp, vp = (np.asarray(a) for a in c.pages[0])
-        for b in range(8):
-            if b in c.table("a"):
-                assert not kp[b].any() and not vp[b].any()
-            else:
-                assert (kp[b] == 1).all() and np.isnan(vp[b]).all()
+        for layer, kind in zip(c.pages, c.layer_kinds):
+            kp, vp = (np.asarray(a) for a in layer)
+            for b in range(8):
+                if b in c.table("a", kind):
+                    assert not kp[b].any() and not vp[b].any()
+                else:
+                    assert (kp[b] == 1).all() and np.isnan(vp[b]).all()
 
     def test_pool_handles_lost_and_reset(self):
         c = self.make()
         assert not c.pages_lost()
-        assert c.pool_bytes() == 2 * 6 * 4 * 2 * 4 * 4
+        window = (2 * 8 * 4 * 2 * 4 * 4 if self.kinds == "two_kinds"
+                  else 0)                            # its own 8 blocks
+        assert c.pool_bytes() == 2 * 6 * 4 * 2 * 4 * 4 + window
         c.pages[0][1].delete()
         assert c.pages_lost()
-        assert c.pool_bytes() == 6 * 4 * 2 * 4 * 4   # live handles only
+        # live handles only
+        assert c.pool_bytes() == 6 * 4 * 2 * 4 * 4 + window
         c.reset_pages()
         assert not c.pages_lost()
         assert not np.asarray(c.pages[0][0]).any()
@@ -448,9 +582,16 @@ class TestPagedAttention:
 # Scheduler policy (pure host logic against a real cache)
 # ---------------------------------------------------------------------------
 class TestScheduler:
-    def make(self, blocks=4, bs=4, max_seqs=3, max_len=16):
-        cache = PagedKVCache([((1, 4), (1, 4))], num_blocks=blocks,
-                             block_size=bs)
+    @pytest.fixture(autouse=True, params=KINDS)
+    def _kinds(self, request):
+        self.kinds = request.param
+
+    def make(self, blocks=4, bs=4, max_seqs=3, max_len=16,
+             window_blocks=12):
+        cache = PagedKVCache(
+            kinds_layout(self.kinds, ((1, 4), (1, 4))),
+            num_blocks=kinds_blocks(self.kinds, blocks, window_blocks),
+            block_size=bs)
         return cache, ContinuousBatchingScheduler(cache, max_seqs, max_len)
 
     @staticmethod
@@ -514,6 +655,57 @@ class TestScheduler:
         sch.complete(a, "eos")
         p = sch.schedule()
         assert p.kind == "prefill" and p.seqs[0].request_id == "b"
+
+    @pytest.mark.parametrize("short", ["full", "window"])
+    def test_admission_refuses_when_either_pool_is_short(self, short):
+        """Two prompts of 8 tokens in blocks of 4: each needs 2 blocks of
+        the full kind and (a window of 6 reaches 2..7) 2 of the window
+        kind.  Whichever pool holds 3, the second is not admitted, takes
+        nothing from the other pool, and gets in when the first is
+        done."""
+        if self.kinds == "one_kind" and short == "window":
+            pytest.skip("a cache of one kind has no window pool")
+        cache, sch = self.make(blocks=3 if short == "full" else 8, bs=4,
+                               window_blocks=3 if short == "window" else 8)
+        a, b = self.seq("a", 8, 2), self.seq("b", 8, 2)
+        sch.submit(a)
+        sch.submit(b)
+        assert sch.schedule().seqs == [a]
+        sch.mark_prefilled(a)
+        a.output.append(9)
+        a.pending = 9
+        used = cache.blocks_used()
+        plan = sch.schedule()                  # b does not fit: a decodes
+        assert plan.kind == "decode" and plan.seqs == [a]
+        assert b.state == "waiting" and "b" not in cache.live_seqs()
+        assert all(not p.tables.get("b") for p in cache.pools.values())
+        # a's next block, of each kind
+        assert cache.blocks_used() == used + len(cache.pools)
+        sch.complete(a, "eos")
+        assert cache.blocks_used() == 0
+        assert sch.schedule().seqs == [b]
+        assert all(len(p.tables["b"]) == 2 for p in cache.pools.values())
+
+    def test_preemption_returns_every_kind_of_block(self):
+        cache, sch = self.make(blocks=4, bs=4, window_blocks=8, max_len=16)
+        a, b = self.seq("a", 8, 6), self.seq("b", 7, 6)
+        for q in (a, b):
+            sch.submit(q)
+            assert sch.schedule().seqs == [q]
+            sch.mark_prefilled(q)
+            q.output.append(1)
+            q.pending = 1
+        assert cache.allocator.num_free == 0
+        plan = sch.schedule()       # a needs a 3rd full block: b gives way
+        assert plan.kind == "decode" and plan.seqs == [a]
+        assert plan.preempted == [b] and b.computed_len == 0
+        for pool in cache.pools.values():
+            assert "b" not in pool.tables and "b" not in pool.first
+            assert pool.allocator.num_used == len(pool.tables["a"])
+        report = cache.leak_report()
+        assert report["leaked_blocks"] == 0 and report["balanced"]
+        assert sch.waiting[0] is b
+        assert_no_block_aliasing(cache)
 
     def test_prefill_bucket_shapes(self):
         assert prefill_bucket(1, 64) == 8
@@ -978,6 +1170,80 @@ class TestWhatCrossesTheBoundary:
         fetched = eng.stats()["logits_fetch_steps"]
         assert fetched == (0 if route == "page" else eng.steps + 1)
         assert eng.cache.allocator.num_used == 0
+
+    def test_two_kinds_pack_a_table_and_a_slot_matrix_each(self):
+        rng = np.random.default_rng(1)
+        rows, chunk, widths = 3, 5, (7, 2)
+        draw = lambda *shape: rng.integers(0, 99, shape).astype(np.int32)
+        tables = [draw(rows, w) for w in widths]
+        slots = [draw(rows, chunk) for _ in widths]
+        parts = [draw(rows, chunk), draw(rows), draw(), tables, draw(rows),
+                 slots, draw()]
+        packed = pack_step_inputs(*parts)
+        flat = [a for x in parts for a in (x if isinstance(x, list) else [x])]
+        assert packed.nbytes == sum(a.nbytes for a in flat)
+        assert np.array_equal(packed, np.concatenate(
+            [a.reshape(-1) for a in flat]))
+        out = unpack_step_inputs(packed, rows, chunk, widths)
+        for got, want in zip(out, parts):
+            if isinstance(want, list):
+                assert len(got) == len(want)
+                for g, w in zip(got, want):
+                    assert np.array_equal(g, w)
+            else:
+                assert np.array_equal(got, want)
+        with pytest.raises(Exception, match="packed step inputs"):
+            unpack_step_inputs(packed, rows, chunk, (7, 3))
+
+    @pytest.mark.parametrize("rows,chunk", [(4, 1), (1, 16)],
+                             ids=["decode", "prefill_b16"])
+    def test_a_one_kind_layout_packs_byte_for_byte_what_the_parent_did(
+            self, rows, chunk):
+        """The tables, the slots and the packed buffer of a model whose
+        layers are all of one kind, against the loops of the cache before
+        kinds (copied here from PR 33's ``kv_cache.py``)."""
+        eng = self.engine()
+        cache, bs = eng.cache, eng.cache.block_size
+        assert list(cache.pools) == ["full"] and cache.table_widths(9) == (9,)
+        sids = [f"s{i}" for i in range(rows)]
+        lens = ([3, 9, 0, 14] if chunk == 1 else [chunk - 3])
+        for sid, n in zip(sids, lens):
+            assert cache.ensure_capacity(sid, n)
+        cache.free_seq(sids[0])             # churn: ids out of order
+        assert cache.ensure_capacity(sids[0], lens[0] + 5)
+        starts = [n - 1 for n in lens] if chunk == 1 else [0]
+        if chunk == 1:
+            starts[2] = -1                  # a padding row
+        width = eng.sched.max_blocks_per_seq
+        want_tables = np.zeros((rows, width), np.int32)
+        want_slots = np.full((rows, chunk), cache.num_blocks * bs, np.int32)
+        for i, (sid, start) in enumerate(zip(sids, starts)):
+            t = cache._tables.get(sid, [])
+            want_tables[i, :len(t)] = t
+            if start < 0:
+                continue
+            for j in range(chunk):
+                pos = start + j
+                if pos < len(t) * bs:
+                    want_slots[i, j] = t[pos // bs] * bs + pos % bs
+        (tables,), (slots,) = eng._tables_and_slots(sids, starts, chunk)
+        assert tables.dtype == slots.dtype == np.int32
+        assert tables.tobytes() == want_tables.tobytes()
+        assert slots.tobytes() == want_slots.tobytes()
+        ids = np.arange(rows * chunk, dtype=np.int32).reshape(rows, chunk)
+        positions = np.asarray(starts, np.int32).clip(0)
+        seq_lens = np.asarray(lens, np.int32)
+        packed = pack_step_inputs(ids, positions, 0, [tables], seq_lens,
+                                  [slots], step=5)
+        want = np.concatenate([
+            np.asarray(a, np.int32).reshape(-1)
+            for a in (ids, positions, 0, want_tables, seq_lens, want_slots,
+                      5)])
+        assert packed.tobytes() == want.tobytes()
+        assert cache.pool_bytes() == sum(
+            a.nbytes for layer in cache.pages for a in layer)
+        assert not [n for n in eng._reg().snapshot()
+                    if n.startswith("serve.kv_full")]    # nothing a kind
 
     def test_the_packed_inputs_unpack_to_what_went_in(self):
         rng = np.random.default_rng(0)

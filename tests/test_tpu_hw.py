@@ -840,3 +840,113 @@ def test_glm5_decode_step_compiled_for_v5e_selects_and_stays_in_place():
     assert (p["index_calls"], p["sparse_calls"], p["latent_calls"]) \
         == (5, 5, 0), p
     assert p["grouped_calls"] == 8, p                  # 4 layers x (up, down)
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the step programs of mimo-v2-flash-ep16-l7 (ISSUE 35),
+# compiled for a v5e ahead of time: both instantiations of the grouped-query
+# kernel lower through Mosaic at the published widths, and the page arrays of
+# both pools stay in place
+# ---------------------------------------------------------------------------
+_AOT_MIMO_SCRIPT = _AOT_DEEPSEEK_SCRIPT.split("from paddle_tpu.inference import ServingEngine")[0] + r"""
+import paddle_tpu.inference.gqa_attention as ga
+ga._interpret = lambda: False
+from paddle_tpu.inference import ServingEngine
+from paddle_tpu.models.mimo_v2 import MimoV2Config, MimoV2ForCausalLM
+from paddle_tpu.observability.registry import MetricsRegistry
+
+# perfbench/configs/mimo-v2-flash-ep16-l7.json + traffic/mixedctx-backlog.json
+traffic = json.load(open("perfbench/traffic/mixedctx-backlog.json"))["engine"]
+SEQS, LEN = traffic["max_seqs"], traffic["max_model_len"]
+BS, BLOCKS = traffic["kv_block_size"], traffic["num_kv_blocks"]
+cfg = MimoV2Config(vocab_size=19072, num_layers=7,
+                   hybrid_layer_pattern=(0, 1, 1, 1, 1, 1, 0),
+                   moe_layer_freq=(0, 1, 1, 1, 1, 1, 1), dtype="bfloat16",
+                   ep_degree=16, ep_rank=0)
+model = MimoV2ForCausalLM(cfg)
+eng = ServingEngine(model, max_seqs=SEQS, max_model_len=LEN,
+                    kv_block_size=BS, num_kv_blocks={"full": 8, "window": 8},
+                    registry=MetricsRegistry())
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+pages, pool_bytes = [], 0
+for i in range(7):
+    blocks = BLOCKS["window" if cfg.is_window(i) else "full"]
+    n = cfg.kv_heads(i)
+    pages.append((S((blocks, BS, n * 192), jnp.bfloat16),
+                  S((blocks, BS, n * 128), jnp.bfloat16)))
+    pool_bytes += blocks * BS * n * 320 * 2
+params = abstract(eng._params)
+widths = eng.cache.table_widths(eng.sched.max_blocks_per_seq)
+for name, rows, chunk in (("serve_decode", SEQS, 1),
+                          ("serve_prefill_b2048", 1, 2048)):
+    # ids, positions, last index, a table a kind, lengths, slots a kind,
+    # the step's number: one buffer
+    packed = S((rows * chunk + rows + 1 + rows * sum(widths) + rows
+                + 2 * rows * chunk + 1,), jnp.int32)
+    c = eng._build_step_fn().lower(
+        params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=rows,
+        chunk=chunk).compile()
+    text, ma = c.as_text(), c.memory_analysis()
+    header = text.split("input_output_alias={", 1)[1].split(
+        "entry_computation_layout", 1)[0]
+    calls = lambda k: len(re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
+        + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    print("aot-program", json.dumps({
+        "name": name, "widths": list(widths),
+        "n_params": sum(int(np.prod(a.shape))
+                        for a in jax.tree.leaves(params)),
+        "pool_copies": len(re.findall(
+            r"= bf16\[(%d|%d),%d,\d+\]\S* copy(-start)?\(" % (
+                BLOCKS["full"], BLOCKS["window"], BS), text)),
+        "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+        "alias_bytes": ma.alias_size_in_bytes, "pool_bytes": pool_bytes,
+        "plan_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+        "full_calls": calls("gqa_full_decode"),
+        "window_calls": calls("gqa_window_decode"),
+        "grouped_calls": calls("moe_grouped")}), flush=True)
+print("aot-serve-ok")
+"""
+
+
+@functools.lru_cache(maxsize=1)
+def _aot_mimo_programs():
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_MIMO_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=1200)
+    if "aot-topology-ok" not in out.stdout:
+        return None, (out.stdout + out.stderr)[-600:]
+    assert out.returncode == 0 and "aot-serve-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    rows = [json.loads(line.split(" ", 1)[1])
+            for line in out.stdout.splitlines()
+            if line.startswith("aot-program ")]
+    return {r["name"]: r for r in rows}, ""
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b2048"])
+def test_mimo_v2_step_compiled_for_v5e_keeps_both_pools_in_place(program):
+    """ISSUE 35: the cell's programs (published widths, 16 held experts,
+    128 rows, the traffic file's two pools) compile for a v5e: a decode
+    step holds one `gqa_full_decode` a full layer and one
+    `gqa_window_decode` a window layer, a prefill neither (XLA in blocks);
+    the 14 page arrays of both pools alias their inputs with no
+    pool-shaped copy; the tables are 80 and 2 wide."""
+    programs, why = _aot_mimo_programs()
+    if programs is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    p = programs[program]
+    assert p["n_params"] == 3_429_955_392, p           # 6.86 GB in bf16
+    assert p["widths"] == [80, 2], p
+    assert p["pool_copies"] == 0, p
+    assert p["aliased"] == 14 and p["alias_bytes"] == p["pool_bytes"], p
+    assert p["plan_bytes"] < 15.75e9, p
+    decode = program == "serve_decode"
+    assert (p["full_calls"], p["window_calls"]) == (
+        (2, 5) if decode else (0, 0)), p
+    assert p["grouped_calls"] == 12, p                 # 6 layers x (up, down)
