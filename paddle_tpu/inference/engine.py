@@ -7,9 +7,18 @@ done.  The engine turns that traffic into fixed-shape device work:
 
     engine = ServingEngine(model, max_seqs=8, kv_block_size=16)
     rid = engine.submit([1, 5, 9], max_new_tokens=32)
-    while engine.step():            # one prefill OR one decode batch
-        ...
+    while engine.has_work():
+        engine.step()               # one prefill OR one decode batch lands
     out = engine.collect(rid)       # {"tokens": [...], "ttft_ms": ...}
+
+A unit of work is a LAUNCH and a LANDING, and the engine launches unit n+1
+before it lands unit n (ISSUE 36): the only thing a decode row needs of
+the unit before it is the token that unit sampled, and that is on the
+device already, so the step program takes the previous program's tokens
+(``prev``) and a word a row that says which of them is the row's id
+(``src``).  The host's part of a step (schedule, tables, the put, the
+call, the copy, the accept) then runs while the chip works.  See
+:meth:`ServingEngine.step`.
 
 Pieces (all under ``paddle_tpu/inference/``):
 
@@ -30,7 +39,12 @@ SLO telemetry rides the PR 3 registry: gauges ``serve.queue_depth`` /
 ``serve.running`` / ``serve.waiting`` / ``serve.kv_occupancy``,
 histograms ``serve.ttft_ms`` / ``serve.tpot_ms``, counters
 ``serve.tokens`` / ``serve.requests`` / ``serve.finished`` /
-``serve.preemptions`` / ``serve.h2d_bytes`` / ``serve.d2h_bytes`` (what
+``serve.preemptions`` / ``serve.units_launched`` / ``serve.units_ahead``
+(launched while another was unread) / ``serve.ahead_rows_discarded`` (rows
+computed for a request that had ended a unit earlier) /
+``serve.ahead_units_dropped`` (launched after a unit whose landing raised)
+/ ``serve.ahead_breaks.<idle|preempt|fault|drain>`` (why a call launched
+nothing ahead) / ``serve.h2d_bytes`` / ``serve.d2h_bytes`` (what
 a step puts on the device and copies back: its int32 inputs in one
 buffer; the next tokens, a finite flag a row and the model's counts) /
 ``serve.logits_fetch_steps`` (the steps whose ``[rows, vocab]`` float32
@@ -70,10 +84,13 @@ same robustness treatment the training path earned:
   with ``reason="poisoned"`` plus a durable record under
   ``<run_dir>/serve_quarantine/``, and replays the step so every other
   request completes token-exact (decode rows are independent).  The
+  boundary is a unit's LANDING: the unit launched after it is dropped
+  unread and its launch-time marks are taken back, so bisect and replay
+  run with nothing in flight.  The
   step program updates the KV pool in place (the pages are donated), so
-  a faulted or probed step leaves its page writes behind — harmless,
-  they land where the replay writes the same values — and a culprit's
-  blocks are zeroed before they are freed; a call that dies after
+  a faulted, probed or dropped unit leaves its page writes behind —
+  harmless, they land where the replay writes the same values — and a
+  culprit's blocks are zeroed before they are freed; a call that dies after
   consuming the pool gets a zeroed pool and the running set goes back
   through recompute-prefill (``serve.pool_rebuilds``).  The
   boundary only covers a step program that has run to completion at
@@ -103,6 +120,7 @@ engine enforces no mesh).
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import json
 import os
@@ -128,8 +146,8 @@ from ..utils import fsio
 from .kv_cache import (PagedKVCache, PagedLayerCache,
                        default_kv_block_size, layout_kinds,
                        window_table_width)
-from .scheduler import (ContinuousBatchingScheduler, SequenceState,
-                        StepPlan)
+from .scheduler import (RUNNING, ContinuousBatchingScheduler,
+                        SequenceState)
 
 __all__ = ["MAX_SEQS_ENV", "SHED_QUEUE_DEPTH_ENV", "NAN_GUARD_ENV",
            "DEADLINE_MS_ENV", "DRAIN_SECS_ENV", "default_max_seqs",
@@ -190,19 +208,23 @@ class CollectTimeout(TimeoutError):
 
 
 def pack_step_inputs(ids, positions, last_index, block_tables, seq_lens,
-                     slot_mapping, step: int = 0) -> np.ndarray:
+                     slot_mapping, step: int = 0, src=None) -> np.ndarray:
     """A step's int32 inputs end to end in one host buffer, so that they
     cross to the device in one put: token ids ``(rows, chunk)``,
     positions ``(rows,)``, the index of the position sampled, block
     tables ``(rows, width)``, sequence lengths ``(rows,)``, write slots
-    ``(rows, chunk)``, and the step's number (what a sampling program
-    folds into the engine's key; a greedy one reads past it).  Over a
-    cache with a pool a kind of layer ``block_tables`` and
-    ``slot_mapping`` are lists, an array a kind: each list goes in where
-    its one array would."""
+    ``(rows, chunk)``, ``src`` ``(rows,)`` (where a row's first id comes
+    from: the row of the previous program's tokens that holds it, or -1,
+    the default, for "the id in this buffer"), and the step's number
+    (what a sampling program folds into the engine's key; a greedy one
+    reads past it).  Over a cache with a pool a kind of layer
+    ``block_tables`` and ``slot_mapping`` are lists, an array a kind: each
+    list goes in where its one array would."""
+    if src is None:
+        src = np.full(np.shape(positions), -1, np.int32)
     flat = []
     for a in (ids, positions, last_index, block_tables, seq_lens,
-              slot_mapping, step):
+              slot_mapping, src, step):
         flat += a if isinstance(a, (list, tuple)) else [a]
     return np.concatenate([np.asarray(a, np.int32).reshape(-1)
                            for a in flat])
@@ -216,7 +238,7 @@ def unpack_step_inputs(packed, rows: int, chunk: int,
     went in as lists, and they come out as lists."""
     kinds = 1 if widths is None else len(widths)
     width, rest = divmod(
-        packed.shape[0] - rows * ((kinds + 1) * chunk + 2) - 2, rows)
+        packed.shape[0] - rows * ((kinds + 1) * chunk + 3) - 2, rows)
     enforce(width > 0 and rest == 0
             and (widths is None or width == sum(widths)),
             f"{packed.shape[0]} packed step inputs do not hold {rows} rows "
@@ -236,7 +258,8 @@ def unpack_step_inputs(packed, rows: int, chunk: int,
     slots = [cut((rows, chunk)) for _ in range(kinds)]
     if widths is None:
         tables, slots = tables[0], slots[0]
-    return [ids, positions, last_index, tables, lens, slots, cut(())]
+    return [ids, positions, last_index, tables, lens, slots, cut((rows,)),
+            cut(())]
 
 
 class _NonfiniteLogits(RuntimeError):
@@ -246,6 +269,41 @@ class _NonfiniteLogits(RuntimeError):
     def __init__(self, request_ids: List[str]):
         super().__init__(f"nonfinite logits for {request_ids}")
         self.request_ids = list(request_ids)
+
+
+@dataclasses.dataclass
+class _Unit:
+    """One step program that was launched and that the host has not read
+    yet (a prefill or a decode batch): what its landing needs."""
+    kind: str                         # "prefill" | "decode"
+    seqs: List[SequenceState]
+    bucket: int                       # a prefill's pad length, else 0
+    number: int                       # what a sampling program folds in
+    t0: float                         # the engine's clock at the launch
+    # per row, whether the program samples the sequence's NEXT token (a
+    # recompute-prefill does not: that token was streamed before)
+    emits: List[bool] = dataclasses.field(default_factory=list)
+    fetch_logits: bool = False
+    # device handles: what the host will read ([next tokens, finite flags,
+    # the model's counts] and the logits where asked for), what stays
+    # (the rest of aux), and the tokens as the next program takes them
+    out: Optional[list] = None
+    logits: Any = None
+    aux: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    carry: Any = None
+    counts: Any = None                # the model's counts, once landed
+    error: Optional[BaseException] = None   # the launch raised
+    marks: List[tuple] = dataclasses.field(default_factory=list)
+    # booked at the landing: root-span attributes and block counts
+    attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    blocks: Dict[str, int] = dataclasses.field(default_factory=dict)
+    stall: str = "stall"              # what the residents it leaves out wait for
+
+    @property
+    def program(self):
+        """The step program's name among those that have run to an end."""
+        return "decode" if self.kind == "decode" else ("prefill",
+                                                        self.bucket)
 
 
 class ServingEngine:
@@ -281,8 +339,8 @@ class ServingEngine:
 
     Resilience knobs (ISSUE 15): ``nan_guard`` enables the per-step
     nonfinite-logits check (env ``PTPU_SERVE_NAN_GUARD``);
-    ``step_timeout`` arms a watchdog around every step (or pass a shared
-    ``watchdog``) — set it above the worst-case COLD compile of your
+    ``step_timeout`` arms a watchdog around every launch and every landing
+    of a step (or pass a shared ``watchdog``) — set it above the worst-case COLD compile of your
     shape set (the watchdog cannot tell XLA compiling from a wedged
     device), or warm the shapes first; ``run_dir`` is where quarantine
     records and the drain spill file land; ``step_fault`` is the test
@@ -413,10 +471,22 @@ class ServingEngine:
         # padding sink) so padded rows stop inflating tokens/s and MFU
         self._pad_real_tokens = 0
         self._pad_slot_tokens = 0
-        # a step's aux outputs (host copies of the counts; the per-token
-        # arrays stay device handles unless a captured request wants
-        # them), the step's root span, and what the model booked so far
-        self._step_aux: Dict[str, Any] = {}
+        # the unit launched and not read yet, if any (ISSUE 36); the
+        # number the next unit folds into the key; events of a unit that
+        # landed outside step(); when the last unit landed; what a program
+        # launched with nothing unread reads as the previous tokens; and
+        # how often running ahead engaged and why not
+        self._in_flight: Optional[_Unit] = None
+        self._unit_no = 0
+        self._early: List[Dict[str, Any]] = []
+        self._landed_at = 0.0
+        self._no_prev = jnp.zeros((self.max_seqs,), jnp.int32)
+        self._ahead: Dict[str, Any] = {
+            "units_launched": 0, "units_ahead": 0,
+            "ahead_rows_discarded": 0, "ahead_units_dropped": 0,
+            "ahead_breaks": {"idle": 0, "preempt": 0, "fault": 0,
+                             "drain": 0}}
+        # the step's root span, and what the model booked so far
         self._step_root: Optional[span] = None
         self._model_counts: Dict[str, Dict[str, Any]] = {
             "counters": {}, "gauges": {}}
@@ -443,12 +513,12 @@ class ServingEngine:
         return get_registry()
 
     # -- jitted step functions --------------------------------------------
-    _STEP_ARGS = ("params", "packed", "pages", "key")
+    _STEP_ARGS = ("params", "packed", "pages", "key", "prev")
 
     def _build_step_fn(self):
-        """The step program, ``fn(params, packed, pages, key, *, rows,
-        chunk)``.  ``packed`` is the step's int32 inputs in one buffer
-        (:func:`pack_step_inputs`), cut apart here at offsets that
+        """The step program, ``fn(params, packed, pages, key, prev, *,
+        rows, chunk)``.  ``packed`` is the step's int32 inputs in one
+        buffer (:func:`pack_step_inputs`), cut apart here at offsets that
         ``rows`` and ``chunk`` fix for each program (decode; each prefill
         bucket).  ``pages`` (per layer its page arrays, as the model
         declared them) is donated and nothing else is: with token-major
@@ -456,14 +526,22 @@ class ServingEngine:
         every returned page array aliases its input, so no step copies
         the pool.  Each layer's view is built here, inside the trace.
 
-        Returns ``(next tokens, finite, logits, pages, aux)``: the float32
-        logits stay on the device unless the host asks for them, so the
-        program says itself which rows of them are finite.  ``key`` is
-        the engine's one key, the same array every step: a sampling
-        program folds the step's number (the last packed word) into it,
-        a greedy one reads neither."""
+        ``prev`` (int32, ``(max_seqs,)`` whatever launched before) is the
+        tokens the program launched immediately before this one sampled,
+        which the host may not have read yet: a row whose ``src`` word is
+        not negative takes its first id from ``prev[src]``, the others the
+        id in the buffer.  Where an id comes from is data: one decode
+        program, one a prefill bucket, whatever the order of units.
+
+        Returns ``(next tokens, finite, logits, pages, aux, carry)``: the
+        float32 logits stay on the device unless the host asks for them,
+        so the program says itself which rows of them are finite, and
+        ``carry`` is the next tokens at ``prev``'s shape, for the program
+        launched next.  ``key`` is the engine's one key, the same array
+        every step: a sampling program folds the step's number (the last
+        packed word) into it, a greedy one reads neither."""
         model, temperature = self.model, self.temperature
-        block_size = self.cache.block_size
+        block_size, max_seqs = self.cache.block_size, self.max_seqs
         # a cache of one kind of layer: one table, one slot matrix, every
         # layer's view over them.  Of several: one of each a kind
         kind_of = [list(self.cache.pools).index(k)
@@ -471,10 +549,12 @@ class ServingEngine:
         widths = (None if len(self.cache.pools) == 1 else
                   self.cache.table_widths(self.sched.max_blocks_per_seq))
 
-        def fn(params, packed, pages, key, *, rows, chunk):
+        def fn(params, packed, pages, key, prev, *, rows, chunk):
             (ids, positions, last_index, block_tables, seq_lens,
-             slot_mapping, step) = unpack_step_inputs(packed, rows, chunk,
-                                                      widths)
+             slot_mapping, src, step) = unpack_step_inputs(
+                 packed, rows, chunk, widths)
+            ids = ids.at[:, 0].set(jnp.where(
+                src >= 0, prev[jnp.maximum(src, 0)], ids[:, 0]))
             if widths is None:
                 block_tables, slot_mapping = [block_tables], [slot_mapping]
             caches = [PagedLayerCache(layer, block_tables[k], seq_lens,
@@ -490,9 +570,12 @@ class ServingEngine:
                 nxt = jax.random.categorical(
                     jax.random.fold_in(key, step), logits / temperature,
                     axis=-1)
-            return (nxt.astype(jnp.int32), jnp.isfinite(logits).all(-1),
-                    logits, [c.pages for c in new_caches],
-                    aux[0] if aux else {})
+            nxt = nxt.astype(jnp.int32)
+            carry = (nxt if rows == max_seqs else
+                     jnp.zeros((max_seqs,), jnp.int32).at[:rows].set(nxt))
+            return (nxt, jnp.isfinite(logits).all(-1), logits,
+                    [c.pages for c in new_caches], aux[0] if aux else {},
+                    carry)
 
         return jax.jit(fn, donate_argnames=("pages",),
                        static_argnames=("rows", "chunk"))
@@ -649,27 +732,49 @@ class ServingEngine:
         return guarded("serve_step")
 
     def step(self) -> List[Dict[str, Any]]:
-        """Run one scheduler-chosen unit of work (one prefill or one
-        decode batch) inside the lifecycle guard: reap expired/cancelled
-        requests first, arm the watchdog around the device work, recover
-        from a hung step by rebuilding the jitted fns and re-admitting
-        the running set (recompute-prefill).  Returns the token events
-        produced; empty when idle AND no queued work remains.
+        """Land one unit of work (one prefill or one decode batch) and
+        return its token events, inside the lifecycle guard: reap
+        expired/cancelled requests first, arm the watchdog around the
+        device work, recover from a hung step by rebuilding the jitted fns
+        and re-admitting the running set (recompute-prefill).  Empty when
+        idle AND no queued work remains.
 
-        The step is one ``engine.step`` span (attributes ``step``,
-        ``kind``, ``rows``, ``bucket``, ``logits_fetched``; a decode step
-        also ``kv_blocks_live``, ``kv_blocks_table``) whose children name where its
-        host time goes — ``reap``, ``schedule``, ``tables``, ``h2d``,
-        ``dispatch``, ``device_wait``, ``logits_copy``, ``guard``,
-        ``accept``, ``gauges``, and the rare ``quarantine`` /
-        ``recover``; ``stats()["phases"]`` sums them."""
+        A unit is a LAUNCH (``tables``, ``h2d``, ``dispatch``: the step
+        program is called and the copies of what the host will read are
+        asked for) and, later, a LANDING (``device_wait``, ``logits_copy``,
+        ``guard``, ``accept``).  The engine runs one unit ahead: before it
+        lands the unit in flight it plans and launches the next one,
+        whenever that plan can be made without the tokens in flight (a
+        decode row takes its id from the previous program's output on the
+        device), so the host's part of a step hides behind the device's.
+        Each call still returns the events of exactly ONE unit, in the
+        order the units were launched; the first call of a busy stretch
+        launches two.  It does NOT run ahead when there is nothing to
+        plan (``idle``), when growing a table would have to preempt
+        (``preempt``: victims are chosen from landed state), after a
+        landing raised (``fault``: the unit launched after it is dropped
+        unread, its marks taken back, and quarantine / bisect / replay run
+        with nothing in flight) and while the engine is not ``serving``
+        (``drain``); ``stats()["ahead"]`` counts each.  Counters, token
+        events and request-trace spans of a unit are booked once, at its
+        landing.
+
+        The call is one ``engine.step`` span (attributes ``step``, and of
+        the LANDED unit ``kind``, ``rows``, ``bucket``, ``logits_fetched``,
+        the model's counts; a decode unit also ``kv_blocks_live``,
+        ``kv_blocks_table``; ``ahead_kind``: the kind launched ahead in
+        this call, or None) whose children name where its host time goes —
+        ``reap``, ``schedule``, ``tables``, ``h2d``, ``dispatch``,
+        ``device_wait``, ``logits_copy``, ``guard``, ``accept``,
+        ``gauges``, and the rare ``quarantine`` / ``recover``;
+        ``stats()["phases"]`` sums them."""
         with self._phase("engine.step") as root:
             self._step_root = root
             with self._phase("reap"):
-                events = self._reap()
+                # what a landing outside step() produced (defrag, drain)
+                events, self._early = self._early + self._reap(), []
             try:
-                with self._step_guard():
-                    events += self._step_inner(root)
+                events += self._step_inner(root)
             except StepTimeout:
                 with self._phase("recover"):
                     events += self._recover_from_hang()
@@ -683,9 +788,44 @@ class ServingEngine:
         return span(name, step=self.steps)
 
     def _step_inner(self, root: span) -> List[Dict[str, Any]]:
+        """Launch the next unit, land the one in flight.  The watchdog is
+        armed around each launch and around the landing, so its deadline
+        bounds one program's call (its trace and compile, when cold) or
+        one program's wait, however many a call holds."""
+        unit, self._in_flight = self._in_flight, None
+        if unit is None:                  # a busy stretch begins
+            with self._step_guard():
+                unit = self._launch_next(None)
+            if unit is None:
+                root.set(kind="other", rows=0, bucket=0, ahead_kind=None)
+                return []
+        with self._step_guard():
+            self._in_flight = self._launch_next(unit)
+        root.set(ahead_kind=(None if self._in_flight is None
+                             else self._in_flight.kind))
+        with self._step_guard():
+            return self._land(unit)
+
+    def _count_ahead(self, name: str, n: int = 1) -> None:
+        """One of ``stats()["ahead"]``'s sums and its ``serve.`` counter."""
+        self._ahead[name] += n
+        self._reg().counter(f"serve.{name}").inc(n)
+
+    def _note_break(self, why: str) -> None:
+        self._ahead["ahead_breaks"][why] += 1
+        self._reg().counter(f"serve.ahead_breaks.{why}").inc()
+
+    def _launch_next(self, prev: Optional[_Unit]) -> Optional[_Unit]:
+        """Plan the next unit and launch it, ``prev`` (launched, unread)
+        or nothing in flight.  None where there is nothing to launch, or
+        nothing that may be launched ahead of ``prev``'s landing."""
         reg = self._reg()
+        if prev is not None and (self._state != "serving"
+                                 or prev.error is not None):
+            self._note_break("drain" if prev.error is None else "fault")
+            return None
         with self._phase("schedule"):
-            plan = self.sched.schedule()
+            plan = self.sched.schedule(ahead=prev is not None)
             for victim in plan.preempted:
                 reg.counter("serve.preemptions").inc()
                 reg.emit("serve.preempt", request_id=victim.request_id,
@@ -695,56 +835,64 @@ class ServingEngine:
                 requesttrace.emit_span(reg, victim.trace_id,
                                        victim.request_id, "preempt",
                                        "preempt", now, now, self._proc)
-            step_kind = (plan.kind if plan.kind in ("prefill", "decode")
-                         else "other")
-            root.set(kind=step_kind, rows=len(plan.seqs),
-                     bucket=plan.bucket)
-            if step_kind == "other":
-                return []
+            if plan.kind not in ("prefill", "decode"):
+                if prev is not None:
+                    self._note_break("preempt" if plan.kind == "wait"
+                                     else "idle")
+                return None
             # head-of-line stall: residents live on this engine but not
-            # in this step's batch wait the full step out.  When the
-            # served step is induced work (a recompute prefill), their
+            # in this unit's batch wait the full unit out.  When the
+            # served unit is induced work (a recompute prefill), their
             # stall is that cause's cost — the survivor decodes late
             # *because of* the failover, not by scheduler bad luck.
-            stall_comp = "stall"
-            if plan.kind == "prefill" and plan.seqs:
-                why = plan.seqs[0].resume_why
-                if why:
-                    stall_comp = _RESUME_COMPONENT.get(why, "stall")
-            served = {s.request_id for s in plan.seqs}
-            t_step0 = float(self.clock())
-        if plan.kind == "prefill":
-            events = self._run_prefill(plan)
-        else:
-            events = self._run_decode(plan)
-        with self._phase("accept"):
-            stalled = [(s.request_id, s.trace_id)
-                       for s in self.sched.running
-                       if s.request_id not in served
-                       and s.trace_id is not None]
-            if stalled:
-                requesttrace.emit_stall_span(reg, stalled, t_step0,
-                                             float(self.clock()),
-                                             self._proc,
-                                             component=stall_comp,
-                                             cause=plan.kind)
-        return events
+            stall = "stall"
+            if plan.kind == "prefill" and plan.seqs[0].resume_why:
+                stall = _RESUME_COMPONENT.get(plan.seqs[0].resume_why,
+                                              "stall")
+        unit = self._start(plan.kind, plan.seqs, plan.bucket, prev,
+                           self._unit_no)
+        unit.stall = stall
+        self._unit_no += 1
+        self._count_ahead("units_launched")
+        if prev is not None:
+            self._count_ahead("units_ahead")
+        return unit
+
+    def _start(self, kind: str, seqs: List[SequenceState], bucket: int,
+               prev: Optional[_Unit], number: int) -> _Unit:
+        """Launch a unit and move the scheduler's launch-time marks."""
+        unit = self._launch(kind, seqs, bucket, prev, number)
+        unit.marks = self.sched.mark_launched(kind, unit.seqs, unit.emits)
+        return unit
+
+    def _settle(self) -> List[Dict[str, Any]]:
+        """Land what is in flight, with nothing launched after it: what
+        ``begin_drain`` / ``defrag`` / ``stop`` do first, so that they act
+        on landed state.  Returns the unit's events."""
+        unit, self._in_flight = self._in_flight, None
+        if unit is None:
+            return []
+        self._step_root = None            # no step() is open
+        self._note_break("drain")
+        return self._land(unit)
 
     def _recover_from_hang(self) -> List[Dict[str, Any]]:
         """Hung-step recovery: the watchdog already dumped every thread's
-        stack.  Device work in flight is abandoned.  The scheduler's marks
-        only move after a step returns, so they are consistent; the pool
-        is not protected that way any more — the step program consumes
-        the page handles, and a step cut between the call and
-        ``update_pages`` leaves them dead — so a lost pool is replaced by
-        a zeroed one.  Rebuild the jitted fns and preempt the running set
-        back to the queue; recompute-prefill rewrites their KV and replays
-        them token-exact, whichever pool they land in."""
+        stack.  Device work in flight is abandoned, every unit of it,
+        read or not.  The pool is not protected any more — the step
+        program consumes the page handles, and a step cut between the
+        call and ``update_pages`` leaves them dead — so a lost pool is
+        replaced by a zeroed one.  Rebuild the jitted fns and preempt the
+        running set back to the queue: a preempted sequence starts over
+        from the tokens that landed, so recompute-prefill rewrites their
+        KV and replays them token-exact, whichever pool they land in."""
         self._jit_step = None
         self._decode_tracked = None
         self._prefill_tracked = {}
         self._proven.clear()
+        self._in_flight = None
         victims = self.sched.preempt_all()
+        self.cache.restore_held()     # their sequences are gone: freed
         self._rebuild_lost_pool()
         self.watchdog_restarts += 1
         reg = self._reg()
@@ -754,13 +902,16 @@ class ServingEngine:
         return []
 
     def has_work(self) -> bool:
-        return self.sched.has_work()
+        """Queued or running requests, a unit in flight, or events of a
+        unit that landed outside ``step()`` still to hand out."""
+        return (self._in_flight is not None or self.sched.has_work()
+                or bool(self._early))
 
     def run(self, max_steps: Optional[int] = None) -> int:
         """Drive :meth:`step` until every submitted request finishes;
         returns the number of steps taken."""
         taken = 0
-        while self.sched.has_work():
+        while self.has_work():
             self.step()
             taken += 1
             if max_steps is not None and taken > max_steps:
@@ -771,22 +922,20 @@ class ServingEngine:
                     f"requests: {', '.join(stuck) or 'none'}")
         return taken
 
-    # -- prefill / decode execution ---------------------------------------
-    # The _apply_* helpers run the jitted step and read the result back
-    # to host.  The step program consumes the page arrays (they are
-    # donated), so the cache takes the new ones the moment the call
-    # returns: a failed or probed step DOES leave its page writes behind.
-    # That is safe because a step writes only its own rows' slots at
-    # positions >= computed_len, which no row reads until mark_decoded /
-    # mark_prefilled advance the lengths, and a replay or a bisection
-    # probe of the same rows writes the same values to the same slots.
-    # So pages are adopted always, scheduler marks only on success (the
-    # _run_* callers).  What a culprit wrote is scrubbed when it is
-    # quarantined; a pool that a failed call consumed is rebuilt
-    # (_rebuild_lost_pool).
+    # -- a unit: its launch and its landing --------------------------------
+    # The step program consumes the page arrays (they are donated), so the
+    # cache takes the new ones the moment the call returns: a failed,
+    # probed or dropped unit DOES leave its page writes behind.  That is
+    # safe because a unit writes only its own rows' slots at positions
+    # that no row reads until a later unit is launched over them, and a
+    # replay or a bisection probe of the same rows writes the same values
+    # to the same slots.  So pages are adopted always; the scheduler's
+    # marks move at the launch and are taken back when a unit is dropped
+    # or faults.  What a culprit wrote is scrubbed when it is quarantined;
+    # a pool that a failed call consumed is rebuilt (_rebuild_lost_pool).
 
     def _wants_logits(self, seqs: List[SequenceState]) -> bool:
-        """Whether this step's logits have a reader on the host: the
+        """Whether this unit's logits have a reader on the host: the
         fault seam, or a row whose request captures them."""
         return (self.step_fault is not None
                 or any(s.capture_logits for s in seqs))
@@ -794,11 +943,12 @@ class ServingEngine:
     def _apply_fault(self, kind: str, seqs: List[SequenceState],
                      finite: np.ndarray,
                      logits_np: Optional[np.ndarray]):
-        """Fault seam + NaN guard, applied to every executed step
+        """Fault seam + NaN guard, applied to every landed unit
         (bisection probes included — injected faults must re-fire on the
         subset that still contains the target).  The guard reads the
         flags the step program computed, a row each; where the seam is
-        set it reads what the hook handed back instead."""
+        set it reads what the hook handed back instead.  A row whose
+        request left while the unit was in flight has nobody to name."""
         with self._phase("guard"):
             if self.step_fault is not None:
                 out = self.step_fault(self, kind,
@@ -810,68 +960,289 @@ class ServingEngine:
                 if self.step_fault is not None:
                     finite = np.isfinite(logits_np[:len(seqs)]).all(axis=-1)
                 bad = [s.request_id for s, ok in zip(seqs, finite)
-                       if not ok]
+                       if not ok and s.state == RUNNING]
                 if bad:
                     raise _NonfiniteLogits(bad)
         return logits_np
 
-    def _device_step(self, fn, rows: int, chunk: int, inputs,
-                     fetch_logits: bool):
-        """Host arrays in (``inputs`` in :func:`pack_step_inputs`'s
-        order), host ``(next tokens, finite flags, logits or None)`` out,
-        the pool updated in place, one span a leg: ``h2d`` (the inputs
-        packed into one buffer with the step's number and put once),
-        ``dispatch`` (the jitted call until it returns, and the cache
-        taking the new page handles — the old ones are dead by then; the
-        copies to the host are asked for here, so that they follow the
-        program out), ``device_wait`` (until the
-        device is done — the copy below would wait for the same), and
-        ``logits_copy`` (what is left of the device-to-host copy by
-        then: the next tokens, a flag a row, the model's counts — and
-        the ``[rows, vocab]`` float32 logits only under ``fetch_logits``;
-        otherwise they stay where they are and go with the step's
-        outputs)."""
+    def _launch(self, kind: str, seqs: List[SequenceState], bucket: int,
+                prev: Optional[_Unit], number: int) -> _Unit:
+        """The first half of a unit, one span a leg: ``tables`` (the
+        rows' ids, positions, block tables and write slots; a decode row
+        that ``prev`` samples for takes its id from ``prev``'s output on
+        the device, by the row's ``src`` word), ``h2d`` (the inputs
+        packed into one buffer with the unit's number and put once) and
+        ``dispatch`` (the jitted call until it returns, the cache taking
+        the new page handles — the old ones are dead by then — and the
+        copies to the host asked for, so that they follow the program
+        out).  Nothing here waits for the device, and no mark moves.  A
+        launch that raises is kept in the unit and raised at its landing,
+        where every fault is met."""
+        unit = _Unit(kind, list(seqs), bucket, number, float(self.clock()))
         reg = self._reg()
-        with self._phase("h2d"):
-            # a replay or a probe of a faulted step runs inside the same
-            # step(), so it carries the same number and draws the same
-            packed = pack_step_inputs(*inputs, step=self.steps % 2 ** 31)
-            packed_d = jax.device_put(packed)
-            reg.counter("serve.h2d_bytes").inc(packed.nbytes)
-        with self._phase("dispatch"):
-            nxt, finite, logits, pages, aux = fn(
-                self._params, packed_d, self.cache.pages, self._key,
-                rows=rows, chunk=chunk)
-            self.cache.update_pages(pages)
-            # the counts come with the outputs; the rest of aux stays.
-            # What the host reads sets out as soon as the program is done
-            out = ([nxt, finite, aux.get("counts", {})]
-                   + ([logits] if fetch_logits else []))
-            for a in jax.tree_util.tree_leaves(out):
-                a.copy_to_host_async()
+        try:
+            with self._phase("tables"):
+                if kind == "prefill":
+                    seq, rows, chunk = seqs[0], 1, bucket
+                    unit.emits = [seq.pending is None]
+                    ctx = seq.context()
+                    L = len(ctx)
+                    ids = np.zeros((1, bucket), np.int32)
+                    ids[0, :L] = ctx
+                    self._note_padding(L, bucket)
+                    tables, slots = self._tables_and_slots(
+                        [seq.request_id], [0], bucket)
+                    inputs = (ids, np.zeros((1,), np.int32), L - 1, tables,
+                              np.asarray([L], np.int32), slots)
+                    src = None            # a prefill takes no id from it
+                    fn = self._prefill_fn(bucket)
+                else:
+                    rows, chunk = self.max_seqs, 1
+                    *inputs, src = self._decode_inputs(unit, prev)
+                    fn = self._decode_fn()
+                unit.fetch_logits = self._wants_logits(seqs)
+            with self._phase("h2d"):
+                # a replay or a probe of a faulted unit carries the
+                # unit's own number, and so draws the same
+                packed = pack_step_inputs(*inputs, step=number % 2 ** 31,
+                                          src=src)
+                packed_d = jax.device_put(packed)
+                reg.counter("serve.h2d_bytes").inc(packed.nbytes)
+            with self._phase("dispatch"):
+                nxt, finite, logits, pages, aux, unit.carry = fn(
+                    self._params, packed_d, self.cache.pages, self._key,
+                    self._no_prev if prev is None else prev.carry,
+                    rows=rows, chunk=chunk)
+                self.cache.update_pages(pages)
+                # the counts come with the outputs; the rest of aux stays.
+                # What the host reads sets out as soon as the program is
+                # done
+                unit.out = ([nxt, finite, aux.get("counts", {})]
+                            + ([logits] if unit.fetch_logits else []))
+                unit.aux, unit.logits = aux, logits
+                for a in jax.tree_util.tree_leaves(unit.out):
+                    a.copy_to_host_async()
+        except StepTimeout:
+            raise
+        except Exception as e:
+            unit.error = e
+        return unit
+
+    def _decode_inputs(self, unit: _Unit, prev: Optional[_Unit]):
+        seqs, B = unit.seqs, self.max_seqs
+        enforce(len(seqs) <= B, f"{len(seqs)} decode rows > max_seqs {B}")
+        unit.emits = [True] * len(seqs)
+        self._note_padding(len(seqs), B)
+        sids = [s.request_id for s in seqs] + [_PAD_SEQ] * (B - len(seqs))
+        ids = np.zeros((B, 1), np.int32)
+        positions = np.zeros((B,), np.int32)
+        lens = np.zeros((B,), np.int32)
+        src = np.full((B,), -1, np.int32)
+        starts = [-1] * B
+        # the rows of the unread unit that hold a sequence's newest token
+        unread = ({} if prev is None else
+                  {id(s): i for i, s in enumerate(prev.seqs)
+                   if prev.emits[i]})
+        for i, s in enumerate(seqs):
+            row = unread.get(id(s), -1)
+            if row >= 0:
+                src[i] = row
+            else:
+                enforce(s.pending is not None,
+                        f"{s.request_id}: decode row without a pending "
+                        "token")
+                ids[i, 0] = s.pending
+            positions[i] = s.computed_len
+            lens[i] = s.computed_len + 1      # includes the written token
+            starts[i] = s.computed_len
+        tables, slots = self._tables_and_slots(sids, starts, 1)
+        self._count_paged_blocks(unit, lens, tables)
+        return ids, positions, 0, tables, lens, slots, src
+
+    def _arrive(self, unit: _Unit):
+        """The landing up to the guard: host ``(next tokens, logits or
+        None)`` of a launched unit, one span a leg: ``device_wait`` (until
+        the device is done — the copy below would wait for the same),
+        ``logits_copy`` (what is left of the device-to-host copy by then:
+        the next tokens, a flag a row, the model's counts — and the
+        ``[rows, vocab]`` float32 logits only where the launch asked for
+        them; otherwise they stay where they are and go with the unit)
+        and ``guard``.  Raises what the launch, the device, the fault
+        seam or the NaN guard raised."""
+        if unit.error is not None:
+            raise unit.error
+        reg = self._reg()
         with self._phase("device_wait"):
             try:
-                jax.block_until_ready((nxt, logits))
+                jax.block_until_ready((unit.out[0], unit.logits))
             except StepTimeout:
                 raise
             except Exception:
-                # the program failed on the device: its page outputs are
-                # as dead as its logits
+                # the program failed on the device: its page outputs, and
+                # those of whatever was launched over them, are as dead
+                # as its logits
                 self.cache.drop_pages()
                 raise
         with self._phase("logits_copy"):
-            out = jax.device_get(out)
-            nxt_np, finite_np, counts, *fetched = out
-            if aux:
-                self._step_aux = dict(aux, counts=counts)
+            out = jax.device_get(unit.out)
+            nxt_np, finite_np, unit.counts, *fetched = out
             reg.counter("serve.d2h_bytes").inc(sum(
                 v.nbytes for v in jax.tree_util.tree_leaves(out)))
-            if fetch_logits:
+            if unit.fetch_logits:
                 reg.counter("serve.logits_fetch_steps").inc()
                 self._logits_fetch_steps += 1
         if self._step_root is not None:
-            self._step_root.set(logits_fetched=fetch_logits)
-        return nxt_np, finite_np, fetched[0] if fetched else None
+            self._step_root.set(logits_fetched=unit.fetch_logits)
+        self._proven.add(unit.program)
+        return nxt_np, self._apply_fault(unit.kind, unit.seqs, finite_np,
+                                         fetched[0] if fetched else None)
+
+    def _land(self, unit: _Unit) -> List[Dict[str, Any]]:
+        """The second half of a unit: wait for it, read it, guard it,
+        accept its tokens.  A landing that raises is the fault boundary:
+        see :meth:`_fault`."""
+        try:
+            nxt_np, logits_np = self._arrive(unit)
+        except StepTimeout:
+            raise                      # the watchdog owns this one
+        except Exception as e:
+            return self._fault(unit, e)
+        # no replay of this unit any more: the window blocks that the
+        # plan made over it let go of are free
+        self.cache.release_held()
+        return self._accept(unit, nxt_np, logits_np)
+
+    def _fault(self, unit: _Unit, error: Exception) -> List[Dict[str, Any]]:
+        """A landing raised (the launch, the device, the fault seam or
+        the NaN guard).  The unit launched after it is dropped unread and
+        both units' launch-time marks are taken back, so what follows
+        runs with nothing in flight, on landed state, as the serial
+        engine's fault path did: a lost pool is rebuilt (every row goes
+        back through recompute-prefill); a program that never ran to its
+        end is the engine's fault and the error propagates; otherwise the
+        culprits are quarantined and a decode unit's survivors replayed,
+        launch and landing back to back, under the unit's own number."""
+        ahead, self._in_flight = self._in_flight, None
+        if ahead is not None:
+            self.sched.unmark(ahead.marks)
+            if ahead.kind == "prefill":
+                self.sched.unadmit(ahead.seqs[0])
+            self._unit_no = ahead.number       # its number is free again
+            self._count_ahead("ahead_units_dropped")
+            self._note_break("fault")
+        self.cache.restore_held()
+        self.sched.unmark(unit.marks)
+        rebuilt = self._rebuild_lost_pool()
+        if unit.program not in self._proven:
+            raise error                # never ran: not a request's fault
+        live = [s for s in unit.seqs if s.state == RUNNING]
+        if rebuilt or not live:        # the engine's loss, not a row's
+            return []
+        survivors = self._quarantine_step(unit.kind, live, error,
+                                          unit.number)
+        if unit.kind == "prefill" or not survivors:
+            return []
+        # replay: the culprit rows are gone, every surviving row is
+        # re-run with the same pending tokens — per-row paged attention
+        # makes the survivors' logits identical to the un-faulted unit's,
+        # and their tokens where they kept their rows (sampling draws a
+        # row's noise by its place in the batch)
+        replay = self._start("decode", survivors, 0, None, unit.number)
+        replay.t0, replay.stall = unit.t0, unit.stall
+        return self._land(replay)
+
+    def _accept(self, unit: _Unit, nxt_np: np.ndarray,
+                logits_np: Optional[np.ndarray]) -> List[Dict[str, Any]]:
+        """Book a landed unit, once: its counters, its root-span
+        attributes, the model's counts, its request-trace spans and its
+        tokens.  A row whose request ended while the unit was in flight
+        (an end-of-sequence token a unit earlier, a cancel, a deadline)
+        is discarded: no token, no event; its blocks went when it ended."""
+        reg, seqs, events = self._reg(), unit.seqs, []
+        # on a request's waterfall a unit starts where the one before it
+        # ended, if it was launched before that
+        t0 = max(unit.t0, self._landed_at)
+        with self._phase("accept"):
+            if self._step_root is not None:
+                self._step_root.set(kind=unit.kind, rows=len(seqs),
+                                    bucket=unit.bucket, **unit.attrs)
+            self._book_paged_blocks(unit)
+            live = [s.state == RUNNING for s in seqs]
+            self._note_aux(unit, live)
+            for s, ok, emits in zip(seqs, live, unit.emits):
+                if ok and emits:
+                    s.in_flight -= 1
+            if not all(live):
+                self._count_ahead("ahead_rows_discarded",
+                                  len(seqs) - sum(live))
+            if unit.kind == "prefill":
+                reg.counter("serve.prefills").inc()
+                if live[0]:
+                    events = self._accept_prefill(unit, t0, nxt_np,
+                                                  logits_np)
+            else:
+                reg.counter("serve.decode_steps").inc()
+                reg.histogram("serve.decode_batch").observe(
+                    float(len(seqs)))
+                for i, s in enumerate(seqs):
+                    if live[i]:
+                        events.append(self._accept_token(
+                            s, int(nxt_np[i]),
+                            logits_np[i] if s.capture_logits else None,
+                            first=False))
+                # one batch-level decode span; the assembler amortizes the
+                # unit across its residents to produce per-request decode
+                # time
+                requesttrace.emit_decode_span(
+                    reg, [(s.request_id, s.trace_id)
+                          for s, ok in zip(seqs, live) if ok], sum(live),
+                    t0, float(self.clock()), self._proc)
+        with self._phase("accept"):
+            served = {s.request_id for s in seqs}
+            if self._in_flight is not None \
+                    and self._in_flight.kind == "prefill":
+                # admitted after this unit was launched: it is no resident
+                served.add(self._in_flight.seqs[0].request_id)
+            stalled = [(s.request_id, s.trace_id)
+                       for s in self.sched.running
+                       if s.request_id not in served
+                       and s.trace_id is not None]
+            self._landed_at = float(self.clock())
+            if stalled:
+                requesttrace.emit_stall_span(reg, stalled, t0,
+                                             self._landed_at, self._proc,
+                                             component=unit.stall,
+                                             cause=unit.kind)
+        return events
+
+    def _accept_prefill(self, unit: _Unit, t0: float, nxt_np, logits_np):
+        seq, reg = unit.seqs[0], self._reg()
+        if seq.trace_id is not None:
+            # the (re-)prefill plus the queue wait before it; a
+            # recompute's wait is attributed to its cause, not "queue"
+            comp = _RESUME_COMPONENT.get(seq.resume_why, "prefill")
+            t_q0 = seq.trace_enqueued
+            if t_q0 is None:
+                t_q0 = seq.arrival
+            if t0 > t_q0:
+                requesttrace.emit_span(
+                    reg, seq.trace_id, seq.request_id, "queue",
+                    "queue" if seq.resume_why is None else comp,
+                    t_q0, t0, self._proc)
+            requesttrace.emit_span(reg, seq.trace_id, seq.request_id,
+                                   "prefill", comp, t0,
+                                   float(self.clock()), self._proc,
+                                   bucket=unit.bucket)
+        seq.resume_why = None
+        seq.trace_enqueued = None
+        if not unit.emits[0]:
+            # recompute prefill after preemption: the next token was
+            # already sampled (and streamed) before eviction — only
+            # the KV was rebuilt; nothing new to emit
+            return []
+        return [self._accept_token(
+            seq, int(nxt_np[0]),
+            logits_np[0] if seq.capture_logits else None, first=True)]
 
     def _rebuild_lost_pool(self) -> bool:
         """If a step program consumed the pool and handed none back (the
@@ -895,182 +1266,58 @@ class ServingEngine:
         return (self.cache.step_tables(sids, self.sched.max_blocks_per_seq),
                 self.cache.step_slots(sids, starts, chunk))
 
-    def _apply_prefill(self, seq: SequenceState, bucket: int):
-        with self._phase("tables"):
-            ctx = seq.context()
-            L = len(ctx)
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :L] = ctx
-            self._note_padding(L, bucket)
-            tables, slots = self._tables_and_slots([seq.request_id], [0],
-                                                   bucket)
-            lens = np.asarray([L], np.int32)
-            fn = self._prefill_fn(bucket)
-        nxt_np, finite, logits_np = self._device_step(
-            fn, 1, bucket,
-            (ids, np.zeros((1,), np.int32), L - 1, tables, lens, slots),
-            self._wants_logits([seq]))
-        self._proven.add(("prefill", bucket))
-        logits_np = self._apply_fault("prefill", [seq], finite, logits_np)
-        return nxt_np, logits_np
-
-    def _apply_decode(self, seqs: List[SequenceState]):
-        with self._phase("tables"):
-            B = self.max_seqs
-            enforce(len(seqs) <= B,
-                    f"{len(seqs)} decode rows > max_seqs {B}")
-            self._note_padding(len(seqs), B)
-            sids = [s.request_id for s in seqs] + \
-                [_PAD_SEQ] * (B - len(seqs))
-            ids = np.zeros((B, 1), np.int32)
-            positions = np.zeros((B,), np.int32)
-            lens = np.zeros((B,), np.int32)
-            starts = [-1] * B
-            for i, s in enumerate(seqs):
-                enforce(s.pending is not None,
-                        f"{s.request_id}: decode row without a pending "
-                        "token")
-                ids[i, 0] = s.pending
-                positions[i] = s.computed_len
-                lens[i] = s.computed_len + 1  # includes the written token
-                starts[i] = s.computed_len
-            tables, slots = self._tables_and_slots(sids, starts, 1)
-            self._note_paged_blocks(lens, tables, seqs)
-            fn = self._decode_fn()
-        nxt_np, finite, logits_np = self._device_step(
-            fn, B, 1, (ids, positions, 0, tables, lens, slots),
-            self._wants_logits(seqs))
-        self._proven.add("decode")
-        logits_np = self._apply_fault("decode", seqs, finite, logits_np)
-        return nxt_np, logits_np
-
-    def _run_prefill(self, plan: StepPlan) -> List[Dict[str, Any]]:
-        seq = plan.seqs[0]
-        t_prefill0 = float(self.clock())
-        try:
-            nxt_np, logits_np = self._apply_prefill(seq, plan.bucket)
-        except StepTimeout:
-            raise                      # the watchdog owns this one
-        except Exception as e:
-            rebuilt = self._rebuild_lost_pool()
-            if ("prefill", plan.bucket) not in self._proven:
-                raise                  # never ran: not a request's fault
-            if not rebuilt:            # else the engine's loss, not seq's
-                self._quarantine_step("prefill", [seq], e)
-            return []
-        with self._phase("accept"):
-            self._note_aux("prefill", [seq])
-            self.sched.mark_prefilled(seq)
-            reg = self._reg()
-            reg.counter("serve.prefills").inc()
-            if seq.trace_id is not None:
-                # the (re-)prefill plus the queue wait before it; a
-                # recompute's wait is attributed to its cause, not "queue"
-                comp = _RESUME_COMPONENT.get(seq.resume_why, "prefill")
-                t_q0 = seq.trace_enqueued
-                if t_q0 is None:
-                    t_q0 = seq.arrival
-                if t_prefill0 > t_q0:
-                    requesttrace.emit_span(
-                        reg, seq.trace_id, seq.request_id, "queue",
-                        "queue" if seq.resume_why is None else comp,
-                        t_q0, t_prefill0, self._proc)
-                requesttrace.emit_span(reg, seq.trace_id, seq.request_id,
-                                       "prefill", comp, t_prefill0,
-                                       float(self.clock()), self._proc,
-                                       bucket=plan.bucket)
-            seq.resume_why = None
-            seq.trace_enqueued = None
-            if seq.pending is not None:
-                # recompute prefill after preemption: the next token was
-                # already sampled (and streamed) before eviction — only
-                # the KV was rebuilt; nothing new to emit
-                return []
-            return [self._accept_token(
-                seq, int(nxt_np[0]),
-                logits_np[0] if seq.capture_logits else None, first=True)]
-
-    def _run_decode(self, plan: StepPlan) -> List[Dict[str, Any]]:
-        seqs = plan.seqs
-        t0 = float(self.clock())
-        try:
-            nxt_np, logits_np = self._apply_decode(seqs)
-        except StepTimeout:
-            raise
-        except Exception as e:
-            rebuilt = self._rebuild_lost_pool()
-            if "decode" not in self._proven:
-                raise                  # never ran: not a request's fault
-            if rebuilt:
-                return []              # every row is queued for recompute
-            survivors = self._quarantine_step("decode", seqs, e)
-            if not survivors:
-                return []
-            # replay: the culprit rows are gone, every surviving row is
-            # re-run with the same pending tokens — per-row paged
-            # attention makes the survivors' logits identical to the
-            # un-faulted step's, and their tokens where they kept their
-            # rows (sampling draws a row's noise by its place in the batch)
-            return self._run_decode(StepPlan("decode", survivors))
-        with self._phase("accept"):
-            self._note_aux("decode", seqs)
-            reg = self._reg()
-            reg.counter("serve.decode_steps").inc()
-            reg.histogram("serve.decode_batch").observe(float(len(seqs)))
-            events = []
-            for i, s in enumerate(seqs):
-                self.sched.mark_decoded(s)
-                events.append(self._accept_token(
-                    s, int(nxt_np[i]),
-                    logits_np[i] if s.capture_logits else None,
-                    first=False))
-            # one batch-level decode span; the assembler amortizes the
-            # step across its residents to produce per-request decode time
-            requesttrace.emit_decode_span(
-                reg, [(s.request_id, s.trace_id) for s in seqs], len(seqs),
-                t0, float(self.clock()), self._proc)
-        return events
-
-    def _note_paged_blocks(self, lens: np.ndarray, tables: List[np.ndarray],
-                           seqs: List[SequenceState]):
-        """How much of a decode step's block tables is live: the pages
+    def _count_paged_blocks(self, unit: _Unit, lens: np.ndarray,
+                            tables: List[np.ndarray]) -> None:
+        """How much of a decode unit's block tables is live: the pages
         its rows hold against rows launched x table width, which is what
         a kernel that walked the whole table would visit.  Over a cache
         with a pool a kind of layer that is the first kind's, and each
-        kind's blocks under the step's rows are counted beside it
-        (``serve.kv_<kind>_blocks_live``)."""
-        tables, reg = tables[0], self._reg()
+        kind's blocks under the unit's rows are counted beside it.
+        Counted at the launch, where the tables are; booked at the
+        landing (:meth:`_book_paged_blocks`)."""
         if len(self.cache.pools) > 1:
             for kind, pool in self.cache.pools.items():
-                held = sum(len(pool.tables.get(s.request_id, ()))
-                           for s in seqs)
-                reg.counter(f"serve.kv_{kind}_blocks_live").inc(held)
-                self._kv_live[kind] = self._kv_live.get(kind, 0) + held
-                if pool.window is not None:
-                    freed = reg.counter(f"serve.kv_{kind}_blocks_freed")
-                    freed.inc(pool.freed_behind - freed.value)
-        live = int(np.sum(-(-lens // self.cache.block_size)))
-        reg.counter("serve.paged_blocks_live").inc(live)
-        reg.counter("serve.paged_blocks_table").inc(tables.size)
-        self._paged_blocks["live"] += live
-        self._paged_blocks["table"] += tables.size
-        if self._step_root is not None:
-            self._step_root.set(kv_blocks_live=live,
-                                kv_blocks_table=tables.size)
+                unit.blocks[kind] = sum(
+                    len(pool.tables.get(s.request_id, ()))
+                    for s in unit.seqs)
+        unit.attrs.update(
+            kv_blocks_live=int(np.sum(-(-lens // self.cache.block_size))),
+            kv_blocks_table=tables[0].size)
 
-    def _note_aux(self, kind: str, seqs: List[SequenceState]) -> None:
-        """Book what the step that just ran handed out beside its logits
-        (nothing for a model without ``aux``).  The model's
-        ``serving_counts`` says which counters the counts add to and
-        which gauges they set; the engine books them in the registry,
-        sums them for ``stats()["model_counts"]`` and sets the counters
-        on the step's span under the name after their last dot.  A
-        captured request keeps its rows of the per-token arrays."""
-        aux, self._step_aux = self._step_aux, {}
+    def _book_paged_blocks(self, unit: _Unit) -> None:
+        """``serve.paged_blocks_live`` / ``serve.paged_blocks_table`` and,
+        a kind, ``serve.kv_<kind>_blocks_live`` /
+        ``serve.kv_<kind>_blocks_freed`` of a landed decode unit."""
+        if not unit.attrs:
+            return
+        reg = self._reg()
+        for kind, held in unit.blocks.items():
+            reg.counter(f"serve.kv_{kind}_blocks_live").inc(held)
+            self._kv_live[kind] = self._kv_live.get(kind, 0) + held
+            pool = self.cache.pools[kind]
+            if pool.window is not None:
+                freed = reg.counter(f"serve.kv_{kind}_blocks_freed")
+                freed.inc(pool.freed_behind - freed.value)
+        live, table = (unit.attrs["kv_blocks_live"],
+                       unit.attrs["kv_blocks_table"])
+        reg.counter("serve.paged_blocks_live").inc(live)
+        reg.counter("serve.paged_blocks_table").inc(table)
+        self._paged_blocks["live"] += live
+        self._paged_blocks["table"] += table
+
+    def _note_aux(self, unit: _Unit, live: List[bool]) -> None:
+        """Book what a landed unit handed out beside its logits (nothing
+        for a model without ``aux``).  The model's ``serving_counts``
+        says which counters the counts add to and which gauges they set;
+        the engine books them in the registry, sums them for
+        ``stats()["model_counts"]`` and sets the counters on the step's
+        span under the name after their last dot.  A captured request
+        keeps its rows of the per-token arrays."""
+        aux, kind = unit.aux, unit.kind
         if not aux:
             return
-        if aux.get("counts"):
-            booked = self.model.serving_counts(aux["counts"], kind)
+        if unit.counts:
+            booked = self.model.serving_counts(unit.counts, kind)
             reg, mine = self._reg(), self._model_counts
             for name, n in booked.get("counters", {}).items():
                 reg.counter(name).inc(n)
@@ -1084,7 +1331,8 @@ class ServingEngine:
                 self._step_root.set(**{
                     name.rsplit(".", 1)[-1]: n
                     for name, n in booked.get("counters", {}).items()})
-        captured = [(i, s) for i, s in enumerate(seqs) if s.capture_logits]
+        captured = [(i, s) for i, s in enumerate(unit.seqs)
+                    if s.capture_logits and live[i]]
         if aux.get("per_token") and captured:
             host = {k: np.asarray(v) for k, v in aux["per_token"].items()}
             for i, s in captured:
@@ -1096,20 +1344,20 @@ class ServingEngine:
                                         for k, v in host.items()})
         # a re-prefill after preemption emits no logits row (the token
         # was sampled before eviction): nothing of its own to keep
-        emits = [(i, s) for i, s in captured
-                 if kind != "prefill" or s.pending is None]
+        emits = [(i, s) for i, s in captured if unit.emits[i]]
         if aux.get("per_logit") and emits:
             host = {k: np.asarray(v) for k, v in aux["per_logit"].items()}
             for i, s in emits:
                 s.per_logit.append({k: v[i] for k, v in host.items()})
 
     # -- poisoned-request quarantine ---------------------------------------
-    def _probe(self, seqs: List[SequenceState]) -> bool:
-        """Re-run the decode step on a subset; True when it faults.  A
-        probe rewrites its rows' pending slots with the values already
-        there and moves no mark, so probing is free to repeat."""
+    def _probe(self, seqs: List[SequenceState], number: int) -> bool:
+        """Re-run the decode unit on a subset, launch and landing back to
+        back; True when it faults.  A probe rewrites its rows' pending
+        slots with the values already there and moves no mark, so probing
+        is free to repeat."""
         try:
-            self._apply_decode(seqs)
+            self._arrive(self._launch("decode", seqs, 0, None, number))
         except StepTimeout:
             raise
         except Exception:
@@ -1117,7 +1365,8 @@ class ServingEngine:
             return True
         return False
 
-    def _bisect(self, seqs: List[SequenceState]) -> List[SequenceState]:
+    def _bisect(self, seqs: List[SequenceState],
+                number: int) -> List[SequenceState]:
         """Find the faulting sequence(s) by halving.  A passing half is
         exonerated (faults here are deterministic per-row).  When the
         whole group faults but neither half does, the fault is an
@@ -1127,17 +1376,19 @@ class ServingEngine:
         mid = len(seqs) // 2
         left, right = seqs[:mid], seqs[mid:]
         culprits: List[SequenceState] = []
-        if self._probe(left):
-            culprits += self._bisect(left)
-        if self._probe(right):
-            culprits += self._bisect(right)
+        if self._probe(left, number):
+            culprits += self._bisect(left, number)
+        if self._probe(right, number):
+            culprits += self._bisect(right, number)
         return culprits or seqs
 
     def _quarantine_step(self, kind: str, seqs: List[SequenceState],
-                         error: Exception) -> List[SequenceState]:
-        """Fault-boundary handler: identify the culprit rows, evict each
-        with ``reason="poisoned"`` and a durable record, return the
-        surviving sequences for replay."""
+                         error: Exception,
+                         number: int) -> List[SequenceState]:
+        """Fault-boundary handler: identify the culprit rows (probes run
+        under the faulted unit's ``number``), evict each with
+        ``reason="poisoned"`` and a durable record, return the surviving
+        sequences for replay."""
         with self._phase("quarantine"):
             t0 = float(self.clock())
             if isinstance(error, _NonfiniteLogits):
@@ -1147,7 +1398,7 @@ class ServingEngine:
                 culprits = list(seqs)
             else:
                 rebuilds = self.pool_rebuilds
-                culprits = self._bisect(seqs)
+                culprits = self._bisect(seqs, number)
                 if self.pool_rebuilds != rebuilds:
                     # a probe lost the pool: every row is queued for
                     # recompute, and the fault will show again there
@@ -1321,7 +1572,7 @@ class ServingEngine:
         deadline = (None if timeout is None
                     else time.monotonic() + timeout)
         while request_id not in self.sched.finished:
-            enforce(self.sched.has_work(),
+            enforce(self.has_work(),
                     f"{request_id}: unknown request (never submitted?)")
             if deadline is not None and time.monotonic() >= deadline:
                 raise CollectTimeout(
@@ -1377,9 +1628,13 @@ class ServingEngine:
     def begin_drain(self) -> None:
         """Stop admission without blocking: new ``submit()`` calls are
         refused, ``/healthz`` goes 503 ``draining``, but already-admitted
-        work keeps stepping.  Idempotent; ``drain()`` calls it first."""
+        work keeps stepping.  The unit in flight lands first (its events
+        come back with the next ``step()``'s), and an engine that is not
+        ``serving`` launches nothing ahead of a landing.  Idempotent;
+        ``drain()`` calls it first."""
         if self._state != "serving":
             return
+        self._early += self._settle()
         self._state = "draining"
         self.sched.admission_open = False
         c = self.sched.counts()
@@ -1590,6 +1845,10 @@ class ServingEngine:
         return {
             "steps": self.steps,
             "phases": phases,
+            # units launched, how many of them while another was unread,
+            # what running ahead cost and why it did not engage
+            "ahead": dict(self._ahead,
+                          ahead_breaks=dict(self._ahead["ahead_breaks"])),
             "logits_fetch_steps": self._logits_fetch_steps,
             "replica_id": self.replica_id,
             "queue_depth": self.sched.queue_depth,
@@ -1648,7 +1907,10 @@ class ServingEngine:
         }
 
     def defrag(self) -> bool:
-        """Compact the KV pool (see ``PagedKVCache.defrag``)."""
+        """Compact the KV pool (see ``PagedKVCache.defrag``), once the
+        unit in flight has landed (its events come back with the next
+        ``step()``'s): the tables renumbered here are the landed ones."""
+        self._early += self._settle()
         return self.cache.defrag()
 
     def start_status_server(self, port: int = 0, host: str = "0.0.0.0"):
@@ -1661,6 +1923,11 @@ class ServingEngine:
         return self.status_server
 
     def stop(self) -> None:
+        """Land what is in flight (its tokens reach the callbacks and
+        ``collect()``), then stop the callback thread, the watchdog and
+        the status server."""
+        if self._state != "stopped":
+            self._early += self._settle()
         self._stop_callbacks(timeout=1.0)
         if self._owns_watchdog and self._watchdog is not None:
             self._watchdog.close()

@@ -45,7 +45,12 @@ query can still attend: growing a sequence past a block's reach frees it,
 a prefill writes a prompt's last blocks only, and the table the step is
 handed is a ring of ``window_table_width`` entries (block ``b`` of the
 sequence at column ``b % width``).  A layout of one kind is one pool, one
-table, one slot matrix: what it was before kinds.
+table, one slot matrix: what it was before kinds.  While the engine plans a
+unit ahead of a landing (ISSUE 36) the blocks let go behind a window are
+HELD, not freed: the unit in flight may fault and be replayed, and its
+replay attends them.  They are freed when that unit has landed
+(:meth:`PagedKVCache.release_held`) or go back to the front of their tables
+when it has not (:meth:`PagedKVCache.restore_held`).
 
 Page layout: token-major, ``pages[block, offset, ...]``.  One token's
 slab is the minor tile (a ``(16, 128)`` bf16 slab is exactly one 4 KB
@@ -341,6 +346,9 @@ class _Pool:
         self.tables: Dict[object, List[int]] = {}
         self.first: Dict[object, int] = {}
         self.freed_behind = 0
+        # (sequence, blocks) let go behind a window while a unit that may
+        # have to be replayed was in flight, oldest first
+        self.held: List[Tuple[object, List[int]]] = []
         self.table_width = (None if window is None
                             else window_table_width(window, block_size))
 
@@ -353,10 +361,11 @@ class _Pool:
             return 0, hi
         return max(0, n - self.window) // self.block_size, hi
 
-    def settle(self, seq_id, num_tokens: int) -> int:
+    def settle(self, seq_id, num_tokens: int, hold: bool = False) -> int:
         """Free the blocks no query at or after ``num_tokens - 1`` can
-        attend; returns how many more the sequence needs to cover
-        ``num_tokens``."""
+        attend (``hold``: take them off the table and keep them back until
+        :meth:`release_held` or :meth:`restore_held`); returns how many
+        more the sequence needs to cover ``num_tokens``."""
         lo, hi = self.span(num_tokens)
         table = self.tables.get(seq_id)
         if not table:
@@ -364,13 +373,40 @@ class _Pool:
         first = self.first[seq_id]
         if lo > first:
             drop = min(len(table), lo - first)
-            self.allocator.free(table[:drop])
+            if hold:
+                self.held.append((seq_id, table[:drop]))
+            else:
+                self.allocator.free(table[:drop])
+                self.freed_behind += drop
             del table[:drop]
             first = self.first[seq_id] = first + drop
-            self.freed_behind += drop
             if not table:
                 return hi - lo
         return hi - first - len(table)
+
+    def release_held(self) -> None:
+        """The unit that might have needed them has landed."""
+        for _, blocks in self.held:
+            self.allocator.free(blocks)
+            self.freed_behind += len(blocks)
+        self.held.clear()
+
+    def restore_held(self) -> None:
+        """That unit is to be replayed: the blocks go back to the front of
+        their tables (newest first, as they came off), and what a table
+        then holds beyond the ring's width, grown for the unit that is
+        dropped, is freed.  A sequence that is gone frees its own."""
+        for seq_id, blocks in reversed(self.held):
+            table = self.tables.get(seq_id)
+            if table is None:
+                self.allocator.free(blocks)
+                continue
+            table[:0] = blocks
+            self.first[seq_id] -= len(blocks)
+            if len(table) > self.table_width:
+                self.allocator.free(table[self.table_width:])
+                del table[self.table_width:]
+        self.held.clear()
 
     def grow(self, seq_id, num_tokens: int, need: int) -> None:
         table = self.tables.setdefault(seq_id, [])
@@ -387,7 +423,8 @@ class _Pool:
 
     def report(self) -> Dict[str, object]:
         report = self.allocator.stats()
-        tabled = sum(len(t) for t in self.tables.values())
+        tabled = (sum(len(t) for t in self.tables.values())
+                  + sum(len(b) for _, b in self.held))
         report["live_seqs"] = len(self.tables)
         report["tabled_blocks"] = tabled
         report["leaked_blocks"] = int(report["num_used"]) - tabled
@@ -593,11 +630,13 @@ class PagedKVCache:
     def live_seqs(self) -> List[object]:
         return list(self._main.tables)
 
-    def ensure_capacity(self, seq_id, num_tokens: int) -> bool:
+    def ensure_capacity(self, seq_id, num_tokens: int,
+                        hold: bool = False) -> bool:
         """Grow ``seq_id``'s tables to cover ``num_tokens`` cache slots,
         letting go first of the window blocks that no query from there on
-        can attend; False (nothing taken) when a pool cannot supply the
-        growth."""
+        can attend (``hold``: a unit in flight may still be replayed over
+        them, so they are kept back, see :meth:`release_held`); False
+        (nothing taken) when a pool cannot supply the growth."""
         pools = self._pool_list
         if len(pools) == 1 and self._main.window is None:
             # a decode step's usual case in a cache of one kind: the table
@@ -606,7 +645,7 @@ class PagedKVCache:
             if table is not None and \
                     num_tokens <= len(table) * self.block_size:
                 return True
-        need = [pool.settle(seq_id, num_tokens) for pool in pools]
+        need = [pool.settle(seq_id, num_tokens, hold) for pool in pools]
         for pool, n in zip(pools, need):
             if n > 0 and not pool.allocator.can_alloc(n):
                 return False
@@ -614,6 +653,20 @@ class PagedKVCache:
             if n > 0 or seq_id not in pool.tables:
                 pool.grow(seq_id, num_tokens, n)
         return True
+
+    def release_held(self) -> None:
+        """Free the window blocks held back while a unit was planned ahead
+        of a landing: the unit in flight then has landed."""
+        for pool in self._pool_list:
+            if pool.held:
+                pool.release_held()
+
+    def restore_held(self) -> None:
+        """Give them back to their sequences instead: the unit in flight
+        then faulted, and its replay attends them."""
+        for pool in self._pool_list:
+            if pool.held:
+                pool.restore_held()
 
     def holds(self, num_tokens: int) -> bool:
         """Whether the pools, empty, could hold a sequence of

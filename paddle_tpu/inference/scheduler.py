@@ -46,6 +46,18 @@ lifecycle single-exit.
   therefore come from a small closed set, and the PR 4 compile tracker
   sees exactly one compilation per bucket — no retrace storms from
   ragged traffic.
+- **One unit ahead of the accept** (ISSUE 36): the engine plans and
+  launches unit n+1 before it reads unit n, so what the NEXT plan needs
+  moves at launch (:meth:`mark_launched`: ``computed_len`` of the launched
+  rows, ``in_flight`` of the rows whose token the unit samples) and what
+  depends on the token at landing (``output``, ``pending``, finishing,
+  the freeing of blocks).  ``schedule(ahead=True)`` plans with a unit
+  unread: a row whose output reaches ``max_new_tokens`` with what is in
+  flight is left out of the decode batch, blocks let go behind a window
+  are held back until that unit has landed (``kv_cache._Pool.settle``),
+  and a plan that would have to preempt is not made (``StepPlan("wait")``:
+  the engine lands first, so that victims are chosen from real state).
+  :meth:`unmark` and :meth:`unadmit` take a launch back.
 """
 from __future__ import annotations
 
@@ -88,8 +100,15 @@ class SequenceState:
       decode step — prompt + generated output *except* ``pending`` (the
       newest sampled token, whose KV is written by the step that feeds
       it back in);
-    - ``computed_len``: cache entries currently on device for this
-      sequence (0 after preemption — recompute rebuilds them).
+    - ``computed_len``: cache entries on device for this sequence once
+      every unit launched so far has run (it moves at launch; 0 after
+      preemption — recompute rebuilds them);
+    - ``in_flight``: tokens that launched units sample for this sequence
+      and the host has not read yet.  While it is not 0, ``pending`` is
+      the newest token that LANDED (None before the first): the unit in
+      flight already feeds it, or takes the id it has to feed from the
+      previous program's output on the device, and ``output`` grows only
+      as units land.
     """
     request_id: str
     prompt: List[int]
@@ -109,6 +128,7 @@ class SequenceState:
     output: List[int] = dataclasses.field(default_factory=list)
     pending: Optional[int] = None       # sampled, KV not yet cached
     computed_len: int = 0
+    in_flight: int = 0                  # sampled by launched units, unread
     logits: List = dataclasses.field(default_factory=list)
     # under capture_logits, what the model hands out a token beside the
     # logits (an expert layer's choices, say), a step at a time
@@ -143,6 +163,11 @@ class SequenceState:
     def total_len(self) -> int:
         return len(self.prompt) + len(self.output)
 
+    def fills_up_in_flight(self) -> bool:
+        """Whether the tokens in flight bring the output to
+        ``max_new_tokens``: the one finish that is known at launch."""
+        return len(self.output) + self.in_flight >= self.max_new_tokens
+
     def should_finish(self) -> Optional[str]:
         if (self.eos_token_id is not None and self.output
                 and self.output[-1] == self.eos_token_id):
@@ -155,7 +180,9 @@ class SequenceState:
 @dataclasses.dataclass
 class StepPlan:
     """What the engine should run this step."""
-    kind: str                               # "prefill" | "decode" | "idle"
+    # "prefill" | "decode" | "idle" | "wait" (planned ahead of a landing:
+    # growing a table would have to preempt, so land first)
+    kind: str
     seqs: List[SequenceState]
     bucket: int = 0                         # prefill pad length
     preempted: List[SequenceState] = dataclasses.field(default_factory=list)
@@ -165,9 +192,11 @@ class ContinuousBatchingScheduler:
     """Admission / preemption / interleaving policy over a
     :class:`PagedKVCache`'s allocator.
 
-    The engine loop is ``plan = schedule(); run(plan); feedback via
-    mark_prefilled / mark_decoded / complete``.  The scheduler owns the
-    queues and the block accounting; it never touches device arrays.
+    The engine loop is ``plan = schedule(); launch(plan);
+    mark_launched(plan)`` and, a unit later, the landing: ``complete`` for
+    what finished, or ``unmark`` / ``unadmit`` for a unit that is dropped
+    unread.  The scheduler owns the queues and the block accounting; it
+    never touches device arrays.
     """
 
     def __init__(self, cache: PagedKVCache, max_seqs: int,
@@ -213,12 +242,17 @@ class ContinuousBatchingScheduler:
         return bool(self.waiting or self.running)
 
     # -- the per-step decision ---------------------------------------------
-    def schedule(self) -> StepPlan:
+    def schedule(self, ahead: bool = False) -> StepPlan:
         """Pick this step's work: one prefill when a waiting sequence
         fits the block budget and a batch slot, else one decode batch
         over the running set (preempting on next-token OOM), else idle.
         Prefill-first keeps TTFT low under load; decode throughput costs
-        at most one interleaved step per admission."""
+        at most one interleaved step per admission.
+
+        ``ahead``: a launched unit has not landed.  Rows that it fills up
+        to ``max_new_tokens`` are left out, window blocks behind a row are
+        held back for its replay, and where a table cannot grow without a
+        victim the plan is ``"wait"``."""
         plan_preempted: List[SequenceState] = []
 
         if (self.waiting and len(self.running) < self.max_seqs
@@ -239,11 +273,15 @@ class ContinuousBatchingScheduler:
             for seq in list(self.running):
                 if seq.state != RUNNING:
                     continue      # already preempted as a victim above
+                if seq.fills_up_in_flight():
+                    continue      # its last token is on its way
                 # a decode step writes the pending token's KV at position
                 # computed_len — grow the table to cover it, preempting
                 # newest-admitted sequences on OOM
                 while not self.cache.ensure_capacity(
-                        seq.request_id, seq.computed_len + 1):
+                        seq.request_id, seq.computed_len + 1, hold=ahead):
+                    if ahead:
+                        return StepPlan("wait", [])
                     victim = self.running[-1]
                     self._preempt(victim)
                     plan_preempted.append(victim)
@@ -273,12 +311,14 @@ class ContinuousBatchingScheduler:
     def preempt_all(self) -> List[SequenceState]:
         """Evict every running sequence back to the queue (recompute) —
         the engine's hang-recovery path.  Device-side work in flight is
-        abandoned; host state stays consistent because engine feedback
-        (``mark_*``) only lands after a step returns.  Newest-first so
+        abandoned, read or not; host state stays consistent because a
+        preempted sequence starts over from its landed tokens
+        (``computed_len`` 0, nothing in flight).  Newest-first so
         re-admission replays in the original admission order."""
         victims = list(reversed(self.running))
         for seq in victims:
             self._preempt(seq)
+            seq.in_flight = 0
         return victims
 
     # -- engine feedback ---------------------------------------------------
@@ -287,6 +327,46 @@ class ContinuousBatchingScheduler:
 
     def mark_decoded(self, seq: SequenceState) -> None:
         seq.computed_len += 1
+
+    def mark_launched(self, kind: str, seqs: Sequence[SequenceState],
+                      emits: Sequence[bool]) -> List[tuple]:
+        """What the next plan needs of a unit, moved as the unit is
+        launched: the rows' ``computed_len``, and ``in_flight`` of the rows
+        whose next token it samples (``emits``: every decode row; a
+        prefill's unless it recomputes a sequence whose next token was
+        sampled before).  Returns what :meth:`unmark` needs to take it
+        back."""
+        marks = []
+        for seq, e in zip(seqs, emits):
+            marks.append((seq, seq.computed_len, bool(e)))
+            if kind == "prefill":
+                self.mark_prefilled(seq)
+            else:
+                self.mark_decoded(seq)
+            seq.in_flight += bool(e)
+        return marks
+
+    def unmark(self, marks: List[tuple]) -> None:
+        """Take back :meth:`mark_launched` of a unit that will not be
+        accepted (dropped unread, or faulted and about to be replayed).
+        Units are taken back newest first."""
+        for seq, computed_len, emits in marks:
+            if seq.state == RUNNING:
+                seq.computed_len = computed_len
+                seq.in_flight = max(0, seq.in_flight - emits)
+
+    def unadmit(self, seq: SequenceState) -> None:
+        """Send a sequence whose prefill is dropped unread back to the
+        head of the queue with its blocks returned: it was never
+        prefilled, so this is no preemption."""
+        if seq.state != RUNNING:
+            return
+        self.running.remove(seq)
+        self.cache.free_seq(seq.request_id)
+        seq.computed_len = 0
+        seq.in_flight = 0
+        seq.state = PREEMPTED if seq.preemptions else WAITING
+        self.waiting.appendleft(seq)
 
     def complete(self, seq: SequenceState, reason: str) -> None:
         """Evict a finished sequence: free its blocks immediately so the

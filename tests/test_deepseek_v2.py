@@ -225,7 +225,7 @@ def test_named_scopes_of_the_new_device_parts(served):
             np.ones((rows,)), np.zeros((rows, chunk)))
         text = eng._build_step_fn().lower(
             eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
-            rows=rows, chunk=chunk).as_text(debug_info=True)
+            eng._no_prev, rows=rows, chunk=chunk).as_text(debug_info=True)
         return text
     decode, prefill = names(4, 1), names(1, 8)
     for scope in ("mla.q", "mla.kv_write", "mla.decode", "moe.route",

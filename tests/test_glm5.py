@@ -241,7 +241,7 @@ def test_named_scopes_of_the_new_device_parts(served):
             np.ones((rows,)), np.zeros((rows, chunk)))
         return eng._build_step_fn().lower(
             eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
-            rows=rows, chunk=chunk).as_text(debug_info=True)
+            eng._no_prev, rows=rows, chunk=chunk).as_text(debug_info=True)
     decode, prefill = text(4, 1), text(1, 16)
     for scope in ("dsa.index_q", "dsa.index_k_write", "dsa.index_scores",
                   "dsa.select", "dsa.attend", "mla.q", "mla.kv_write",

@@ -353,7 +353,7 @@ class TestGPTScopes:
                                   tables, lens, slots)
         text = eng._build_step_fn().lower(
             eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
-            rows=2, chunk=1).as_text(debug_info=True)
+            eng._no_prev, rows=2, chunk=1).as_text(debug_info=True)
         names = set(re.findall(r'loc\("([^"]+)"', text))
         for scope in ("gpt.embed", "gpt.block/attn/", "gpt.block/mlp/",
                       "gpt.head/"):

@@ -882,12 +882,14 @@ class TestPoolInPlace:
             np.ones((rows,)), np.zeros((rows, chunk)))
         compiled = eng._build_step_fn().lower(
             eng._params, packed, eng.cache.pages, jax.random.PRNGKey(0),
-            rows=rows, chunk=chunk).compile()
+            eng._no_prev, rows=rows, chunk=chunk).compile()
         header = compiled.as_text().split("input_output_alias={", 1)[1]
         header = header.split("entry_computation_layout", 1)[0]
         aliased = [int(p) for p in re.findall(r"\((\d+), \{\}", header)]
         flat, _ = jax.tree_util.tree_flatten(eng._params)
-        first = len(flat) + 1                # params, the packed inputs
+        # params, the packed inputs; the key and the previous program's
+        # tokens come after the pages
+        first = len(flat) + 1
         n = sum(len(layer) for layer in eng.cache.pages)
         assert n == {"gpt": 2, "deepseek_v2": 1}[family] * 2    # layers
         assert sorted(aliased) == list(range(first, first + n))
@@ -896,8 +898,10 @@ class TestPoolInPlace:
     def test_a_step_consumes_the_old_handles_and_holds_one_pool(self):
         eng = self.engine()
         pool = eng.cache.pool_bytes()
-        eng.submit([1, 2, 3], max_new_tokens=3)
-        for _ in range(3):                   # a prefill, then decodes
+        eng.submit([1, 2, 3], max_new_tokens=4)
+        # every call launches a unit (the first two: a prefill, then
+        # decodes), and whatever is launched consumes the handles
+        for _ in range(3):
             old = [a for kv in eng.cache.pages for a in kv]
             eng.step()
             assert all(a.is_deleted() for a in old)
@@ -964,15 +968,21 @@ class TestStepSpans:
         root, kids = self.step_spans(tracing, step)
         assert {k[0].split("/", 1)[1] for k in kids} == PHASES
         attrs = root[3]
+        # the root's attributes are the LANDED unit's, and the kind of
+        # the unit launched ahead of that landing
         assert attrs["kind"] == kind and attrs["rows"] == 1
         assert attrs["bucket"] == (8 if kind == "prefill" else 0)
-        # children lie inside the root, in the order the step runs them
+        assert attrs["ahead_kind"] == "decode"
+        # children lie inside the root, in the order the step runs them:
+        # the launch of the next unit, then the landing of the one in
+        # flight; the first call of a busy stretch launches both
         assert all(root[1] <= k[1] and k[2] <= root[2] for k in kids)
         order = [k[0].split("/", 1)[1]
                  for k in sorted(kids, key=lambda k: k[1])]
-        assert order == ["reap", "schedule", "tables", "h2d", "dispatch",
-                         "device_wait", "logits_copy", "guard", "accept",
-                         "accept", "gauges"]
+        launch = ["schedule", "tables", "h2d", "dispatch"]
+        assert order == (["reap"] + launch * (2 if kind == "prefill" else 1)
+                         + ["device_wait", "logits_copy", "guard", "accept",
+                            "accept", "gauges"])
         # the phases' self times make up the step within 2%
         total = root[2] - root[1]
         covered = sum(k[2] - k[1] for k in kids)
@@ -991,17 +1001,20 @@ class TestStepSpans:
         tree = tracing.span_tree_totals()
         for name, row in phases.items():
             assert row == tree["engine.step/" + name]
-            want = {"accept": 6}.get(name, 3)      # one dispatch a step
+            # one dispatch a unit; the first call plans two units and the
+            # last finds none to plan
+            want = {"accept": 6, "schedule": 4}.get(name, 3)
             assert row["count"] == want, name
             assert 0 <= row["self_ms"] <= row["total_ms"]
         root = tree["engine.step"]
         assert root["self_ms"] <= 0.02 * root["total_ms"]
         assert phases["guard"]["total_ms"] >= 3 * 50 * 0.99
         # int32 everywhere: ids, positions, last index, tables (8 blocks a
-        # sequence), lengths, slots, the step's number
+        # sequence), lengths, slots, where each row's id comes from, the
+        # step's number
         i32 = 4
-        prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8 + 1)      # bucket 8, 1 row
-        decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2 + 1)   # 2 slots
+        prefill = i32 * (8 + 1 + 1 + 8 + 1 + 8 + 1 + 1)  # bucket 8, 1 row
+        decode = i32 * (2 + 2 + 1 + 2 * 8 + 2 + 2 + 2 + 1)   # 2 slots
         assert h2d.value - h0 == prefill + 2 * decode
         # next tokens (int32), a finite flag a row and, since this engine's
         # fault seam is set, float32 logits over the vocabulary; with them
@@ -1178,8 +1191,8 @@ class TestWhatCrossesTheBoundary:
         tables = [draw(rows, w) for w in widths]
         slots = [draw(rows, chunk) for _ in widths]
         parts = [draw(rows, chunk), draw(rows), draw(), tables, draw(rows),
-                 slots, draw()]
-        packed = pack_step_inputs(*parts)
+                 slots, draw(rows), draw()]
+        packed = pack_step_inputs(*parts[:6], src=parts[6], step=parts[7])
         flat = [a for x in parts for a in (x if isinstance(x, list) else [x])]
         assert packed.nbytes == sum(a.nbytes for a in flat)
         assert np.array_equal(packed, np.concatenate(
@@ -1235,10 +1248,12 @@ class TestWhatCrossesTheBoundary:
         seq_lens = np.asarray(lens, np.int32)
         packed = pack_step_inputs(ids, positions, 0, [tables], seq_lens,
                                   [slots], step=5)
+        # ... and, since ISSUE 36, a word a row that says where its id
+        # comes from (-1: this buffer), ahead of the step's number
         want = np.concatenate([
             np.asarray(a, np.int32).reshape(-1)
             for a in (ids, positions, 0, want_tables, seq_lens, want_slots,
-                      5)])
+                      np.full((rows,), -1), 5)])
         assert packed.tobytes() == want.tobytes()
         assert cache.pool_bytes() == sum(
             a.nbytes for layer in cache.pages for a in layer)
@@ -1250,8 +1265,8 @@ class TestWhatCrossesTheBoundary:
         rows, chunk, width = 3, 5, 7
         parts = [rng.integers(0, 99, shape).astype(np.int32)
                  for shape in ((rows, chunk), (rows,), (), (rows, width),
-                               (rows,), (rows, chunk), ())]
-        packed = pack_step_inputs(*parts)
+                               (rows,), (rows, chunk), (rows,), ())]
+        packed = pack_step_inputs(*parts[:6], src=parts[6], step=parts[7])
         assert packed.dtype == np.int32 and packed.ndim == 1
         assert packed.nbytes == sum(a.nbytes for a in parts)
         for got, want in zip(unpack_step_inputs(packed, rows, chunk), parts):
@@ -1302,11 +1317,15 @@ class TestWhatCrossesTheBoundary:
         want, want_logits = reference(
             eng._params, ids, positions, np.asarray(last, np.int32),
             eng.cache.pages, tables, lens, slots)
-        nxt, finite, logits, pages, _ = eng._build_step_fn()(
+        nxt, finite, logits, pages, _, carry = eng._build_step_fn()(
             eng._params,
             pack_step_inputs(ids, positions, last, tables, lens, slots),
-            eng.cache.pages, jax.random.PRNGKey(0), rows=rows, chunk=chunk)
+            eng.cache.pages, jax.random.PRNGKey(0), eng._no_prev,
+            rows=rows, chunk=chunk)
         eng.cache.update_pages(pages)
+        # the tokens as the program launched next takes them
+        assert carry.shape == (eng.max_seqs,) and carry.dtype == jnp.int32
+        assert np.asarray(carry)[:rows].tolist() == np.asarray(nxt).tolist()
         assert np.asarray(nxt).tolist() == np.asarray(want).tolist()
         np.testing.assert_allclose(np.asarray(logits),
                                    np.asarray(want_logits, np.float32),
@@ -1424,9 +1443,9 @@ class TestTheKeyIsData:
         assert pack_step_inputs(*parts)[-1] == 0
 
         def tokens(key, step):
-            nxt, _, _, pages, _ = fn(
+            nxt, _, _, pages, _, _ = fn(
                 eng._params, pack_step_inputs(*parts, step=step),
-                eng.cache.pages, key, rows=4, chunk=1)
+                eng.cache.pages, key, eng._no_prev, rows=4, chunk=1)
             eng.cache.update_pages(pages)
             return np.asarray(nxt).tolist()
         key, other = jax.random.PRNGKey(0), jax.random.PRNGKey(1)

@@ -297,7 +297,7 @@ class TestConsumedPool:
         def prepare(eng):
             real = eng._build_step_fn()
 
-            def flaky(params, packed, pages, key, *, rows, chunk):
+            def flaky(params, packed, pages, key, prev, *, rows, chunk):
                 if (chunk == 1) == (kind == "decode"):
                     calls["n"] += 1
                     if calls["n"] == nth:
@@ -306,7 +306,7 @@ class TestConsumedPool:
                             for a in kv:
                                 a.delete()
                         raise RuntimeError("device lost mid-step")
-                return real(params, packed, pages, key, rows=rows,
+                return real(params, packed, pages, key, prev, rows=rows,
                             chunk=chunk)
             eng._jit_step = flaky
 
@@ -325,7 +325,7 @@ class TestConsumedPool:
     def test_a_first_run_that_dies_with_the_pool_still_propagates(self):
         eng = make_engine(tiny_model(), max_seqs=2, kv_block_size=4)
 
-        def dies(params, packed, pages, key, **program):
+        def dies(params, packed, pages, key, prev, **program):
             pages[0][0].delete()
             raise RuntimeError("device lost on the first step")
         eng._jit_step = dies
@@ -370,12 +370,12 @@ class TestConsumedPool:
         def prepare(eng):
             real = eng._build_step_fn()
 
-            def flaky(params, packed, pages, key, *, rows, chunk):
+            def flaky(params, packed, pages, key, prev, *, rows, chunk):
                 if state["armed"] and not state["died"]:
                     state["died"] = 1
                     pages[0][0].delete()
                     raise RuntimeError("device lost in a probe")
-                return real(params, packed, pages, key, rows=rows,
+                return real(params, packed, pages, key, prev, rows=rows,
                             chunk=chunk)
             eng._jit_step = flaky
 

@@ -440,6 +440,58 @@ def test_fused_block_kernels_on_tpu():
 # ---------------------------------------------------------------------------
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
+_LAUNCH_AHEAD_SCRIPT = r"""
+# ISSUE 36: a launch issued while the previous program runs does not wait
+# for the device.  The benchmark's own gpt3-xl engine (its builder, its
+# configuration, the backlog cell's engine arguments, so the programs are
+# the ones the cells compile), 32 rows of 512-token prompts: a decode unit
+# keeps the device busy for several milliseconds, the bare jitted call
+# takes about one.
+import json
+from perfbench.harness.manifest import Manifest
+m = Manifest(None, [])
+config = m.load_config("gpt3-xl")
+traffic = m.load_traffic("doc-backlog")
+system = m.load_entry(config["entry"])(config, 36)
+eng = system.build_for_serving(traffic["engine"])
+rng = np.random.default_rng(36)
+vocab = system.shape["vocab"]
+for _ in range(32):
+    eng.submit(rng.integers(0, vocab, 500).tolist(), max_new_tokens=96)
+for _ in range(32 + 8):                     # the prefills, a few decodes
+    eng.step()
+
+def phases():
+    ph = eng.stats()["phases"]
+    return {k: (ph[k]["count"], ph[k]["total_ms"])
+            for k in ("dispatch", "device_wait", "h2d", "tables")}
+
+a0, p0 = dict(eng.stats()["ahead"]), phases()
+calls = 64
+for _ in range(calls):
+    assert len(eng.step()) == 32
+a1, p1 = eng.stats()["ahead"], phases()
+mean = {k: (p1[k][1] - p0[k][1]) / (p1[k][0] - p0[k][0]) for k in p0}
+launched = a1["units_launched"] - a0["units_launched"]
+ahead = a1["units_ahead"] - a0["units_ahead"]
+print("launch-ahead", json.dumps({"mean_ms": mean, "launched": launched,
+                                  "ahead": ahead}))
+assert launched == ahead == calls, (launched, ahead)
+# every one of these launches found the previous program still running
+# (the landing after it waited for that program longer than the launch
+# took), and returned in about the bare call's time
+assert mean["device_wait"] > 2.0 * mean["dispatch"], mean
+assert mean["dispatch"] < 3.0, mean
+eng.stop()
+print("launch-ahead-ok")
+"""
+
+
+def test_a_launch_ahead_does_not_wait_for_the_device_on_tpu():
+    _require_tpu()
+    _run(_LAUNCH_AHEAD_SCRIPT, "launch-ahead-ok", timeout=900)
+
+
 _IMPORTS = r"""
 import paddle_tpu, paddle_tpu.distributed.launch, paddle_tpu.inference.fleet
 import paddle_tpu.bench
@@ -556,14 +608,15 @@ for tag, LAYERS, HEADS, DIM, BLOCKS, BS, SEQS, LEN in (
     width = LEN // BS
     for name, rows, chunk in (("serve_decode", SEQS, 1),
                               ("serve_prefill_b512", 1, 512)):
-        # ids, positions, last index, tables, lengths, slots, the step's
-        # number: one buffer
-        packed = S((2 * rows * chunk + 2 * rows + 2 + rows * width,),
+        # ids, positions, last index, tables, lengths, slots, where each
+        # row's id comes from, the step's number: one buffer; and the
+        # previous program's tokens
+        packed = S((2 * rows * chunk + 3 * rows + 2 + rows * width,),
                    jnp.int32)
         c = eng._build_step_fn().lower(
             abstract(eng._params), packed, pages,
-            abstract(jax.random.PRNGKey(0)), rows=rows,
-            chunk=chunk).compile()
+            abstract(jax.random.PRNGKey(0)), S((SEQS,), jnp.int32),
+            rows=rows, chunk=chunk).compile()
         text, ma = c.as_text(), c.memory_analysis()
         # an operation whose result is a whole page array, other than the
         # in-place write and what it is fused into
@@ -698,11 +751,11 @@ for name, rows, chunk in (("serve_decode", SEQS, 1),
                           ("serve_prefill_b1024", 1, 1024)):
     # ids, positions, last index, tables, lengths, slots, the step's
     # number: one buffer
-    packed = S((2 * rows * chunk + 2 * rows + 2 + rows * (LEN // BS),),
+    packed = S((2 * rows * chunk + 3 * rows + 2 + rows * (LEN // BS),),
                jnp.int32)
     c = eng._build_step_fn().lower(
-        params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=rows,
-        chunk=chunk).compile()
+        params, packed, pages, abstract(jax.random.PRNGKey(0)),
+        S((SEQS,), jnp.int32), rows=rows, chunk=chunk).compile()
     text, ma = c.as_text(), c.memory_analysis()
     header = text.split("input_output_alias={", 1)[1].split(
         "entry_computation_layout", 1)[0]
@@ -788,10 +841,10 @@ abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
 pages = [(S((BLOCKS, BS, 640), jnp.bfloat16),
           S((BLOCKS, BS, 128), jnp.bfloat16))] * 5
 params = abstract(eng._params)
-packed = S((2 * SEQS + 2 * SEQS + 2 + SEQS * (LEN // BS),), jnp.int32)
+packed = S((2 * SEQS + 3 * SEQS + 2 + SEQS * (LEN // BS),), jnp.int32)
 c = eng._build_step_fn().lower(
-    params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=SEQS,
-    chunk=1).compile()
+    params, packed, pages, abstract(jax.random.PRNGKey(0)),
+    S((SEQS,), jnp.int32), rows=SEQS, chunk=1).compile()
 text, ma = c.as_text(), c.memory_analysis()
 header = text.split("input_output_alias={", 1)[1].split(
     "entry_computation_layout", 1)[0]
@@ -884,10 +937,10 @@ for name, rows, chunk in (("serve_decode", SEQS, 1),
     # ids, positions, last index, a table a kind, lengths, slots a kind,
     # the step's number: one buffer
     packed = S((rows * chunk + rows + 1 + rows * sum(widths) + rows
-                + 2 * rows * chunk + 1,), jnp.int32)
+                + 2 * rows * chunk + rows + 1,), jnp.int32)
     c = eng._build_step_fn().lower(
-        params, packed, pages, abstract(jax.random.PRNGKey(0)), rows=rows,
-        chunk=chunk).compile()
+        params, packed, pages, abstract(jax.random.PRNGKey(0)),
+        S((SEQS,), jnp.int32), rows=rows, chunk=chunk).compile()
     text, ma = c.as_text(), c.memory_analysis()
     header = text.split("input_output_alias={", 1)[1].split(
         "entry_computation_layout", 1)[0]
