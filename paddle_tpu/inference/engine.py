@@ -59,8 +59,11 @@ behind the window),
 gauge ``serve.kv_pool_bytes`` (the live page handles: one pool).  Every
 ``step()`` is an ``engine.step`` span of :mod:`observability.tracing`
 whose children name its phases (see :meth:`ServingEngine.step`);
-``stats()["phases"]`` sums them.  ``start_status_server()`` exposes them
-on the
+``stats()["phases"]`` sums them; a request's wait in the queue is one
+``engine.request/queue`` span, recorded when its first prefill is
+launched; the unit ledger's sums (what the device starved for, how long
+the host waited for it) are ``stats()["units"]`` and nowhere else.
+``start_status_server()`` exposes them on the
 PR 5 monitor (``/statusz`` serving section; ``/healthz`` goes 503 when
 the admission queue exceeds ``PTPU_SHED_QUEUE_DEPTH`` — load shedding).
 
@@ -140,6 +143,7 @@ import jax.numpy as jnp
 from ..framework.errors import enforce
 from ..observability import requesttrace
 from ..observability.compilation import track_jit
+from ..observability.tracing import record as record_span
 from ..observability.tracing import span, span_tree_totals
 from ..supervisor.watchdog import StepTimeout, Watchdog, guarded
 from ..utils import fsio
@@ -162,6 +166,22 @@ DEADLINE_MS_ENV = "PTPU_SERVE_DEADLINE_MS"
 DRAIN_SECS_ENV = "PTPU_SERVE_DRAIN_SECS"
 
 _PAD_SEQ = "__pad__"          # never a real request id
+# a ``device_wait`` no longer than this found the device done already (the
+# unit is ``late``: the HOST was the slower of the two).  The span and
+# ``block_until_ready`` of arrays that ARE ready cost 2.5 us back to back
+# on a v5e's host (p99 5.5-9.8) and, after 5 ms of sleep, 18-23 us (p90
+# 29-62, p99 51-100; ``perfbench/tools/ledger_cost.py``, PERF.md PR 37)
+LATE_EPS_S = 100e-6
+# why the device had nothing of this engine's to run before a unit that was
+# launched with nothing in flight: ``ahead_breaks``' words, and the first
+# unit of the engine's life
+_STARVED_WHY = ("start", "idle", "preempt", "fault", "drain")
+
+
+def _unit_sums() -> Dict[str, Any]:
+    """What the ledger sums over landed units of one kind (or bucket)."""
+    return {"units": 0, "rows": 0, "device_s": 0.0, "units_bound": 0,
+            "device_s_bound": 0.0, "wait_s": 0.0}
 _CB_STOP = object()           # callback-thread shutdown sentinel
 
 # recompute cause → trace-span component (ISSUE 18): the re-prefill (and
@@ -298,6 +318,19 @@ class _Unit:
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     blocks: Dict[str, int] = dataclasses.field(default_factory=dict)
     stall: str = "stall"              # what the residents it leaves out wait for
+    # the ledger's stamps (ISSUE 37), both on ``time.perf_counter()`` and
+    # both the ends of spans the engine opens anyway: of the unit's
+    # ``dispatch`` and of its ``device_wait`` (when the HOST saw the device
+    # done)
+    enqueued: Optional[float] = None
+    done: Optional[float] = None
+    # derived at ``done``: the wait itself, whether it found the device done
+    # already, the unit's time on the device as the host can see it, and
+    # whether that is exact (neither this unit nor the one before was late)
+    wait_s: float = 0.0
+    late: bool = False
+    device_s: float = 0.0
+    exact: bool = True
 
     @property
     def program(self):
@@ -494,6 +527,21 @@ class ServingEngine:
         # a kind's blocks under the rows of the decode steps so far
         self._kv_live: Dict[str, int] = {}
         self._logits_fetch_steps = 0
+        # the unit ledger (ISSUE 37): sums over landed units by kind and, a
+        # prefill's, by bucket; what the device starved for, by reason; the
+        # units launched ahead of a late landing; seconds inside step().
+        # Beside them, what the next unit's sums need of the last one
+        # (when the host saw the device done, whether it was late), why
+        # nothing is in flight, and when this engine could first have run
+        self._units: Dict[str, Any] = {
+            "by_kind": {"prefill": _unit_sums(), "decode": _unit_sums()},
+            "prefill_by_bucket": {},
+            "starved": {why: [0, 0.0] for why in _STARVED_WHY},
+            "host_late": 0, "step_s": 0.0}
+        self._last_done: Optional[float] = None
+        self._last_late = False
+        self._starve_why = "idle"
+        self._born = time.perf_counter()
 
     # -- plumbing ----------------------------------------------------------
     def serve_dir(self) -> Optional[str]:
@@ -628,7 +676,7 @@ class ServingEngine:
         seq = SequenceState(request_id=rid, prompt=prompt,
                             max_new_tokens=int(max_new_tokens),
                             eos_token_id=eos_token_id,
-                            arrival=now,
+                            arrival=now, queued=time.perf_counter(),
                             on_token=on_token,
                             capture_logits=self.capture_logits,
                             deadline=(None if deadline_ms is None
@@ -767,7 +815,26 @@ class ServingEngine:
         ``reap``, ``schedule``, ``tables``, ``h2d``, ``dispatch``,
         ``device_wait``, ``logits_copy``, ``guard``, ``accept``,
         ``gauges``, and the rare ``quarantine`` / ``recover``;
-        ``stats()["phases"]`` sums them."""
+        ``stats()["phases"]`` sums them.
+
+        Every unit has a number, and the spans say whose they are: a child
+        that works for a unit carries ``unit`` beside ``step`` (the launch
+        children the launched unit's, the landing children the landed
+        unit's; ``reap`` and ``gauges`` work for none), the root ``unit``
+        (landed) and ``ahead_unit``.  Two of those spans' ends are the
+        unit's stamps (``_Unit.enqueued``, the end of its ``dispatch``, and
+        ``done``, the end of its ``device_wait``) and the ledger sums what
+        follows from them, a few float
+        operations a landing (``stats()["units"]``): the host's wait for
+        the unit; the unit's time on the device, ``done(n) -
+        max(done(n-1), enqueued(n))``, exact unless the host came ``late``
+        to this landing or the last (a wait of ``LATE_EPS_S`` or less
+        found the device done already); and for a unit launched with
+        nothing in flight how long the device had nothing of this engine's
+        to run, ``enqueued(n) - done(n-1)``, by the reason nothing was
+        launched ahead (``ahead_breaks``' words, and ``start``).  The call
+        whose launch ended such an interval says so on its root:
+        ``starved_t0``, ``starved_t1``, ``starved_why``."""
         with self._phase("engine.step") as root:
             self._step_root = root
             with self._phase("reap"):
@@ -781,11 +848,15 @@ class ServingEngine:
             with self._phase("gauges"):
                 self.steps += 1
                 self._update_gauges()
+        self._units["step_s"] += root.elapsed
         return events
 
-    def _phase(self, name: str) -> span:
-        """A span of this step: children share the root's ``step``."""
-        return span(name, step=self.steps)
+    def _phase(self, name: str, unit: Optional[int] = None) -> span:
+        """A span of this step: children share the root's ``step``, and
+        one that works for a unit says which."""
+        if unit is None:
+            return span(name, step=self.steps)
+        return span(name, step=self.steps, unit=unit)
 
     def _step_inner(self, root: span) -> List[Dict[str, Any]]:
         """Launch the next unit, land the one in flight.  The watchdog is
@@ -800,9 +871,9 @@ class ServingEngine:
                 root.set(kind="other", rows=0, bucket=0, ahead_kind=None)
                 return []
         with self._step_guard():
-            self._in_flight = self._launch_next(unit)
-        root.set(ahead_kind=(None if self._in_flight is None
-                             else self._in_flight.kind))
+            self._in_flight = ahead = self._launch_next(unit)
+        root.set(ahead_kind=None if ahead is None else ahead.kind,
+                 ahead_unit=None if ahead is None else ahead.number)
         with self._step_guard():
             return self._land(unit)
 
@@ -814,6 +885,7 @@ class ServingEngine:
     def _note_break(self, why: str) -> None:
         self._ahead["ahead_breaks"][why] += 1
         self._reg().counter(f"serve.ahead_breaks.{why}").inc()
+        self._starve_why = why
 
     def _launch_next(self, prev: Optional[_Unit]) -> Optional[_Unit]:
         """Plan the next unit and launch it, ``prev`` (launched, unread)
@@ -824,7 +896,8 @@ class ServingEngine:
                                  or prev.error is not None):
             self._note_break("drain" if prev.error is None else "fault")
             return None
-        with self._phase("schedule"):
+        number = self._unit_no
+        with self._phase("schedule", number) as planning:
             plan = self.sched.schedule(ahead=prev is not None)
             for victim in plan.preempted:
                 reg.counter("serve.preemptions").inc()
@@ -836,9 +909,12 @@ class ServingEngine:
                                        victim.request_id, "preempt",
                                        "preempt", now, now, self._proc)
             if plan.kind not in ("prefill", "decode"):
+                planning.set(unit=None)           # it planned no unit
                 if prev is not None:
                     self._note_break("preempt" if plan.kind == "wait"
                                      else "idle")
+                else:
+                    self._starve_why = "idle"
                 return None
             # head-of-line stall: residents live on this engine but not
             # in this unit's batch wait the full unit out.  When the
@@ -849,8 +925,14 @@ class ServingEngine:
             if plan.kind == "prefill" and plan.seqs[0].resume_why:
                 stall = _RESUME_COMPONENT.get(plan.seqs[0].resume_why,
                                               "stall")
-        unit = self._start(plan.kind, plan.seqs, plan.bucket, prev,
-                           self._unit_no)
+        if plan.kind == "prefill" and plan.seqs[0].queued is not None:
+            # the request's wait, once: from submit() to the plan that
+            # took it
+            seq = plan.seqs[0]
+            record_span("engine.request/queue", seq.queued, planning.start,
+                        request_id=seq.request_id, unit=number)
+            seq.queued = None
+        unit = self._start(plan.kind, plan.seqs, plan.bucket, prev, number)
         unit.stall = stall
         self._unit_no += 1
         self._count_ahead("units_launched")
@@ -863,7 +945,23 @@ class ServingEngine:
         """Launch a unit and move the scheduler's launch-time marks."""
         unit = self._launch(kind, seqs, bucket, prev, number)
         unit.marks = self.sched.mark_launched(kind, unit.seqs, unit.emits)
+        if prev is None and unit.enqueued is not None:
+            self._book_starved(unit)
         return unit
+
+    def _book_starved(self, unit: _Unit) -> None:
+        """A unit launched with nothing in flight ends an interval in which
+        the device had nothing of this engine's to run: the ledger books it
+        under the reason nothing was launched ahead, and the root of the
+        call that is open says where it lay."""
+        since, why = self._idle_since()
+        gap = max(0.0, unit.enqueued - since)
+        row = self._units["starved"][why]
+        row[0] += 1
+        row[1] += gap
+        if self._step_root is not None:
+            self._step_root.set(starved_t0=since, starved_t1=unit.enqueued,
+                                starved_why=why)
 
     def _settle(self) -> List[Dict[str, Any]]:
         """Land what is in flight, with nothing launched after it: what
@@ -891,6 +989,7 @@ class ServingEngine:
         self._prefill_tracked = {}
         self._proven.clear()
         self._in_flight = None
+        self._starve_why, self._last_late = "fault", False
         victims = self.sched.preempt_all()
         self.cache.restore_held()     # their sequences are gone: freed
         self._rebuild_lost_pool()
@@ -940,8 +1039,7 @@ class ServingEngine:
         return (self.step_fault is not None
                 or any(s.capture_logits for s in seqs))
 
-    def _apply_fault(self, kind: str, seqs: List[SequenceState],
-                     finite: np.ndarray,
+    def _apply_fault(self, unit: _Unit, finite: np.ndarray,
                      logits_np: Optional[np.ndarray]):
         """Fault seam + NaN guard, applied to every landed unit
         (bisection probes included — injected faults must re-fire on the
@@ -949,7 +1047,8 @@ class ServingEngine:
         flags the step program computed, a row each; where the seam is
         set it reads what the hook handed back instead.  A row whose
         request left while the unit was in flight has nobody to name."""
-        with self._phase("guard"):
+        kind, seqs = unit.kind, unit.seqs
+        with self._phase("guard", unit.number):
             if self.step_fault is not None:
                 out = self.step_fault(self, kind,
                                       [s.request_id for s in seqs],
@@ -977,11 +1076,12 @@ class ServingEngine:
         copies to the host asked for, so that they follow the program
         out).  Nothing here waits for the device, and no mark moves.  A
         launch that raises is kept in the unit and raised at its landing,
-        where every fault is met."""
+        where every fault is met.  ``enqueued`` is the end of
+        ``dispatch``."""
         unit = _Unit(kind, list(seqs), bucket, number, float(self.clock()))
         reg = self._reg()
         try:
-            with self._phase("tables"):
+            with self._phase("tables", number):
                 if kind == "prefill":
                     seq, rows, chunk = seqs[0], 1, bucket
                     unit.emits = [seq.pending is None]
@@ -1001,14 +1101,14 @@ class ServingEngine:
                     *inputs, src = self._decode_inputs(unit, prev)
                     fn = self._decode_fn()
                 unit.fetch_logits = self._wants_logits(seqs)
-            with self._phase("h2d"):
+            with self._phase("h2d", number):
                 # a replay or a probe of a faulted unit carries the
                 # unit's own number, and so draws the same
                 packed = pack_step_inputs(*inputs, step=number % 2 ** 31,
                                           src=src)
                 packed_d = jax.device_put(packed)
                 reg.counter("serve.h2d_bytes").inc(packed.nbytes)
-            with self._phase("dispatch"):
+            with self._phase("dispatch", number) as sp:
                 nxt, finite, logits, pages, aux, unit.carry = fn(
                     self._params, packed_d, self.cache.pages, self._key,
                     self._no_prev if prev is None else prev.carry,
@@ -1022,6 +1122,7 @@ class ServingEngine:
                 unit.aux, unit.logits = aux, logits
                 for a in jax.tree_util.tree_leaves(unit.out):
                     a.copy_to_host_async()
+            unit.enqueued = sp.end
         except StepTimeout:
             raise
         except Exception as e:
@@ -1072,7 +1173,7 @@ class ServingEngine:
         if unit.error is not None:
             raise unit.error
         reg = self._reg()
-        with self._phase("device_wait"):
+        with self._phase("device_wait", unit.number) as waited:
             try:
                 jax.block_until_ready((unit.out[0], unit.logits))
             except StepTimeout:
@@ -1083,7 +1184,8 @@ class ServingEngine:
                 # as its logits
                 self.cache.drop_pages()
                 raise
-        with self._phase("logits_copy"):
+        self._note_done(unit, waited)
+        with self._phase("logits_copy", unit.number):
             out = jax.device_get(unit.out)
             nxt_np, finite_np, unit.counts, *fetched = out
             reg.counter("serve.d2h_bytes").inc(sum(
@@ -1094,8 +1196,36 @@ class ServingEngine:
         if self._step_root is not None:
             self._step_root.set(logits_fetched=unit.fetch_logits)
         self._proven.add(unit.program)
-        return nxt_np, self._apply_fault(unit.kind, unit.seqs, finite_np,
+        return nxt_np, self._apply_fault(unit, finite_np,
                                          fetched[0] if fetched else None)
+
+    def _idle_since(self):
+        """``(since, why)``: when the host last saw the device finish a
+        unit of this engine's, and why nothing was launched ahead of that
+        landing; before the first unit, when the engine was built and
+        ``"start"``."""
+        if self._last_done is None:
+            return self._born, "start"
+        return self._last_done, self._starve_why
+
+    def _note_done(self, unit: _Unit, waited: span) -> None:
+        """The host has seen the device finish ``unit``: its ``done``
+        stamp and what follows from it.  The unit's time on the device is
+        counted from when the device could start it: when it finished the
+        unit before, or when this one was handed over if that was later
+        (it was launched with nothing in flight).  Where the host came
+        late to this landing or to the last, one of the two ends is later
+        than the device's own and the figure is no longer exact (too long
+        for this unit, too short for the next: their sum still holds).  A
+        unit launched ahead of a late landing may have found the device
+        idle, for how long the host cannot say: it is counted."""
+        unit.done, unit.wait_s = waited.end, waited.elapsed
+        unit.late = waited.elapsed <= LATE_EPS_S
+        unit.device_s = unit.done - max(self._idle_since()[0], unit.enqueued)
+        unit.exact = not (unit.late or self._last_late)
+        self._last_done, self._last_late = unit.done, unit.late
+        if unit.late and self._in_flight is not None:
+            self._units["host_late"] += 1
 
     def _land(self, unit: _Unit) -> List[Dict[str, Any]]:
         """The second half of a unit: wait for it, read it, guard it,
@@ -1123,6 +1253,7 @@ class ServingEngine:
         culprits are quarantined and a decode unit's survivors replayed,
         launch and landing back to back, under the unit's own number."""
         ahead, self._in_flight = self._in_flight, None
+        self._starve_why = "fault"
         if ahead is not None:
             self.sched.unmark(ahead.marks)
             if ahead.kind == "prefill":
@@ -1162,10 +1293,11 @@ class ServingEngine:
         # on a request's waterfall a unit starts where the one before it
         # ended, if it was launched before that
         t0 = max(unit.t0, self._landed_at)
-        with self._phase("accept"):
+        with self._phase("accept", unit.number):
             if self._step_root is not None:
                 self._step_root.set(kind=unit.kind, rows=len(seqs),
-                                    bucket=unit.bucket, **unit.attrs)
+                                    bucket=unit.bucket, unit=unit.number,
+                                    **unit.attrs)
             self._book_paged_blocks(unit)
             live = [s.state == RUNNING for s in seqs]
             self._note_aux(unit, live)
@@ -1182,8 +1314,6 @@ class ServingEngine:
                                                   logits_np)
             else:
                 reg.counter("serve.decode_steps").inc()
-                reg.histogram("serve.decode_batch").observe(
-                    float(len(seqs)))
                 for i, s in enumerate(seqs):
                     if live[i]:
                         events.append(self._accept_token(
@@ -1197,7 +1327,7 @@ class ServingEngine:
                     reg, [(s.request_id, s.trace_id)
                           for s, ok in zip(seqs, live) if ok], sum(live),
                     t0, float(self.clock()), self._proc)
-        with self._phase("accept"):
+        with self._phase("accept", unit.number):
             served = {s.request_id for s in seqs}
             if self._in_flight is not None \
                     and self._in_flight.kind == "prefill":
@@ -1213,7 +1343,25 @@ class ServingEngine:
                                              self._landed_at, self._proc,
                                              component=unit.stall,
                                              cause=unit.kind)
+        self._book_landing(unit)
         return events
+
+    def _book_landing(self, unit: _Unit) -> None:
+        """A landed unit into the ledger's sums, once: under its kind and,
+        a prefill, under its bucket."""
+        rows = [self._units["by_kind"][unit.kind]]
+        if unit.kind == "prefill":
+            rows.append(self._units["prefill_by_bucket"].setdefault(
+                unit.bucket, _unit_sums()))
+        for row in rows:
+            row["units"] += 1
+            row["rows"] += len(unit.seqs)
+            row["wait_s"] += unit.wait_s
+            if unit.exact:
+                row["device_s"] += unit.device_s
+            else:
+                row["units_bound"] += 1
+                row["device_s_bound"] += unit.device_s
 
     def _accept_prefill(self, unit: _Unit, t0: float, nxt_np, logits_np):
         seq, reg = unit.seqs[0], self._reg()
@@ -1389,7 +1537,7 @@ class ServingEngine:
         under the faulted unit's ``number``), evict each with
         ``reason="poisoned"`` and a durable record, return the surviving
         sequences for replay."""
-        with self._phase("quarantine"):
+        with self._phase("quarantine", number):
             t0 = float(self.clock())
             if isinstance(error, _NonfiniteLogits):
                 bad = set(error.request_ids)
@@ -1737,7 +1885,7 @@ class ServingEngine:
             prompt=[int(t) for t in record["prompt"]],
             max_new_tokens=int(record["max_new_tokens"]),
             eos_token_id=record.get("eos_token_id"),
-            arrival=float(self.clock()),
+            arrival=float(self.clock()), queued=time.perf_counter(),
             capture_logits=self.capture_logits)
         seq.output = [int(t) for t in record.get("output", [])]
         seq.pending = seq.output[-1] if seq.output else None
@@ -1764,7 +1912,6 @@ class ServingEngine:
             seq.resume_why = record.get("resume_why") or "failover"
         self.sched.submit(seq)
         self._submit_order.append(seq.request_id)
-        self._reg().counter("serve.resumed").inc()
         self._update_gauges()
         return seq.request_id
 
@@ -1828,7 +1975,34 @@ class ServingEngine:
         reg.gauge("serve.kv_blocks_used").set(
             float(self.cache.blocks_used()))
         reg.gauge("serve.kv_pool_bytes").set(float(self.cache.pool_bytes()))
-        reg.gauge("serve.shed").set(1.0 if self.should_shed() else 0.0)
+
+    def _ledger(self) -> Dict[str, Any]:
+        """``stats()["units"]``: the ledger's sums as they stand, on
+        ``time.perf_counter()``.  ``by_kind`` / ``prefill_by_bucket``:
+        landed units, their rows, the host's wait for them (``wait_s``)
+        and their time on the device (``device_s`` over the exact ones,
+        ``device_s_bound`` over the ``units_bound`` others); ``starved``:
+        ``{why: [intervals, seconds]}`` before units launched with nothing
+        in flight; ``host_late``: units launched ahead of a landing the
+        host came late to (the gap before such a unit is not bounded from
+        here: while this stays 0 the host is not what the device waits
+        for); ``step_s``: seconds inside ``step()``.  ``now_s`` stamps the
+        snapshot and ``last_done_s`` is up to where the sums account: what
+        lies between the two is the unit in flight, which is booked when
+        it lands, or, with nothing in flight, a starved interval that is
+        still open, booked when the next unit is launched: ``starving``
+        is its reason then, else None."""
+        u = self._units
+        since, why = self._idle_since()
+        return {
+            "by_kind": {k: dict(v) for k, v in u["by_kind"].items()},
+            "prefill_by_bucket": {k: dict(v) for k, v
+                                  in u["prefill_by_bucket"].items()},
+            "starved": {k: list(v) for k, v in u["starved"].items()},
+            "host_late": u["host_late"], "step_s": u["step_s"],
+            "eps_s": LATE_EPS_S, "now_s": time.perf_counter(),
+            "last_done_s": since,
+            "starving": None if self._in_flight is not None else why}
 
     def stats(self) -> Dict[str, Any]:
         """Engine-state snapshot for ``/statusz`` (counts the registry
@@ -1849,6 +2023,7 @@ class ServingEngine:
             # what running ahead cost and why it did not engage
             "ahead": dict(self._ahead,
                           ahead_breaks=dict(self._ahead["ahead_breaks"])),
+            "units": self._ledger(),
             "logits_fetch_steps": self._logits_fetch_steps,
             "replica_id": self.replica_id,
             "queue_depth": self.sched.queue_depth,

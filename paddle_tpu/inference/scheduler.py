@@ -151,6 +151,10 @@ class SequenceState:
     trace_id: Optional[str] = None
     resume_why: Optional[str] = None
     trace_enqueued: Optional[float] = None
+    # when it was submitted, on ``time.perf_counter()``, the clock of the
+    # engine's spans (ISSUE 37): the engine records the wait as a span when
+    # it launches the request's first prefill, once, and clears this
+    queued: Optional[float] = None
 
     def context(self) -> List[int]:
         """Tokens needing cached KV before the next decode step.

@@ -11,7 +11,8 @@ they all report through:
 - :mod:`tracing` — nesting ``span()`` context managers (with
   attributes, on ``time.perf_counter()``) that feed the profiler's host
   annotations, an aggregated span tree, ``spans_between`` for a
-  benchmark's readers, and a chrome-trace exporter;
+  benchmark's readers, and a chrome-trace exporter; ``record_span`` for
+  a span whose two ends were stamped elsewhere (a request's queue wait);
 - :mod:`sinks` — the run-scoped JSONL ``MetricsWriter`` (fsync'd via
   ``utils/fsio``), a periodic stderr summary line, and a Prometheus
   textfile exporter;
@@ -97,13 +98,14 @@ from .sinks import (MetricsWriter, PrometheusTextfile, StderrSummary,
                     default_interval, metrics_dir, render_prometheus)
 from .tracing import (dropped, export_chrome_trace, reset_tracing, span,
                       span_tree_totals, spans_between, trace_events)
+from .tracing import record as record_span
 
 __all__ = [
     # registry
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "split_labels",
     # tracing
-    "span", "span_tree_totals", "spans_between", "dropped",
+    "span", "record_span", "span_tree_totals", "spans_between", "dropped",
     "export_chrome_trace", "trace_events", "reset_tracing",
     # sinks
     "MetricsWriter", "StderrSummary", "PrometheusTextfile", "metrics_dir",

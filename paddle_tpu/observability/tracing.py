@@ -1,4 +1,6 @@
-"""Typed span tracing (ISSUE 3; repaired for the serving loop, ISSUE 26).
+"""Typed span tracing (ISSUE 3; repaired for the serving loop, ISSUE 26;
+the ring holds a whole serving run and takes spans that were never a ``with``
+block, ISSUE 37).
 
 ``with span("data_load"): ...`` / ``with span("step"): ...`` nest on a
 per-thread stack; a nested span's identity is its *path* ("step/dispatch"),
@@ -21,12 +23,17 @@ Every span feeds three consumers at once:
 
 All three are process-wide and thread-safe; the buffer holds the newest
 ``BUFFER_SPANS`` spans, and :func:`dropped` says when a reader's window is
-no longer whole.
+no longer whole.  A span whose two ends were stamped elsewhere (a request's
+wait in a queue: from ``submit()`` to the launch that took it) goes into the
+tree and the buffer through :func:`record`.  ``perf_counter()`` is the one
+clock of everything here; ``_WALL_OFFSET`` is the only way from it to wall
+time.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -34,10 +41,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import fsio
 
-__all__ = ["span", "span_tree_totals", "spans_between", "dropped",
+__all__ = ["span", "record", "span_tree_totals", "spans_between", "dropped",
            "export_chrome_trace", "reset_tracing", "trace_events"]
 
-BUFFER_SPANS = 65536
+# a serving run whole: a chat engine makes 215 calls a second of 11 spans
+# each, and a benchmark run reads back over 80 s of them (189,200)
+BUFFER_SPANS = 1 << 18
 # perf_counter() + this = time.time(): taken once, so exported wall times
 # keep the spacing the monotonic clock measured
 _WALL_OFFSET = time.time() - time.perf_counter()
@@ -47,9 +56,39 @@ _tls = threading.local()
 _lock = threading.Lock()
 # path -> [count, total_s, self_s]
 _tree: Dict[str, list] = {}
-# (path, t0, dur, tid, attrs) in order of completion, t0 on perf_counter()
+# (path, t0, dur, tid, names, *values) in order of completion, t0 on
+# perf_counter(): the attributes flat, their names one shared tuple a set
+# of names and the path one shared string, so that a record of the serving
+# loop's children (two attributes) is the tuple and its two floats, 144
+# bytes (195 a record with the roots and the integers, a full ring 51 MB)
 _buffer: deque = deque(maxlen=BUFFER_SPANS)
 _dropped = [0, float("-inf")]      # evicted spans, end of the newest one
+_names: Dict[tuple, tuple] = {}
+
+
+def _keep(path: str, t0: float, dur: float, self_s: float,
+          attrs: Dict[str, Any]) -> None:
+    """One completed span into the tree and the buffer."""
+    names = tuple(attrs)
+    tid = threading.get_ident()
+    with _lock:
+        row = _tree.get(path)
+        if row is None:
+            _tree[path] = [1, dur, self_s]
+        else:
+            row[0] += 1
+            row[1] += dur
+            row[2] += self_s
+        if len(_buffer) == _buffer.maxlen:
+            old = _buffer[0]
+            _dropped[0] += 1
+            _dropped[1] = max(_dropped[1], old[1] + old[2])
+        _buffer.append((path, t0, dur, tid, _names.setdefault(names, names),
+                        *attrs.values()))
+
+
+def _attrs(rec: tuple) -> Dict[str, Any]:
+    return dict(zip(rec[4], rec[5:]))
 
 
 class span:
@@ -61,11 +100,13 @@ class span:
     ...     sp.set(kind="decode")
 
     ``elapsed`` (seconds) is available after exit — callers that need the
-    number (hapi's step breakdown) read it instead of re-timing.
+    number (hapi's step breakdown) read it instead of re-timing — and so
+    are ``start`` (from entry on) and ``end``, the span's two stamps on
+    ``perf_counter()``: the serving engine's unit ledger is made of them.
     """
 
-    __slots__ = ("name", "path", "elapsed", "attrs", "_t0", "_child",
-                 "_event")
+    __slots__ = ("name", "path", "elapsed", "attrs", "start", "end",
+                 "_child", "_event")
 
     def __init__(self, name: str, **attrs: Any):
         self.name = str(name)
@@ -83,7 +124,7 @@ class span:
         if stack is None:
             stack = _tls.stack = []
         if stack:
-            self.path = stack[-1].path + "/" + self.name
+            self.path = sys.intern(stack[-1].path + "/" + self.name)
         stack.append(self)
         # feed the profiler's host-annotation machinery (TraceAnnotation
         # into the device timeline + the flat host table)
@@ -92,13 +133,13 @@ class span:
             from ..profiler import RecordEvent as _RecordEvent
         self._event = _RecordEvent(self.path)
         self._event.begin()
-        self._t0 = time.perf_counter()
+        self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        dt = time.perf_counter() - self._t0
+        self.end = time.perf_counter()
         self._event.end()
-        self.elapsed = dt
+        self.elapsed = dt = self.end - self.start
         stack = _tls.stack
         if stack and stack[-1] is self:
             stack.pop()
@@ -108,21 +149,18 @@ class span:
             del stack[stack.index(self):]
         if stack:
             stack[-1]._child += dt
-        self_s = max(0.0, dt - self._child)
-        tid = threading.get_ident()
-        with _lock:
-            row = _tree.get(self.path)
-            if row is None:
-                _tree[self.path] = [1, dt, self_s]
-            else:
-                row[0] += 1
-                row[1] += dt
-                row[2] += self_s
-            if len(_buffer) == _buffer.maxlen:
-                old = _buffer[0]
-                _dropped[0] += 1
-                _dropped[1] = max(_dropped[1], old[1] + old[2])
-            _buffer.append((self.path, self._t0, dt, tid, self.attrs))
+        _keep(self.path, self.start, dt, max(0.0, dt - self._child),
+              self.attrs)
+
+
+def record(path: str, start: float, end: float, **attrs: Any) -> None:
+    """A completed span that was never a ``with`` block: ``path`` whole
+    (``"engine.request/queue"``), its two ends on ``perf_counter()``.  It
+    counts in the tree under its path (all of it self time; no parent's
+    self time shrinks) and sits in the buffer and the chrome export like
+    any span, on the thread that recorded it."""
+    dur = max(0.0, end - start)
+    _keep(sys.intern(path), start, dur, dur, attrs)
 
 
 def span_tree_totals(reset: bool = False) -> Dict[str, Dict[str, float]]:
@@ -144,8 +182,8 @@ def spans_between(t0: float, t1: float
     :func:`dropped` whether the buffer still holds all of them."""
     with _lock:
         items = list(_buffer)
-    return [(path, s, s + dur, attrs) for path, s, dur, _, attrs in items
-            if s < t1 and s + dur > t0]
+    return [(r[0], r[1], r[1] + r[2], _attrs(r)) for r in items
+            if r[1] < t1 and r[1] + r[2] > t0]
 
 
 def dropped(since: float = float("-inf")) -> int:
@@ -162,10 +200,10 @@ def trace_events() -> list:
     with _lock:
         items = list(_buffer)
     pid = os.getpid()
-    return [{"name": path, "ph": "X", "ts": (t0 + _WALL_OFFSET) * 1e6,
-             "dur": dur * 1e6, "pid": pid, "tid": tid,
-             **({"args": attrs} if attrs else {})}
-            for path, t0, dur, tid, attrs in items]
+    return [{"name": r[0], "ph": "X", "ts": (r[1] + _WALL_OFFSET) * 1e6,
+             "dur": r[2] * 1e6, "pid": pid, "tid": r[3],
+             **({"args": _attrs(r)} if r[4] else {})}
+            for r in items]
 
 
 def export_chrome_trace(path: str, reset: bool = False) -> int:
