@@ -6,6 +6,7 @@ from .gpt_pipeline import GPTPipeline  # noqa: F401
 from .deepseek_v2 import (DeepseekV2Config,  # noqa: F401
                           DeepseekV2ForCausalLM, deepseek_v2_tiny)
 from .glm5 import Glm5Config, Glm5ForCausalLM, glm5_tiny  # noqa: F401
+from .dots3 import Dots3Config, Dots3ForCausalLM, dots3_tiny  # noqa: F401
 from .mimo_v2 import (MimoV2Config, MimoV2ForCausalLM,  # noqa: F401
                       mimo_v2_tiny)
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
@@ -17,6 +18,7 @@ __all__ = ["BertConfig", "BertModel", "BertForPretraining",
            "bert_large",
            "DeepseekV2Config", "DeepseekV2ForCausalLM", "deepseek_v2_tiny",
            "Glm5Config", "Glm5ForCausalLM", "glm5_tiny",
+           "Dots3Config", "Dots3ForCausalLM", "dots3_tiny",
            "MimoV2Config", "MimoV2ForCausalLM", "mimo_v2_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPipeline", "gpt_tiny",
            "gpt_125m", "gpt_350m", "gpt_1p3b", "gpt_6p7b"]

@@ -1,0 +1,121 @@
+"""DeepSeek-V2's and GLM-5's attention does not move when the latent
+decoder gains what another model needs (a shape a layer kind, a window
+kind, an output gate): the jaxprs of one ``LatentAttention`` forward of
+each model, in decode and in prefill over the page pool, and of the two
+latent kernels are compared as text with a copy taken before that change
+(``fixtures/latent_programs.json.gz``).  Only what such a change could
+move is pinned: not the engine's step, the stack or the head around it.
+
+To take the copy anew on purpose (after a change that is MEANT to move
+these programs): ``PYTHONPATH=. JAX_PLATFORMS=cpu python
+tests/test_latent_programs.py --write``."""
+import difflib
+import gzip
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
+                       "latent_programs.json.gz")
+BLOCKS, BLOCK = 32, 8
+# (name, rows, tokens a row): a decode step; a prefill chunk in the plain
+# form (DeepSeek-V2) or the indexer's blocked form (GLM-5); and DeepSeek-V2's
+# blocked form, which a chunk takes once its scores pass _SCORE_BUDGET
+PHASES = {"deepseek_v2": (("decode", 2, 1), ("prefill", 1, 64),
+                          ("prefill_blocked", 1, 6144)),
+          "glm5": (("decode", 2, 1), ("prefill", 1, 64))}
+
+
+def program_texts():
+    """``{name: jaxpr text}`` of layer 0's attention of the tiny DeepSeek-V2
+    and GLM-5 over a page pool, and of ``mla_latent_attn`` /
+    ``dsa_sparse_attn`` in interpret mode, all traced under one matmul
+    precision (a jaxpr writes down the precision in force)."""
+    with jax.default_matmul_precision("highest"):
+        return _program_texts()
+
+
+def _attention_text(model, rows, chunk):
+    from paddle_tpu.inference.kv_cache import PagedLayerCache
+    c = model.config
+    attn = model.layers[0].attn
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    pages = tuple(jax.ShapeDtypeStruct((BLOCKS, BLOCK) + per, jnp.float32)
+                  for per in model.kv_cache_layout()[0])
+    width = -(-(chunk + 64) // BLOCK)
+
+    def forward(params, h, positions, pages, tables, lens, slots, last):
+        cache = PagedLayerCache(pages, tables, lens, slots, BLOCK)
+        return attn.apply(params, h, positions, cache,
+                          last_index=None if chunk == 1 else last)
+
+    return str(jax.make_jaxpr(forward)(
+        attn.state_dict(),
+        jax.ShapeDtypeStruct((rows, chunk, c.hidden_size), jnp.float32),
+        i32(rows, chunk), pages, i32(rows, width), i32(rows),
+        i32(rows, chunk), i32(rows)))
+
+
+def _program_texts():
+    import paddle_tpu as pt
+    from paddle_tpu.inference.latent_attention import latent_attention_pallas
+    from paddle_tpu.inference.sparse_attention import dsa_sparse_attn_pallas
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
+                                               deepseek_v2_tiny)
+    from paddle_tpu.models.glm5 import Glm5ForCausalLM, glm5_tiny
+
+    out = {}
+    for name, cls, cfg in (
+            ("deepseek_v2", DeepseekV2ForCausalLM, deepseek_v2_tiny()),
+            ("glm5", Glm5ForCausalLM, glm5_tiny())):
+        pt.seed(0)
+        model = cls(cfg)
+        for phase, rows, chunk in PHASES[name]:
+            out[f"{name}.{phase}"] = _attention_text(model, rows, chunk)
+    q = jax.ShapeDtypeStruct((2, 4, 128), jnp.float32)
+    pages = jax.ShapeDtypeStruct((6, 8, 128), jnp.float32)
+    table = jax.ShapeDtypeStruct((2, 3), jnp.int32)
+    lens = jax.ShapeDtypeStruct((2,), jnp.int32)
+    out["mla_latent_attn"] = str(jax.make_jaxpr(
+        lambda *a: latent_attention_pallas(*a, 64, 0.1, interpret=True))(
+        q, pages, table, lens))
+    slots = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    out["dsa_sparse_attn"] = str(jax.make_jaxpr(
+        lambda *a: dsa_sparse_attn_pallas(*a, value_width=64, scale=0.1,
+                                          interpret=True))(
+        q, pages, slots, lens))
+    return out
+
+
+@pytest.fixture(scope="module")
+def programs():
+    return program_texts()
+
+
+@pytest.fixture(scope="module")
+def before():
+    with gzip.open(FIXTURE, "rt") as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", [
+    f"{model}.{phase}" for model, phases in PHASES.items()
+    for phase, _, _ in phases] + ["mla_latent_attn", "dsa_sparse_attn"])
+def test_the_program_is_the_one_before_the_change(programs, before, name):
+    now, then = programs[name], before[name]
+    if now != then:
+        diff = "\n".join(list(difflib.unified_diff(
+            then.splitlines(), now.splitlines(), "before", "now",
+            lineterm=""))[:60])
+        pytest.fail(f"{name} moved:\n{diff}")
+
+
+if __name__ == "__main__" and "--write" in sys.argv:
+    os.makedirs(os.path.dirname(FIXTURE), exist_ok=True)
+    with gzip.open(FIXTURE, "wt") as f:
+        json.dump(program_texts(), f)
+    print("wrote", FIXTURE)

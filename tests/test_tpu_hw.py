@@ -1025,3 +1025,100 @@ def test_mimo_v2_step_compiled_for_v5e_keeps_both_pools_in_place(program):
     assert (p["full_calls"], p["window_calls"]) == (
         (2, 5) if decode else (0, 0)), p
     assert p["grouped_calls"] == 12, p                 # 6 layers x (up, down)
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the decode step of dots3-note-ep16-l5, compiled for a v5e
+# ahead of time: the window instantiation of the latent kernel and
+# the sparse kernels lower through Mosaic at the published widths, and the
+# seven page arrays of the two latent pools stay in place
+# ---------------------------------------------------------------------------
+_AOT_DOTS3_SCRIPT = _AOT_DEEPSEEK_SCRIPT.split("from paddle_tpu.inference import ServingEngine")[0] + r"""
+import paddle_tpu.inference.sparse_attention as sa
+sa._interpret = lambda: False
+from paddle_tpu.inference import ServingEngine
+from paddle_tpu.models.dots3 import Dots3Config, Dots3ForCausalLM
+from paddle_tpu.observability.registry import MetricsRegistry
+
+# perfbench/configs/dots3-note-ep16-l5.json + traffic/agentctx-backlog.json
+traffic = json.load(open("perfbench/traffic/agentctx-backlog.json"))["engine"]
+SEQS, LEN = traffic["max_seqs"], traffic["max_model_len"]
+BS, BLOCKS = traffic["kv_block_size"], traffic["num_kv_blocks"]
+TYPES = ("full_attention",) + ("sliding_attention",) * 3 + ("full_attention",)
+cfg = Dots3Config(vocab_size=19008, num_layers=5, layer_types=TYPES,
+                  dtype="bfloat16", ep_degree=16, ep_rank=0)
+eng = ServingEngine(Dots3ForCausalLM(cfg), max_seqs=SEQS, max_model_len=LEN,
+                    kv_block_size=BS, num_kv_blocks={"full": 8, "window": 8},
+                    registry=MetricsRegistry())
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+pages, pool_bytes = [], 0
+for t in TYPES:
+    shapes = ([(BLOCKS["full"], BS, 640), (BLOCKS["full"], BS, 128)]
+              if t == "full_attention" else [(BLOCKS["window"], BS, 1152)])
+    pages.append(tuple(S(s, jnp.bfloat16) for s in shapes))
+    pool_bytes += sum(int(np.prod(s)) * 2 for s in shapes)
+params = abstract(eng._params)
+widths = eng.cache.table_widths(eng.sched.max_blocks_per_seq)
+# ids, positions, last index, a table a kind, lengths, slots a kind, the
+# step's number: one buffer
+rows, chunk = SEQS, 1
+packed = S((rows * chunk + rows + 1 + rows * sum(widths) + rows
+            + 2 * rows * chunk + rows + 1,), jnp.int32)
+c = eng._build_step_fn().lower(
+    params, packed, pages, abstract(jax.random.PRNGKey(0)),
+    S((SEQS,), jnp.int32), rows=rows, chunk=chunk).compile()
+text, ma = c.as_text(), c.memory_analysis()
+header = text.split("input_output_alias={", 1)[1].split(
+    "entry_computation_layout", 1)[0]
+calls = lambda k: len(re.findall(
+    r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
+    + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+print("aot-program", json.dumps({
+    "widths": list(widths),
+    "n_params": sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)),
+    "pool_copies": len(re.findall(
+        r"= bf16\[(%d|%d),%d,\d+\]\S* copy(-start)?\(" % (
+            BLOCKS["full"], BLOCKS["window"], BS), text)),
+    "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+    "alias_bytes": ma.alias_size_in_bytes, "pool_bytes": pool_bytes,
+    "plan_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+    "window_calls": calls("mla_window_decode"),
+    "index_calls": calls("dsa_index_scores"),
+    "sparse_calls": calls("dsa_sparse_attn"),
+    "latent_calls": calls("mla_latent_attn"),
+    "grouped_calls": calls("moe_grouped")}), flush=True)
+print("aot-serve-ok")
+"""
+
+
+def test_dots3_decode_step_compiled_for_v5e_keeps_both_latent_pools_in_place():
+    """The cell's decode program (published widths, 16 held
+    experts, 128 rows, the traffic file's two pools) compiles for a v5e:
+    one `mla_window_decode` a sliding layer, one `dsa_index_scores` and one
+    `dsa_sparse_attn` a full layer, no dense latent kernel; all seven page
+    arrays (2 x 2 full + 3 x 1 window) alias their inputs with no
+    pool-shaped copy; the tables are 88 and 5 wide."""
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_DOTS3_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=1200)
+    if "aot-topology-ok" not in out.stdout:
+        pytest.skip("no v5e topology from libtpu here: "
+                    + (out.stdout + out.stderr)[-600:])
+    assert out.returncode == 0 and "aot-serve-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    p = json.loads(next(line for line in out.stdout.splitlines()
+                        if line.startswith("aot-program ")).split(" ", 1)[1])
+    # 2,577,137,664 in matrices + 67,072 in norms and biases: 5.15 GB
+    assert p["n_params"] == 2_577_204_736, p
+    assert p["widths"] == [88, 5], p
+    assert p["pool_copies"] == 0, p
+    assert p["aliased"] == 7 and p["alias_bytes"] == p["pool_bytes"], p
+    assert p["plan_bytes"] < 15.75e9, p
+    assert (p["window_calls"], p["index_calls"], p["sparse_calls"],
+            p["latent_calls"]) == (3, 2, 2, 0), p
+    assert p["grouped_calls"] == 8, p                  # 4 layers x (up, down)
