@@ -35,7 +35,7 @@ MXU, near the chip's ridge (2 * heads FLOPs a byte read).
 
 Its neighbours, for a model with an indexer, are ``dsa_index_scores`` and
 ``dsa_sparse_attn`` in ``sparse_attention.py`` (scores over every live
-index key; this kernel's body over the selected rows only).
+index key; the live latent pages in waves, under the indexer's selection).
 
 ``width`` is a multiple of 128 (a 576-value row is stored in 640): the
 page's minor dimension is then whole lane tiles, which is what keeps XLA
@@ -191,9 +191,8 @@ def latent_attention_pallas(q, pages, block_tables, seq_lens,
                             interpret: Optional[bool] = None,
                             name: str = "mla_latent_attn",
                             window: Optional[int] = None):
-    """``name`` is the kernel's name in a trace: ``sparse_attention.py``
-    runs this body over gathered rows as ``dsa_sparse_attn``, and
-    :func:`latent_window_attention_pallas` over a ring as
+    """``name`` is the kernel's name in a trace:
+    :func:`latent_window_attention_pallas` runs this body over a ring as
     ``mla_window_decode``."""
     from jax.experimental.pallas import tpu as pltpu
     _check_shapes(q, pages, block_tables, seq_lens, value_width)

@@ -1,5 +1,6 @@
 """Learned sparse attention over latent pages (ISSUE 32): DeepSeek sparse
-attention as the DeepSeek-V3.2-Exp report defines it, which GLM-5 uses.
+attention as the DeepSeek-V3.2-Exp report defines it, which GLM-5 and dots3
+use.
 
 A layer with an indexer keeps TWO page arrays under one block table: the
 latent rows of ``latent_attention`` and, a token, one narrow index key.
@@ -22,17 +23,26 @@ A decode step then runs, a layer,
   length.
 - :func:`dsa_select` — the exact selection: ``lax.top_k`` (never
   ``approx_max_k``), equal scores to the lower position; positions that do
-  not exist come back as -1.  :func:`dsa_slots` turns positions into pool
-  slots through the block table.
-- :func:`dsa_sparse_attn` — the absorbed form over the selected rows only.
-  The rows arrive by an XLA gather (``(rows, top_k, width)``, contiguous),
-  and the kernel ``dsa_sparse_attn`` is the latent kernel's body walking
-  them in chunks: row DMAs inside a kernel would be ``top_k`` copies of
-  1,280 bytes a row a layer, which the DMA engine's issue rate bounds far
-  below the gather (PERF.md section 6, PR 32).
+  not exist come back as -1.  From the same values, a row's THRESHOLD (the
+  ``top_k``-th largest score) and the highest selected position that ties
+  with it: ``score > threshold``, or ``score == threshold`` at or before
+  that position, is the selected set exactly.
+- :func:`dsa_sparse_attn` — the absorbed form over the selected entries.
+  The kernel ``dsa_sparse_attn`` walks the row's LIVE latent pages through
+  the block table, in waves as ``dsa_index_scores`` does, reads the row's
+  index scores beside each wave and masks the entries the threshold test
+  leaves out: nothing is gathered and no slot is looked up.  It streams
+  every live entry (``serve.dsa_context_tokens``) to attend the selected
+  ones (``serve.dsa_selected_tokens``), as DeepSeek-V3.2-Exp's own decode
+  adds the selection to the scores as a mask.  Gathering the selected rows
+  by XLA, looking up their slots and cutting them into pages cost ~30 ns an
+  entry selected on a v5e (PERF.md section 5); row DMAs inside a kernel
+  would be ``top_k`` copies of 1,280 bytes a row a layer, which the DMA
+  engine's issue rate bounds.
 - ``*_reference`` — the ``jax.numpy`` float32 ``HIGHEST`` twin of each: the
   route off the TPU and the kernels' oracle, as
-  ``latent_attention_reference`` is.
+  ``latent_attention_reference`` is; the attention's gathers the selected
+  rows through :func:`dsa_slots`.
 - :func:`dsa_prefill_mask` and :func:`blocked_attention` — a chunk on
   itself, in blocks of queries: index scores of a block against the keys it
   can see, the exact ``top_k``-th score as the block's threshold (ties to
@@ -44,12 +54,12 @@ A decode step then runs, a layer,
 The three attention kernels of a latent model: ``mla_latent_attn``
 (``latent_attention.py``: every live page), ``dsa_index_scores`` and
 ``dsa_sparse_attn`` (here: scores over every live index key, attention over
-the selected rows).
+every live latent page under the selection).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,13 +67,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..framework.errors import enforce
-from ..ops.flash_attention import _interpret
-from .latent_attention import (latent_attention_pallas,
-                               latent_attention_reference)
+from ..ops.flash_attention import _interpret, _LANES, _NEG_INF
+from .latent_attention import latent_attention_reference
 
 __all__ = ["dsa_index_scores", "dsa_index_scores_pallas",
-           "dsa_index_scores_reference", "dsa_select", "dsa_slots",
-           "dsa_sparse_attn", "dsa_sparse_attn_pallas",
+           "dsa_index_scores_reference", "dsa_select", "Selection",
+           "dsa_slots", "dsa_sparse_attn", "dsa_sparse_attn_pallas",
            "dsa_sparse_attn_reference", "dsa_prefill_mask",
            "blocked_attention", "index_scores_dense", "select_mask",
            "query_block"]
@@ -103,6 +112,28 @@ def dsa_index_scores_reference(q, w, pages, block_tables, seq_lens):
         return jnp.where(jnp.arange(max_ctx) < ln, score, _NEG)
 
     return jax.vmap(per_seq)(q, w, block_tables, seq_lens)
+
+
+def _last_wave(ln, tokens: int):
+    """The index of a row's last wave that holds a live entry (0 for an
+    empty row)."""
+    return jnp.maximum((ln + tokens - 1) // tokens - 1, 0)
+
+
+def _wave_page(j: int, p: int, block_size: int):
+    """The index map of wave slot ``j`` of ``p``: the row's table entry
+    ``ti * p + j``.  Past the row's last wave the slots keep that wave's
+    entries, and a slot of the last wave past the row's last page keeps the
+    entry it held a wave before (the last page where there was none): a
+    dead slot or a dead wave names the block index its slot already holds,
+    and nothing is fetched again."""
+    def index(bi, ti, lens, tbl, *_):
+        last = jnp.maximum((lens[bi] + block_size - 1) // block_size - 1, 0)
+        page = jnp.minimum(ti, _last_wave(lens[bi], p * block_size)) * p + j
+        page = jnp.where(page <= last, page,
+                         jnp.where(page >= p, page - p, last))
+        return (tbl[bi, page], 0, 0)
+    return index
 
 
 def _index_kernel(lens_ref, table_ref, q_ref, w_ref, *refs, block_size,
@@ -187,11 +218,17 @@ def dsa_index_scores(q, w, pages, block_tables, seq_lens):
 
 
 # -- the selection --------------------------------------------------------------
-def dsa_select(scores, top_k: int):
-    """``scores (rows, context)`` float32, minus infinity where a row may
-    not look -> ``(positions (rows, top_k) int32, count (rows,))``: the
-    positions of the ``top_k`` largest scores, largest first, equal scores
-    to the lower position; -1 where the row has fewer."""
+class Selection(NamedTuple):
+    """What :func:`dsa_select` keeps of each row of scores."""
+    positions: jax.Array   # (rows, top_k) int32, largest first; -1: none
+    counts: jax.Array      # (rows,) int32: positions that exist
+    threshold: jax.Array   # (rows,) float32: the top_k-th largest score
+    last_tied: jax.Array   # (rows,) int32: last selected position at it
+
+
+def _top_positions(scores, top_k: int):
+    """``(positions (rows, top_k), counts (rows,), values (rows, min(top_k,
+    context)))`` of :func:`dsa_select`."""
     rows, ctx = scores.shape
     # minus zero as zero: equal scores are equal whatever their sign bit
     vals, idx = lax.top_k(jnp.where(scores == 0, 0.0, scores),
@@ -199,7 +236,27 @@ def dsa_select(scores, top_k: int):
     pos = jnp.where(vals > _NEG, idx.astype(jnp.int32), -1)
     if ctx < top_k:
         pos = jnp.pad(pos, ((0, 0), (0, top_k - ctx)), constant_values=-1)
-    return pos, jnp.sum(pos >= 0, axis=1).astype(jnp.int32)
+    return pos, jnp.sum(pos >= 0, axis=1).astype(jnp.int32), vals
+
+
+def dsa_select(scores, top_k: int) -> Selection:
+    """``scores (rows, context)`` float32, minus infinity where a row may
+    not look -> the positions of the ``top_k`` largest scores, largest
+    first, equal scores to the lower position, -1 where the row has fewer;
+    and the same set as a test a position can apply alone: ``score >
+    threshold``, or ``score == threshold`` and ``position <= last_tied``
+    (the threshold is minus infinity where a row has fewer than ``top_k``
+    scores: every live entry passes)."""
+    pos, counts, vals = _top_positions(scores, top_k)
+    rows, ctx = scores.shape
+    if ctx < top_k:
+        threshold = jnp.full((rows,), _NEG, jnp.float32)
+        last_tied = jnp.full((rows,), -1, jnp.int32)
+    else:
+        threshold = vals[:, -1]
+        last_tied = jnp.max(jnp.where(vals == threshold[:, None], pos, -1),
+                            axis=1)
+    return Selection(pos, counts, threshold, last_tied)
 
 
 def dsa_slots(positions, block_tables, block_size: int):
@@ -210,11 +267,7 @@ def dsa_slots(positions, block_tables, block_size: int):
     return jnp.where(positions >= 0, blk * block_size + p % block_size, 0)
 
 
-# -- attention over the selected rows --------------------------------------------
-def _gathered(pages, slots):
-    return jnp.take(pages.reshape(-1, pages.shape[-1]), slots, axis=0)
-
-
+# -- attention under the selection ------------------------------------------------
 def _chunk(top_k: int) -> int:
     for c in (512, 256, 128):
         if top_k % c == 0:
@@ -222,41 +275,158 @@ def _chunk(top_k: int) -> int:
     return top_k
 
 
-def _as_pages(rows):
-    """``rows (b, top_k, width)`` as the latent kernel's pages and table."""
-    b, k, w = rows.shape
-    c = _chunk(k)
-    table = jnp.arange(b * (k // c), dtype=jnp.int32).reshape(b, k // c)
-    return rows.reshape(b * (k // c), c, w), table
-
-
 def dsa_sparse_attn_reference(q, pages, slots, counts, value_width: int,
                               scale: float):
     """``q (rows, heads, width)`` over the ``counts[b]`` first of the pool
-    slots ``slots (rows, top_k)`` of ``pages``: ``(rows, heads,
+    slots ``slots (rows, top_k)`` of ``pages``, gathered: ``(rows, heads,
     value_width)``."""
-    rows, table = _as_pages(_gathered(pages, slots))
-    return latent_attention_reference(q, rows, table, counts, value_width,
-                                      scale)
+    rows = jnp.take(pages.reshape(-1, pages.shape[-1]), slots, axis=0)
+    b, k, w = rows.shape
+    c = _chunk(k)
+    table = jnp.arange(b * (k // c), dtype=jnp.int32).reshape(b, k // c)
+    return latent_attention_reference(q, rows.reshape(b * (k // c), c, w),
+                                      table, counts, value_width, scale)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("value_width", "scale", "interpret"))
-def dsa_sparse_attn_pallas(q, pages, slots, counts, value_width: int,
-                           scale: float, interpret: Optional[bool] = None):
-    rows, table = _as_pages(_gathered(pages, slots))
-    return latent_attention_pallas(q, rows, table, counts, value_width,
-                                   scale, interpret=interpret,
-                                   name="dsa_sparse_attn")
+def _walk_kernel(lens_ref, table_ref, thr_ref, tied_ref, q_ref, score_ref,
+                 *refs, scale, block_size, pages_per_step, value_width):
+    del table_ref
+    page_refs = refs[:pages_per_step]
+    o_ref, m_scr, l_scr, acc_scr = refs[pages_per_step:]
+    b, t = pl.program_id(0), pl.program_id(1)
+    ln, thr, tied = lens_ref[b], thr_ref[b], tied_ref[b]
+    tokens = pages_per_step * block_size
+    base = t * tokens
+
+    @pl.when(t == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr[...], _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr[...])
+        acc_scr[...] = jnp.zeros_like(acc_scr[...])
+
+    def attend(lo: int, n: int):
+        """One flash update over the wave's pages ``lo .. lo + n``."""
+        kvs = [page_refs[j][0] for j in range(lo, lo + n)]  # (block, width)
+        s = jnp.concatenate([lax.dot_general(
+            q_ref[0], kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) for kv in kvs],
+            axis=1) * scale                                 # (heads, n block)
+        score = score_ref[0, :, lo * block_size:(lo + n) * block_size]
+        cols = base + lo * block_size + lax.broadcasted_iota(
+            jnp.int32, score.shape, 1)                      # (1, n block)
+        keep = (cols < ln) & ((score > thr)
+                              | ((score == thr) & (cols <= tied)))
+        s = jnp.where(keep, s, _NEG_INF)
+        m_prev = m_scr[...]                                 # (heads, _LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new[:, :1]), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        p = p.astype(kvs[0].dtype)
+        pv = sum(jnp.dot(p[:, i * block_size:(i + 1) * block_size],
+                         kv[:, :value_width],
+                         preferred_element_type=jnp.float32)
+                 for i, kv in enumerate(kvs))
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv
+        m_scr[...] = m_new
+
+    # a wave of live pages is one update, so the MXU's products and the
+    # softmax of its pages can overlap; the row's last wave goes page by
+    # page, and its pages past the length compute nothing
+    @pl.when(base + tokens <= ln)
+    def _whole():
+        attend(0, pages_per_step)
+
+    @pl.when((base < ln) & (base + tokens > ln))
+    def _last():
+        for j in range(pages_per_step):
+            pl.when(base + j * block_size < ln)(
+                functools.partial(attend, j, 1))
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...][:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
-def dsa_sparse_attn(q, pages, slots, counts, value_width: int, scale: float):
+def _wave_pages(block_size: int, max_blocks: int) -> int:
+    """Pages a grid step of the walk: about 1,024 tokens."""
+    return max(1, min(max_blocks, 1024 // block_size))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_width", "scale", "pages_per_step", "interpret"))
+def dsa_sparse_attn_pallas(q, pages, block_tables, seq_lens, scores,
+                           threshold, last_tied, value_width: int,
+                           scale: float, pages_per_step: Optional[int] = None,
+                           interpret: Optional[bool] = None):
+    """``q (rows, heads, width)`` over the live pages of ``pages
+    (num_blocks, block_size, width)``, an entry kept where its index score
+    ``scores (rows, max context)`` passes the row's ``threshold`` /
+    ``last_tied`` (:class:`Selection`): ``(rows, heads, value_width)``.
+    Jitted, so a step program lowers it once for all layers."""
+    from jax.experimental.pallas import tpu as pltpu
+    b, h, w = q.shape
+    block_size, max_blocks = pages.shape[1], block_tables.shape[1]
+    enforce(pages.ndim == 3 and pages.shape[2] == w
+            and 0 < value_width <= w,
+            f"latent pages {pages.shape} / value width {value_width} "
+            f"disagree with q {q.shape}")
+    enforce(block_tables.shape[0] == b and seq_lens.shape == (b,)
+            and scores.shape == (b, max_blocks * block_size),
+            f"tables {block_tables.shape} / lens {seq_lens.shape} / scores "
+            f"{scores.shape} disagree with batch {b}")
+    p = pages_per_step or _wave_pages(block_size, max_blocks)
+    tokens = p * block_size
+    waves = -(-max_blocks // p)
+    # a wave's scores are one block: the context padded to whole waves
+    scores = jnp.pad(scores.astype(jnp.float32),
+                     ((0, 0), (0, waves * tokens - scores.shape[1])),
+                     constant_values=_NEG).reshape(b, 1, waves * tokens)
+
+    def score_index(bi, ti, lens, *_):
+        return (bi, 0, jnp.minimum(ti, _last_wave(lens[bi], tokens)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,   # seq_lens, block_tables, threshold, tied
+        grid=(b, waves),
+        in_specs=[pl.BlockSpec((1, h, w), lambda bi, ti, *_: (bi, 0, 0)),
+                  pl.BlockSpec((1, 1, tokens), score_index)]
+        + [pl.BlockSpec((1, block_size, w), _wave_page(j, p, block_size))
+           for j in range(p)],
+        out_specs=pl.BlockSpec((1, h, value_width),
+                               lambda bi, ti, *_: (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, _LANES), jnp.float32),        # m
+            pltpu.VMEM((h, _LANES), jnp.float32),        # l
+            pltpu.VMEM((h, value_width), jnp.float32),   # acc
+        ],
+    )
+    kernel = functools.partial(_walk_kernel, scale=scale,
+                               block_size=block_size, pages_per_step=p,
+                               value_width=value_width)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
+        name="dsa_sparse_attn",
+        interpret=_interpret() if interpret is None else interpret,
+    )(jnp.asarray(seq_lens, jnp.int32), jnp.asarray(block_tables, jnp.int32),
+      threshold.astype(jnp.float32), last_tied.astype(jnp.int32), q, scores,
+      *([pages] * p))
+
+
+def dsa_sparse_attn(q, pages, block_tables, seq_lens, scores,
+                    sel: Selection, value_width: int, scale: float):
+    """The walk on a TPU; elsewhere the reference over the selected rows
+    gathered (interpret-mode Pallas is orders slower than XLA on the
+    CPU)."""
     if _on_tpu():
-        return dsa_sparse_attn_pallas(q, pages, slots, counts,
-                                      value_width=int(value_width),
-                                      scale=float(scale))
-    return dsa_sparse_attn_reference(q, pages, slots, counts, value_width,
-                                     scale)
+        return dsa_sparse_attn_pallas(
+            q, pages, block_tables, seq_lens, scores, sel.threshold,
+            sel.last_tied, value_width=int(value_width), scale=float(scale))
+    return dsa_sparse_attn_reference(
+        q, pages, dsa_slots(sel.positions, block_tables, pages.shape[1]),
+        sel.counts, value_width, scale)
 
 
 # -- prefill: a chunk on itself, in blocks of queries ----------------------------
@@ -387,7 +557,7 @@ def dsa_prefill_mask(index, ln, last, top_k: int, block: int):
         mask, col = lax.fori_loop(0, _live_blocks(ln, block, first, per),
                                   one, (mask, col))
     with jax.named_scope("dsa.select"):
-        pos, _ = dsa_select(col[None], top_k)
+        pos, _, _ = _top_positions(col[None], top_k)
     return mask, pos[0]
 
 

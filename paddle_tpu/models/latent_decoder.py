@@ -27,8 +27,8 @@ LayerNorm):
   ``sum_j w_j ReLU(q_idx_j . k_idx)``.  The index keys are a second,
   narrower page array under the same block table, written with the latent
   rows in one ``cache.write``.  Decode: scores over the live pages, exact
-  selection, the absorbed form over the selected rows.  Prefill: the chunk
-  in blocks of queries.
+  selection, the absorbed form over the live latent pages with the entries
+  not selected masked out.  Prefill: the chunk in blocks of queries.
 - The first ``first_k_dense_replace`` layers' feed-forward is a SwiGLU of
   ``intermediate_size``; the others are expert layers
   (:class:`paddle_tpu.nn.DroplessMoE`, either published router).
@@ -444,19 +444,21 @@ class LatentAttention(Layer):
 
     def _sparse_decode(self, q_row, index, cache):
         from ..inference.sparse_attention import (
-            dsa_index_scores, dsa_select, dsa_slots, dsa_sparse_attn)
+            dsa_index_scores, dsa_select, dsa_sparse_attn)
         sh = self.shape
         q_i, _, w_i = index
         with jax.named_scope("dsa.index_scores"):
             scores = dsa_index_scores(q_i[:, 0], w_i[:, 0], cache.pages[1],
                                       cache.block_tables, cache.seq_lens)
         with jax.named_scope("dsa.select"):
-            pos, kept = dsa_select(scores, sh.index_topk)
-            slots = dsa_slots(pos, cache.block_tables, cache.block_size)
+            sel = dsa_select(scores, sh.index_topk)
         with jax.named_scope("dsa.attend"):
-            o_lat = dsa_sparse_attn(q_row, cache.pages[0], slots, kept,
-                                    sh.kv_lora_rank, sh.softmax_scale)
-        return o_lat, {"selected": pos, "kept": jnp.sum(kept),
+            o_lat = dsa_sparse_attn(q_row, cache.pages[0],
+                                    cache.block_tables, cache.seq_lens,
+                                    scores, sel, sh.kv_lora_rank,
+                                    sh.softmax_scale)
+        return o_lat, {"selected": sel.positions,
+                       "kept": jnp.sum(sel.counts),
                        "scored": jnp.sum(cache.seq_lens)}
 
 

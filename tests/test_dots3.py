@@ -17,6 +17,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.inference import ServingEngine
+from paddle_tpu.inference import sparse_attention as sa
 from paddle_tpu.inference.kv_cache import WindowLayer, window_table_width
 from paddle_tpu.models.dots3 import (FULL, SLIDING, Dots3Config,
                                      Dots3ForCausalLM, dots3_tiny)
@@ -448,3 +449,50 @@ def test_a_freed_window_block_is_never_read():
     assert np.isfinite(np.stack(got["logits"])).all()
     np.testing.assert_allclose(np.stack(got["logits"]),
                                plain[len(prompt) - 1:], atol=ATOL)
+
+
+# -- the full layers' walk over the live latent pages --------------------------
+@pytest.mark.parametrize("bs", [4, 8])
+def test_the_walk_reads_the_rows_live_pages_only(bs):
+    """NaN in every block no row holds (but block 0, which the oracle
+    gathers where a row has no position): the walk's output is finite and
+    the oracle's, at the full kind's widths (rows of 640, values of 512):
+    a dead slot of a wave and a dead wave name a block the row holds."""
+    rng = np.random.default_rng(bs)
+    lens, t, blocks = [23, 0, 2 * bs, 13, 30], 32 // bs, 64
+    tables = rng.permutation(blocks)[:len(lens) * t].reshape(len(lens), t)
+    pages = rng.normal(size=(blocks, bs, 640)).astype(np.float32)
+    held = {int(tables[i, k]) for i, n in enumerate(lens)
+            for k in range(-(-n // bs))}
+    pages[np.setdiff1d(np.arange(1, blocks), sorted(held))] = np.nan
+    live = np.arange(t * bs)[None, :] < np.asarray(lens)[:, None]
+    scores = jnp.asarray(np.where(live, rng.normal(size=live.shape), -np.inf),
+                         jnp.float32)
+    q = jnp.asarray(rng.normal(size=(len(lens), 8, 640)), jnp.float32)
+    pages, tables = jnp.asarray(pages), jnp.asarray(tables, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    sel = sa.dsa_select(scores, 8)
+    got = np.asarray(sa.dsa_sparse_attn_pallas(
+        q, pages, tables, lens, scores, sel.threshold, sel.last_tied, 512,
+        0.05, pages_per_step=3, interpret=True))
+    assert np.isfinite(got).all() and not got[1].any()
+    np.testing.assert_allclose(got, np.asarray(sa.dsa_sparse_attn_reference(
+        q, pages, sa.dsa_slots(sel.positions, tables, bs), sel.counts, 512,
+        0.05)), atol=2e-5)
+
+
+def test_decode_through_the_walk_gives_the_gather_routes_logits(
+        monkeypatch):
+    """The engine's decode steps with the TPU's route (the index kernel and
+    the walk, interpreted) against the same engine off the TPU (the gather
+    oracle): the same logits and the same selected positions."""
+    cfg, _, _, prompts, want = _served(0)
+    monkeypatch.setattr(sa, "_on_tpu", lambda: True)
+    _, eng = _engine(cfg)
+    rids = [eng.submit(p, max_new_tokens=NEW) for p in prompts]
+    eng.run()
+    got = [eng.collect(r) for r in rids]
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g["per_logit"]["dsa_selected"],
+                                      w["per_logit"]["dsa_selected"])
+    np.testing.assert_allclose(_got(got), _got(want), atol=ATOL)

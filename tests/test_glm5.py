@@ -293,15 +293,95 @@ def test_sparse_attention_kernel_matches_its_reference(case):
     scores = jnp.where(jnp.arange(32)[None, :] < lens[:, None],
                        jnp.asarray(rng.normal(size=(4, 32)), jnp.float32),
                        -jnp.inf)
-    pos, kept = sa.dsa_select(scores, 8)
-    slots = sa.dsa_slots(pos, tables, 8)
-    got = sa.dsa_sparse_attn_pallas(q, pages, slots, kept, 16, 0.25,
+    sel = sa.dsa_select(scores, 8)
+    got = sa.dsa_sparse_attn_pallas(q, pages, tables, lens, scores,
+                                    sel.threshold, sel.last_tied, 16, 0.25,
                                     interpret=True)
-    want = sa.dsa_sparse_attn_reference(q, pages, slots, kept, 16, 0.25)
+    want = sa.dsa_sparse_attn_reference(
+        q, pages, sa.dsa_slots(sel.positions, tables, 8), sel.counts, 16,
+        0.25)
     assert got.shape == (4, 4, 16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     assert not np.asarray(got)[np.asarray(lens) == 0].any()
-    assert np.asarray(kept).tolist() == [min(8, n) for n in LENS[case]]
+    assert np.asarray(sel.counts).tolist() == [min(8, n) for n in LENS[case]]
+
+
+def _tied_scores(rng, lens, ctx):
+    """Row 0: six 2s and fifteen 1s scattered, the rest below: the twelfth
+    largest falls inside the 1s.  Row 1: every score equal."""
+    s = rng.normal(size=(len(lens), ctx)).astype(np.float32) - 3.0
+    at = rng.permutation(lens[0])
+    s[0, at[:6]], s[0, at[6:21]] = 2.0, 1.0
+    s[1] = 0.5
+    return s
+
+
+def _signed_zeros(rng, lens, ctx):
+    """Four positive scores, then zeros of both signs across the boundary
+    of the top 8, the rest negative."""
+    s = -1.0 - rng.random((len(lens), ctx)).astype(np.float32)
+    for i, n in enumerate(lens):
+        at = rng.permutation(n)
+        s[i, at[:4]] = 1.0 + rng.random(4)
+        s[i, at[4:14]] = np.where(rng.random(10) < 0.5, 0.0, -0.0)
+    return s
+
+
+# (page size, row width, lengths, table width, top_k, pages a grid step,
+#  scores or None, pool blocks over the blocks the rows need)
+WALK_CASES = {
+    "ties_at_the_boundary": (8, 128, [40, 33], 5, 12, None, _tied_scores, 1),
+    "minus_zero_as_zero": (8, 128, [30, 24, 17], 4, 8, None, _signed_zeros,
+                           1),
+    "rows_shorter_than_top_k": (8, 128, [5, 12, 3, 16], 3, 16, None, None,
+                                1),
+    "table_narrower_than_top_k": (8, 128, [5, 8, 1], 1, 16, None, None, 1),
+    "padding_rows": (8, 128, [0, 30, 0, 17], 4, 8, None, None, 1),
+    "partial_last_page": (8, 128, [19, 27, 33], 5, 8, None, None, 1),
+    "scattered_tables": (8, 128, [40, 23, 31], 5, 8, None, None, 4),
+    "wave_not_dividing_table": (8, 128, [40, 9, 33, 17], 5, 8, 2, None, 1),
+    "three_pages_a_wave": (8, 128, [40, 9, 33, 17], 5, 8, 3, None, 1),
+    "pages_of_16": (16, 128, [70, 16, 45], 5, 12, None, None, 1),
+    "pages_of_16_rows_of_640": (16, 640, [70, 0, 45], 5, 12, 2, None, 1),
+    "rows_of_640": (8, 640, [35, 12, 26], 5, 8, None, None, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_the_walk_matches_the_gather_oracle(case):
+    """The kernel walks every live page and keeps an entry by the
+    threshold test; the oracle gathers the selected rows by their slots.
+    Same entries, same attention, and the threshold test alone gives the
+    positions ``dsa_select`` hands out."""
+    bs, width, lens, t, top_k, per_step, make, spare = WALK_CASES[case]
+    rng = np.random.default_rng(sum(lens) + bs + width)
+    b, ctx = len(lens), t * bs
+    blocks = spare * b * t
+    pages = jnp.asarray(rng.normal(size=(blocks, bs, width)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(blocks)[:b * t].reshape(b, t),
+                         jnp.int32)
+    raw = (rng.normal(size=(b, ctx)).astype(np.float32) if make is None
+           else make(rng, lens, ctx))
+    live = np.arange(ctx)[None, :] < np.asarray(lens)[:, None]
+    scores = jnp.asarray(np.where(live, raw, -np.inf), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, 4, width)), jnp.float32)
+    lens = jnp.asarray(lens, jnp.int32)
+    sel = sa.dsa_select(scores, top_k)
+    kept = (np.asarray(scores) > np.asarray(sel.threshold)[:, None]) | (
+        (np.asarray(scores) == np.asarray(sel.threshold)[:, None])
+        & (np.arange(ctx)[None, :] <= np.asarray(sel.last_tied)[:, None]))
+    for row, pos in zip(kept & live, np.asarray(sel.positions)):
+        assert sorted(np.flatnonzero(row)) == sorted(p for p in pos if p >= 0)
+    got = sa.dsa_sparse_attn_pallas(q, pages, tables, lens, scores,
+                                    sel.threshold, sel.last_tied, 64, 0.1,
+                                    pages_per_step=per_step, interpret=True)
+    want = sa.dsa_sparse_attn_reference(
+        q, pages, sa.dsa_slots(sel.positions, tables, bs), sel.counts, 64,
+        0.1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert not np.asarray(got)[np.asarray(lens) == 0].any()
+    assert np.asarray(sel.counts).tolist() == [
+        min(top_k, int(n)) for n in lens]
 
 
 def test_contexts_of_at_most_top_k_give_exactly_dense_latent_attention():
@@ -312,10 +392,11 @@ def test_contexts_of_at_most_top_k_give_exactly_dense_latent_attention():
     scores = jnp.where(jnp.arange(32)[None, :] < lens[:, None],
                        jnp.asarray(rng.normal(size=(4, 32)), jnp.float32),
                        -jnp.inf)
-    pos, kept = sa.dsa_select(scores, 8)
-    assert np.asarray(kept).tolist() == [5, 8, 1, 7]
+    sel = sa.dsa_select(scores, 8)
+    assert np.asarray(sel.counts).tolist() == [5, 8, 1, 7]
     sparse = sa.dsa_sparse_attn_reference(
-        q, pages, sa.dsa_slots(pos, tables, 8), kept, 16, 0.25)
+        q, pages, sa.dsa_slots(sel.positions, tables, 8), sel.counts, 16,
+        0.25)
     dense = latent_attention_reference(q, pages, tables, lens, 16, 0.25)
     np.testing.assert_allclose(np.asarray(sparse), np.asarray(dense),
                                atol=1e-6)
@@ -343,10 +424,13 @@ def test_ties_go_to_the_lower_position():
     s[0, :10] = [1, 3, 3, 3, 0, 3, 2, 3, 3, 0]       # five 3s, top 4
     s[1, :6] = 7.0                                    # all tied, top 4
     s[2, :3] = [0.0, -0.0, 0.0]                       # fewer than top_k
-    pos, kept = sa.dsa_select(jnp.asarray(s), 4)
+    pos, kept, threshold, last_tied = sa.dsa_select(jnp.asarray(s), 4)
     assert np.asarray(pos).tolist() == [[1, 2, 3, 5], [0, 1, 2, 3],
                                         [0, 1, 2, -1]]
     assert np.asarray(kept).tolist() == [4, 4, 3]
+    # the same sets as a threshold and the last tied position selected
+    assert np.asarray(threshold).tolist() == [3.0, 7.0, -np.inf]
+    assert np.asarray(last_tied).tolist() == [5, 3, -1]
     # the prefill's threshold form selects the same sets (keys lead)
     mask = np.asarray(sa.select_mask(jnp.asarray(s.T), 4)).T
     for row, want in zip(mask, pos):

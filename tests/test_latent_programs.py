@@ -1,8 +1,9 @@
 """DeepSeek-V2's and GLM-5's attention does not move when the latent
 decoder gains what another model needs (a shape a layer kind, a window
 kind, an output gate): the jaxprs of one ``LatentAttention`` forward of
-each model, in decode and in prefill over the page pool, and of the two
-latent kernels are compared as text with a copy taken before that change
+each model, in decode and in prefill over the page pool, and of the
+kernels ``mla_latent_attn`` and ``dsa_sparse_attn`` are compared as text
+with a copy taken before that change
 (``fixtures/latent_programs.json.gz``).  Only what such a change could
 move is pinned: not the engine's step, the stack or the head around it.
 
@@ -33,8 +34,9 @@ PHASES = {"deepseek_v2": (("decode", 2, 1), ("prefill", 1, 64),
 def program_texts():
     """``{name: jaxpr text}`` of layer 0's attention of the tiny DeepSeek-V2
     and GLM-5 over a page pool, and of ``mla_latent_attn`` /
-    ``dsa_sparse_attn`` in interpret mode, all traced under one matmul
-    precision (a jaxpr writes down the precision in force)."""
+    ``dsa_sparse_attn`` (the walk over the live pages) in interpret mode,
+    all traced under one matmul precision (a jaxpr writes down the
+    precision in force)."""
     with jax.default_matmul_precision("highest"):
         return _program_texts()
 
@@ -83,11 +85,12 @@ def _program_texts():
     out["mla_latent_attn"] = str(jax.make_jaxpr(
         lambda *a: latent_attention_pallas(*a, 64, 0.1, interpret=True))(
         q, pages, table, lens))
-    slots = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    scores = jax.ShapeDtypeStruct((2, 24), jnp.float32)
+    threshold = jax.ShapeDtypeStruct((2,), jnp.float32)
     out["dsa_sparse_attn"] = str(jax.make_jaxpr(
         lambda *a: dsa_sparse_attn_pallas(*a, value_width=64, scale=0.1,
                                           interpret=True))(
-        q, pages, slots, lens))
+        q, pages, table, lens, scores, threshold, lens))
     return out
 
 

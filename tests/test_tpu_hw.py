@@ -870,9 +870,11 @@ c = eng._build_step_fn().lower(
 text, ma = c.as_text(), c.memory_analysis()
 header = text.split("input_output_alias={", 1)[1].split(
     "entry_computation_layout", 1)[0]
+# a kernel's calls by the instruction's name: the walk's operands name the
+# index kernel's output
 calls = lambda k: len(re.findall(
-    r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
-    + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    r"^\s*%" + k + r"[\w.-]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+    text, re.M))
 print("aot-program", json.dumps({
     "n_params": sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)),
     "pool_copies": len(re.findall(
@@ -1072,9 +1074,11 @@ c = eng._build_step_fn().lower(
 text, ma = c.as_text(), c.memory_analysis()
 header = text.split("input_output_alias={", 1)[1].split(
     "entry_computation_layout", 1)[0]
+# a kernel's calls by the instruction's name: the walk's operands name the
+# index kernel's output
 calls = lambda k: len(re.findall(
-    r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
-    + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    r"^\s*%" + k + r"[\w.-]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+    text, re.M))
 print("aot-program", json.dumps({
     "widths": list(widths),
     "n_params": sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)),
@@ -1089,7 +1093,13 @@ print("aot-program", json.dumps({
     "index_calls": calls("dsa_index_scores"),
     "sparse_calls": calls("dsa_sparse_attn"),
     "latent_calls": calls("mla_latent_attn"),
-    "grouped_calls": calls("moe_grouped")}), flush=True)
+    "grouped_calls": calls("moe_grouped"),
+    # the selected latent rows gathered, and their pool slots
+    "row_arrays": len(re.findall(
+        r"= bf16\[(?:%d,640|%d,2048,640)\]" % (SEQS * 2048, SEQS), text)),
+    "slot_arrays": len(re.findall(
+        r"= s32\[%d\]|= s32\[%d,2048\][^\n]*(?:gather|take_along_axis)"
+        % (SEQS * 2048, SEQS), text))}), flush=True)
 print("aot-serve-ok")
 """
 
@@ -1100,7 +1110,10 @@ def test_dots3_decode_step_compiled_for_v5e_keeps_both_latent_pools_in_place():
     one `mla_window_decode` a sliding layer, one `dsa_index_scores` and one
     `dsa_sparse_attn` a full layer, no dense latent kernel; all seven page
     arrays (2 x 2 full + 3 x 1 window) alias their inputs with no
-    pool-shaped copy; the tables are 88 and 5 wide."""
+    pool-shaped copy; the tables are 88 and 5 wide.  The walk reads the
+    selected rows from the pool: no `(128 x 2,048, 640)` array of gathered
+    rows and no `(128, 2,048)` array of slots (the gather route planned
+    10.69 GB)."""
     out = subprocess.run(
         [sys.executable, "-c", _AOT_DOTS3_SCRIPT], cwd=str(REPO),
         env=dict(_sub_env(), JAX_PLATFORMS="cpu",
@@ -1122,3 +1135,5 @@ def test_dots3_decode_step_compiled_for_v5e_keeps_both_latent_pools_in_place():
     assert (p["window_calls"], p["index_calls"], p["sparse_calls"],
             p["latent_calls"]) == (3, 2, 2, 0), p
     assert p["grouped_calls"] == 8, p                  # 4 layers x (up, down)
+    assert (p["row_arrays"], p["slot_arrays"]) == (0, 0), p
+    print("plan_bytes", p["plan_bytes"])
