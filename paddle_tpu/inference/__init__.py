@@ -17,6 +17,8 @@ KV cache), :mod:`.kv_cache` (block allocator + page arrays),
 :mod:`.paged_attention` (ragged decode kernel + lax fallback),
 :mod:`.latent_attention` (the same over latent pages, absorbed form),
 :mod:`.gqa_attention` (grouped-query heads, a window and a sink),
+:mod:`.ssm_state` (a Mamba-2 layer's state a sequence: the decode kernel
+and the chunked scan),
 :mod:`.scheduler` (admission/preemption policy).  The legacy Config
 routes onto it via ``enable_continuous_batching`` +
 ``set_decoder_model`` — see docs/ARCHITECTURE.md "Serving"."""
@@ -282,15 +284,18 @@ __all__ += ["DataType", "PlaceType", "PrecisionType", "Tensor",
 # -- the serving subsystem (ISSUE 6) ----------------------------------------
 from .engine import CollectTimeout, ServingEngine  # noqa: E402
 from .gqa_attention import gqa_decode  # noqa: E402
-from .kv_cache import BlockAllocator, PagedKVCache, WindowLayer  # noqa: E402
+from .kv_cache import (BlockAllocator, PagedKVCache,  # noqa: E402
+                       StateLayer, WindowLayer)
 from .latent_attention import latent_attention  # noqa: E402
 from .paged_attention import paged_attention  # noqa: E402
 from .scheduler import ContinuousBatchingScheduler  # noqa: E402
+from .ssm_state import ssm_chunk_scan, ssm_state_decode  # noqa: E402
 
 __all__ += ["ServingEngine", "CollectTimeout", "PagedKVCache",
             "BlockAllocator", "ContinuousBatchingScheduler",
             "paged_attention", "latent_attention", "EnginePredictor",
-            "WindowLayer", "gqa_decode"]
+            "WindowLayer", "gqa_decode", "StateLayer", "ssm_state_decode",
+            "ssm_chunk_scan"]
 
 # -- the serving fleet (ISSUE 16) -------------------------------------------
 from . import fleet  # noqa: E402

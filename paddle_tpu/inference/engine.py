@@ -147,8 +147,8 @@ from ..observability.tracing import record as record_span
 from ..observability.tracing import span, span_tree_totals
 from ..supervisor.watchdog import StepTimeout, Watchdog, guarded
 from ..utils import fsio
-from .kv_cache import (PagedKVCache, PagedLayerCache,
-                       default_kv_block_size, layout_kinds,
+from .kv_cache import (STATE, PagedKVCache, PagedLayerCache,
+                       StateLayerCache, default_kv_block_size, layout_kinds,
                        window_table_width)
 from .scheduler import (RUNNING, ContinuousBatchingScheduler,
                         SequenceState)
@@ -239,7 +239,8 @@ def pack_step_inputs(ids, positions, last_index, block_tables, seq_lens,
     (what a sampling program folds into the engine's key; a greedy one
     reads past it).  Over a cache with a pool a kind of layer
     ``block_tables`` and ``slot_mapping`` are lists, an array a kind: each
-    list goes in where its one array would."""
+    list goes in where its one array would (a state kind's table is its
+    slot a row, ``(rows, 1)``, and it has no write slots)."""
     if src is None:
         src = np.full(np.shape(positions), -1, np.int32)
     flat = []
@@ -251,12 +252,16 @@ def pack_step_inputs(ids, positions, last_index, block_tables, seq_lens,
 
 
 def unpack_step_inputs(packed, rows: int, chunk: int,
-                       widths: Optional[Sequence[int]] = None):
+                       widths: Optional[Sequence[int]] = None,
+                       slot_kinds: Optional[int] = None):
     """Cut :func:`pack_step_inputs`'s buffer apart again (inside a trace:
     static slices).  The table width is whatever is left of the length;
     ``widths`` are the kinds' table widths where the tables and the slots
-    went in as lists, and they come out as lists."""
+    went in as lists, and they come out as lists; ``slot_kinds`` the kinds
+    that have write slots, where not all have (a state kind has none)."""
     kinds = 1 if widths is None else len(widths)
+    if slot_kinds is not None:
+        kinds = slot_kinds
     width, rest = divmod(
         packed.shape[0] - rows * ((kinds + 1) * chunk + 3) - 2, rows)
     enforce(width > 0 and rest == 0
@@ -428,6 +433,10 @@ class ServingEngine:
             blocks_per_seq if window is None
             else min(blocks_per_seq, window_table_width(window, block_size)))
             for kind, window in kinds.items()}
+        if STATE in kinds:
+            # a slot a row, and a few for those held while a unit that
+            # still writes them is in flight (kv_cache.py)
+            roomy[STATE] = self.max_seqs + 8
         if num_kv_blocks is None:
             num_kv_blocks = roomy
         elif isinstance(num_kv_blocks, Mapping):
@@ -591,23 +600,29 @@ class ServingEngine:
         model, temperature = self.model, self.temperature
         block_size, max_seqs = self.cache.block_size, self.max_seqs
         # a cache of one kind of layer: one table, one slot matrix, every
-        # layer's view over them.  Of several: one of each a kind
-        kind_of = [list(self.cache.pools).index(k)
+        # layer's view over them.  Of several: one of each a kind, but a
+        # state kind's table is a slot a row and it has no write slots
+        kinds = list(self.cache.pools)
+        paged = [k for k in kinds if k != STATE]
+        kind_of = [(kinds.index(k), paged.index(k) if k != STATE else None)
                    for k in self.cache.layer_kinds]
         widths = (None if len(self.cache.pools) == 1 else
                   self.cache.table_widths(self.sched.max_blocks_per_seq))
+        slot_kinds = None if len(paged) == len(kinds) else len(paged)
 
         def fn(params, packed, pages, key, prev, *, rows, chunk):
             (ids, positions, last_index, block_tables, seq_lens,
              slot_mapping, src, step) = unpack_step_inputs(
-                 packed, rows, chunk, widths)
+                 packed, rows, chunk, widths, slot_kinds)
             ids = ids.at[:, 0].set(jnp.where(
                 src >= 0, prev[jnp.maximum(src, 0)], ids[:, 0]))
             if widths is None:
                 block_tables, slot_mapping = [block_tables], [slot_mapping]
-            caches = [PagedLayerCache(layer, block_tables[k], seq_lens,
-                                      slot_mapping[k], block_size=block_size)
-                      for layer, k in zip(pages, kind_of)]
+            caches = [StateLayerCache(layer, block_tables[k][:, 0], seq_lens)
+                      if s is None else
+                      PagedLayerCache(layer, block_tables[k], seq_lens,
+                                      slot_mapping[s], block_size=block_size)
+                      for layer, (k, s) in zip(pages, kind_of)]
             logits, new_caches, *aux = model.apply(
                 params, ids, caches, positions, last_index,
                 method="serving_step")
@@ -736,7 +751,8 @@ class ServingEngine:
         """Terminal eviction with reason ``deadline`` / ``cancelled``:
         free blocks, bump counters, emit the timeline record, and deliver
         the terminal event down the callback path."""
-        self.sched.evict(seq, reason)
+        # a unit in flight may still step its state slot: held till it lands
+        self.sched.evict(seq, reason, hold=self._in_flight is not None)
         self.lifecycle_counts[reason] += 1
         reg = self._reg()
         if reason == "cancelled":
@@ -1251,9 +1267,16 @@ class ServingEngine:
         back through recompute-prefill); a program that never ran to its
         end is the engine's fault and the error propagates; otherwise the
         culprits are quarantined and a decode unit's survivors replayed,
-        launch and landing back to back, under the unit's own number."""
+        launch and landing back to back, under the unit's own number.
+
+        Over a cache with a state kind (``kv_cache.StateLayer``) a unit
+        steps its rows' states IN PLACE, and the faulted unit, the probes
+        of its bisection and the unit dropped after it have done so: a
+        replay would step them twice.  So there every row of theirs that
+        is still running starts over by recompute-prefill instead."""
         ahead, self._in_flight = self._in_flight, None
         self._starve_why = "fault"
+        stepped = list(unit.seqs) + ([] if ahead is None else ahead.seqs)
         if ahead is not None:
             self.sched.unmark(ahead.marks)
             if ahead.kind == "prefill":
@@ -1268,9 +1291,12 @@ class ServingEngine:
             raise error                # never ran: not a request's fault
         live = [s for s in unit.seqs if s.state == RUNNING]
         if rebuilt or not live:        # the engine's loss, not a row's
+            self._restart_stepped(stepped)
             return []
         survivors = self._quarantine_step(unit.kind, live, error,
                                           unit.number)
+        if self._restart_stepped(stepped):
+            return []
         if unit.kind == "prefill" or not survivors:
             return []
         # replay: the culprit rows are gone, every surviving row is
@@ -1281,6 +1307,20 @@ class ServingEngine:
         replay = self._start("decode", survivors, 0, None, unit.number)
         replay.t0, replay.stall = unit.t0, unit.stall
         return self._land(replay)
+
+    def _restart_stepped(self, seqs: List[SequenceState]) -> bool:
+        """Over a cache with a state kind, send the running ones of
+        ``seqs`` back through recompute-prefill (their states were stepped
+        by units that will not be accepted); True when the cache has one."""
+        if STATE not in self.cache.pools:
+            return False
+        seen = set()
+        for seq in seqs:
+            if seq.state == RUNNING and id(seq) not in seen:
+                seen.add(id(seq))
+                self.sched.preempt(seq)
+                self._reg().counter("serve.preemptions").inc()
+        return True
 
     def _accept(self, unit: _Unit, nxt_np: np.ndarray,
                 logits_np: Optional[np.ndarray]) -> List[Dict[str, Any]]:
@@ -1459,7 +1499,8 @@ class ServingEngine:
         says which counters the counts add to and which gauges they set;
         the engine books them in the registry, sums them for
         ``stats()["model_counts"]`` and sets the counters on the step's
-        span under the name after their last dot.  A captured request
+        span under the name after their last dot, and the ``attrs`` it
+        names on the span as they are.  A captured request
         keeps its rows of the per-token arrays."""
         aux, kind = unit.aux, unit.kind
         if not aux:
@@ -1478,7 +1519,8 @@ class ServingEngine:
             if self._step_root is not None:
                 self._step_root.set(**{
                     name.rsplit(".", 1)[-1]: n
-                    for name, n in booked.get("counters", {}).items()})
+                    for name, n in booked.get("counters", {}).items()},
+                    **booked.get("attrs", {}))
         captured = [(i, s) for i, s in enumerate(unit.seqs)
                     if s.capture_logits and live[i]]
         if aux.get("per_token") and captured:
@@ -1617,7 +1659,8 @@ class ServingEngine:
             seq.logits.append(np.array(logits_row))
         reason = seq.should_finish()
         if reason is not None:
-            self.sched.complete(seq, reason)
+            self.sched.complete(seq, reason,
+                                hold=self._in_flight is not None)
             reg.counter("serve.finished").inc()
             reg.emit("serve.finish", request_id=seq.request_id,
                      reason=reason, generated=len(seq.output),

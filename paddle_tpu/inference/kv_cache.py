@@ -52,6 +52,20 @@ replay attends them.  They are freed when that unit has landed
 (:meth:`PagedKVCache.release_held`) or go back to the front of their tables
 when it has not (:meth:`PagedKVCache.restore_held`).
 
+A third kind, ``state`` (:class:`StateLayer`), is no row a
+token: a layer that keeps a fixed-size state a SEQUENCE (a state-space
+layer's convolution tail and recurrent state) declares the shapes and
+dtypes of its arrays, and its pool is ``num_blocks`` SLOTS of them, a slot
+a sequence, taken when the sequence is admitted and given back when it
+ends.  The step is handed a slot a row (a table one entry wide); the
+arrays hold one slot more than the pool counts, the padding rows' slot,
+which no sequence ever holds.  A slot let go while a unit is in flight
+(``free_seq(..., hold=True)``: the engine says so) is HELD until that unit
+has landed: a row of it that is discarded at its landing still writes the
+slot, and no new sequence may hold it by then.
+The model resets a slot itself: a sequence's first unit (position 0)
+starts from zeros whatever the slot held.
+
 Page layout: token-major, ``pages[block, offset, ...]``.  One token's
 slab is the minor tile (a ``(16, 128)`` bf16 slab is exactly one 4 KB
 TPU tile) and a block is ``block_size`` of them, contiguous.  That is the layout XLA's scatter of new tokens wants, and
@@ -92,11 +106,13 @@ import jax.tree_util as _tree_util
 from ..framework.errors import enforce
 
 __all__ = ["KV_BLOCK_SIZE_ENV", "default_kv_block_size", "BlockAllocator",
-           "PagedLayerCache", "PagedKVCache", "WindowLayer", "layout_kinds",
-           "window_table_width", "FULL", "WINDOW"]
+           "PagedLayerCache", "PagedKVCache", "WindowLayer", "StateLayer",
+           "StateLayerCache", "layout_kinds", "window_table_width", "FULL",
+           "WINDOW", "STATE"]
 
 FULL = "full"
 WINDOW = "window"
+STATE = "state"
 
 KV_BLOCK_SIZE_ENV = "PTPU_KV_BLOCK_SIZE"
 
@@ -113,17 +129,29 @@ class WindowLayer(NamedTuple):
     window: int
 
 
+class StateLayer(NamedTuple):
+    """A layer's entry in ``kv_cache_layout()`` that keeps a fixed-size
+    state a sequence, not a row a token: ``shapes`` / ``dtypes`` of its
+    arrays, one slot's each."""
+    shapes: Tuple[Tuple[int, ...], ...]
+    dtypes: Tuple[str, ...]
+
+
 def layout_kinds(layout) -> Dict[str, Optional[int]]:
-    """The kinds of layer a layout holds, in the order they first appear,
-    each with its reach (None: everything)."""
+    """The kinds of layer a layout holds, in the order they first appear
+    (the state kind, which pages nothing, last), each with its reach
+    (None: everything)."""
     kinds: Dict[str, Optional[int]] = {}
     for layer in layout:
         if isinstance(layer, WindowLayer):
             enforce(layer.window >= 1 and kinds.get(WINDOW, layer.window)
                     == layer.window, "window layers of one reach, >= 1")
             kinds[WINDOW] = int(layer.window)
-        else:
+        elif not isinstance(layer, StateLayer):
             kinds[FULL] = None
+    if any(isinstance(layer, StateLayer) for layer in layout):
+        enforce(kinds, "a layout of state layers needs a paged layer too")
+        kinds[STATE] = None
     return kinds
 
 
@@ -320,6 +348,31 @@ _tree_util.register_pytree_node(PagedLayerCache, _plc_flatten,
                                 _plc_unflatten)
 
 
+class StateLayerCache:
+    """One state layer's jit-visible view: ``pages``, its state arrays,
+    ``(slots + 1,) + one slot's shape`` each (named as a paged layer's,
+    so that a step hands every layer's back alike); ``slots (batch,)``
+    int32, a row's slot (padding rows: the last, which no sequence
+    holds); ``seq_lens (batch,)`` as :class:`PagedLayerCache`'s."""
+
+    __slots__ = ("pages", "slots", "seq_lens")
+
+    def __init__(self, pages, slots, seq_lens):
+        self.pages = tuple(pages)
+        self.slots = slots
+        self.seq_lens = seq_lens
+
+    def replace(self, **kw) -> "StateLayerCache":
+        fields = {s: getattr(self, s) for s in self.__slots__}
+        fields.update(kw)
+        return StateLayerCache(**fields)
+
+
+_tree_util.register_pytree_node(
+    StateLayerCache, lambda c: ((c.pages, c.slots, c.seq_lens), None),
+    lambda _, children: StateLayerCache(*children))
+
+
 @functools.partial(jax.jit, donate_argnums=0)
 def _zero_blocks(pages, block_ids):
     """Zeros into ``block_ids`` of every page array, in place (the pool
@@ -470,6 +523,63 @@ class _Pool:
         return out
 
 
+class _SlotPool:
+    """The state kind's share of the cache: ``num_blocks`` slots, a slot a
+    sequence, taken when it is admitted and given back when it ends
+    (``tables[seq]`` is ``[its slot]``).  The kind's arrays hold one row
+    more, the padding rows' slot, which no sequence ever holds.  A slot
+    given back while a unit that steps it is in flight is ``held`` until
+    that unit has landed."""
+
+    window = None
+    table_width = 1
+
+    def __init__(self, num_blocks: int, layers: List[int]):
+        self.layers = layers
+        self.num_blocks = int(num_blocks)
+        self.num_slots = self.num_blocks + 1        # rows of its arrays
+        self.allocator = BlockAllocator(self.num_blocks, 1)
+        self.tables: Dict[object, List[int]] = {}
+        self.freed_behind = 0
+        self.held: List[Tuple[object, List[int]]] = []
+
+    def span(self, num_tokens: int) -> Tuple[int, int]:
+        return 0, int(num_tokens > 0)
+
+    def settle(self, seq_id, num_tokens: int, hold: bool = False) -> int:
+        return 0 if seq_id in self.tables else self.span(num_tokens)[1]
+
+    def grow(self, seq_id, num_tokens: int, need: int) -> None:
+        if need > 0:
+            self.tables[seq_id] = self.allocator.alloc(need)
+
+    def free_seq(self, seq_id, hold: bool = False) -> None:
+        table = self.tables.pop(seq_id, None)
+        if table and hold:
+            self.held.append((seq_id, table))
+        elif table:
+            self.allocator.free(table)
+
+    def release_held(self) -> None:
+        """The unit that stepped them has landed, or is dropped: their
+        sequences are gone either way."""
+        for _, slots in self.held:
+            self.allocator.free(slots)
+            self.freed_behind += len(slots)
+        self.held.clear()
+
+    restore_held = release_held
+    report = _Pool.report
+
+    def table_array(self, seq_ids, max_blocks: int) -> np.ndarray:
+        """A slot a row; padding rows get the spare."""
+        out = np.full((len(seq_ids), 1), self.num_blocks, np.int32)
+        for i, sid in enumerate(seq_ids):
+            if sid in self.tables:
+                out[i, 0] = self.tables[sid][0]
+        return out
+
+
 class PagedKVCache:
     """Whole-model paged KV store: per-layer page arrays and, for each
     kind of layer, the allocator + per-sequence block tables.
@@ -485,25 +595,36 @@ class PagedKVCache:
         """``layout``: per layer, the per-token shape of each of its page
         arrays, as the model's ``kv_cache_layout()`` declares them (keys
         and values of 2 heads of 4: ``[((2, 4), (2, 4))]``), a window
-        layer's inside a :class:`WindowLayer`.  ``num_blocks``: the
-        pool's blocks, of a layout of several kinds a mapping from each
-        kind to its pool's."""
+        layer's inside a :class:`WindowLayer`, a state layer's a
+        :class:`StateLayer`.  ``num_blocks``: the pool's blocks, of a
+        layout of several kinds a mapping from each kind to its pool's (of
+        the state kind: its slots)."""
         block_size = (default_kv_block_size() if block_size is None
                       else int(block_size))
         layout = list(layout)
         self.kinds = layout_kinds(layout)
         self.layer_kinds = [WINDOW if isinstance(layer, WindowLayer)
+                            else STATE if isinstance(layer, StateLayer)
                             else FULL for layer in layout]
         self.layout = [tuple(tuple(int(d) for d in shape) for shape in (
-            layer.shapes if isinstance(layer, WindowLayer) else layer))
-            for layer in layout]
+            layer.shapes if isinstance(layer, (WindowLayer, StateLayer))
+            else layer)) for layer in layout]
         enforce(self.layout and all(self.layout), "empty cache layout")
         self.num_layers = len(self.layout)
         self._arity = [len(layer) for layer in self.layout]
         self.num_arrays = sum(self._arity)
-        # values a token keeps in each page array, in the pool's order
+        self.dtype = jnp.dtype(dtype)
+        # each array's dtype: the cache's, or a state layer's own
+        self._dtypes = [jnp.dtype(d) for layer in layout for d in (
+            layer.dtypes if isinstance(layer, StateLayer)
+            else [self.dtype] * len(layer))]
+        # values a token (a slot, of a state layer) keeps in each array, in
+        # the pool's order
         self._token_sizes = [int(np.prod(shape)) for layer in self.layout
                              for shape in layer]
+        self._is_state = [kind == STATE for kind, n in
+                          zip(self.layer_kinds, self._arity)
+                          for _ in range(n)]
         self.block_size = block_size
         if not isinstance(num_blocks, Mapping):
             enforce(len(self.kinds) == 1,
@@ -513,13 +634,15 @@ class PagedKVCache:
         enforce(set(num_blocks) == set(self.kinds),
                 f"blocks for {sorted(num_blocks)}, layers of "
                 f"{sorted(self.kinds)}")
-        self.pools: Dict[str, _Pool] = {
-            kind: _Pool(window, num_blocks[kind], block_size,
-                        [i for i, k in enumerate(self.layer_kinds)
-                         if k == kind])
+        self.pools: Dict[str, Union[_Pool, _SlotPool]] = {
+            kind: _SlotPool(num_blocks[kind], self._layers_of(kind))
+            if kind == STATE else
+            _Pool(window, num_blocks[kind], block_size,
+                  self._layers_of(kind))
             for kind, window in self.kinds.items()}
         # the first kind's pool answers for "the" pool where one is asked
-        # for: all there is of a layout of one kind
+        # for: all there is of a layout of one kind (the state kind, last,
+        # never answers)
         self._pool_list = list(self.pools.values())
         self._main = self._pool_list[0]
         # the slots behind each page array, in the pool's order
@@ -529,8 +652,10 @@ class PagedKVCache:
         self.num_blocks = self._main.num_blocks
         self.num_slots = self._main.num_slots
         self.slot_pad = self.num_slots          # OOB sentinel, mode="drop"
-        self.dtype = jnp.dtype(dtype)
         self.reset_pages()
+
+    def _layers_of(self, kind: str) -> List[int]:
+        return [i for i, k in enumerate(self.layer_kinds) if k == kind]
 
     @property
     def allocator(self) -> BlockAllocator:
@@ -563,9 +688,12 @@ class PagedKVCache:
         """A zeroed pool.  The old handles, if any are left, are let go
         first, so the pool is never held twice."""
         self._pages: List[Tuple[jnp.ndarray, ...]] = []
+        dtypes = iter(self._dtypes)
         self._pages = [
-            tuple(jnp.zeros((self.pools[kind].num_blocks, self.block_size)
-                            + shape, self.dtype) for shape in layer)
+            tuple(jnp.zeros(
+                ((self.pools[kind].num_slots,) if kind == STATE else
+                 (self.pools[kind].num_blocks, self.block_size)) + shape,
+                next(dtypes)) for shape in layer)
             for layer, kind in zip(self.layout, self.layer_kinds)]
 
     def _live_handles(self) -> List[jnp.ndarray]:
@@ -588,18 +716,27 @@ class PagedKVCache:
         (From the shapes: ``Array.nbytes`` costs 2 us a call, and this is
         read in every step.)"""
         arrays = itertools.chain.from_iterable(self._pages)
-        return self.dtype.itemsize * sum(
-            n * m for a, n, m in zip(arrays, self._token_sizes,
-                                     self._array_slots)
+        return sum(
+            d.itemsize * n * m for a, d, n, m in zip(
+                arrays, self._dtypes, self._token_sizes, self._array_slots)
             if not a.is_deleted())
 
     def bytes_per_token(self) -> int:
-        """What one cached token takes over all layers, padding of the
-        declared shapes included."""
-        return self.dtype.itemsize * sum(self._token_sizes)
+        """What one cached token takes over all paged layers, padding of
+        the declared shapes included."""
+        return self.dtype.itemsize * sum(
+            n for n, st in zip(self._token_sizes, self._is_state) if not st)
+
+    def state_bytes_per_seq(self) -> int:
+        """What one sequence's slot takes over all state layers."""
+        return sum(d.itemsize * n for d, n, st in zip(
+            self._dtypes, self._token_sizes, self._is_state) if st)
 
     def block_bytes(self, kind: str) -> int:
-        """What one block of ``kind``'s pool takes over its layers."""
+        """What one block of ``kind``'s pool takes over its layers (of the
+        state kind: one slot)."""
+        if kind == STATE:
+            return self.state_bytes_per_seq()
         return self.dtype.itemsize * self.block_size * sum(
             int(np.prod(shape)) for i in self.pools[kind].layers
             for shape in self.layout[i])
@@ -674,9 +811,15 @@ class PagedKVCache:
         return all(hi - lo <= pool.num_blocks for pool in self.pools.values()
                    for lo, hi in [pool.span(num_tokens)])
 
-    def free_seq(self, seq_id) -> None:
-        for pool in self.pools.values():
-            pool.free_seq(seq_id)
+    def free_seq(self, seq_id, hold: bool = False) -> None:
+        """Give back ``seq_id``'s blocks and its state slot (``hold``: a
+        unit in flight still steps it, so it is kept back until
+        :meth:`release_held`)."""
+        for kind, pool in self.pools.items():
+            if kind == STATE:
+                pool.free_seq(seq_id, hold)
+            else:
+                pool.free_seq(seq_id)
 
     def slot(self, seq_id, pos: int, kind: Optional[str] = None) -> int:
         """Flat page slot of cache position ``pos`` for ``seq_id``."""
@@ -736,12 +879,13 @@ class PagedKVCache:
                 for p in self.pools.values()]
 
     def step_slots(self, seq_ids, starts, chunk: int) -> List[np.ndarray]:
-        """:meth:`slot_array` of each kind, in the kinds' order."""
+        """:meth:`slot_array` of each paged kind, in the kinds' order (the
+        state kind writes by its table's slot)."""
         return [p.slot_array(seq_ids, starts, chunk)
-                for p in self.pools.values()]
+                for kind, p in self.pools.items() if kind != STATE]
 
     def table_widths(self, max_blocks: int) -> Tuple[int, ...]:
-        return tuple(max_blocks if p.window is None else p.table_width
+        return tuple(p.table_width or max_blocks
                      for p in self.pools.values())
 
     def layer_caches(self, block_tables, seq_lens: np.ndarray,
@@ -749,17 +893,20 @@ class PagedKVCache:
         """The per-layer views a model's ``serving_step`` takes, over the
         live pages (the engine's step program builds the same views
         inside its trace).  ``block_tables`` and ``slot_mapping``: one
-        array, or of several kinds one a kind in the kinds' order."""
+        array, or of several kinds one a kind in the kinds' order (slots
+        of the paged kinds only)."""
         if len(self.pools) == 1 and not isinstance(block_tables,
                                                    (list, tuple)):
             block_tables, slot_mapping = [block_tables], [slot_mapping]
         sl = jnp.asarray(seq_lens, jnp.int32)
-        by_kind = {kind: (jnp.asarray(bt, jnp.int32),
-                          jnp.asarray(sm, jnp.int32))
-                   for kind, bt, sm in zip(self.pools, block_tables,
-                                           slot_mapping)}
-        return [PagedLayerCache(layer, by_kind[kind][0], sl,
-                                by_kind[kind][1], block_size=self.block_size)
+        tables = {kind: jnp.asarray(bt, jnp.int32)
+                  for kind, bt in zip(self.pools, block_tables)}
+        slots = {kind: jnp.asarray(sm, jnp.int32) for kind, sm in zip(
+            [k for k in self.pools if k != STATE], slot_mapping)}
+        return [StateLayerCache(layer, tables[kind][:, 0], sl)
+                if kind == STATE else
+                PagedLayerCache(layer, tables[kind], sl, slots[kind],
+                                block_size=self.block_size)
                 for layer, kind in zip(self._pages, self.layer_kinds)]
 
     # -- defrag ------------------------------------------------------------
@@ -768,8 +915,10 @@ class PagedKVCache:
         permute its layers' page arrays to match.  Returns True when a
         permutation was applied."""
         moved = False
-        for pool in self.pools.values():
-            perm = pool.allocator.defrag(pool.tables)
+        for kind, pool in self.pools.items():
+            # a slot is whole: nothing of the state kind's to compact
+            perm = (None if kind == STATE
+                    else pool.allocator.defrag(pool.tables))
             if perm is None:
                 continue
             moved = True

@@ -298,6 +298,12 @@ class ContinuousBatchingScheduler:
                                 preempted=plan_preempted)
         return StepPlan("idle", [], preempted=plan_preempted)
 
+    def preempt(self, seq: SequenceState) -> None:
+        """Send one running sequence back to the head of the queue for
+        recompute (its landed tokens stay; nothing of it is in flight)."""
+        self._preempt(seq)
+        seq.in_flight = 0
+
     def _preempt(self, seq: SequenceState) -> None:
         self.running.remove(seq)
         self.cache.free_seq(seq.request_id)
@@ -372,16 +378,20 @@ class ContinuousBatchingScheduler:
         seq.state = PREEMPTED if seq.preemptions else WAITING
         self.waiting.appendleft(seq)
 
-    def complete(self, seq: SequenceState, reason: str) -> None:
+    def complete(self, seq: SequenceState, reason: str,
+                 hold: bool = False) -> None:
         """Evict a finished sequence: free its blocks immediately so the
         next schedule() can admit into the reclaimed space."""
-        self.evict(seq, reason)
+        self.evict(seq, reason, hold)
 
-    def evict(self, seq: SequenceState, reason: str) -> None:
+    def evict(self, seq: SequenceState, reason: str,
+              hold: bool = False) -> None:
         """Terminal eviction from ANY live state — finish, cancel,
         deadline, quarantine and drain-spill all exit through here:
         remove the sequence from whichever queue holds it, free its
-        blocks, record the reason, file it under ``finished``."""
+        blocks, record the reason, file it under ``finished``.  ``hold``:
+        a unit in flight may still step its state slot
+        (:meth:`PagedKVCache.free_seq`)."""
         if seq in self.running:
             self.running.remove(seq)
         else:
@@ -389,7 +399,7 @@ class ContinuousBatchingScheduler:
                 self.waiting.remove(seq)
             except ValueError:
                 pass
-        self.cache.free_seq(seq.request_id)
+        self.cache.free_seq(seq.request_id, hold)
         seq.state = FINISHED
         seq.finish_reason = reason
         self.finished[seq.request_id] = seq

@@ -9,6 +9,8 @@ from .glm5 import Glm5Config, Glm5ForCausalLM, glm5_tiny  # noqa: F401
 from .dots3 import Dots3Config, Dots3ForCausalLM, dots3_tiny  # noqa: F401
 from .mimo_v2 import (MimoV2Config, MimoV2ForCausalLM,  # noqa: F401
                       mimo_v2_tiny)
+from .nemotron_h import (NemotronHConfig,  # noqa: F401
+                         NemotronHForCausalLM, nemotron_h_tiny)
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertForSequenceClassification, bert_tiny,
                    bert_base, bert_large)
@@ -20,5 +22,6 @@ __all__ = ["BertConfig", "BertModel", "BertForPretraining",
            "Glm5Config", "Glm5ForCausalLM", "glm5_tiny",
            "Dots3Config", "Dots3ForCausalLM", "dots3_tiny",
            "MimoV2Config", "MimoV2ForCausalLM", "mimo_v2_tiny",
+           "NemotronHConfig", "NemotronHForCausalLM", "nemotron_h_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTPipeline", "gpt_tiny",
            "gpt_125m", "gpt_350m", "gpt_1p3b", "gpt_6p7b"]

@@ -12,6 +12,9 @@ last_index=...) -> (y, cache, extra)``; ``extra`` is whatever the attention
 hands out beside its result, or None) and says which layers are expert
 layers; it extends :meth:`DecoderForCausalLM._aux` and
 :meth:`DecoderForCausalLM.serving_counts` with what its attention counts.
+A model whose layers are each a mixer OR a feed-forward builds
+them as :class:`OneBranchLayer` (``x += f(norm(x))``) and says which of
+them keep a cache (:attr:`DecoderForCausalLM.cache_layers`).
 
 A configuration is any object with ``vocab_size`` (the rows of the
 embedding and of the head held here), ``hidden_size``, ``intermediate_size``,
@@ -29,12 +32,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..framework.errors import enforce
 from ..nn import initializer as I
 from ..nn.dropless_moe import DroplessMoE, SwiGLU
 from ..nn.layer import Layer, LayerList
 
 __all__ = ["RMSNorm", "rms_norm", "plain_rotary", "DecoderLayer",
-           "DecoderForCausalLM"]
+           "OneBranchLayer", "DecoderForCausalLM", "feed_forward"]
 
 # an expert layer's row buffer holds every (token, choice) pair and a dense
 # layer's gate and up products are twice the stream's width, so a long chunk
@@ -63,6 +67,36 @@ class RMSNorm(Layer):
 
     def forward(self, x):
         return rms_norm(x, self.weight.value, self.eps)
+
+
+def feed_forward(mlp, is_moe: bool, h, valid):
+    """``mlp`` (a dense or an expert feed-forward) on ``h (tokens,
+    hidden)`` -> ``(y, aux or None)``.  A long chunk goes through
+    ``_FFN_TOKENS`` at a time (a 16,384-token chunk's pairs would be
+    gigabytes of rows), and only the parts that hold a valid token: a
+    chunk is padded to its bucket, and a padding part's result stays
+    zero."""
+    one = ((lambda a, v: mlp(a, v)) if is_moe
+           else (lambda a, v: (mlp(a), None)))
+    t = h.shape[0]
+    if t <= _FFN_TOKENS or t % _FFN_TOKENS:
+        return one(h, valid)
+    parts = t // _FFN_TOKENS
+    if valid is None:
+        valid = jnp.ones((t,), bool)
+    hs = h.reshape(parts, _FFN_TOKENS, -1)
+    vs = valid.reshape(parts, _FFN_TOKENS)
+    zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                         jax.eval_shape(one, hs[0], vs[0]))
+    ys, auxes = lax.map(
+        lambda a: lax.cond(jnp.any(a[1]), one, lambda *_: zeros, *a),
+        (hs, vs))
+    if auxes is None:
+        return ys.reshape(t, -1), None
+    return ys.reshape(t, -1), {
+        "load": jnp.sum(auxes["load"], axis=0),
+        "dropped": jnp.sum(auxes["dropped"]),
+        "topk": auxes["topk"].reshape(t, -1)}
 
 
 class DecoderLayer(Layer):
@@ -105,31 +139,43 @@ class DecoderLayer(Layer):
 
     def _feed_forward(self, h, valid):
         """The layer's feed-forward on ``h (tokens, hidden)`` -> ``(y,
-        aux or None)``.  A long chunk goes through ``_FFN_TOKENS`` at a
-        time (a 16,384-token chunk's pairs would be gigabytes of rows),
-        and only the parts that hold a valid token: a chunk is padded to
-        its bucket, and a padding part's result stays zero."""
-        one = ((lambda a, v: self.mlp(a, v)) if self.is_moe
-               else (lambda a, v: (self.mlp(a), None)))
-        t = h.shape[0]
-        if t <= _FFN_TOKENS or t % _FFN_TOKENS:
-            return one(h, valid)
-        parts = t // _FFN_TOKENS
-        if valid is None:
-            valid = jnp.ones((t,), bool)
-        hs = h.reshape(parts, _FFN_TOKENS, -1)
-        vs = valid.reshape(parts, _FFN_TOKENS)
-        zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
-                             jax.eval_shape(one, hs[0], vs[0]))
-        ys, auxes = lax.map(
-            lambda a: lax.cond(jnp.any(a[1]), one, lambda *_: zeros, *a),
-            (hs, vs))
-        if auxes is None:
-            return ys.reshape(t, -1), None
-        return ys.reshape(t, -1), {
-            "load": jnp.sum(auxes["load"], axis=0),
-            "dropped": jnp.sum(auxes["dropped"]),
-            "topk": auxes["topk"].reshape(t, -1)}
+        aux or None)``: :func:`feed_forward`."""
+        return feed_forward(self.mlp, self.is_moe, h, valid)
+
+
+class OneBranchLayer(Layer):
+    """``x += f(norm(x))``, ``f`` a mixer (attention, or any layer that
+    takes ``(h, positions, cache, last_index=...)`` and returns ``(y,
+    cache, extra)``) or a feed-forward: a layer of a model that names one
+    or the other a layer."""
+
+    def __init__(self, config, make_mixer=None, make_mlp=None,
+                 is_moe: bool = False):
+        """Exactly one of ``make_mixer()`` / ``make_mlp()`` builds the
+        branch, called after the norm's parameters are drawn."""
+        super().__init__()
+        enforce((make_mixer is None) != (make_mlp is None),
+                "a mixer or a feed-forward")
+        c = self.config = config
+        self.norm = RMSNorm(c.hidden_size, c.rms_norm_eps, c.dtype)
+        self.mixer = None if make_mixer is None else make_mixer()
+        self.mlp = None if make_mlp is None else make_mlp()
+        self.is_moe = bool(is_moe)
+
+    def forward(self, x, positions, cache=None, valid=None, last_index=None):
+        """-> ``(x, cache, aux, extra)`` as :class:`DecoderLayer`'s."""
+        h = self.norm(x)
+        if self.mixer is not None:
+            y, cache, extra = self.mixer(h, positions, cache,
+                                         last_index=last_index)
+            return x + y, cache, None, extra
+        b, s, hidden = x.shape
+        y, aux = feed_forward(self.mlp, self.is_moe,
+                              h.reshape(b * s, hidden),
+                              None if valid is None else valid.reshape(-1))
+        if aux is not None:
+            aux["topk"] = aux["topk"].reshape(b, s, -1)
+        return x + y.reshape(b, s, hidden), cache, aux, None
 
 
 class DecoderForCausalLM(Layer):
@@ -137,6 +183,9 @@ class DecoderForCausalLM(Layer):
     ``vocab_size`` rows (the slice held here)."""
 
     _head_scope = "decoder.head"
+    # a layer's entry among the step's caches (None: it keeps none); unset,
+    # layer i keeps cache i
+    cache_layers = None
 
     def __init__(self, config, make_layer):
         """``make_layer(i)`` builds layer ``i``, called in order after the
@@ -156,10 +205,12 @@ class DecoderForCausalLM(Layer):
         x = jnp.take(self.embed.value, input_ids, axis=0)
         new_caches, auxes, extras = [], [], []
         for i, layer in enumerate(self.layers):
+            j = i if self.cache_layers is None else self.cache_layers[i]
             x, cache, aux, extra = layer(
-                x, positions, None if caches is None else caches[i], valid,
-                last_index)
-            new_caches.append(cache)
+                x, positions, None if caches is None or j is None
+                else caches[j], valid, last_index)
+            if j is not None:
+                new_caches.append(cache)
             if aux is not None:
                 auxes.append(aux)
             if extra is not None:

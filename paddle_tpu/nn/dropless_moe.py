@@ -16,6 +16,14 @@ unbiased sigmoid weighs, renormalised if asked AND times
 ``routed_scaling_factor``).  What absent experts would add is left out: on a mesh the
 exchange goes around this layer, and on one chip nothing stands in for it.
 
+An expert is a SwiGLU (``activation="swiglu"``) or, with no gate, ``relu(x
+W_up)^2 W_down`` (``"relu2"``).  With ``latent_size`` the routed
+experts work in a narrower latent: ``u = h W_latent_down`` goes in, their
+weighted sum comes out through ``W_latent_up``; the router and the shared
+expert read ``h`` at full width.  ``shared_width`` gives the shared expert
+a width of its own (else ``n_shared_experts x expert_width``), in the
+experts' activation.
+
 Dropless: every (token, held expert) pair the router chose is computed.
 The pairs are sorted by expert and the experts' products run over the
 sorted rows (``ops/grouped_matmul.py``: Pallas kernels on a TPU,
@@ -35,11 +43,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..framework.errors import enforce
-from ..ops.grouped_matmul import grouped_matmul, grouped_swiglu
+from ..ops.grouped_matmul import (grouped_matmul, grouped_relu2,
+                                  grouped_swiglu)
 from . import initializer as I
 from .layer import Layer
 
-__all__ = ["SwiGLU", "DroplessMoE", "group_limited_topk"]
+__all__ = ["SwiGLU", "Relu2MLP", "DroplessMoE", "group_limited_topk"]
 
 
 class SwiGLU(Layer):
@@ -56,6 +65,21 @@ class SwiGLU(Layer):
     def forward(self, x):
         g = x @ self.w_gate.value
         return (jax.nn.silu(g) * (x @ self.w_up.value)) @ self.w_down.value
+
+
+class Relu2MLP(Layer):
+    """Bias-free ``W_down(relu(x W_up)^2)``."""
+
+    def __init__(self, hidden_size: int, width: int, dtype="float32",
+                 std: float = 0.02):
+        super().__init__()
+        init = I.NormalInDtype(std)
+        self.w_up = self.create_parameter((hidden_size, width), dtype, init)
+        self.w_down = self.create_parameter((width, hidden_size), dtype, init)
+
+    def forward(self, x):
+        u = jnp.maximum(x @ self.w_up.value, 0)
+        return (u * u) @ self.w_down.value
 
 
 def group_limited_topk(scores, n_group: int, topk_group: int, top_k: int):
@@ -79,10 +103,14 @@ class DroplessMoE(Layer):
                  routed_scaling_factor: float = 1.0,
                  norm_topk_prob: bool = False, ep_degree: int = 1,
                  ep_rank: int = 0, dtype="float32", std: float = 0.02,
-                 scoring_func: str = "softmax"):
+                 scoring_func: str = "softmax", activation: str = "swiglu",
+                 latent_size: Optional[int] = None,
+                 shared_width: Optional[int] = None):
         super().__init__()
         enforce(scoring_func in ("softmax", "sigmoid"),
                 f"scoring_func {scoring_func!r}")
+        enforce(activation in ("swiglu", "relu2"),
+                f"activation {activation!r}")
         enforce(scoring_func == "softmax" or n_group == 1,
                 "the sigmoid router is built for one group")
         enforce(num_experts % ep_degree == 0 and 0 <= ep_rank < ep_degree,
@@ -97,7 +125,7 @@ class DroplessMoE(Layer):
         self.norm_topk_prob = bool(norm_topk_prob)
         self.ep_degree, self.ep_rank = int(ep_degree), int(ep_rank)
         self.held = self.num_experts // self.ep_degree
-        self.scoring_func = scoring_func
+        self.scoring_func, self.activation = scoring_func, activation
         init = I.NormalInDtype(std)
         self.router = self.create_parameter(
             (hidden_size, num_experts), dtype, init)
@@ -107,14 +135,24 @@ class DroplessMoE(Layer):
             # some choices of a sigmoid in (0, 1), whatever `std` is)
             self.router_bias = self.create_parameter(
                 (num_experts,), "float32", I.NormalInDtype(0.02))
-        self.w_gate = self.create_parameter(
-            (self.held, hidden_size, expert_width), dtype, init)
+        self.latent_down = self.latent_up = None
+        if latent_size:
+            self.latent_down = self.create_parameter(
+                (hidden_size, latent_size), dtype, init)
+            self.latent_up = self.create_parameter(
+                (latent_size, hidden_size), dtype, init)
+        inner = latent_size or hidden_size
+        if activation == "swiglu":
+            self.w_gate = self.create_parameter(
+                (self.held, inner, expert_width), dtype, init)
         self.w_up = self.create_parameter(
-            (self.held, hidden_size, expert_width), dtype, init)
+            (self.held, inner, expert_width), dtype, init)
         self.w_down = self.create_parameter(
-            (self.held, expert_width, hidden_size), dtype, init)
-        self.shared = (SwiGLU(hidden_size, n_shared_experts * expert_width,
-                              dtype, std) if n_shared_experts else None)
+            (self.held, expert_width, inner), dtype, init)
+        mlp = SwiGLU if activation == "swiglu" else Relu2MLP
+        self.shared = (mlp(hidden_size, shared_width
+                           or n_shared_experts * expert_width, dtype, std)
+                       if n_shared_experts else None)
 
     # -- routing -----------------------------------------------------------
     def route(self, h):
@@ -142,7 +180,7 @@ class DroplessMoE(Layer):
 
     # -- the held experts' part ----------------------------------------------
     def experts(self, h, w, idx, valid=None, tile: Optional[int] = None):
-        """``sum_e w_e SwiGLU_e(h)`` over the held experts among ``idx``;
+        """``sum_e w_e expert_e(h)`` over the held experts among ``idx``;
         ``valid (tokens,)`` leaves padding tokens out.  ``tile`` is the
         row tile of the grouped product: 32 on a TPU (a tile of 128 was
         never faster there, at 256 tokens a step or at 1,024), and 1
@@ -185,8 +223,12 @@ class DroplessMoE(Layer):
         xs = jnp.take(h_ext, jnp.where(row_pair < pairs, row_pair // k, t),
                       axis=0)
         sizes = load[:held]
-        act = grouped_swiglu(xs, self.w_gate.value, self.w_up.value, sizes,
-                             tile_expert, tiles_used, tile)
+        if self.activation == "swiglu":
+            act = grouped_swiglu(xs, self.w_gate.value, self.w_up.value,
+                                 sizes, tile_expert, tiles_used, tile)
+        else:
+            act = grouped_relu2(xs, self.w_up.value, sizes, tile_expert,
+                                tiles_used, tile)
         out = grouped_matmul(act, self.w_down.value, sizes, tile_expert,
                              tiles_used, tile)
         # rows nobody placed hold whatever was there: select, never scale
@@ -203,8 +245,15 @@ class DroplessMoE(Layer):
         """``h (tokens, hidden)`` -> ``(y, aux)``."""
         with jax.named_scope("moe.route"):
             w, idx = self.route(h)
+        u = h
+        if self.latent_down is not None:
+            with jax.named_scope("moe.latent"):
+                u = h @ self.latent_down.value
         with jax.named_scope("moe.experts"):
-            y, aux = self.experts(h, w, idx, valid, tile)
+            y, aux = self.experts(u, w, idx, valid, tile)
+        if self.latent_up is not None:
+            with jax.named_scope("moe.latent"):
+                y = y @ self.latent_up.value
         if self.shared is not None:
             with jax.named_scope("moe.shared"):
                 y = y + self.shared(h)

@@ -5,7 +5,9 @@ multiplies each expert's rows by that expert's weights.  Two routes, one
 row layout (``nn/dropless_moe.py`` builds it):
 
 - :func:`grouped_swiglu` / :func:`grouped_matmul` with ``tile > 1`` — the
-  Pallas kernels ``moe_grouped_swiglu`` and ``moe_grouped_down``.  Every
+  Pallas kernels ``moe_grouped_swiglu`` and ``moe_grouped_down``
+  (:func:`grouped_relu2`, ``moe_grouped_relu2``, for an expert with no
+  gate: ``relu(x @ w_up[e])^2``).  Every
   expert's rows are padded to a multiple of ``tile``, so a tile of rows
   belongs to ONE expert and a grid step is a plain ``(tile, K) x (K, tn)``
   product: no masks, no accumulator.  ``tile_expert`` (scalar prefetch)
@@ -35,7 +37,7 @@ from jax.experimental import pallas as pl
 
 from .flash_attention import _interpret
 
-__all__ = ["grouped_swiglu", "grouped_matmul"]
+__all__ = ["grouped_swiglu", "grouped_relu2", "grouped_matmul"]
 
 _VMEM_LIMIT = 96 * 1024 * 1024
 
@@ -58,6 +60,14 @@ def _swiglu_kernel(te_ref, used_ref, x_ref, wg_ref, wu_ref, o_ref):
         g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
         o_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
+
+
+def _relu2_kernel(te_ref, used_ref, x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        u = jnp.maximum(jnp.dot(x_ref[...], w_ref[0],
+                                preferred_element_type=jnp.float32), 0.0)
+        o_ref[...] = (u * u).astype(o_ref.dtype)
 
 
 def _matmul_kernel(te_ref, used_ref, x_ref, w_ref, o_ref):
@@ -112,6 +122,19 @@ def grouped_swiglu(x, w_gate, w_up, group_sizes, tile_expert, tiles_used,
     return _grouped_call(_swiglu_kernel, "moe_grouped_swiglu", x,
                          (w_gate, w_up), tile_expert, tiles_used, tile,
                          col_tile, interpret)
+
+
+def grouped_relu2(x, w_up, group_sizes, tile_expert, tiles_used, tile: int,
+                  col_tile: int = 512, interpret: Optional[bool] = None):
+    """``relu(x @ w_up[e])^2`` for rows ``x (M, K)`` grouped by expert,
+    weights ``(E, K, F)``: ``(M, F)``."""
+    if tile == 1:
+        u = jnp.maximum(lax.ragged_dot(x, w_up, group_sizes,
+                                       preferred_element_type=jnp.float32),
+                        0.0)
+        return (u * u).astype(x.dtype)
+    return _grouped_call(_relu2_kernel, "moe_grouped_relu2", x, (w_up,),
+                         tile_expert, tiles_used, tile, col_tile, interpret)
 
 
 def grouped_matmul(x, w, group_sizes, tile_expert, tiles_used, tile: int,

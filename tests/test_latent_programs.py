@@ -4,8 +4,13 @@ kind, an output gate): the jaxprs of one ``LatentAttention`` forward of
 each model, in decode and in prefill over the page pool, and of the
 kernels ``mla_latent_attn`` and ``dsa_sparse_attn`` are compared as text
 with a copy taken before that change
-(``fixtures/latent_programs.json.gz``).  Only what such a change could
-move is pinned: not the engine's step, the stack or the head around it.
+(``fixtures/latent_programs.json.gz``).
+
+Nor do the whole serving step programs of DeepSeek-V2, GLM-5, dots3 and
+MiMo-V2 (``<model>.step_decode`` / ``<model>.step_prefill``: the engine's
+packing, the stack, the expert layers, the head) move when the stack, the
+cache and the expert layer gain what a model of another shape needs (a
+block that is one branch, state held a sequence, latent relu2 experts).
 
 To take the copy anew on purpose (after a change that is MEANT to move
 these programs): ``PYTHONPATH=. JAX_PLATFORMS=cpu python
@@ -29,6 +34,10 @@ BLOCKS, BLOCK = 32, 8
 PHASES = {"deepseek_v2": (("decode", 2, 1), ("prefill", 1, 64),
                           ("prefill_blocked", 1, 6144)),
           "glm5": (("decode", 2, 1), ("prefill", 1, 64))}
+# the models whose whole engine step program is pinned, decode and a
+# prefill bucket
+STEP_MODELS = ("deepseek_v2", "glm5", "dots3", "mimo_v2")
+STEP_PREFILL = 32
 
 
 def program_texts():
@@ -62,6 +71,49 @@ def _attention_text(model, rows, chunk):
         i32(rows, chunk), i32(rows)))
 
 
+def _step_models():
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
+                                               deepseek_v2_tiny)
+    from paddle_tpu.models.dots3 import Dots3ForCausalLM, dots3_tiny
+    from paddle_tpu.models.glm5 import Glm5ForCausalLM, glm5_tiny
+    from paddle_tpu.models.mimo_v2 import MimoV2ForCausalLM, mimo_v2_tiny
+    return {"deepseek_v2": (DeepseekV2ForCausalLM,
+                            deepseek_v2_tiny(ep_degree=4)),
+            "glm5": (Glm5ForCausalLM, glm5_tiny(ep_degree=4)),
+            "dots3": (Dots3ForCausalLM, dots3_tiny(ep_degree=4)),
+            "mimo_v2": (MimoV2ForCausalLM, mimo_v2_tiny(ep_degree=4))}
+
+
+def _step_texts(name, cls, cfg):
+    """The engine's step program of a tiny engine over ``cls(cfg)``, a
+    decode step and a prefill of ``STEP_PREFILL``, as jaxpr text."""
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.inference import ServingEngine
+    from paddle_tpu.inference.engine import pack_step_inputs
+    pt.seed(0)
+    eng = ServingEngine(cls(cfg), max_seqs=2, kv_block_size=8,
+                        max_model_len=64)
+    fn = eng._build_step_fn()
+    out = {}
+    for phase, rows, chunk in (("step_decode", 2, 1),
+                               ("step_prefill", 1, STEP_PREFILL)):
+        sids = [f"none-{i}" for i in range(rows)]
+        tables = eng.cache.step_tables(sids, eng.sched.max_blocks_per_seq)
+        slots = eng.cache.step_slots(sids, [-1] * rows, chunk)
+        if len(tables) == 1:
+            tables, slots = tables[0], slots[0]
+        packed = pack_step_inputs(
+            np.zeros((rows, chunk), np.int32), np.zeros((rows,), np.int32),
+            0, tables, np.zeros((rows,), np.int32), slots)
+        out[f"{name}.{phase}"] = str(jax.make_jaxpr(
+            lambda *a: fn(*a, rows=rows, chunk=chunk))(
+            eng._params, packed, eng.cache.pages, eng._key, eng._no_prev))
+    eng.stop()
+    return out
+
+
 def _program_texts():
     import paddle_tpu as pt
     from paddle_tpu.inference.latent_attention import latent_attention_pallas
@@ -91,6 +143,8 @@ def _program_texts():
         lambda *a: dsa_sparse_attn_pallas(*a, value_width=64, scale=0.1,
                                           interpret=True))(
         q, pages, table, lens, scores, threshold, lens))
+    for name, (cls, cfg) in _step_models().items():
+        out.update(_step_texts(name, cls, cfg))
     return out
 
 
@@ -107,7 +161,9 @@ def before():
 
 @pytest.mark.parametrize("name", [
     f"{model}.{phase}" for model, phases in PHASES.items()
-    for phase, _, _ in phases] + ["mla_latent_attn", "dsa_sparse_attn"])
+    for phase, _, _ in phases] + ["mla_latent_attn", "dsa_sparse_attn"]
+    + [f"{model}.{phase}" for model in STEP_MODELS
+       for phase in ("step_decode", "step_prefill")])
 def test_the_program_is_the_one_before_the_change(programs, before, name):
     now, then = programs[name], before[name]
     if now != then:

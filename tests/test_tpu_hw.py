@@ -1137,3 +1137,115 @@ def test_dots3_decode_step_compiled_for_v5e_keeps_both_latent_pools_in_place():
     assert p["grouped_calls"] == 8, p                  # 4 layers x (up, down)
     assert (p["row_arrays"], p["slot_arrays"]) == (0, 0), p
     print("plan_bytes", p["plan_bytes"])
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the step programs of nemotron3-super-ep8-l11,
+# compiled for a v5e ahead of time: the state kernel lowers through Mosaic at
+# the published widths, and every state array and page array stays in place
+# ---------------------------------------------------------------------------
+_AOT_NEMOTRON_SCRIPT = _AOT_DEEPSEEK_SCRIPT.split("from paddle_tpu.inference import ServingEngine")[0] + r"""
+import paddle_tpu.inference.gqa_attention as ga
+import paddle_tpu.inference.ssm_state as ss
+ga._interpret = ss._interpret = lambda: False
+from paddle_tpu.inference import ServingEngine
+from paddle_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
+from paddle_tpu.observability.registry import MetricsRegistry
+
+# perfbench/configs/nemotron3-super-ep8-l11.json + traffic/statectx-backlog
+traffic = json.load(open("perfbench/traffic/statectx-backlog.json"))["engine"]
+SEQS, LEN = traffic["max_seqs"], traffic["max_model_len"]
+BS, BLOCKS = traffic["kv_block_size"], traffic["num_kv_blocks"]
+cfg = NemotronHConfig(vocab_size=16384, hybrid_override_pattern="MEMEMEMEM*E",
+                      n_routed_experts=512, dtype="bfloat16", ep_degree=8,
+                      ep_rank=0)
+eng = ServingEngine(NemotronHForCausalLM(cfg), max_seqs=SEQS,
+                    max_model_len=LEN, kv_block_size=BS,
+                    num_kv_blocks={"full": 8, "state": 2},
+                    registry=MetricsRegistry())
+sh = SingleDeviceSharding(dev)
+S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+slots = BLOCKS["state"] + 1           # and the padding rows' spare
+tail, state = (slots, 4, 10240), (slots, 128, 64, 128)
+page = (BLOCKS["full"], BS, 256)
+pages = [(S(page, jnp.bfloat16),) * 2 if k == "*" else
+         (S(tail, jnp.bfloat16), S(state, jnp.float32))
+         for k in cfg.hybrid_override_pattern if k != "E"]
+pool_bytes = 2 * int(np.prod(page)) * 2 + 5 * (
+    int(np.prod(tail)) * 2 + int(np.prod(state)) * 4)
+params = abstract(eng._params)
+widths = eng.cache.table_widths(eng.sched.max_blocks_per_seq)
+for name, rows, chunk in (("serve_decode", SEQS, 1),
+                          ("serve_prefill_b2048", 1, 2048)):
+    # ids, positions, last index, a table a kind (the state kind's: a slot
+    # a row), lengths, slots of the paged kind, the step's number
+    packed = S((rows * chunk + rows + 1 + rows * sum(widths) + rows
+                + rows * chunk + rows + 1,), jnp.int32)
+    c = eng._build_step_fn().lower(
+        params, packed, pages, abstract(jax.random.PRNGKey(0)),
+        S((SEQS,), jnp.int32), rows=rows, chunk=chunk).compile()
+    text, ma = c.as_text(), c.memory_analysis()
+    header = text.split("input_output_alias={", 1)[1].split(
+        "entry_computation_layout", 1)[0]
+    calls = lambda k: len(re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*" + k + "|" + k
+        + r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    print("aot-program", json.dumps({
+        "name": name, "widths": list(widths),
+        "n_params": sum(int(np.prod(a.shape))
+                        for a in jax.tree.leaves(params)),
+        "pool_copies": len(re.findall(
+            r"= (?:bf16\[%d,128,256\]|bf16\[%d,4,10240\]|f32\[%d,128,64,128\])"
+            r"\S* copy(-start)?\(" % (BLOCKS["full"], slots, slots), text)),
+        "aliased": len(re.findall(r"\(\d+, \{\}", header)),
+        "alias_bytes": ma.alias_size_in_bytes, "pool_bytes": pool_bytes,
+        "plan_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes,
+        "ssm_calls": calls("ssm_state_decode"),
+        "full_calls": calls("gqa_full_decode"),
+        "grouped_calls": calls("moe_grouped")}), flush=True)
+print("aot-serve-ok")
+"""
+
+
+@functools.lru_cache(maxsize=1)
+def _aot_nemotron_programs():
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_NEMOTRON_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=1200)
+    if "aot-topology-ok" not in out.stdout:
+        return None, (out.stdout + out.stderr)[-600:]
+    assert out.returncode == 0 and "aot-serve-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    rows = [json.loads(line.split(" ", 1)[1])
+            for line in out.stdout.splitlines()
+            if line.startswith("aot-program ")]
+    return {r["name"]: r for r in rows}, ""
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b2048"])
+def test_nemotron_h_step_compiled_for_v5e_keeps_state_and_pages_in_place(
+        program):
+    """The cell's programs (published widths, 64 held experts,
+    128 rows, the traffic file's pool and 136 state slots) compile for a
+    v5e: a decode step holds one `ssm_state_decode` a Mamba layer and one
+    `gqa_full_decode`, a prefill neither; the 10 state arrays and 2 page
+    arrays alias their inputs with no state-shaped or pool-shaped copy;
+    the tables are 80 wide and a slot a row."""
+    programs, why = _aot_nemotron_programs()
+    if programs is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    p = programs[program]
+    assert p["n_params"] == 2_752_338_304, p           # 5.50 GB in bf16
+    assert p["widths"] == [80, 1], p
+    assert p["pool_copies"] == 0, p
+    assert p["aliased"] == 12 and p["alias_bytes"] == p["pool_bytes"], p
+    assert p["plan_bytes"] < 15.75e9, p
+    decode = program == "serve_decode"
+    assert (p["ssm_calls"], p["full_calls"]) == (
+        (5, 1) if decode else (0, 0)), p
+    assert p["grouped_calls"] == 10, p                 # 5 layers x (up, down)
+    print(program, "plan_bytes", p["plan_bytes"])
