@@ -17,15 +17,26 @@ them, and ``conv_kernel - 1`` = 3 is not one).
   one token a row, the slot's state read from HBM, stepped once and
   written back IN PLACE (``input_output_aliases``; the step program is
   handed the pool donated), and the row's new convolution tail written into
-  its slot beside it, a group's share of the channels a grid step.  The
-  tails stay in HBM (a DMA a step); :func:`ssm_tail_read`,
-  ``ssm_tail_read`` in a trace, gathers the rows' old tails from there.
-  (With an XLA gather and scatter of 128 tails the compiler kept every
-  tail array in VMEM for the step: a copy in and a copy out a layer.)  The grid walks head blocks outermost and rows
-  innermost, a head block being one group's heads, and the slot a row is a
-  scalar-prefetch operand.  Padding rows (``seq_lens`` 0) compute nothing;
-  their slot is the pool's spare, the one no sequence holds, so they are
-  consecutive blocks of one index and the pipeline moves none of it.
+  its slot beside it.  The grid walks rows outermost and blocks of ``G``
+  groups innermost (a group's heads are those that read one ``B`` and
+  ``C``); the slot a row is a scalar-prefetch operand.  ``G`` is
+  :func:`_groups_a_step`'s: the largest divisor of the groups whose state
+  blocks in and out, double-buffered, the row's other blocks and a group's
+  float32 temporaries fit under the kernel's VMEM limit (all 8 groups, a
+  row's whole 4 MiB state, at Nemotron-3-Super's widths: 128 steps a call
+  for 128 rows).  A step walks its groups one at a time; ``y = S C`` is a
+  product on the MXU (``C`` against the heads' rows of ``S``: ``y``
+  lane-dense, no reduction across lanes).  The window's ``G`` groups of
+  channels go to the row's slot by an ASYNCHRONOUS DMA, started before the
+  state is stepped and waited at the step's end: a synchronous one queued
+  behind the state's own DMAs and waited for them.  The tails stay in HBM;
+  :func:`ssm_tail_read`, ``ssm_tail_read`` in a trace, gathers the rows'
+  old tails from there.  (With an XLA gather and scatter of 128 tails the
+  compiler kept every tail array in VMEM for the step: a copy in and a
+  copy out a layer.)  Padding rows (``seq_lens`` 0) compute and write
+  nothing; their slot is the pool's spare, the one no sequence holds, and
+  they stay on its last block, so they are consecutive blocks of one index
+  and the pipeline moves it once.
 - :func:`ssm_state_decode_reference` — the same in ``jax.numpy``: the
   route off the TPU and the kernel's oracle.
 - :func:`ssm_chunk_scan` — a chunk of tokens from a given state, in XLA:
@@ -45,6 +56,7 @@ them, and ``conv_kernel - 1`` = 3 is not one).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -59,6 +71,9 @@ __all__ = ["ssm_state_decode", "ssm_state_decode_pallas",
            "causal_conv", "conv_step", "initial_state"]
 
 _VMEM_LIMIT = 32 << 20
+# float32 arrays of a group's state size that a grid step of the decode
+# kernel holds beside its blocks: the decayed state, the update, the sum
+_GROUP_TEMPS = 3
 _HI = lax.Precision.HIGHEST
 
 
@@ -218,82 +233,155 @@ def ssm_tail_read(tails, slots):
     return _tail_read_pallas(tails, slots, interpret=_interpret())
 
 
-def _decode_kernel(slots_ref, lens_ref, x_ref, dt_ref, a_ref, b_ref, c_ref,
-                   d_ref, new_tail_ref, s_ref, t_ref, y_ref, s_out_ref,
-                   t_out_ref):
+def _groups_a_step(groups, state_bytes, other_bytes, vmem=_VMEM_LIMIT):
+    """The groups a grid step of ``ssm_state_decode`` covers: the largest
+    divisor ``G`` of ``groups`` whose blocks, each double-buffered (the state
+    in and out, 4 x ``G`` x a group's ``state_bytes``; the row's other
+    blocks, 4 x ``G`` x ``other_bytes``), with a group's float32
+    temporaries beside them, fit under ``vmem``; 1 where none does."""
+    def fits(g):
+        return (4 * g * (state_bytes + other_bytes)
+                + _GROUP_TEMPS * state_bytes <= vmem)
+    return max((g for g in range(1, groups + 1)
+                if groups % g == 0 and fits(g)), default=1)
+
+
+def _vmem_bytes(shape, dtype):
+    """A block's bytes in VMEM: its last two dims padded to whole tiles,
+    ``(8, 128)`` in float32, ``(16, 128)`` in bf16."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 32 // item
+    *lead, r, c = shape
+    return (math.prod(lead) * -(-r // sub) * sub * -(-c // 128) * 128
+            * item)
+
+
+def _group_bytes(hb, p, n, k, cw, tail_dtype):
+    """``(state, other)``: one group's VMEM bytes in a grid step of
+    ``ssm_state_decode``: its heads' state block, and its share of the
+    row's other blocks (x in both layouts, y, dt, A, D, B, C and ``cw``
+    channels of the ``k``-row window)."""
+    f32 = jnp.float32
+    other = (_vmem_bytes((hb, p), f32) + 3 * _vmem_bytes((1, hb * p), f32)
+             + 2 * _vmem_bytes((hb, 1), f32) + 2 * _vmem_bytes((1, n), f32)
+             + _vmem_bytes((k, cw), tail_dtype))
+    return _vmem_bytes((hb, p, n), f32), other
+
+
+def _decode_kernel(slots_ref, lens_ref, x_ref, xf_ref, dt_ref, a_ref, b_ref,
+                   c_ref, d_ref, new_tail_ref, s_ref, t_ref, y_ref, s_out_ref,
+                   t_out_ref, sem):
     from jax.experimental.pallas import tpu as pltpu
     del t_ref                         # the same HBM buffer as t_out_ref
-    j, i = pl.program_id(0), pl.program_id(1)
+    i, j = pl.program_id(0), pl.program_id(1)
+    gs, hb, p = x_ref.shape[1:]
+    n = b_ref.shape[3]
+    cw = new_tail_ref.shape[2]
+    window = pltpu.make_async_copy(new_tail_ref, t_out_ref.at[
+        pl.ds(slots_ref[i], 1), :, pl.ds(j * cw, cw)], sem)
 
     @pl.when(lens_ref[i] > 0)
     def _():
-        cb = new_tail_ref.shape[2]
-        pltpu.sync_copy(new_tail_ref, t_out_ref.at[
-            pl.ds(slots_ref[i], 1), :, pl.ds(j * cb, cb)])
-        dt = dt_ref[0]                              # (hb, 1)
-        x = x_ref[0]                                # (hb, p)
-        decay = jnp.exp(dt * a_ref[...])            # (hb, 1)
-        s = (s_ref[0] * decay[:, :, None]
-             + (dt * x)[:, :, None] * b_ref[0, 0][None])   # (hb, p, n)
-        s_out_ref[0] = s
-        y_ref[0] = jnp.sum(s * c_ref[0, 0][None], axis=-1) + d_ref[...] * x
+        window.start()                # lands while the state is stepped
+
+        def group(g, carry):
+            heads = pl.ds(g * hb, hb)
+            dt = dt_ref[0, g]                           # (hb, 1)
+            s = (s_ref[0, heads] * jnp.exp(dt * a_ref[g])[:, :, None]
+                 + (dt * x_ref[0, g])[:, :, None] * b_ref[0, g][None])
+            s_out_ref[0, heads] = s                     # (hb, p, n)
+            # y = S C on the MXU: C on 8 rows against the heads' (hb x p)
+            # rows of S gives y lane-dense, with no lane reduction
+            sc = lax.dot_general(
+                jnp.broadcast_to(c_ref[0, g], (8, n)), s.reshape(hb * p, n),
+                (((1,), (1,)), ((), ())), precision=_HI,
+                preferred_element_type=jnp.float32)     # (8, hb * p)
+            y_ref[0, g] = sc[:1] + d_ref[g] * xf_ref[0, g]
+            return carry
+
+        lax.fori_loop(0, gs, group, 0)
+        window.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssm_state_decode_pallas(states, tails, new_tails, slots, lens, x, dt, a,
-                            bm, cm, d, interpret: Optional[bool] = None):
-    """The kernel (interpret-mode off TPU); arguments and results as
-    :func:`ssm_state_decode_reference`'s, but ``states`` and ``tails`` are
-    written in place and their spare slot (the padding rows') may be left
-    anyhow.  Jitted so that a step program lowers it once for all its
-    layers."""
+@functools.partial(jax.jit, static_argnames=("gs", "interpret"))
+def _decode_call(states, tails, new_tails, slots, lens, x, dt, a, bm, cm, d,
+                 gs: int, interpret: bool):
+    """The ``pallas_call``, ``gs`` groups a grid step (the program takes
+    them from :func:`_groups_a_step`)."""
     from jax.experimental.pallas import tpu as pltpu
     rows, h, p = x.shape
     groups, n = bm.shape[1], bm.shape[2]
     hb = h // groups                                # a group's heads
     k, channels = tails.shape[1:]
-    cb = channels // groups                         # a grid step's channels
+    cw = channels // groups * gs                    # a grid step's channels
+    blocks = groups // gs
     f32 = lambda t: t.astype(jnp.float32)          # noqa: E731
-    if interpret is None:
-        interpret = _interpret()
 
     def by_row(shape):
-        return pl.BlockSpec((1,) + shape,
-                            lambda j, i, sl, ln: (i, j) + (0,) * (len(shape)
-                                                               - 1))
+        return pl.BlockSpec((1, gs) + shape,
+                            lambda i, j, sl, ln: (i, j) + (0,) * len(shape))
 
-    def by_head(shape):
-        return pl.BlockSpec(shape, lambda j, i, sl, ln: (j, 0))
+    def by_group(shape):
+        return pl.BlockSpec((gs,) + shape,
+                            lambda i, j, sl, ln: (j,) + (0,) * len(shape))
 
-    state_spec = pl.BlockSpec((1, hb, p, n),
-                              lambda j, i, sl, ln: (sl[i], j, 0, 0))
+    # a padding row stays on its spare slot's last block: consecutive
+    # steps of one block index, which the pipeline moves once
+    state_spec = pl.BlockSpec(
+        (1, gs * hb, p, n),
+        lambda i, j, sl, ln: (sl[i], jnp.where(ln[i] > 0, j, blocks - 1),
+                              0, 0))
     tail_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # slots, seq_lens
-        grid=(groups, rows),
-        in_specs=[by_row((hb, p)), by_row((hb, 1)), by_head((hb, 1)),
-                  by_row((1, 1, n)), by_row((1, 1, n)), by_head((hb, 1)),
-                  pl.BlockSpec((1, k, cb),
-                               lambda j, i, sl, ln: (i, 0, j)),
+        grid=(rows, blocks),
+        in_specs=[by_row((hb, p)), by_row((1, hb * p)), by_row((hb, 1)),
+                  by_group((hb, 1)), by_row((1, n)), by_row((1, n)),
+                  by_group((1, hb * p)),
+                  pl.BlockSpec((1, k, cw), lambda i, j, sl, ln: (i, 0, j)),
                   state_spec, tail_spec],
-        out_specs=[by_row((hb, p)), state_spec, tail_spec])
+        out_specs=[by_row((1, hb * p)), state_spec, tail_spec],
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())])
     y, states, tails = pl.pallas_call(
         _decode_kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((rows, h, p), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((rows, groups, 1, hb * p),
+                                        jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype),
                    jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
         # the state and the tails, counted after the 2 prefetched
-        input_output_aliases={9: 1, 10: 2},
+        input_output_aliases={10: 1, 11: 2},
         name="ssm_state_decode",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(jnp.asarray(slots, jnp.int32), jnp.asarray(lens, jnp.int32),
-      f32(x), f32(dt)[..., None], f32(a)[:, None],
-      f32(bm)[:, :, None, :], f32(cm)[:, :, None, :], f32(d)[:, None],
+      f32(x).reshape(rows, groups, hb, p),
+      f32(x).reshape(rows, groups, 1, hb * p),
+      f32(dt).reshape(rows, groups, hb, 1), f32(a).reshape(groups, hb, 1),
+      f32(bm)[:, :, None, :], f32(cm)[:, :, None, :],
+      jnp.repeat(f32(d), p).reshape(groups, 1, hb * p),
       new_tails.astype(tails.dtype), states, tails)
-    return y, states, tails
+    return y.reshape(rows, h, p), states, tails
+
+
+def ssm_state_decode_pallas(states, tails, new_tails, slots, lens, x, dt, a,
+                            bm, cm, d, interpret: Optional[bool] = None):
+    """The kernel (interpret-mode off TPU); arguments and results as
+    :func:`ssm_state_decode_reference`'s, but ``states`` and ``tails`` are
+    written in place and their spare slot (the padding rows') may be left
+    anyhow.  A grid step covers the groups :func:`_groups_a_step` picks from
+    the shapes; the call is jitted, so that a step program lowers it once
+    for all its layers."""
+    groups, n = bm.shape[1], bm.shape[2]
+    hb, p = x.shape[1] // groups, x.shape[2]
+    k, channels = tails.shape[1:]
+    gs = _groups_a_step(groups, *_group_bytes(
+        hb, p, n, k, channels // groups, tails.dtype))
+    return _decode_call(states, tails, new_tails, slots, lens, x, dt, a, bm,
+                        cm, d, gs=gs,
+                        interpret=_interpret() if interpret is None
+                        else interpret)
 
 
 def ssm_state_decode(states, tails, new_tails, slots, lens, x, dt, a, bm, cm,

@@ -275,9 +275,16 @@ def test_the_state_check_sees_the_precision_the_state_is_kept_in(
 
 
 # -- the kernels ---------------------------------------------------------
-def test_the_decode_kernel_matches_its_oracle():
+# (groups, the groups a VMEM budget fits, the G the rule picks from them):
+# the public entry, whose rule takes every group at these widths; budgets
+# of 1, 2, 4 and all 8 groups; and one of 5 of 6, which 5 does not divide
+@pytest.mark.parametrize("groups,fit,gs", [(2, None, None), (8, 1, 1),
+                                           (8, 2, 2), (8, 4, 4), (8, 8, 8),
+                                           (6, 5, 3)])
+def test_the_decode_kernel_matches_its_oracle(groups, fit, gs):
     r = np.random.default_rng(0)
-    h, p, n, g, rows, slots = 8, 16, 128, 2, 6, 7
+    h, p, n, g, rows, slots = 8 if gs is None else 2 * groups, 16, 128, \
+        groups, 6, 7
     f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)  # noqa: E731
     states = f(slots + 1, h, p, n)
     at = jnp.asarray([5, 0, 3, 6, 7, 7], jnp.int32)     # out of order
@@ -285,11 +292,23 @@ def test_the_decode_kernel_matches_its_oracle():
     x, dt = f(rows, h, p), jnp.abs(f(rows, h)) * 0.3
     a, d = -jnp.exp(f(h)), f(h)
     bm, cm = f(rows, g, n), f(rows, g, n)
-    tails, new = f(slots + 1, 4, 256), f(rows, 4, 256)
+    tails, new = f(slots + 1, 4, 128 * g), f(rows, 4, 128 * g)
     y0, s0, t0 = ssm_state.ssm_state_decode_reference(
         states, tails, new, at, lens, x, dt, a, bm, cm, d)
-    y1, s1, t1 = ssm_state.ssm_state_decode_pallas(
-        states, tails, new, at, lens, x, dt, a, bm, cm, d, interpret=True)
+    if gs is None:
+        y1, s1, t1 = ssm_state.ssm_state_decode_pallas(
+            states, tails, new, at, lens, x, dt, a, bm, cm, d,
+            interpret=True)
+    else:
+        state_b, other_b = ssm_state._group_bytes(h // g, p, n, 4, 128,
+                                                  tails.dtype)
+        budget = (4 * fit * (state_b + other_b)
+                  + ssm_state._GROUP_TEMPS * state_b)
+        assert ssm_state._groups_a_step(g, state_b, other_b,
+                                        vmem=budget) == gs
+        y1, s1, t1 = ssm_state._decode_call(
+            states, tails, new, at, lens, x, dt, a, bm, cm, d, gs=gs,
+            interpret=True)
     np.testing.assert_allclose(np.asarray(y1)[:4], np.asarray(y0)[:4],
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(s1)[:slots],

@@ -1168,6 +1168,27 @@ S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 abstract = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
 slots = BLOCKS["state"] + 1           # and the padding rows' spare
 tail, state = (slots, 4, 10240), (slots, 128, 64, 128)
+
+# the state kernel alone at the cell's decode shapes: the groups a grid
+# step the rule picks, and the VMEM the compiled call is given
+f32 = jnp.float32
+group_bytes = ss._group_bytes(16, 64, 128, 4, 10240 // 8, jnp.bfloat16)
+gs = ss._groups_a_step(8, *group_bytes)
+text = jax.jit(ss.ssm_state_decode_pallas).lower(
+    S(state, f32), S(tail, jnp.bfloat16), S((SEQS, 4, 10240), jnp.bfloat16),
+    S((SEQS,), jnp.int32), S((SEQS,), jnp.int32), S((SEQS, 128, 64), f32),
+    S((SEQS, 128), f32), S((128,), f32), S((SEQS, 8, 128), f32),
+    S((SEQS, 8, 128), f32), S((128,), f32)).compile().as_text()
+calls = [line for line in text.splitlines() if "ssm_state_decode" in line
+         and 'custom_call_target="tpu_custom_call"' in line]
+print("aot-kernel", json.dumps({
+    "rows": SEQS, "slots": slots, "groups_a_step": gs,
+    "planned_bytes": 4 * gs * sum(group_bytes) + ss._GROUP_TEMPS
+    * group_bytes[0], "vmem_limit": ss._VMEM_LIMIT,
+    "scoped_vmem": [int(v) for call in calls for v in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+        r'"size":"(\d+)"', call)],
+    "ssm_calls": len(calls)}), flush=True)
 page = (BLOCKS["full"], BS, 256)
 pages = [(S(page, jnp.bfloat16),) * 2 if k == "*" else
          (S(tail, jnp.bfloat16), S(state, jnp.float32))
@@ -1222,8 +1243,25 @@ def _aot_nemotron_programs():
         f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
     rows = [json.loads(line.split(" ", 1)[1])
             for line in out.stdout.splitlines()
-            if line.startswith("aot-program ")]
-    return {r["name"]: r for r in rows}, ""
+            if line.startswith(("aot-program ", "aot-kernel "))]
+    return {r.get("name", "kernel"): r for r in rows}, ""
+
+
+def test_nemotron_h_state_kernel_compiled_for_v5e_takes_a_row_a_step():
+    """The state kernel alone at the cell's decode shapes (128 rows, 137
+    slots, 128 heads of 64, state 128, 8 groups, 10,240 window channels)
+    lowers through Mosaic as one custom call, and the rule gives a grid
+    step all 8 groups of a row: blocks planned at 22.75 MiB, under the
+    32 MiB of VMEM the call is given."""
+    programs, why = _aot_nemotron_programs()
+    if programs is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    k = programs["kernel"]
+    assert (k["rows"], k["slots"]) == (128, 137), k
+    assert k["groups_a_step"] == 8, k
+    assert k["planned_bytes"] == 23_855_104 <= k["vmem_limit"], k
+    assert k["scoped_vmem"] == [k["vmem_limit"]] == [32 << 20], k
+    assert k["ssm_calls"] == 1, k
 
 
 @pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_b2048"])
