@@ -10,20 +10,24 @@ online softmax over KV blocks keeps everything in VMEM (the whole point of a
 TPU-native rewrite: HBM bandwidth is the bottleneck, SURVEY §7 hard-part 2).
 
 Layout: q, k, v are (batch, heads, seq, head_dim), flattened to
-(batch*heads, seq, head_dim) for the kernel; grid = (batch*heads, q block,
-kv block) with kv innermost — the flash (m, l, acc) recurrence lives in
-VMEM scratch across the kv steps, in fp32, so per-step residency is
-O(block) and sequence length is HBM-bound (S=65536 runs single-chip).
-Backward is recompute-based (no probability tensor saved): a dkdv kernel on a
-(bh, kv block, q block) grid accumulating into revisited f32 output blocks,
-and a dq kernel over Q blocks, both replaying p = exp(qk - lse).  Backward
-VMEM residency is O(block), so sequence length is bounded by HBM, not the
-16MB scoped-vmem limit (S=8192 fwd+bwd measured 30ms vs 737ms for XLA
-attention on v5e in July; a claim to re-measure).
+(batch*heads, seq, head_dim) for the kernel.  The training kernels run on
+(batch*heads, block, block) grids.  The forward keeps the flash (m, l, acc)
+recurrence in VMEM scratch across a q block's kv blocks, in fp32, so
+per-step residency is O(block) and sequence length is HBM-bound (S=65536
+runs single-chip).  Backward is recompute-based (no probability tensor
+saved): a dkdv kernel over KV blocks and a dq kernel over Q blocks, both
+replaying p = exp(qk - lse) and accumulating into revisited f32 output
+blocks.  Backward VMEM residency is O(block), so sequence length is bounded
+by HBM, not the scoped-vmem limit.
 
-Causal masking is block-skipped: programs never visit KV blocks strictly
-above the diagonal, so the causal fwd does ~half the FLOPs — the fusion
-`fused_softmax_mask_upper_triangle` only saves bandwidth, not compute.
+Causal masking pays only where the diagonal crosses a block
+(``_pair_class``): a pair wholly above the diagonal, or wholly past the real
+key length, computes nothing, and its index maps repeat the block of the
+nearest live step, so it fetches nothing either; a pair wholly on or under
+the diagonal, and inside the key length, runs with no mask at all; only a
+pair the diagonal or the key length cuts builds one.  So the causal kernels
+do ~half the FLOPs of the full square, and most of their pairs no mask
+work.
 
 Attention-prob dropout runs IN-KERNEL (the reference's Philox-offset
 trick, counter-based): the keep mask for element (bh, row, col) is a pure
@@ -62,6 +66,11 @@ _NEG_INF = -1e30
 # they travel as (bh, seq, _LANES) with the value broadcast across the 128
 # lanes; kernels slice lanes back down to the KV-block width elementwise.
 _LANES = 128
+# The training kernels trace two bodies a pair class (masked and not), and
+# Mosaic keeps each body's (block_q, block_k) float32 temporaries apart: at
+# blocks of 1024 in float32 with heads of 128, flash_bwd_dkdv plans 19.3 MB,
+# past the 16 MB default scoped limit (a v5e core has 128 MiB of VMEM).
+_VMEM_LIMIT = 64 << 20
 
 
 
@@ -94,8 +103,9 @@ def _stat_tile(x, width):
 
 
 def _block_sizes(seq_q: int, seq_k: int):
-    # swept on v5e (3D-grid kernels, bh·S·d with d=64, best-of-3 fwd+bwd;
-    # July figures, to re-measure): at S=2048, 512/512 = 13.9ms vs
+    # swept on v5e for the kernels that masked every pair (bh·S·d with
+    # d=64, best-of-3 fwd+bwd; July figures, not re-measured since the
+    # mask split): at S=2048, 512/512 = 13.9ms vs
     # 19.5ms for 1024 and 46ms for 128 (small blocks starve the MXU when
     # the contraction dim is only 64); at S>=4096 the longer grid favors
     # 1024/1024 (S=4096: 23.1 vs 25.6ms; S=8192: 30.1 vs 35.2ms).
@@ -144,6 +154,126 @@ def _keep_mask(seed_u32, bh, rows, cols, dropout_p):
 
 
 # ---------------------------------------------------------------------------
+# Pair classes: where the causal diagonal and the real key length fall
+# ---------------------------------------------------------------------------
+def _pair_class(qi, kj, block_q, block_k, offset, kv_len, causal):
+    """Where the pair (q block ``qi``, kv block ``kj``) lies against the
+    causal diagonal and the real key length, as ``(live, masked)``.
+
+    ``live``: some (row, col) of the pair attends: a col below ``kv_len``
+    and, causal, on or under the diagonal of bottom-right alignment (row +
+    ``offset`` >= col).  ``masked``: live, and some element does not
+    attend: the diagonal crosses the pair, or it holds ``kv_len``.  A live
+    pair that is not masked runs with no mask at all.  Pure: the indices
+    and lengths are Python ints (``flash_pair_counts``) or int32 scalars
+    read in a kernel."""
+    first_col = kj * block_k
+    last_col = first_col + block_k - 1
+    live = first_col < kv_len
+    cut = last_col >= kv_len
+    if causal:
+        live = live & ((qi + 1) * block_q - 1 + offset >= first_col)
+        cut = cut | (qi * block_q + offset < last_col)
+    return live, live & cut
+
+
+def _cuts_length(kv_len, block_k) -> bool:
+    """Whether some pair can hold the real key length inside it (a traced
+    length always can); where none can, no mask compares a col with it."""
+    return not isinstance(kv_len, int) or kv_len % block_k != 0
+
+
+def _positions(qi, kj, block_q, block_k):
+    """The (rows, cols) of a pair's (block_q, block_k) scores."""
+    rows = qi * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    cols = kj * block_k + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    return rows, cols
+
+
+def _mask_scores(s, rows, cols, *, kv_len, offset, causal, cut_len):
+    """``s`` with every element that does not attend at -inf."""
+    valid = cols < kv_len if cut_len else None
+    if causal:
+        below = rows + offset >= cols
+        valid = below if valid is None else valid & below
+    return jnp.where(valid, s, _NEG_INF)
+
+
+def _by_class(live, masked, body, can_mask):
+    """``body(mask)`` where ``live``: ``mask=True`` where the pair is also
+    ``masked``, ``False`` where it lies wholly inside; nothing elsewhere.
+    ``can_mask`` False (static) traces the unmasked body alone."""
+    if not can_mask:
+        pl.when(live)(lambda: body(False))
+        return
+    pl.when(live & masked)(lambda: body(True))
+    pl.when(live & jnp.logical_not(masked))(lambda: body(False))
+
+
+def _last_kv(qi, block_q, block_k, offset, kv_len, causal):
+    """The last kv block that q block ``qi`` attends (0 where it attends
+    none), for a kv-side index map to clamp to (``_pair_class``'s live
+    pairs of a q block are kv blocks 0 to this one)."""
+    last = jnp.maximum(kv_len - 1, 0) // block_k
+    if causal:
+        diag = jnp.maximum(qi * block_q + block_q - 1 + offset, 0) // block_k
+        last = jnp.minimum(diag, last)
+    return last
+
+
+def _first_q(kj, block_q, block_k, offset, kv_len, causal, num_q):
+    """The first q block that attends kv block ``kj`` (the last q block
+    where none does), for a q-side index map to clamp to (``_pair_class``'s
+    live pairs of a kv block are q blocks from this one on)."""
+    if not causal:
+        return 0
+    first = jnp.minimum(jnp.maximum(kj * block_k - offset, 0) // block_q,
+                        num_q - 1)
+    return jnp.where(kj * block_k < kv_len, first, num_q - 1)
+
+
+def _kv_map(block_q, block_k, offset, kv_len, causal):
+    """A kv-side block's index map on a (bh, q block, kv block) grid: a
+    step past the q block's last live kv block repeats that block's index,
+    so its DMA is not issued."""
+    def kv_map(b, i, j):
+        return b, jnp.minimum(
+            j, _last_kv(i, block_q, block_k, offset, kv_len, causal)), 0
+    return kv_map
+
+
+def _q_map(block_q, block_k, offset, kv_len, causal, num_q):
+    """A q-side block's index map on a (bh, kv block, q block) grid: a
+    step before the kv block's first live q block takes that block's
+    index, so the steps above the diagonal fetch only what the first live
+    one needs."""
+    def q_map(b, j, i):
+        return b, jnp.maximum(i, _first_q(j, block_q, block_k, offset,
+                                          kv_len, causal, num_q)), 0
+    return q_map
+
+
+def flash_pair_counts(seq_q: int, seq_k: int, causal: bool = True):
+    """The training kernels' (q block, kv block) pairs of one batch*head
+    by class, for ``flash_attention`` over these lengths: ``skipped`` (no
+    element attends: no compute, no fetch), ``interior`` (every element
+    attends: no mask) and ``diagonal`` (the diagonal or the key length cuts
+    it: the mask is built).  Static per shape."""
+    sq, sk = _pad_to_legal(seq_q), _pad_to_legal(seq_k)
+    bq, bk = _block_sizes(sq, sk)
+    counts = {"skipped": 0, "interior": 0, "diagonal": 0}
+    for qi in range(sq // bq):
+        for kj in range(sk // bk):
+            live, masked = _pair_class(qi, kj, bq, bk, seq_k - seq_q, seq_k,
+                                       causal)
+            counts["diagonal" if masked else "interior" if live
+                   else "skipped"] += 1
+    return counts
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 def _init_state(kj, m_scr, l_scr, acc_scr):
@@ -157,30 +287,28 @@ def _init_state(kj, m_scr, l_scr, acc_scr):
 
 
 def _attend_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, qi, kj,
-                  work, scale, causal, block_q, block_k, kv_len, offset,
-                  keep=None):
+                  live, masked, scale, causal, block_q, block_k, kv_len,
+                  offset, keep=None):
     """The online-softmax recurrence over one (q block, kv block) pair,
     the body both forward entries share (training's ``flash_fwd`` and the
-    serving ``flash_prefill``), taken only where ``work``.  ``kv_len`` is
-    a static int (training) or a scalar read in the kernel (serving);
-    ``keep(rows, cols)``, where given, is the dropout mask of P.V."""
+    serving ``flash_prefill``), taken only where ``live`` and masked only
+    where ``masked`` (``_pair_class``).  ``kv_len`` is a static int
+    (training) or a scalar read in the kernel (serving); ``keep(rows,
+    cols)``, where given, is the dropout mask of P.V."""
+    cut_len = _cuts_length(kv_len, block_k)
 
-    @pl.when(work)
-    def _step():
+    def step(mask):
         # dots stay in the input dtype (bf16 on the fast path) with fp32
         # accumulation — casting to fp32 would run the MXU at 1/4 rate
         q = q_ref[0]                                      # (bq, d)
         k = k_ref[0]                                      # (bk, d)
         v = v_ref[0]
         s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
-        rows = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = kj * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = cols < kv_len
-        if causal:
-            valid = valid & (rows + offset >= cols)
-        s = jnp.where(valid, s, _NEG_INF)
+        if mask or keep is not None:
+            rows, cols = _positions(qi, kj, block_q, block_k)
+        if mask:
+            s = _mask_scores(s, rows, cols, kv_len=kv_len, offset=offset,
+                             causal=causal, cut_len=cut_len)
         # stats are lane-broadcast (bq, _LANES) tiles, all lanes equal
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -195,6 +323,8 @@ def _attend_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, qi, kj,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
         m_scr[...] = m_new
 
+    _by_class(live, masked, step, causal or cut_len)
+
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
@@ -203,28 +333,25 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # the flash (m, l, acc) state lives in VMEM scratch across the innermost
     # kv steps — residency is O(block) in sequence length (a 2D grid that
     # kept full k/v resident hit the 16MB scoped-vmem limit at S=8192 f32).
+    # Queries are bottom-right aligned against the REAL key length
+    # (``offset`` = kv_len - q_len over unpadded lengths).
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     kj = pl.program_id(2)
-    num_kv = pl.num_programs(2)
     seed = seed_ref[0, 0].astype(jnp.uint32)
     _init_state(kj, m_scr, l_scr, acc_scr)
-
-    # visit only blocks that touch real keys and (causal) the lower
-    # triangle; queries are bottom-right aligned against the REAL key
-    # length (``offset`` = kv_len - q_len over unpadded lengths)
-    work = kj * block_k < kv_len
-    if causal:
-        work &= (qi + 1) * block_q - 1 + offset >= kj * block_k
     keep = None
     if dropout_p > 0.0:
         keep = lambda rows, cols: _keep_mask(  # noqa: E731
             seed, bh, rows, cols, dropout_p)
+    live, masked = _pair_class(qi, kj, block_q, block_k, offset, kv_len,
+                               causal)
     _attend_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, qi=qi, kj=kj,
-                  work=work, scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, kv_len=kv_len, offset=offset, keep=keep)
+                  live=live, masked=masked, scale=scale, causal=causal,
+                  block_q=block_q, block_k=block_k, kv_len=kv_len,
+                  offset=offset, keep=keep)
 
-    @pl.when(kj == num_kv - 1)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...]
@@ -237,23 +364,24 @@ def _flash_fwd(q, k, v, seed, scale, causal, dropout_p, kv_len, offset):
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk)
-    grid = (bh, sq // bq, sk // bk)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=bq, block_k=bk, kv_len=kv_len, offset=offset,
         dropout_p=dropout_p)
+    at_q = lambda b, i, j: (b, i, 0)  # noqa: E731
+    at_kv = _kv_map(bq, bk, offset, kv_len, causal)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, sq // bq, sk // bk),
         in_specs=[
             pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),       # seed
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, d), at_q),
+            pl.BlockSpec((1, bk, d), at_kv),
+            pl.BlockSpec((1, bk, d), at_kv),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), at_q),
+            pl.BlockSpec((1, bq, _LANES), at_q),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
@@ -265,6 +393,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, dropout_p, kv_len, offset):
             pltpu.VMEM((bq, d), jnp.float32),        # acc
         ],
         name="flash_fwd",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
     )(seed, q, k, v)
     return out, lse[:, :, 0]  # keep the compact (bh, sq) form as residual
@@ -273,6 +402,27 @@ def _flash_fwd(q, k, v, seed, scale, causal, dropout_p, kv_len, offset):
 # ---------------------------------------------------------------------------
 # Backward (recompute): dkdv over KV blocks, dq over Q blocks
 # ---------------------------------------------------------------------------
+def _replay(q, k, v, do, lse, delta, seed, bh, *, qi, kj, mask, scale,
+            causal, block_q, block_k, kv_len, offset, dropout_p, cut_len):
+    """One pair's probabilities and score gradients replayed from the saved
+    ``lse`` and ``delta``: ``(pd, ds)``, ``pd`` the dropped probabilities
+    that weighed V, ``ds`` the gradient of the (scaled) scores."""
+    s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
+    if mask or dropout_p > 0.0:
+        rows, cols = _positions(qi, kj, block_q, block_k)
+    if mask:
+        s = _mask_scores(s, rows, cols, kv_len=kv_len, offset=offset,
+                         causal=causal, cut_len=cut_len)
+    p = jnp.exp(s - lse)                                  # (bq, bk)
+    if dropout_p > 0.0:
+        pd = jnp.where(_keep_mask(seed, bh, rows, cols, dropout_p),
+                       p * (1.0 / (1.0 - dropout_p)), 0.0)
+    else:
+        pd = p
+    dp = _dot(do, v, (((1,), (1,)), ((), ())))
+    return pd, (pd * dp - p * delta) * scale
+
+
 def _dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, *, scale, causal, block_q, block_k,
                  kv_len, offset, dropout_p):
@@ -285,50 +435,33 @@ def _dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     seed = seed_ref[0, 0].astype(jnp.uint32)
-    keep_scale = 1.0 / (1.0 - dropout_p)
 
     @pl.when(qi == 0)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    # causal block-skip: a block whose every (row, col) pair sits strictly
-    # above the diagonal contributes nothing; padded-KV blocks likewise
-    work = kj * block_k < kv_len
-    if causal:
-        work &= (qi + 1) * block_q - 1 + offset >= kj * block_k
+    cut_len = _cuts_length(kv_len, block_k)
 
-    @pl.when(work)
-    def _accumulate():
-        k = k_ref[0]                                      # (bk, d)
-        v = v_ref[0]
+    def accumulate(mask):
         q = q_ref[0]                                      # (bq, d)
         do = do_ref[0]
         # lane-broadcast stats: every lane holds the row's value, so widening
         # to block_k lanes gives an elementwise-ready (bq, bk) tile
-        lse = _stat_tile(lse_ref[0], block_k)
-        delta = _stat_tile(delta_ref[0], block_k)
-        s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
-        rows = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = kj * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = cols < kv_len
-        if causal:
-            valid = valid & (rows + offset >= cols)
-        s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse)                              # (bq, bk)
-        if dropout_p > 0.0:
-            pd = jnp.where(_keep_mask(seed, bh, rows, cols, dropout_p),
-                           p * keep_scale, 0.0)
-        else:
-            pd = p
+        pd, ds = _replay(
+            q, k_ref[0], v_ref[0], do, _stat_tile(lse_ref[0], block_k),
+            _stat_tile(delta_ref[0], block_k), seed, bh, qi=qi, kj=kj,
+            mask=mask, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, kv_len=kv_len, offset=offset,
+            dropout_p=dropout_p, cut_len=cut_len)
         dv_ref[0] += _dot(
             pd.astype(do.dtype), do, (((0,), (0,)), ((), ())))
-        dp = _dot(do, v, (((1,), (1,)), ((), ())))
-        ds = (pd * dp - p * delta) * scale
         dk_ref[0] += _dot(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())))
+
+    live, masked = _pair_class(qi, kj, block_q, block_k, offset, kv_len,
+                               causal)
+    _by_class(live, masked, accumulate, causal or cut_len)
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -340,46 +473,32 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     seed = seed_ref[0, 0].astype(jnp.uint32)
-    keep_scale = 1.0 / (1.0 - dropout_p)
 
     @pl.when(kj == 0)
     def _init():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
-    work = kj * block_k < kv_len
-    if causal:
-        work &= (qi + 1) * block_q - 1 + offset >= kj * block_k
+    cut_len = _cuts_length(kv_len, block_k)
 
-    @pl.when(work)
-    def _accumulate():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = _stat_tile(lse_ref[0], block_k)  # lane-broadcast → (bq, bk)
-        delta = _stat_tile(delta_ref[0], block_k)
+    def accumulate(mask):
         k = k_ref[0]
-        v = v_ref[0]
-        s = _dot(q, k, (((1,), (1,)), ((), ()))) * scale
-        rows = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = kj * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = cols < kv_len
-        if causal:
-            valid = valid & (rows + offset >= cols)
-        s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        if dropout_p > 0.0:
-            pd = jnp.where(_keep_mask(seed, bh, rows, cols, dropout_p),
-                           p * keep_scale, 0.0)
-        else:
-            pd = p
-        dp = _dot(do, v, (((1,), (1,)), ((), ())))
-        ds = (pd * dp - p * delta) * scale
+        _, ds = _replay(
+            q_ref[0], k, v_ref[0], do_ref[0],
+            _stat_tile(lse_ref[0], block_k),  # lane-broadcast → (bq, bk)
+            _stat_tile(delta_ref[0], block_k), seed, bh, qi=qi, kj=kj,
+            mask=mask, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, kv_len=kv_len, offset=offset,
+            dropout_p=dropout_p, cut_len=cut_len)
         dq_ref[0] += _dot(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())))
 
+    live, masked = _pair_class(qi, kj, block_q, block_k, offset, kv_len,
+                               causal)
+    _by_class(live, masked, accumulate, causal or cut_len)
+
 
 def _flash_bwd(scale, causal, dropout_p, kv_len, offset, res, g):
+    from jax.experimental.pallas import tpu as pltpu
     q, k, v, seed, out, lse = res
     do = g
     bh, sq, d = q.shape
@@ -391,62 +510,50 @@ def _flash_bwd(scale, causal, dropout_p, kv_len, offset, res, g):
     # backward, so the standard recurrence still holds.
     lse_b = jnp.broadcast_to(lse[..., None], (bh, sq, _LANES))
     delta_b = jnp.broadcast_to(delta[..., None], (bh, sq, _LANES))
+    static = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
+                  kv_len=kv_len, offset=offset, dropout_p=dropout_p)
+    nq, nk = sq // bq, sk // bk
 
-    dkdv = functools.partial(
-        _dkdv_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        kv_len=kv_len, offset=offset, dropout_p=dropout_p)
-    # f32 outputs: they double as the cross-q-block accumulators (Mosaic
-    # keeps a revisited output block in VMEM until the revisit chain ends)
-    dk, dv = pl.pallas_call(
-        dkdv,
-        grid=(bh, sk // bk, sq // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, j, i: (0, 0)),        # seed
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),  # q
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),  # k
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),  # v
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),  # do
-            pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0)),  # lse
-            pl.BlockSpec((1, bq, _LANES),
-                         lambda b, j, i: (b, i, 0)),              # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-        ],
-        name="flash_bwd_dkdv",
-        interpret=_interpret(),
-    )(seed, q, k, v, do, lse_b, delta_b)
-    dk = dk.astype(k.dtype)
-    dv = dv.astype(v.dtype)
+    def call(kernel, grid, at_q, at_kv, out_specs, out_shape, name):
+        return pl.pallas_call(
+            functools.partial(kernel, **static),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1), lambda b, x, y: (0, 0)),    # seed
+                pl.BlockSpec((1, bq, d), at_q),                  # q
+                pl.BlockSpec((1, bk, d), at_kv),                 # k
+                pl.BlockSpec((1, bk, d), at_kv),                 # v
+                pl.BlockSpec((1, bq, d), at_q),                  # do
+                pl.BlockSpec((1, bq, _LANES), at_q),             # lse
+                pl.BlockSpec((1, bq, _LANES), at_q),             # delta
+            ],
+            out_specs=out_specs, out_shape=out_shape, name=name,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=_interpret(),
+        )(seed, q, k, v, do, lse_b, delta_b)
 
-    dqk = functools.partial(
-        _dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        kv_len=kv_len, offset=offset, dropout_p=dropout_p)
-    dq = pl.pallas_call(
-        dqk,
-        grid=(bh, sq // bq, sk // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),         # seed
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),  # q
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),  # k
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),  # v
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),  # do
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),  # lse
-            pl.BlockSpec((1, bq, _LANES),
-                         lambda b, i, j: (b, i, 0)),              # delta
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-        name="flash_bwd_dq",
-        interpret=_interpret(),
-    )(seed, q, k, v, do, lse_b, delta_b).astype(q.dtype)
+    # f32 outputs: they double as the accumulators across the innermost
+    # steps (Mosaic keeps a revisited output block in VMEM until the
+    # revisit chain ends)
+    kv_block = lambda b, j, i: (b, j, 0)  # noqa: E731
+    dk, dv = call(
+        _dkdv_kernel, (bh, nk, nq),
+        _q_map(bq, bk, offset, kv_len, causal, nq), kv_block,
+        [pl.BlockSpec((1, bk, d), kv_block),
+         pl.BlockSpec((1, bk, d), kv_block)],
+        [jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+         jax.ShapeDtypeStruct((bh, sk, d), jnp.float32)],
+        "flash_bwd_dkdv")
+    q_block = lambda b, i, j: (b, i, 0)  # noqa: E731
+    dq = call(
+        _dq_kernel, (bh, nq, nk), q_block,
+        _kv_map(bq, bk, offset, kv_len, causal),
+        pl.BlockSpec((1, bq, d), q_block),
+        jax.ShapeDtypeStruct((bh, sq, d), jnp.float32), "flash_bwd_dq")
     seed_zero = np.zeros(seed.shape, jax.dtypes.float0)
-    return dq, dk, dv, seed_zero
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            seed_zero)
 
 
 # ---------------------------------------------------------------------------
@@ -527,10 +634,10 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     _init_state(kj, m_scr, l_scr, acc_scr)
     # a pair on or under the diagonal whose q and kv blocks both start
     # inside the real length; a q block wholly past it keeps the zeros
-    work = ((kj * block_k < length) & (qi * block_q < length)
-            & ((qi + 1) * block_q - 1 >= kj * block_k))
+    live, masked = _pair_class(qi, kj, block_q, block_k, 0, length, True)
     _attend_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, qi=qi, kj=kj,
-                  work=work, scale=scale, causal=True, block_q=block_q,
+                  live=live & (qi * block_q < length), masked=masked,
+                  scale=scale, causal=True, block_q=block_q,
                   block_k=block_k, kv_len=length, offset=0)
 
     @pl.when(kj == pl.num_programs(2) - 1)
@@ -549,23 +656,21 @@ def _prefill_call(q, k, v, lengths, scale, block_q, block_k, heads):
     from jax.experimental.pallas import tpu as pltpu
     bh, s, d = q.shape
 
-    def last(n, block):          # the last block that starts inside n
-        return jnp.maximum(n - 1, 0) // block
-
     def q_map(b, i, j, lens):
         # a q block past the length computes nothing: keep the last live
         # one's tile instead of fetching its own
-        return b, jnp.minimum(i, last(lens[b // heads], block_q)), 0
+        last = jnp.maximum(lens[b // heads] - 1, 0) // block_q
+        return b, jnp.minimum(i, last), 0
 
     def kv_map(b, i, j, lens):
         # a skipped pair repeats the previous step's block index, so its
         # DMA is not issued: clamp to the causal diagonal and the length,
         # and a dead q block to the block the last live one ended on
         n = lens[b // heads]
-        stop = last(n, block_k)
-        diag = ((i + 1) * block_q - 1) // block_k
-        return b, jnp.where(i * block_q < n,
-                            jnp.minimum(j, jnp.minimum(diag, stop)), stop), 0
+        return b, jnp.where(
+            i * block_q < n,
+            jnp.minimum(j, _last_kv(i, block_q, block_k, 0, n, True)),
+            _last_kv(i, block_q, block_k, 0, n, False)), 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(bh, s // block_q, s // block_k),

@@ -1,6 +1,8 @@
 """OpTest-style parity tests for the fused-op family (reference test model:
 unittests/op_test.py — numpy/XLA reference forward + gradient comparison,
 dtype sweep)."""
+import importlib
+
 import numpy as np
 import pytest
 
@@ -399,6 +401,139 @@ class TestFlashRagged:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-4)
 
+
+
+def _pair_ref(q, k, v, causal, dropout_p=0.0, seed=0):
+    """``_sdpa_ref`` with the kernels' counter-based dropout mask applied to
+    P.V (``_keep_mask`` over the whole (seq, seq) grid of each head)."""
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (d ** -0.5)
+    rows = jnp.arange(sq)[:, None] + jnp.zeros((1, sk), jnp.int32)
+    cols = jnp.arange(sk)[None, :] + jnp.zeros((sq, 1), jnp.int32)
+    if causal:
+        s = jnp.where(rows + (sk - sq) >= cols, s, jnp.finfo(s.dtype).min)
+    p = jax.nn.softmax(s, axis=-1)
+    if dropout_p > 0.0:
+        bh = jnp.arange(b * h).reshape(b, h, 1, 1)
+        keep = fa._keep_mask(jnp.uint32(seed), bh, rows, cols, dropout_p)
+        p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def _dense_classes(nq, nk, bq, bk, offset, kv_len, causal):
+    """Each pair's (live, masked) from its elements, one by one."""
+    rows = np.arange(nq * bq)[:, None]
+    cols = np.arange(nk * bk)[None, :]
+    attends = (cols < kv_len) & ((rows + offset >= cols) if causal
+                                 else (rows >= 0))
+    tiles = attends.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    live = tiles.any(axis=(2, 3))
+    return live, live & ~tiles.all(axis=(2, 3))
+
+
+class TestFlashPairClasses:
+    """The training kernels' pairs by where the causal diagonal and the
+    real key length fall: skipped (no grid step), interior (no mask),
+    diagonal (the mask is built)."""
+
+    @pytest.mark.parametrize("seq_q,seq_k,causal,want", [
+        (2048, 2048, True, (6, 6, 4)),      # gpt3-125m.train, blocks of 512
+        (1024, 2048, True, (1, 5, 2)),      # bottom-right: offset 1024
+        (2000, 2000, True, (6, 6, 4)),      # padded to 2048: the length cut
+        (2000, 2000, False, (0, 12, 4)),    # ... is the last kv block's
+        (2048, 2048, False, (0, 16, 0)),
+    ], ids=["cell", "offset", "ragged", "ragged-noncausal", "noncausal"])
+    def test_counts_a_batch_head(self, seq_q, seq_k, causal, want):
+        fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+        got = fa.flash_pair_counts(seq_q, seq_k, causal)
+        assert (got["skipped"], got["interior"], got["diagonal"]) == want
+
+    @pytest.mark.parametrize("nq,nk,bq,bk,offset,kv_len,causal", [
+        (4, 4, 128, 128, 0, 512, True),
+        (4, 4, 128, 128, 0, 450, True),
+        (2, 4, 128, 128, 256, 512, True),
+        (3, 4, 128, 128, 70, 450, True),
+        (4, 4, 128, 128, 126, 512, True),     # the diagonal one col in
+        (4, 4, 128, 128, 127, 512, True),
+        (4, 2, 128, 128, -256, 256, True),      # q blocks with nothing
+        (4, 4, 64, 128, -100, 400, True),
+        (2, 4, 256, 128, 0, 300, True),
+        (4, 4, 128, 128, 0, 450, False),
+        (3, 5, 128, 128, 0, 257, False),        # kv blocks wholly padding
+    ])
+    def test_classes_and_clamps_agree_with_the_elements(
+            self, nq, nk, bq, bk, offset, kv_len, causal):
+        fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+        live, masked = _dense_classes(nq, nk, bq, bk, offset, kv_len,
+                                      causal)
+        for qi in range(nq):
+            for kj in range(nk):
+                assert fa._pair_class(qi, kj, bq, bk, offset, kv_len,
+                                      causal) == (live[qi, kj],
+                                                  masked[qi, kj]), (qi, kj)
+        # the index maps clamp to the ends of the live runs: a q block's
+        # live kv blocks are 0..last, a kv block's live q blocks first..
+        for qi in range(nq):
+            last = int(fa._last_kv(qi, bq, bk, offset, kv_len, causal))
+            assert 0 <= last < nk
+            if live[qi].any():
+                assert list(live[qi]) == [j <= last for j in range(nk)], qi
+        for kj in range(nk):
+            first = int(fa._first_q(kj, bq, bk, offset, kv_len, causal, nq))
+            assert 0 <= first < nq
+            if live[:, kj].any():
+                assert list(live[:, kj]) == [i >= first
+                                             for i in range(nq)], kj
+
+
+class TestFlashPairGrid:
+    """The training entry at blocks of 128 on 512 tokens, a 4 x 4 pair
+    grid, so skipped, interior, diagonal and length-cut pairs all run:
+    forward and all three gradients against the dense reference."""
+
+    @pytest.mark.parametrize("sq,sk,causal,dropout_p", [
+        (512, 512, True, 0.0),
+        (256, 512, True, 0.0),              # bottom-right offset
+        (450, 450, True, 0.0),              # ragged: kv_len < padded
+        (450, 450, False, 0.0),
+        (512, 512, True, 0.3),
+        (512, 256, True, 0.0),              # q blocks with nothing to see
+    ], ids=["causal", "offset", "ragged", "noncausal-ragged", "dropout",
+            "dead-q-blocks"])
+    def test_forward_and_grads_match_the_reference(
+            self, monkeypatch, sq, sk, causal, dropout_p):
+        fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_block_sizes",
+                            lambda a, b: (min(a, 128), min(b, 128)))
+        r = np.random.RandomState(sq + sk)
+        q = jnp.asarray(r.randn(1, 2, sq, 32) * 0.5, jnp.float32)
+        k = jnp.asarray(r.randn(1, 2, sk, 32) * 0.5, jnp.float32)
+        v = jnp.asarray(r.randn(1, 2, sk, 32) * 0.5, jnp.float32)
+        # rows that see no key at all (sq > sk, causal) come out zero;
+        # the reference spreads them over every key: compare the others
+        seen = max(sq - sk, 0) if causal else 0
+
+        def loss(attend):
+            return lambda q_, k_, v_: jnp.sum(
+                attend(q_, k_, v_)[:, :, seen:] ** 2)
+
+        flash = lambda q_, k_, v_: fa.flash_attention(   # noqa: E731
+            q_, k_, v_, causal=causal, dropout_p=dropout_p, seed=11)
+        ref = lambda q_, k_, v_: _pair_ref(              # noqa: E731
+            q_, k_, v_, causal, dropout_p, seed=11)
+        out = np.asarray(flash(q, k, v))
+        np.testing.assert_allclose(out[:, :, seen:],
+                                   np.asarray(ref(q, k, v))[:, :, seen:],
+                                   rtol=2e-5, atol=2e-5)
+        assert not out[:, :, :seen].any()
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b_, name in zip(gf, gr, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=3e-4, atol=3e-4,
+                                       err_msg=f"d{name}")
 
 class TestFlashKVCache:
     """Decode kernel vs full attention over the cache prefix (reference

@@ -1287,3 +1287,75 @@ def test_nemotron_h_step_compiled_for_v5e_keeps_state_and_pages_in_place(
         (5, 1) if decode else (0, 0)), p
     assert p["grouped_calls"] == 10, p                 # 5 layers x (up, down)
     print(program, "plan_bytes", p["plan_bytes"])
+
+
+# ---------------------------------------------------------------------------
+# no chip needed: the three training flash kernels compiled for a v5e ahead
+# of time, with a masked and an unmasked body each
+# ---------------------------------------------------------------------------
+_AOT_FLASH_SCRIPT = r"""
+import importlib, json, re
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    dev = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+except Exception as e:                 # no libtpu here, or its lock is held
+    print("aot-skip:", repr(e)[:300])
+    raise SystemExit(0)
+print("aot-topology-ok", flush=True)
+fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+fa._interpret = lambda: False
+sh = SingleDeviceSharding(dev)
+# gpt3-125m.train's attention; and the longest f32 shape at heads of 128,
+# whose blocks of 1024 plan the most VMEM of the two bodies
+for name, shape, dt in (("cell", (8, 12, 2048, 64), jnp.bfloat16),
+                        ("s8192_f32_d128", (1, 2, 8192, 128), jnp.float32)):
+    x = jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    loss = lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, causal=True).astype(jnp.float32) ** 2)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    row = {"name": name, "vmem_limit": fa._VMEM_LIMIT}
+    for kernel in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+        calls = [line for line in text.splitlines() if kernel in line
+                 and 'custom_call_target="tpu_custom_call"' in line]
+        row[kernel] = [int(v) for call in calls for v in re.findall(
+            r'"scoped_memory_configs":\[\{"memory_space":"1",'
+            r'"offset":"\d+","size":"(\d+)"', call)]
+    print("aot-flash", json.dumps(row), flush=True)
+print("aot-flash-ok")
+"""
+
+
+@functools.lru_cache(maxsize=1)
+def _aot_flash_kernels():
+    out = subprocess.run(
+        [sys.executable, "-c", _AOT_FLASH_SCRIPT], cwd=str(REPO),
+        env=dict(_sub_env(), JAX_PLATFORMS="cpu",
+                 JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=600)
+    if "aot-topology-ok" not in out.stdout:
+        return None, (out.stdout + out.stderr)[-600:]
+    assert out.returncode == 0 and "aot-flash-ok" in out.stdout, \
+        f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    rows = [json.loads(line.split(" ", 1)[1])
+            for line in out.stdout.splitlines()
+            if line.startswith("aot-flash ")]
+    return {r["name"]: r for r in rows}, ""
+
+
+@pytest.mark.parametrize("shape", ["cell", "s8192_f32_d128"])
+def test_flash_training_kernels_compiled_for_v5e_fit_their_vmem(shape):
+    """``flash_fwd``, ``flash_bwd_dkdv`` and ``flash_bwd_dq`` lower
+    through Mosaic at gpt3-125m.train's 8 x 12 x 2048 x 64 in bf16 and at
+    8,192 tokens in f32 with heads of 128 (blocks of 1024, where the two
+    bodies plan past the 16 MB default), each one custom call given the
+    module's VMEM limit: a body that grows past it fails the compile."""
+    kernels, why = _aot_flash_kernels()
+    if kernels is None:
+        pytest.skip(f"no v5e topology from libtpu here: {why}")
+    row = kernels[shape]
+    for kernel in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+        assert row[kernel] == [row["vmem_limit"]] == [64 << 20], row
